@@ -12,6 +12,7 @@ TPU implementation — the registry accepts all of them.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Callable, Dict, Optional, Type
 
@@ -367,10 +368,23 @@ class ToolkitBase:
         self._check_dist_path()
         self._check_sample_pipeline()
         self._check_elastic()
-        self.feature = jnp.asarray(self.datum.feature)
-        self.label = jnp.asarray(self.datum.label.astype(np.int32))
-        self.mask = jnp.asarray(self.datum.mask)
         self.build_model()
+
+    # Single-device copies of the datum, uploaded on first use. The
+    # full-batch and sampled trainers (and the serve/stream stacks on top of
+    # them) read these; the dist trainers place their own sharded arrays and
+    # never do, so no whole [V, f] copy lands on device 0 beside the shards.
+    @functools.cached_property
+    def feature(self) -> jax.Array:
+        return jnp.asarray(self.datum.feature)
+
+    @functools.cached_property
+    def label(self) -> jax.Array:
+        return jnp.asarray(self.datum.label.astype(np.int32))
+
+    @functools.cached_property
+    def mask(self) -> jax.Array:
+        return jnp.asarray(self.datum.mask)
 
     @classmethod
     def from_arrays(
@@ -822,8 +836,10 @@ class ToolkitBase:
             )
             self._run_span = None
         from neutronstarlite_tpu.obs import collectors
+        from neutronstarlite_tpu.utils.platform import device_facts
 
         fields: dict = {
+            "device": device_facts(),
             "epochs": len(self.epoch_times),
             "epoch_time": collectors.steady_state_stats(self.epoch_times),
             "avg_epoch_s": self.avg_epoch_time(),

@@ -407,7 +407,8 @@ class FullBatchTrainer(ToolkitBase):
         cfg = self.cfg
         key = jax.random.PRNGKey(self.seed + 1)
         log.info(
-            "GNNmini::Engine[TPU.%s] running [%d] Epochs",
+            "GNNmini::Engine[%s.%s] running [%d] Epochs",
+            jax.default_backend(),
             type(self).__name__,
             cfg.epochs,
         )

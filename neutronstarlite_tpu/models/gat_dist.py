@@ -465,17 +465,17 @@ class DistGATTrainer(ToolkitBase):
         key = jax.random.PRNGKey(self.seed + 1)
         if self.mg is not None:
             log.info(
-                "GNNmini::Engine[Dist.TPU.GATimpl] %d partitions (Mb=%d El=%d), [%d] Epochs",
-                self.mg.partitions,
+                "GNNmini::Engine[Dist.%s.GATimpl] %d partitions (Mb=%d El=%d), [%d] Epochs",
+                jax.default_backend(), self.mg.partitions,
                 self.mg.mb,
                 self.mg.el,
                 cfg.epochs,
             )
         else:  # KERNEL:fused_edge — the ring fused tables replace the mirrors
             log.info(
-                "GNNmini::Engine[Dist.TPU.GATimpl] %d partitions "
+                "GNNmini::Engine[Dist.%s.GATimpl] %d partitions "
                 "(fused_edge ring, vp=%d), [%d] Epochs",
-                self.dist.partitions, self.dist.vp, cfg.epochs,
+                jax.default_backend(), self.dist.partitions, self.dist.vp, cfg.epochs,
             )
         start_epoch = self.ckpt_begin()
         loss = None

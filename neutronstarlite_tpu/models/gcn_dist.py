@@ -408,6 +408,19 @@ class DistGCNTrainer(ToolkitBase):
                 stats["max_block"], stats["mean_block"],
             )
             if layer_kind == "ell":
+                if (
+                    getattr(cfg, "pallas_kernel", False)
+                    and os.environ.get("NTS_PALLAS_RESIDENT", "0") == "1"
+                    and jax.default_backend() == "tpu"
+                ):
+                    raise ValueError(
+                        "PALLAS:1 with NTS_PALLAS_RESIDENT=1 selects the "
+                        "resident-table executor, which cannot lower to "
+                        "Mosaic (ops/pallas_kernels.py) and runs in "
+                        "interpret mode only; on a TPU the request would "
+                        "have to be swapped for XLA — unset "
+                        "NTS_PALLAS_RESIDENT to run the bsp kernel"
+                    )
                 if getattr(cfg, "pallas_kernel", False) and os.environ.get(
                     "NTS_PALLAS_RESIDENT", "0"
                 ) != "1":
@@ -469,16 +482,9 @@ class DistGCNTrainer(ToolkitBase):
 
                     # NTS_PALLAS_RESIDENT=1 + PALLAS:1 keeps the interpret
                     # -only per-shard resident executor for CPU-mesh
-                    # experiments (it cannot lower to Mosaic; on TPU it
-                    # downgrades to XLA with a warning)
+                    # experiments (it cannot lower to Mosaic; refused on
+                    # TPU above)
                     kern = "pallas" if cfg.pallas_kernel else "xla"
-                    if kern == "pallas" and jax.default_backend() == "tpu":
-                        log.warning(
-                            "NTS_PALLAS_RESIDENT dist executor is "
-                            "interpret-only (Mosaic gather restriction); "
-                            "running the XLA per-shard executor on TPU"
-                        )
-                        kern = "xla"
                     pair = DistEllPair.build(self.dist, kernel=kern)
                     est = pair.padding_stats(stats["real_edges"])
                     self.blocks = pair.shard(self.mesh)
@@ -1006,8 +1012,8 @@ class DistGCNTrainer(ToolkitBase):
         cfg = self.cfg
         key = jax.random.PRNGKey(self.seed + 1)
         log.info(
-            "GNNmini::Engine[Dist.TPU.GCNimpl] %d partitions, [%d] Epochs",
-            self.dist.partitions,
+            "GNNmini::Engine[Dist.%s.GCNimpl] %d partitions, [%d] Epochs",
+            jax.default_backend(), self.dist.partitions,
             cfg.epochs,
         )
         start_epoch = self.ckpt_begin()

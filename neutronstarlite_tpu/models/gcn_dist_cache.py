@@ -310,9 +310,9 @@ class DistGCNCacheTrainer(ToolkitBase):
         key = jax.random.PRNGKey(self.seed + 1)
         use_hist = self._use_hist
         log.info(
-            "GNNmini::Engine[Dist.TPU.GCNimpl.cached] %d partitions "
+            "GNNmini::Engine[Dist.%s.GCNimpl.cached] %d partitions "
             "(mc=%d mf=%d el=%d), refresh=%d, [%d] Epochs",
-            self.cmg.partitions, self.cmg.mc, self.cmg.mf, self.cmg.el,
+            jax.default_backend(), self.cmg.partitions, self.cmg.mc, self.cmg.mf, self.cmg.el,
             self.cache_refresh, cfg.epochs,
         )
         start_epoch = self.ckpt_begin()

@@ -69,7 +69,7 @@ class GCNSampleTrainer(ToolkitBase):
     def _finalize_datum(self) -> None:
         # the training batch stream (sample/parallel.py) forks its
         # persistent worker pool — that must happen BEFORE the first JAX
-        # backend touch (the jnp.asarray datum upload in the base method):
+        # backend touch (build_model, called by the base method):
         # forking after PJRT's runtime threads exist risks a deadlocked
         # child (module docstring's fork-safety note)
         cfg = self.cfg
@@ -414,9 +414,9 @@ class GCNSampleTrainer(ToolkitBase):
         cfg = self.cfg
         key = jax.random.PRNGKey(self.seed + 1)
         log.info(
-            "GNNmini::Engine[TPU.GCNSampleimpl] B=%d fanout=%s [%d] Epochs "
+            "GNNmini::Engine[%s.GCNSampleimpl] B=%d fanout=%s [%d] Epochs "
             "(%d sample workers, sampling %s)",
-            cfg.batch_size, self.fanouts, cfg.epochs, self.sample_workers,
+            jax.default_backend(), cfg.batch_size, self.fanouts, cfg.epochs, self.sample_workers,
             self.sample_mode,
         )
         loss = None

@@ -1,15 +1,23 @@
-"""ctypes bindings for the native preprocessing runtime (libnts_native.so).
+"""ctypes bindings for the native preprocessing runtime.
 
-Builds the shared library on first use if the toolchain is available
-(one g++ invocation, cached beside this file); everything degrades to the
-NumPy implementations when the library can't be built (NTS_NO_NATIVE=1
-forces the fallback).
+Builds the shared library on first use if the toolchain is available (one
+g++ invocation); everything degrades to the NumPy implementations when the
+library can't be built (NTS_NO_NATIVE=1 forces the fallback).
+
+The library is compiled with ``-march=native``, and tools copy checkouts
+between machines, so the built file is named for what it was built from
+and where: ``libnts_native.<key>.so`` with the key a digest of the source,
+the compile command and this host's identity. A library built elsewhere,
+or from another source, has another name and is never loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 from typing import Optional, Tuple
 
@@ -20,24 +28,53 @@ from neutronstarlite_tpu.utils.logging import get_logger
 log = get_logger("native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libnts_native.so")
+_SRC = os.path.join(_DIR, "graph_native.cpp")
+_FLAGS = ["-O3", "-march=native", "-fPIC", "-shared", "-fopenmp", "-std=c++17"]
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
-    src = os.path.join(_DIR, "graph_native.cpp")
-    cmd = [
-        os.environ.get("CXX", "g++"),
-        "-O3", "-march=native", "-fPIC", "-shared", "-fopenmp", "-std=c++17",
-        "-o", _SO, src,
-    ]
+def _host_identity() -> str:
+    """What tells this machine from any other the tree may be copied to:
+    the kernel's boot id (new on every boot of every machine; a sealed
+    machine made from this one's disk shares its hostname and machine-id),
+    with the hostname as the fallback where /proc is not readable."""
+    try:
+        with open("/proc/sys/kernel/random/boot_id") as fh:
+            boot_id = fh.read().strip()
+    except OSError:
+        boot_id = ""
+    return f"{platform.machine()}/{platform.node()}/{boot_id}"
+
+
+def _so_path() -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as fh:
+        h.update(fh.read())
+    h.update(" ".join([os.environ.get("CXX", "g++")] + _FLAGS).encode())
+    h.update(_host_identity().encode())
+    return os.path.join(_DIR, f"libnts_native.{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
+    # build under a private name and rename: concurrent first users (bench
+    # workers, replica children) never load a half-written file
+    tmp = f"{so}.tmp.{os.getpid()}"
+    cmd = [os.environ.get("CXX", "g++"), *_FLAGS, "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-        return True
-    except Exception as e:  # toolchain missing / compile error -> fallback
+        os.replace(tmp, so)
+    except (OSError, subprocess.SubprocessError) as e:
+        # toolchain missing / compile error -> fallback
         log.warning("native build failed (%s); using NumPy fallback", e)
         return False
+    for old in glob.glob(os.path.join(_DIR, "libnts_native*.so")):
+        if old != so:  # another host's or another source's build
+            try:
+                os.remove(old)
+            except OSError:
+                pass
+    return True
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -47,18 +84,13 @@ def get_lib() -> Optional[ctypes.CDLL]:
     _tried = True
     if os.environ.get("NTS_NO_NATIVE", "0") == "1":
         return None
-    # rebuild when missing or staler than its source (-march=native output
-    # is machine-specific, so the .so is never shipped, only built here)
-    src = os.path.join(_DIR, "graph_native.cpp")
-    stale = not os.path.exists(_SO) or (
-        os.path.exists(src) and os.path.getmtime(src) > os.path.getmtime(_SO)
-    )
-    if stale and not _build():
+    so = _so_path()
+    if not os.path.exists(so) and not _build(so):
         return None
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
     except OSError as e:
-        log.warning("failed to load %s: %s", _SO, e)
+        log.warning("failed to load %s: %s", so, e)
         return None
 
     i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
@@ -93,6 +125,9 @@ def get_lib() -> Optional[ctypes.CDLL]:
     ]
     lib.nts_dedup_remap.restype = ctypes.c_int64
     lib.nts_native_version.restype = ctypes.c_int
+    # libgomp's, reached through the library that links it
+    lib.omp_set_num_threads.argtypes = [ctypes.c_int]
+    lib.omp_set_num_threads.restype = None
     _lib = lib
     log.info("native runtime loaded (v%d)", lib.nts_native_version())
     return _lib
@@ -100,6 +135,23 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def use_one_thread() -> None:
+    """Run this process's native calls on the calling thread only. For
+    forked sampler workers: GNU OpenMP's thread pool does not survive a
+    fork, so in the child of a parent that has run a parallel region (the
+    graph build) the next multi-threaded region never returns."""
+    lib = get_lib()
+    if lib is not None:
+        lib.omp_set_num_threads(1)
+
+
+def builder_name() -> str:
+    """Which host graph builder this process uses: ``native vN`` or
+    ``NumPy`` (the fallback after a failed build or NTS_NO_NATIVE=1)."""
+    lib = get_lib()
+    return f"native v{lib.nts_native_version()}" if lib is not None else "NumPy"
 
 
 def build_adjacency(

@@ -38,7 +38,8 @@ armed ledger) — see :func:`cost_enabled` for why the auto gate exists.
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, Optional
+import re
+from typing import Any, Dict, List, Optional
 
 from neutronstarlite_tpu.utils.logging import get_logger
 
@@ -136,6 +137,17 @@ def memory_from_compiled(compiled) -> Optional[Dict[str, Optional[int]]]:
     return out
 
 
+_CUSTOM_CALL_RE = re.compile(r"custom_call\s*@([\w.$-]+)")
+
+
+def custom_call_targets(lowered_text: str) -> List[str]:
+    """The custom-call targets a lowered (StableHLO) module holds, sorted
+    and unique. A Pallas kernel lowered for the chip is a
+    ``tpu_custom_call`` (Mosaic); the same kernel under ``interpret=True``
+    leaves none — which is how a run shows its kernel was not emulated."""
+    return sorted(set(_CUSTOM_CALL_RE.findall(lowered_text)))
+
+
 def capture_program_cost(
     metrics,
     label: str,
@@ -172,6 +184,7 @@ def capture_program_cost(
     try:
         if compiled is None and jitted is not None:
             lowered = jitted.lower(*args)
+            fields["custom_calls"] = custom_call_targets(lowered.as_text())
             if memory_capture_enabled():
                 compiled = lowered.compile()
             else:

@@ -15,9 +15,7 @@ program costs), keyed by what makes two rows comparable:
                   runtime = different baseline
 
 Row kinds: ``run`` (a trainer finished — models/base.finalize_metrics),
-``suite`` (one tier-1 suite execution — scripts/ci_tier1.sh), ``probe``
-(one bench.py backend-probe attempt, INCLUDING timeouts — the probe
-history that was invisible since BENCH_r05 becomes queryable), ``serve``
+``suite`` (one tier-1 suite execution — scripts/ci_tier1.sh), ``serve``
 (one tools/serve_bench execution: tail latency + shed rate keyed by cfg
 fingerprint PLUS the load shape — mode/replicas/continuous-batching —
 so the sentinel trend-gates serve p99 the way it gates epoch time
@@ -151,7 +149,7 @@ def append_row(row: Dict[str, Any],
 
     The existing rows are carried over as RAW LINES (no per-append JSON
     re-parse of up to NTS_LEDGER_KEEP multi-KB rows — this runs on every
-    finalize and every probe attempt); only the new row is serialized.
+    finalize); only the new row is serialized.
     Trimming counts lines, which over-counts by at most the torn lines
     readers already skip."""
     d = directory or ledger_dir()
@@ -206,7 +204,6 @@ def _hist_quantiles(summary: Dict[str, Any]) -> Dict[str, Any]:
 def run_row(
     summary: Dict[str, Any],
     graph_digest: Optional[str],
-    probes: Optional[List[Dict[str, Any]]] = None,
 ) -> Dict[str, Any]:
     """One ``kind=run`` row from a finalized run_summary record. The
     scalars mirror what ``--diff`` gates on (plus the new program
@@ -255,7 +252,6 @@ def run_row(
         "final_loss": (summary.get("result") or {}).get("loss"),
         "hist_quantiles": _hist_quantiles(summary),
         "program_costs": summary.get("program_costs") or [],
-        "probes": probes or [],
     }
 
 
@@ -350,27 +346,4 @@ def fleet_row(
         "targets_lost": int(targets_lost),
         "polls": int(polls),
         "hist_quantiles": hist_quantiles or {},
-    }
-
-
-def probe_row(attempt: int, outcome: str, seconds: float,
-              platform: Optional[str], scale: float = 1.0,
-              error: Optional[str] = None) -> Dict[str, Any]:
-    """One ``kind=probe`` row per bench.py backend-probe attempt —
-    appended EVEN ON TIMEOUT, so the probe-failure history since r05 is
-    finally queryable from one file. The backend key is the probe's OWN
-    answer (or "unprobed"): bench's supervisor process deliberately never
-    initializes the accelerator backend, so the in-process fingerprint
-    the run/suite rows use is off-limits here."""
-    return {
-        "kind": "probe",
-        "ts": time.time(),
-        "cfg": f"bench_scale_{scale:g}",
-        "graph_digest": "probe",
-        "backend": platform or "unprobed",
-        "attempt": int(attempt),
-        "outcome": str(outcome),
-        "seconds": float(seconds),
-        "platform": platform,
-        "error": (str(error)[:300] if error else None),
     }

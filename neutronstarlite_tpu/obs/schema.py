@@ -11,8 +11,8 @@ Envelope (all events):
                    fault | recovery | heartbeat | rank_loss | replan |
                    serve_request | batch_flush | shed | serve_summary |
                    graph_delta | tune_trial | tune_decision | span |
-                   stream_rotated | hist | slo_status | backend_probe |
-                   program_cost | model_drift | tensor_stats |
+                   stream_rotated | hist | slo_status | program_cost |
+                   model_drift | tensor_stats |
                    nonfinite_provenance | telemetry | target_loss |
                    straggler | rollout | delta_commit | finetune_round |
                    epoch_scan
@@ -224,14 +224,6 @@ slo_status (obs/slo.py): one objective's burn-rate verdict — emitted on
   burn_rate: number | null (long window), burn_rate_short: number | null,
   window_count: int | absent (samples in the window)
 
-backend_probe (bench.py): one accelerator-backend probe attempt — the
-  subprocess PJRT-init check bench runs before measuring; a timed-out
-  probe (the stale-anchor cause) now leaves a typed trace
-  attempt: int > 0, outcome: str (ok | timeout | error, open set),
-  seconds: number >= 0 (attempt wall time),
-  platform: str | null (the answering backend; null on failure),
-  devices / error / init_s: open context fields
-
 program_cost (obs/cost.py): one compiled/lowered XLA program's own cost
   numbers, captured once at build time per executable (train steps, ring
   bodies, serve AOT buckets, tuner micro-trials) and keyed by a stable
@@ -247,7 +239,10 @@ program_cost (obs/cost.py): one compiled/lowered XLA program's own cost
   alias_bytes, generated_code_bytes, peak_bytes} nullable ints — the
   Compiled.memory_analysis() buffer allocation; null on the
   lowering-only capture path and on backends without it),
-  platform: str | null | absent, error: str | absent
+  platform: str | null | absent, error: str | absent,
+  custom_calls: array of str | absent (lowering-path captures: the
+  custom-call targets in the lowered module, e.g. tpu_custom_call for a
+  Mosaic kernel — absent on compiled= captures)
 
 tensor_stats (obs/numerics.py): one tensor group's numerics snapshot —
   the stats-fused step output (params/grads/activations per layer, the
@@ -360,7 +355,10 @@ run_summary:
               (nullable when fewer than 2 epochs ran),
   phases: object  name -> {total_s, count}  (PhaseTimers snapshot),
   memory: object  with "available" bool; explicit nulls where the backend
-          exposes no memory_stats (CPU)
+          exposes no memory_stats (CPU),
+  device: object | absent  {platform: str, device_kind: str, count: int > 0}
+          as JAX reports the device the run executed on (absent on
+          summaries synthesized from a stream that died before its own)
 """
 
 from __future__ import annotations
@@ -393,7 +391,6 @@ KNOWN_KINDS = (
     "stream_rotated",
     "hist",
     "slo_status",
-    "backend_probe",
     "program_cost",
     "model_drift",
     "tensor_stats",
@@ -471,6 +468,17 @@ def validate_event(obj: Any) -> None:
         ):
             _fail("run_summary.memory must be an object with an "
                   "'available' bool")
+        dev = obj.get("device")
+        if dev is not None:
+            if not isinstance(dev, dict):
+                _fail("run_summary.device must be an object")
+            for key in ("platform", "device_kind"):
+                if not isinstance(dev.get(key), str) or not dev[key]:
+                    _fail(f"run_summary.device.{key} must be a non-empty "
+                          "string")
+            if (not isinstance(dev.get("count"), int)
+                    or isinstance(dev["count"], bool) or dev["count"] <= 0):
+                _fail("run_summary.device.count must be a positive int")
     elif kind == "run_start":
         if not isinstance(obj.get("algorithm"), str):
             _fail("run_start.algorithm must be a string")
@@ -757,20 +765,6 @@ def validate_event(obj: Any) -> None:
         if "window_count" in obj and obj["window_count"] is not None \
                 and not isinstance(obj["window_count"], int):
             _fail("slo_status.window_count must be an int when present")
-    elif kind == "backend_probe":
-        a = obj.get("attempt")
-        if not isinstance(a, int) or isinstance(a, bool) or a <= 0:
-            _fail(f"backend_probe.attempt must be a positive int, got {a!r}")
-        if not isinstance(obj.get("outcome"), str) or not obj["outcome"]:
-            _fail("backend_probe.outcome must be a non-empty string")
-        _require_number(obj, "seconds")
-        if obj["seconds"] < 0:
-            _fail(f"backend_probe.seconds must be >= 0, got "
-                  f"{obj['seconds']!r}")
-        p = obj.get("platform")
-        if p is not None and not isinstance(p, str):
-            _fail(f"backend_probe.platform must be a string or null, "
-                  f"got {p!r}")
     elif kind == "program_cost":
         if not isinstance(obj.get("label"), str) or not obj["label"]:
             _fail("program_cost.label must be a non-empty string")
@@ -792,6 +786,13 @@ def validate_event(obj: Any) -> None:
                 ):
                     _fail(f"program_cost.memory.{k} must be an int or "
                           f"null, got {v!r}")
+        calls = obj.get("custom_calls")
+        if calls is not None and not (
+            isinstance(calls, list)
+            and all(isinstance(c, str) and c for c in calls)
+        ):
+            _fail(f"program_cost.custom_calls must be an array of "
+                  f"non-empty strings, got {calls!r}")
     elif kind == "tensor_stats":
         if not isinstance(obj.get("name"), str) or not obj["name"]:
             _fail("tensor_stats.name must be a non-empty string")

@@ -68,16 +68,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from neutronstarlite_tpu.graph.storage import CSCGraph
 from neutronstarlite_tpu.utils.logging import get_logger
-
-try:  # pallas TPU backend may be absent on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
 
 log = get_logger("bsp_ell")
 
@@ -535,6 +529,13 @@ def _bsp_kernel(key_ref, nbr_ref, wgt_ref, ldst_ref, x_ref, o_ref, *, dt, vt, t_
     # f32 (preferred_element_type) in-block and across blocks. The build
     # costs O(K * R * vt) VPU compares per block — the lever that makes
     # SMALLER src tiles attractive (the plan's bsp_vt_* sweep).
+    # The MXU rounds f32 operands to bf16 at the default precision (on the
+    # v5e: 3.4e-3 relative against the f64 golden, tests/test_tpu.py, where
+    # every XLA aggregation path is f32-exact). An f32 slab asks for f32
+    # products; the bf16 production slab keeps the default, full-rate dot.
+    precision = (
+        lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    )
     col = lax.broadcasted_iota(jnp.int32, (R, vt), 1)
     w = jnp.zeros((R, vt), jnp.float32)
     for k in range(K):  # K is a small static constant: full unroll
@@ -544,7 +545,7 @@ def _bsp_kernel(key_ref, nbr_ref, wgt_ref, ldst_ref, x_ref, o_ref, *, dt, vt, t_
         w = w + jnp.where(col == nb[:, None], wb[:, None], 0.0)
     acc = lax.dot_general(
         w.astype(x.dtype), x, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=precision, preferred_element_type=jnp.float32,
     )  # [R, f]
     # ldst rides in [8-row, R] VMEM blocks (Mosaic tiling needs sublane
     # multiples of 8); this block's row is a dynamic sublane select
@@ -554,7 +555,7 @@ def _bsp_kernel(key_ref, nbr_ref, wgt_ref, ldst_ref, x_ref, o_ref, *, dt, vt, t_
     ).astype(jnp.float32)
     o_ref[:] += lax.dot_general(
         onehot, acc, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=precision, preferred_element_type=jnp.float32,
     )
 
 
@@ -564,8 +565,6 @@ def _bsp_kernel(key_ref, nbr_ref, wgt_ref, ldst_ref, x_ref, o_ref, *, dt, vt, t_
 def _bsp_call(blk_key, nbr, wgt, ldst, xp, *, dt, vt, t_dst, t_src, interpret):
     B, K, R = nbr.shape
     f = xp.shape[1]
-    if not _HAS_PLTPU:  # pragma: no cover - exercised only on minimal builds
-        raise RuntimeError("pallas TPU backend unavailable for bsp_ell")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # ONE packed (dst_tile, src_tile) key drives both index maps —
         # SMEM holds ~1 MB of scalars total (see BspEll.blk_key)

@@ -56,13 +56,6 @@ from neutronstarlite_tpu.ops.ell import (
     ell_tables_aggregate,
 )
 
-try:  # pallas TPU backend may be absent on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
-
 DEFAULT_ROW_TILE = 512
 _K_CHUNK = 8  # static inner unroll; K beyond this iterates a fori_loop
 # bucket levels wider than this stay on the XLA path (row-vectorized kernel

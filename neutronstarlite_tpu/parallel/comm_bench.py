@@ -439,9 +439,9 @@ def main(argv=None) -> int:
     )
     args = ap.parse_args(argv)
 
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
+    from neutronstarlite_tpu.utils.platform import start_runtime
 
-    honor_platform_env()
+    start_runtime()
     if args.mesh:
         from neutronstarlite_tpu.parallel.partitioner import MeshSpec
 

@@ -133,9 +133,9 @@ class DistBsp:
                 vp, offs, nbr_g, w, dt=dt, vt=vt, k_slots=k_slots,
                 r_rows=r_rows, src_num=P * vp,
                 # tables stay numpy: both stacked layouts below re-lay or
-                # pad them host-side, then upload ONCE via jnp.stack —
-                # jnp tables here would device-round-trip gigabytes at
-                # exactly the scale that segments (r5 review finding)
+                # pad them host-side and stack them on the host; shard()
+                # sends each device its own slice (a jnp stack would land
+                # every shard's tables whole on device 0 first)
                 keep_host=True,
             )
             for offs, nbr_g, w, _deg in per_dev
@@ -155,32 +155,32 @@ class DistBsp:
                     return t.nbr, t.wgt, t.ldst, t.blk_key
                 k, r = t.nbr.shape[1], t.nbr.shape[2]
                 return (
-                    jnp.concatenate(
-                        [t.nbr, jnp.zeros((pad_b, k, r), jnp.int32)]
+                    np.concatenate(
+                        [t.nbr, np.zeros((pad_b, k, r), np.int32)]
                     ),
-                    jnp.concatenate(
-                        [t.wgt, jnp.zeros((pad_b, k, r), jnp.float32)]
+                    np.concatenate(
+                        [t.wgt, np.zeros((pad_b, k, r), np.float32)]
                     ),
-                    jnp.concatenate(
-                        [t.ldst, jnp.zeros((pad_b, r), jnp.int32)]
+                    np.concatenate(
+                        [t.ldst, np.zeros((pad_b, r), np.int32)]
                     ),
                     # the device's LAST key: extends that tile's
                     # consecutive run (the kernel's ordering invariant —
                     # tables are data-then-filler grouped, NOT tile-
                     # sorted) and the pad blocks never re-zero a tile
                     # (weight-0 accumulate)
-                    jnp.concatenate(
-                        [t.blk_key, jnp.full(pad_b, t.blk_key[-1], jnp.int32)]
+                    np.concatenate(
+                        [t.blk_key, np.full(pad_b, t.blk_key[-1], np.int32)]
                     ),
                 )
 
             padded = [pad(t) for t in tables]
             return DistBsp(
-                nbr=jnp.stack([p[0] for p in padded]),
-                wgt=jnp.stack([p[1] for p in padded]),
-                ldst=jnp.stack([p[2] for p in padded]),
-                blk_key=jnp.stack([p[3] for p in padded]),
-                first_tile=jnp.zeros((P, 1), jnp.int32),
+                nbr=np.stack([p[0] for p in padded]),
+                wgt=np.stack([p[1] for p in padded]),
+                ldst=np.stack([p[2] for p in padded]),
+                blk_key=np.stack([p[3] for p in padded]),
+                first_tile=np.zeros((P, 1), np.int32),
                 partitions=P, vp=vp, dt=int(dt), vt=int(vt),
                 n_seg=1, b_seg=0, t_seg=0,
             )
@@ -251,11 +251,11 @@ class DistBsp:
             total_blocks / max(real_blocks, 1), real_blocks,
         )
         return DistBsp(
-            nbr=jnp.stack([r[0] for r in relaid]),
-            wgt=jnp.stack([r[1] for r in relaid]),
-            ldst=jnp.stack([r[2] for r in relaid]),
-            blk_key=jnp.stack([r[3] for r in relaid]),
-            first_tile=jnp.stack([jnp.asarray(r[4]) for r in relaid]),
+            nbr=np.stack([r[0] for r in relaid]),
+            wgt=np.stack([r[1] for r in relaid]),
+            ldst=np.stack([r[2] for r in relaid]),
+            blk_key=np.stack([r[3] for r in relaid]),
+            first_tile=np.stack([r[4] for r in relaid]),
             partitions=P, vp=vp, dt=int(dt), vt=int(vt),
             n_seg=int(S_max), b_seg=int(b_seg_u), t_seg=int(t_seg_u),
         )

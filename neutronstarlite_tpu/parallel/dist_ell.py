@@ -183,10 +183,12 @@ class DistEll:
                 ids = assign[p][li]
                 inv[p, ids] = (starts[li] + np.arange(len(ids))).astype(np.int32)
 
+        # host arrays: shard() sends each device its own slice (a jnp array
+        # here would land every shard's tables whole on device 0 first)
         return DistEll(
-            nbr=[jnp.asarray(n) for n in nbrs],
-            wgt=[jnp.asarray(w) for w in wgts],
-            inv_perm=jnp.asarray(inv),
+            nbr=nbrs,
+            wgt=wgts,
+            inv_perm=inv,
             partitions=P,
             vp=vp,
             slot_chunk=int(slot_chunk),

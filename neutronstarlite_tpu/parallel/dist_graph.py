@@ -30,7 +30,6 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from neutronstarlite_tpu.graph.storage import CSCGraph, partition_offsets
@@ -238,7 +237,7 @@ class DistGraph(PaddedVertexSpace):
 
         sh = NamedSharding(mesh, PS("p", None, None))
         return (
-            jax.device_put(jnp.asarray(self.block_src), sh),
-            jax.device_put(jnp.asarray(self.block_dst), sh),
-            jax.device_put(jnp.asarray(self.block_weight), sh),
+            jax.device_put(self.block_src, sh),
+            jax.device_put(self.block_dst, sh),
+            jax.device_put(self.block_weight, sh),
         )

@@ -161,9 +161,10 @@ class RingBlockedEll:
                     nbr[p, :, : n.shape[1]] = n
                     wgt[p, :, : w.shape[1]] = w
                     dstr[p, :, : d.shape[1]] = d
-                nbrs.append(jnp.asarray(nbr))
-                wgts.append(jnp.asarray(wgt))
-                dsts.append(jnp.asarray(dstr))
+                # host arrays: shard() sends each device its own slice
+                nbrs.append(nbr)
+                wgts.append(wgt)
+                dsts.append(dstr)
             step_nbr.append(nbrs)
             step_wgt.append(wgts)
             step_dst.append(dsts)

@@ -307,7 +307,7 @@ class CachedMirrorGraph(MirrorGraph):
 
         def put(a):
             spec = PS(PARTITION_AXIS, *([None] * (a.ndim - 1)))
-            return jax.device_put(jnp.asarray(a), NamedSharding(mesh, spec))
+            return jax.device_put(a, NamedSharding(mesh, spec))
 
         return put(self.fetch_ids), put(self.cached_ids)
 

@@ -33,36 +33,13 @@ log = get_logger("mesh")
 _dist_initialized = False
 
 
-def _resolve_shard_map():
-    """``jax.shard_map`` (the stable name, jax >= 0.6) or the
-    ``jax.experimental.shard_map`` fallback older runtimes ship — with the
-    ``check_vma``/``check_rep`` kwarg rename bridged, so every dist module
-    can call one function regardless of the installed jax."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as legacy
-
-    def compat(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return legacy(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-    return compat
-
-
-shard_map = _resolve_shard_map()
+shard_map = jax.shard_map
 
 
 def pcast_varying(x, axes):
-    """``lax.pcast(x, axes, to="varying")`` where the runtime has it
-    (jax >= 0.7 VMA typing); identity on older runtimes, whose legacy
-    shard_map has no varying-manual-axes type system to satisfy."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axes, to="varying")
+    """``lax.pcast(x, axes, to="varying")``: mark a shard_map value as
+    varying over ``axes`` for the VMA type system."""
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def maybe_initialize_distributed() -> None:
@@ -143,7 +120,7 @@ def validate_mesh_request(pv: int, pf: int) -> None:
     ``Pv x Pf`` that exceeds the visible device count dies HERE with a
     one-line error naming both numbers, instead of a deep shard_map trace
     later. Sim meshes honor ``jax_num_cpu_devices`` /
-    ``--xla_force_host_platform_device_count`` (utils/platform.py): the
+    ``--xla_force_host_platform_device_count``: the
     count checked is whatever ``jax.devices()`` reports on this rig."""
     if pv < 1 or pf < 1:
         raise ValueError(

@@ -32,7 +32,6 @@ import dataclasses
 from typing import Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from neutronstarlite_tpu.graph.storage import CSCGraph, partition_offsets
@@ -48,7 +47,7 @@ def shard_tables(mesh, arrays) -> Tuple[jax.Array, ...]:
 
     def put(a):
         spec = PS(PARTITION_AXIS, *([None] * (np.ndim(a) - 1)))
-        return jax.device_put(jnp.asarray(a), NamedSharding(mesh, spec))
+        return jax.device_put(np.asarray(a), NamedSharding(mesh, spec))
 
     return tuple(put(a) for a in arrays)
 

@@ -58,9 +58,12 @@ def apply_launcher_overrides(cfg: InputInfo) -> InputInfo:
 
 def main(argv=None) -> int:
     from neutronstarlite_tpu.parallel.mesh import maybe_initialize_distributed
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
+    from neutronstarlite_tpu.utils.platform import (
+        configure_compile_cache,
+        start_runtime,
+    )
 
-    honor_platform_env()
+    configure_compile_cache()
     maybe_initialize_distributed()
     argv = argv if argv is not None else sys.argv[1:]
     if len(argv) < 1:
@@ -74,6 +77,9 @@ def main(argv=None) -> int:
     toolkit = cls(cfg, base_dir=os.path.dirname(os.path.abspath(cfg_path)))
     toolkit.init_graph()
     toolkit.init_nn()
+    # the sampled trainer has forked its sampler pool by now (it must do so
+    # before the first backend touch), so the device can be named
+    start_runtime()
     # the supervised wrapper (resilience/): per-epoch health guards +
     # rollback to the last good checkpoint with bounded retries; exits
     # non-zero only when NTS_MAX_RESTARTS is exhausted

@@ -35,9 +35,11 @@ from typing import List, Sequence
 
 import numpy as np
 
+from neutronstarlite_tpu import native as native_rt
 from neutronstarlite_tpu.graph.storage import CSCGraph
 from neutronstarlite_tpu.sample.sampler import SampledBatch, Sampler
 from neutronstarlite_tpu.utils.logging import get_logger
+from neutronstarlite_tpu.utils.platform import backend_is_live
 
 log = get_logger("sample_parallel")
 
@@ -76,18 +78,6 @@ def _serve(make_one, in_q, out_q):
             out_q.put((epoch, i, _WorkerError(
                 f"{e}\n{traceback.format_exc(limit=5)}"
             )))
-
-
-def _jax_backend_live() -> bool:
-    """True when a JAX backend has already been initialized in this
-    process (fork-safety gate; checked WITHOUT triggering an init)."""
-    try:
-        import sys
-
-        xb = sys.modules.get("jax._src.xla_bridge")
-        return bool(xb is not None and getattr(xb, "_backends", None))
-    except Exception:  # pragma: no cover - conservative default
-        return True
 
 
 def default_workers() -> int:
@@ -160,7 +150,7 @@ class ParallelEpochSampler:
         if (
             self.workers > 1
             and self.ctx_method == "fork"
-            and _jax_backend_live()
+            and backend_is_live()
         ):
             # the invariant "fork before the first JAX backend touch" only
             # holds for the first trainer in a pristine process; forking
@@ -192,6 +182,10 @@ class ParallelEpochSampler:
             make_one = self._make_one  # graph shared copy-on-write
 
             def worker():
+                # workers already shard the batches across processes; one
+                # native thread each also keeps the forked child off the
+                # parent's OpenMP pool, which a fork does not carry over
+                native_rt.use_one_thread()
                 _serve(make_one, in_q, out_q)
 
             targets = [dict(target=worker) for _ in range(self.workers)]

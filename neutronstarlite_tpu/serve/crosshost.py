@@ -106,6 +106,7 @@ from neutronstarlite_tpu.serve.fleet import (
     choose_replica,
 )
 from neutronstarlite_tpu.utils.logging import get_logger
+from neutronstarlite_tpu.utils.platform import backend_is_live, tpu_chip_nodes
 
 log = get_logger("serve")
 
@@ -191,9 +192,12 @@ def _write_port_file(path: str, payload: Dict[str, Any]) -> None:
 def child_main(argv=None) -> int:
     """The long-running replica process: serve until SIGTERM/SIGINT."""
     from neutronstarlite_tpu.utils.config import InputInfo
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
+    from neutronstarlite_tpu.utils.platform import (
+        configure_compile_cache,
+        start_runtime,
+    )
 
-    honor_platform_env()
+    configure_compile_cache()
     ap = argparse.ArgumentParser(
         description="cross-host serve replica: load a checkpoint, serve "
         "POST /predict + scrape surfaces on one exporter port until "
@@ -228,6 +232,7 @@ def child_main(argv=None) -> int:
     except ServeSetupError as e:
         print(f"serve replica {args.replica}: {e}", file=sys.stderr)
         return 2
+    start_runtime()  # after the engine's toolkit forked its sampler pool
     # NTS_STREAM_LOG: follow a shared DeltaLog — the margin must be
     # reserved BEFORE warmup so in-margin appends never touch the ladder
     stream_root = os.environ.get("NTS_STREAM_LOG", "")
@@ -555,6 +560,13 @@ class CrossHostFleet:
             raise ValueError(f"replicas must be >= 1, got {replicas}")
         cfg_path = os.path.abspath(cfg_path)
         ckpt_dir = os.path.abspath(ckpt_dir)
+        # pin the SPAWN-TIME tracing env into the recipes so a supervised
+        # restart / rollout respawn (which re-reads os.environ) keeps the
+        # child's trace config stable
+        child_extra_env = _pin_trace_env(dict(extra_env or {}))
+        check_children_can_start(
+            replicas, {**os.environ, **child_extra_env}
+        )
         spawn_dir = spawn_dir or tempfile.mkdtemp(prefix="nts-crosshost-")
         os.makedirs(spawn_dir, exist_ok=True)
         reps: List[_RouterReplica] = []
@@ -564,10 +576,7 @@ class CrossHostFleet:
                     cfg_path=cfg_path, ckpt_dir=ckpt_dir, replica=f"r{i}",
                     seed=seed + i,
                     port_file=os.path.join(spawn_dir, f"r{i}.port.json"),
-                    # pin the SPAWN-TIME tracing env into the recipe so a
-                    # supervised restart / rollout respawn (which re-reads
-                    # os.environ) keeps the child's trace config stable
-                    extra_env=_pin_trace_env(dict(extra_env or {})),
+                    extra_env=dict(child_extra_env),
                 )
                 r = _RouterReplica(i, recipe=recipe)
                 r.proc = _spawn_child(recipe)
@@ -1301,6 +1310,45 @@ class CrossHostFleet:
 
 
 # ---- child process plumbing -------------------------------------------------
+
+
+def tpu_chips_free_for_children() -> Optional[int]:
+    """How many TPU chips a child of this process could open, read without
+    opening one: 0 when this process already runs on the TPU (a chip
+    belongs to one process at a time, and a JAX process opens every local
+    chip), else the chip device nodes the host exposes. None: the host has
+    no TPU and children run on the CPU."""
+    if backend_is_live():
+        import jax
+
+        if jax.default_backend() == "tpu":
+            return 0
+    return tpu_chip_nodes() or None
+
+
+def check_children_can_start(replicas: int, child_env: Dict[str, str]) -> None:
+    """Refuse a spawn whose children would wait for a chip that never
+    comes free. Every child inherits one environment and no device of its
+    own, so each opens all local chips: on a TPU host one child can start,
+    and none when this process already holds the chips. The process fleet
+    is CPU-only until children are handed their own devices."""
+    if child_env.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return
+    chips = tpu_chips_free_for_children()
+    if chips is None:
+        return
+    if chips == 0 or replicas > 1:
+        raise RuntimeError(
+            f"refusing to spawn {replicas} replica process(es) on a TPU "
+            f"host with {chips} chip(s) free for them: a chip belongs to "
+            "one process at a time and every child opens all local "
+            "chips, so the children beyond the first (all of them, when "
+            "this process already runs on the TPU) would hang at backend "
+            "start-up. The process fleet is CPU-only today: pass "
+            "extra_env={'JAX_PLATFORMS': 'cpu'}, or serve from one "
+            "process (serve.server, or serve.fleet's in-process "
+            "ReplicaSet)"
+        )
 
 
 def _spawn_child(recipe: LaunchRecipe) -> subprocess.Popen:

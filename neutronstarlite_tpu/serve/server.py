@@ -680,9 +680,12 @@ class InferenceServer:
 
 def main(argv=None) -> int:
     from neutronstarlite_tpu.utils.config import InputInfo
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
+    from neutronstarlite_tpu.utils.platform import (
+        configure_compile_cache,
+        start_runtime,
+    )
 
-    honor_platform_env()
+    configure_compile_cache()
     ap = argparse.ArgumentParser(
         description="serve a trained checkpoint: load, AOT-warm the bucket "
         "ladder, answer --requests random per-node predictions, print SLOs"
@@ -707,6 +710,7 @@ def main(argv=None) -> int:
     except ServeSetupError as e:
         print(f"serve: {e}", file=sys.stderr)
         return 2
+    start_runtime()  # after the engine's toolkit forked its sampler pool
     engine.warmup()
     server = InferenceServer(engine)
     rng = np.random.default_rng(args.seed + 1)

@@ -65,25 +65,17 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     # contract: no accelerator claimed — CPU host, topology compiler only
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["NTS_PALLAS_FORCE_COMPILED"] = "1"
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
-
-    honor_platform_env()
-
     import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    os.environ["NTS_PALLAS_FORCE_COMPILED"] = "1"
+    from neutronstarlite_tpu.utils.platform import start_runtime
+
+    start_runtime()
+
     import numpy as np
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("JAX_COMPILATION_CACHE_DIR", "/tmp/nts_jit_cache"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
-    except Exception as e:  # pragma: no cover
-        print(f"compile cache unavailable: {e}", file=sys.stderr, flush=True)
 
     from neutronstarlite_tpu.ops.bsp_ell import (
         DEFAULT_DT,

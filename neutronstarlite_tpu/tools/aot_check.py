@@ -364,18 +364,19 @@ def main(argv=None) -> int:
     # environment selects an accelerator platform): this tool's contract is
     # that no accelerator is ever claimed — the topology compile below goes
     # to the compiler, not to chips
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     # Pallas must emit real Mosaic while tracing on this CPU host — the
     # interpret default would compile the emulation (set at TOOL entry,
     # not inside the reusable _dist_gcn_case: a hidden env mutation there
     # would flip every later pallas call in a shared process)
     os.environ["NTS_PALLAS_FORCE_COMPILED"] = "1"
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
+    from neutronstarlite_tpu.utils.platform import start_runtime
 
-    honor_platform_env()
+    start_runtime()
 
     import numpy as np
-    import jax
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 

@@ -61,9 +61,12 @@ def main(argv=None) -> int:
 
     bench.start_watchdog(args.deadline)
 
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
+    from neutronstarlite_tpu.utils.platform import (
+        configure_compile_cache,
+        start_runtime,
+    )
 
-    honor_platform_env()
+    configure_compile_cache()
 
     cache_dir, v_num, e_num, gen_s = bench.build_and_cache_graph(args.scale)
     host_graph, src, dst = bench.load_cached_graph(cache_dir)
@@ -95,6 +98,7 @@ def main(argv=None) -> int:
         cfg, src, dst, datum, host_graph=host_graph
     )
     build_s = time.time() - t0
+    start_runtime()  # after the trainer forked its sampler pool
 
     sampler = tr.samplers[0]
     n_train = len(sampler.seed_nids)

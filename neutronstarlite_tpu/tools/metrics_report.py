@@ -20,10 +20,8 @@ each record against the obs schema, and renders:
   critical-path breakdown, retry cost — when the stream carries ``span``
   records;
 - per run: the latency-histogram block (merged ``hist`` records with
-  their bounded-error quantiles), the slo timeline (``slo_status`` +
-  ``shed`` records interleaved), and the backend-probe block
-  (``backend_probe`` records; probe-only streams — a bench whose backend
-  never answered — render as their own small block);
+  their bounded-error quantiles) and the slo timeline (``slo_status`` +
+  ``shed`` records interleaved);
 - per run: the program-cost block (``program_cost`` records, obs/cost —
   XLA's own FLOPs/bytes/memory per labeled executable) and the
   prediction-drift block (``model_drift`` records, tools/drift_audit —
@@ -752,29 +750,6 @@ def render_numerics(events: List[Dict[str, Any]],
     return lines
 
 
-def render_probes(events: List[Dict[str, Any]]) -> List[str]:
-    """The ``backend_probe`` block (bench.py's subprocess PJRT check) —
-    the stale-anchor cause, visible at last. Empty without probes."""
-    probes = [e for e in events if e["event"] == "backend_probe"]
-    if not probes:
-        return []
-    lines = ["backend probes:"]
-    for e in probes:
-        lines.append(
-            f"#backend_probe=attempt {e['attempt']} "
-            f"outcome={e['outcome']} "
-            f"platform={e.get('platform') or '?'} "
-            f"{e['seconds']:.1f}s"
-            + (f" (timeout_s={e['timeout_s']:g})"
-               if e.get("timeout_s") is not None else "")
-        )
-        err = e.get("error")
-        if err:
-            tail = str(err).strip().splitlines()[-1][:160]
-            lines.append(f"    error: {tail}")
-    return lines
-
-
 _TIMELINE_SKIP = ("event", "run_id", "schema", "ts", "seq", "error")
 
 
@@ -955,6 +930,12 @@ def render_run(path: str, rec: Dict[str, Any]) -> str:
     for name, v in sorted((rec.get("counters") or {}).items()):
         v = int(v) if float(v).is_integer() else v
         lines.append(f"#{name}={v}")
+    dev = rec.get("device")
+    if dev:
+        lines.append(
+            f"#device={dev.get('platform')} {dev.get('device_kind')} "
+            f"x{dev.get('count')}"
+        )
     mem = rec.get("memory") or {}
     if mem.get("available"):
         lines.append(f"#peak_hbm_bytes={mem.get('peak_bytes_in_use')}")
@@ -977,7 +958,6 @@ def render_run(path: str, rec: Dict[str, Any]) -> str:
     lines.extend(rec.get("_scan") or [])
     lines.extend(rec.get("_hists") or [])
     lines.extend(rec.get("_slo") or [])
-    lines.extend(rec.get("_probe") or [])
     lines.extend(rec.get("_trace") or [])
     timeline = rec.get("_timeline") or []
     if timeline:
@@ -1279,7 +1259,6 @@ def main(argv=None) -> int:
         all_events.extend(events)
         rec = summarize(p, events)
         srec = summarize_serve(events)
-        probe_lines = render_probes(events)
         fleet_lines = render_fleet(events)
         if rec is None and srec is None:
             if fleet_lines:
@@ -1307,23 +1286,6 @@ def main(argv=None) -> int:
                     "_hists": render_hists(events),
                     "_slo": slo_timeline(events),
                     "_timeline": recovery_timeline(events),
-                })
-                continue
-            if probe_lines:
-                # a probe-only stream (bench.py's backend check with no
-                # run behind it — every timed-out round since r05 looks
-                # like this) renders its own small block
-                probes = [
-                    e for e in events if e["event"] == "backend_probe"
-                ]
-                rows.append({
-                    "event": "backend_probe_report",
-                    "run_id": probes[-1]["run_id"],
-                    "attempts": len(probes),
-                    "outcomes": [e["outcome"] for e in probes],
-                    "_path": p,
-                    "_probe_only": True,
-                    "_probe": probe_lines,
                 })
                 continue
             only_stream = render_stream(events)
@@ -1381,7 +1343,6 @@ def main(argv=None) -> int:
             rec["_fleet"] = fleet_lines
             rec["_hists"] = hist_lines
             rec["_slo"] = slo_lines
-            rec["_probe"] = probe_lines
             rec["_trace"] = trace_lines
         if srec is not None:
             srec["_path"] = p
@@ -1409,10 +1370,7 @@ def main(argv=None) -> int:
         ))
     else:
         for rec in rows:
-            if rec.get("_probe_only"):
-                print(f"== backend probe — {rec['_path']}")
-                print("\n".join(rec["_probe"]))
-            elif rec.get("_fleet_only"):
+            if rec.get("_fleet_only"):
                 lines = [f"== fleet {rec.get('run_id', '?')} — "
                          f"{rec['_path']}"]
                 lines.extend(rec["_fleet"])
@@ -1447,7 +1405,6 @@ def main(argv=None) -> int:
             print("\n".join(tracing_lines))
             print()
         train_rows = [r for r in rows if not r.get("_serve")
-                      and not r.get("_probe_only")
                       and not r.get("_fleet_only")
                       and not r.get("_stream_only")]
         if len(train_rows) > 1:

@@ -56,9 +56,9 @@ def main(argv=None) -> int:
     V = max(int(V * args.scale), 64)
     E = max(int(E * args.scale), 512)
 
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
+    from neutronstarlite_tpu.utils.platform import start_runtime
 
-    honor_platform_env()
+    start_runtime()
     import jax
     import jax.numpy as jnp
 

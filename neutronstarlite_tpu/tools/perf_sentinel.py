@@ -35,7 +35,7 @@ DOTS_PASSED dropped below the baseline median.
 
 Usage:
   python -m neutronstarlite_tpu.tools.perf_sentinel check
-      [--ledger DIR] [--kind run|suite|probe] [--k 8]
+      [--ledger DIR] [--kind run|suite|serve] [--k 8]
       [--min-baseline 2] [--nsigma 3.0] [--floor 0.08] [--max-tol 0.5]
       [--suite-budget S] [--suite-fatal] [--json]
   python -m neutronstarlite_tpu.tools.perf_sentinel record-suite
@@ -94,7 +94,6 @@ GATED_METRICS = {
         "wire_quant_rel_err",
     ),
     "suite": ("suite_duration_s",),
-    "probe": ("seconds",),
     # serve rows (tools/serve_bench --> obs/ledger.serve_row): tail
     # latency + shed rate trend-gate exactly like epoch time — the key
     # embeds mode/replicas/CB so trajectories never mix load shapes.

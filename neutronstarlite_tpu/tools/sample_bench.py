@@ -143,9 +143,12 @@ def main(argv=None) -> int:
 
     import bench  # graph cache + LAYERS/N_LABELS (one source of the workload)
 
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
+    from neutronstarlite_tpu.utils.platform import (
+        configure_compile_cache,
+        start_runtime,
+    )
 
-    honor_platform_env()
+    configure_compile_cache()
 
     cache_dir, v_num, e_num, gen_s = bench.build_and_cache_graph(args.scale)
     host_graph, src, dst = bench.load_cached_graph(cache_dir)
@@ -173,6 +176,7 @@ def main(argv=None) -> int:
     rows = {
         m: measure_mode(m, cfg, src, dst, datum, host_graph) for m in modes
     }
+    start_runtime()  # after the first trainer forked its sampler pool
 
     head = rows.get("fused") or rows.get("pipelined") or rows[modes[0]]
     sync = rows.get("sync")

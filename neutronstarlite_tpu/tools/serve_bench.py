@@ -402,9 +402,6 @@ def _run_targets_mode(args) -> int:
 
 
 def main(argv=None) -> int:
-    from neutronstarlite_tpu.utils.platform import honor_platform_env
-
-    honor_platform_env()
     ap = argparse.ArgumentParser(
         description="closed/open-loop serving benchmark over the serve/ "
         "stack; prints one BENCH-compatible JSON line"
@@ -455,9 +452,16 @@ def main(argv=None) -> int:
                     "streams (default: NTS_METRICS_DIR)")
     args = ap.parse_args(argv)
     if args.targets:
+        # a pure HTTP client: it must not open the device the replicas need
         return _run_targets_mode(args)
     if not args.cfg:
         ap.error("cfg is required without --targets")
+    from neutronstarlite_tpu.utils.platform import (
+        configure_compile_cache,
+        start_runtime,
+    )
+
+    configure_compile_cache()
     if args.cb is not None:
         os.environ["NTS_SERVE_CB"] = args.cb
     if args.route is not None:
@@ -499,6 +503,7 @@ def main(argv=None) -> int:
         )
     except ServeSetupError as e:
         raise SystemExit(f"serve_bench: {e}")
+    start_runtime()  # after the toolkits forked their sampler pools
     from neutronstarlite_tpu.serve.fleet import FleetOptions, ReplicaSet
 
     t0 = time.perf_counter()

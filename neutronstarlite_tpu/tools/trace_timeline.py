@@ -357,7 +357,7 @@ def fleet_align(streams: List[Stream]) -> Dict[str, Any]:
 
 _INSTANT_KINDS = ("fault", "recovery", "shed", "rank_loss", "replan",
                   "tune_trial", "tune_decision", "slo_status",
-                  "backend_probe", "delta_commit", "finetune_round")
+                  "delta_commit", "finetune_round")
 _ENVELOPE_OR_SPAN = (
     "event", "run_id", "schema", "ts", "seq", "name", "cat", "span_id",
     "trace_id", "parent_id", "t0", "dur_s", "rank", "thread",
@@ -443,8 +443,6 @@ def chrome_trace(streams: List[Stream]) -> Dict[str, Any]:
             if e["event"] == "slo_status":
                 # the burn-rate verdict, readable off the marker name
                 label = f"{e.get('metric')}={e.get('state')}"
-            if e["event"] == "backend_probe":
-                label = f"attempt{e.get('attempt')}:{e.get('outcome')}"
             events.append({
                 "ph": "i",
                 "name": f"{e['event']}:{label}",

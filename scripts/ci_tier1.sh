@@ -596,9 +596,6 @@ import glob, json, os, tempfile, urllib.request
 
 import numpy as np
 
-from neutronstarlite_tpu.utils.platform import honor_platform_env
-
-honor_platform_env()
 from neutronstarlite_tpu.serve.engine import InferenceEngine
 from neutronstarlite_tpu.serve.server import InferenceServer
 from neutronstarlite_tpu.tools.serve_bench import ensure_checkpoint
@@ -779,9 +776,6 @@ import glob, json, os, tempfile, time
 
 import numpy as np
 
-from neutronstarlite_tpu.utils.platform import honor_platform_env
-
-honor_platform_env()
 from neutronstarlite_tpu.serve.delta import GraphDelta, plan_delta
 from neutronstarlite_tpu.serve.engine import InferenceEngine
 from neutronstarlite_tpu.serve.fleet import ReplicaSet
@@ -1233,9 +1227,6 @@ import glob, json, os, shutil, signal, threading, time
 
 import numpy as np
 
-from neutronstarlite_tpu.utils.platform import honor_platform_env
-
-honor_platform_env()
 from neutronstarlite_tpu.obs import httpc, ledger, schema
 from neutronstarlite_tpu.serve.crosshost import CrossHostFleet
 from neutronstarlite_tpu.serve.engine import InferenceEngine
@@ -1478,9 +1469,6 @@ if JAX_PLATFORMS=cpu NTS_TRACE=1 NTS_METRICS_DIR=/tmp/_t1_trace/obs \
     timeout -k 10 900 python - > /tmp/_t1_trace.log 2>&1 <<'EOF'
 import glob, os, signal, threading, time
 
-from neutronstarlite_tpu.utils.platform import honor_platform_env
-
-honor_platform_env()
 from neutronstarlite_tpu.serve.crosshost import CrossHostFleet
 from neutronstarlite_tpu.tools import trace_timeline as tt
 from neutronstarlite_tpu.tools.serve_bench import (
@@ -1613,9 +1601,6 @@ import glob, json, os, time
 
 import numpy as np
 
-from neutronstarlite_tpu.utils.platform import honor_platform_env
-
-honor_platform_env()
 from neutronstarlite_tpu.graph.digest import graph_digest
 from neutronstarlite_tpu.models import get_algorithm
 from neutronstarlite_tpu.obs import httpc, schema

@@ -84,13 +84,16 @@ def test_worker_subprocess_contract(tmp_path, monkeypatch):
             sys.executable, os.path.join(env["PYTHONPATH"], "bench.py"),
             "--worker", "--worker-config", "eager/ell/float32",
             "--epochs", "1", "--warmup", "1", "--cache-dir", d,
-            "--kernel-tile", "0",
+            "--kernel-tile", "0", "--platform", "cpu",
         ],
         capture_output=True, text=True, timeout=300, env=env,
     )
     assert r.returncode == 0, r.stderr[-1500:]
     info = json.loads(r.stdout.strip().splitlines()[-1])
     assert info["epoch_s"] > 0
+    # every result names the device it was measured on, as JAX reported it
+    assert info["device"]["platform"] == "cpu"
+    assert info["device"]["device_kind"] and info["device"]["count"] >= 1
     assert len(info["epoch_times"]) == 2  # warmup + measured
     assert np.isfinite(info["loss"])
     # the obs run_summary record rides the worker JSON — the supervisor
@@ -98,6 +101,26 @@ def test_worker_subprocess_contract(tmp_path, monkeypatch):
     assert info["metrics"]["event"] == "run_summary"
     assert info["metrics"]["epochs"] == 2
     assert info["metrics"]["epoch_time"]["first_s"] > 0
+
+
+def test_worker_refuses_another_platform(tmp_path):
+    """A worker measures only on the platform it was asked for (default
+    tpu): on the CPU rig it must exit non-zero BEFORE loading any graph,
+    print no result line, and say what JAX reported."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(__file__))
+    r = subprocess.run(
+        [
+            sys.executable, os.path.join(env["PYTHONPATH"], "bench.py"),
+            "--worker", "--worker-config", "eager/ell/float32",
+            "--cache-dir", str(tmp_path / "never_read"),
+        ],
+        capture_output=True, text=True, timeout=300, env=env,
+    )
+    assert r.returncode == 2, (r.returncode, r.stderr[-1500:])
+    assert "'platform': 'cpu'" in r.stderr and "'tpu'" in r.stderr
+    assert "epoch_s" not in r.stdout
 
 
 def test_bench_matrix_measures_one_cfg():
@@ -130,52 +153,6 @@ def test_run_nts_partitions_override(monkeypatch, tmp_path):
     monkeypatch.delenv("NTS_PARTITIONS_OVERRIDE")
     cfg = apply_launcher_overrides(InputInfo.read_from_cfg_file(str(cfg_path)))
     assert cfg.partitions == 2
-
-
-def test_last_good_salvage_round_trip(tmp_path, monkeypatch):
-    """Backend-down salvage: a persisted same-scale measurement is re-emitted
-    marked stale (rc 0); wrong scale or no file yields the null record (rc 1)."""
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(tmp_path / "last.json"))
-    out = {
-        "metric": "gcn_reddit_full_batch_epoch_time", "value": 4.2,
-        "unit": "s", "vs_baseline": 0.238,
-        "extra": {"scale": 1.0, "path": "ell"},
-    }
-    bench.save_last_good(out)
-    rec = bench.load_last_good(1.0)
-    assert rec["value"] == 4.2 and rec["measured_at"]
-    assert bench.load_last_good(0.05) is None  # scale mismatch
-
-    rc = bench.emit_stale_or_fail(1.0, "backend unavailable", diag="x" * 900)
-    assert rc == 0
-    rc = bench.emit_stale_or_fail(0.05, "backend unavailable")
-    assert rc == 1
-    # live-backend failure (likely regression): salvage but NOT success
-    rc = bench.emit_stale_or_fail(1.0, "every sweep config failed",
-                                  rc_on_salvage=4)
-    assert rc == 4
-
-
-def test_stale_emission_content(tmp_path, monkeypatch, capsys):
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(tmp_path / "last.json"))
-    bench.save_last_good({
-        "metric": "gcn_reddit_full_batch_epoch_time", "value": 7.0,
-        "unit": "s", "vs_baseline": 0.143, "extra": {"scale": 1.0},
-    })
-    assert bench.emit_stale_or_fail(1.0, "every sweep config failed") == 0
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    rec = json.loads(line)
-    assert rec["value"] == 7.0
-    assert rec["extra"]["stale"] is True
-    assert "every sweep config failed" in rec["extra"]["stale_reason"]
-    assert rec["extra"]["measured_at"]
-    assert "measured_at" not in rec  # moved into extra, schema unchanged
-    # round 5: the MEASURED same-host CPU baseline rides the stale record
-    # so even a chip-down round ships a real anchor (ref 276.84 s/epoch
-    # np=1 CPU from baseline/results/summary.json)
-    anchor = rec["extra"].get("cpu_anchor")
-    assert anchor and anchor["reference_np1_cpu_epoch_s"] > 0
-    assert "baseline/run_baseline.py" in anchor["source"]
 
 
 def test_bench_sample_contract(tmp_path, monkeypatch, capsys):
@@ -222,7 +199,7 @@ def test_worker_paths_agree(tmp_path, monkeypatch):
                 sys.executable, os.path.join(env["PYTHONPATH"], "bench.py"),
                 "--worker", "--worker-config", f"eager/{path}/float32",
                 "--epochs", "1", "--warmup", "1", "--cache-dir", d,
-                "--kernel-tile", str(tile),
+                "--kernel-tile", str(tile), "--platform", "cpu",
             ],
             capture_output=True, text=True, timeout=300, env=env,
         )
@@ -240,7 +217,7 @@ def test_sweep_hang_fences(tmp_path, monkeypatch, capsys):
     calls = []
 
     def fake_worker(order, path, precision, epochs, warmup, cache_dir,
-                    kernel_tile, timeout_s):
+                    kernel_tile, timeout_s, platform):
         calls.append((order, path, round(timeout_s)))
         if path == "pallas":
             return {"error": f"TIMEOUT after {timeout_s:.0f}s", "wall_s": 1.0}
@@ -251,15 +228,13 @@ def test_sweep_hang_fences(tmp_path, monkeypatch, capsys):
     monkeypatch.setattr(bench, "start_watchdog", lambda *a: None)
     monkeypatch.setattr(bench, "run_worker_config", fake_worker)
     monkeypatch.setattr(
-        bench, "probe_backend", lambda *a, **k: {"init_s": 0.1}
-    )
-    monkeypatch.setattr(
         bench, "build_and_cache_graph",
         lambda scale: (str(tmp_path), 1000, 5000, 0.1),
     )
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(tmp_path / "last.json"))
     rc = bench.main(["--deadline", "1000", "--epochs", "1", "--warmup", "0"])
-    assert rc == 0
+    # the winner was measured and is printed, but a leg that timed out is a
+    # failure of the run: it shows in extra.sweep AND in the exit code
+    assert rc == 4
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     # the winner is the fastest NON-hung path, measured at the leg cap
     assert rec["extra"]["path"] == "ell"
@@ -278,3 +253,25 @@ def test_sweep_hang_fences(tmp_path, monkeypatch, capsys):
         if r["path"] == "pallas" and "skipped" in str(r.get("error", ""))
     ]
     assert skipped, rec["extra"]["sweep"]
+
+
+def test_failed_final_measurement_prints_no_number(tmp_path, monkeypatch,
+                                                   capsys):
+    """A sweep timing is never substituted for a failed final measurement:
+    the run exits non-zero and prints no result line."""
+
+    def fake_worker(order, path, precision, epochs, warmup, cache_dir,
+                    kernel_tile, timeout_s, platform):
+        if epochs == 7:  # the final measurement
+            return {"error": "worker rc=1", "wall_s": 1.0}
+        return {"epoch_s": 2.0, "loss": 0.5, "device": "fake", "wall_s": 1.0}
+
+    monkeypatch.setattr(bench, "start_watchdog", lambda *a: None)
+    monkeypatch.setattr(bench, "run_worker_config", fake_worker)
+    monkeypatch.setattr(
+        bench, "build_and_cache_graph",
+        lambda scale: (str(tmp_path), 1000, 5000, 0.1),
+    )
+    rc = bench.main(["--deadline", "1000", "--epochs", "7", "--warmup", "0"])
+    assert rc == 1
+    assert capsys.readouterr().out.strip() == ""

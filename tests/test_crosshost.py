@@ -27,6 +27,7 @@ import json
 import os
 import threading
 import time
+import zlib
 
 import numpy as np
 import pytest
@@ -35,6 +36,13 @@ from neutronstarlite_tpu.obs import registry, schema
 from neutronstarlite_tpu.obs.httpc import HttpRefused
 from neutronstarlite_tpu.serve import crosshost
 from neutronstarlite_tpu.serve.batcher import RequestShedError
+
+
+def _ckpt_tag(ckpt_dir: str) -> float:
+    """What a fake replica answers with: names the checkpoint it serves.
+    A wide modulus (exact in float32): two checkpoints under ``% 97`` of a
+    salted ``hash`` collided in about one suite run in a hundred."""
+    return float(zlib.crc32(ckpt_dir.encode()) % 65521)
 
 
 # ---- rig: fake processes + in-memory transport -----------------------------
@@ -122,7 +130,7 @@ class FakeWorld:
 
     def predict(self, port, proc, payload):
         ids = payload["node_ids"]
-        tag = float(abs(hash(proc.recipe.ckpt_dir)) % 97)
+        tag = _ckpt_tag(proc.recipe.ckpt_dir)
         return json.dumps({
             "status": "ok", "dtype": "float32",
             "values": [[tag + float(i)] for i in ids],
@@ -228,7 +236,7 @@ def test_route_state_sees_breach_and_drains(world, tmp_path):
         for _ in range(4):
             v = fleet.predict([5])
             assert v[0, 0] == pytest.approx(
-                float(abs(hash(fleet.replicas[1].ckpt_dir)) % 97) + 5.0
+                _ckpt_tag(fleet.replicas[1].ckpt_dir) + 5.0
             )
     finally:
         fleet.close()
@@ -548,7 +556,7 @@ def test_replica_killed_mid_rollout_aborts_and_rolls_back(
         assert len(world.alive()) == 2
         v = fleet.predict([3])
         assert v[0, 0] == pytest.approx(
-            float(abs(hash(old_ckpt)) % 97) + 3.0
+            _ckpt_tag(old_ckpt) + 3.0
         )
     finally:
         fleet.close()
@@ -597,6 +605,48 @@ def test_launch_recipe_argv_env(tmp_path):
     env = r.env()
     assert env["NTS_METRICS_PORT"] == "0"  # ephemeral, via port file
     assert env["NTS_SERVE_BUCKETS"] == "1-4"
+
+
+def test_spawn_refuses_children_without_chips(world, tmp_path, monkeypatch):
+    """On a TPU host every child opens all local chips and a chip belongs
+    to one process: a spawn that would leave children waiting for a chip
+    refuses BEFORE forking anything; a CPU-pinned fleet is untouched."""
+    tpu_env = {"JAX_PLATFORMS": "tpu,cpu"}
+    cpu_env = {"JAX_PLATFORMS": "cpu"}
+    for free, replicas in ((1, 2), (4, 2), (0, 1)):
+        monkeypatch.setattr(
+            crosshost, "tpu_chips_free_for_children", lambda n=free: n
+        )
+        with pytest.raises(RuntimeError, match="CPU-only today"):
+            crosshost.check_children_can_start(replicas, tpu_env)
+        crosshost.check_children_can_start(replicas, cpu_env)
+    # one child, chips free: it can open them
+    monkeypatch.setattr(crosshost, "tpu_chips_free_for_children", lambda: 1)
+    crosshost.check_children_can_start(1, tpu_env)
+    # no TPU on the host: children fall to the CPU, nothing to refuse
+    monkeypatch.setattr(
+        crosshost, "tpu_chips_free_for_children", lambda: None
+    )
+    crosshost.check_children_can_start(3, tpu_env)
+
+    # through spawn(): refused with zero children forked
+    monkeypatch.setattr(crosshost, "tpu_chips_free_for_children", lambda: 1)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    with pytest.raises(RuntimeError, match="refusing to spawn 3"):
+        crosshost.CrossHostFleet.spawn(
+            str(tmp_path / "a.cfg"), str(tmp_path / "ck"), 3,
+            spawn_dir=str(tmp_path / "sp"),
+        )
+    assert world.spawns == 0
+
+
+def test_chip_probe_opens_nothing_on_the_cpu_rig():
+    """JAX is live on the CPU here (conftest), so the answer comes from
+    the host's chip device nodes alone: None on a host without a TPU, the
+    node count on one with (the pytest parent holds no chip)."""
+    from neutronstarlite_tpu.utils.platform import tpu_chip_nodes
+
+    assert crosshost.tpu_chips_free_for_children() == (tpu_chip_nodes() or None)
 
 
 def test_normalize_base_and_targets_env(monkeypatch):

@@ -175,6 +175,10 @@ def test_run_emits_schema_valid_stream(smoke_metrics_dir):
     if not summ["memory"]["available"]:
         assert summ["memory"]["peak_bytes_in_use"] is None
     assert summ["result"]["acc"]["train"] is not None
+    # the device as JAX reported it (conftest: the 8-virtual-device CPU rig)
+    assert summ["device"] == {
+        "platform": "cpu", "device_kind": "cpu", "count": 8,
+    }
 
 
 def test_metrics_report_renders_reference_shape(smoke_metrics_dir, capsys):

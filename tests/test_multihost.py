@@ -37,8 +37,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _WORKER = r"""
 import json, os, sys
 sys.path.insert(0, os.environ["NTS_TEST_REPO"])
-from neutronstarlite_tpu.utils.platform import honor_platform_env
-honor_platform_env(min_devices=2)
 from neutronstarlite_tpu.parallel.mesh import maybe_initialize_distributed
 maybe_initialize_distributed()
 

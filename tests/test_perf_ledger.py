@@ -94,21 +94,12 @@ def test_crashed_writer_leaves_previous_state(tmp_path):
     assert len(ledger.read_rows(directory=d)) == len(NOISE)
 
 
-def test_suite_and_probe_rows(tmp_path):
+def test_suite_row(tmp_path):
     d = str(tmp_path)
     ledger.append_row(ledger.suite_row(900.0, 420, 0, 1200.0), directory=d)
-    ledger.append_row(
-        ledger.probe_row(1, "timeout", 120.0, None, scale=1.0,
-                         error="hang"),
-        directory=d,
-    )
     rows = ledger.read_rows(directory=d)
-    assert [r["kind"] for r in rows] == ["suite", "probe"]
+    assert [r["kind"] for r in rows] == ["suite"]
     assert rows[0]["dots_passed"] == 420 and rows[0]["timeout_s"] == 1200.0
-    # the probe row never initializes a backend: its key is the probe's
-    # own (absent) answer
-    assert rows[1]["backend"] == "unprobed"
-    assert rows[1]["outcome"] == "timeout"
 
 
 # ---- sentinel: the acceptance pair ------------------------------------------

@@ -50,12 +50,29 @@ def test_capture_from_jitted_lowering_no_compile(tmp_path):
     assert rec["source"] == "lowered"
     assert rec["flops"] > 0 and rec["bytes_accessed"] > 0
     assert rec["memory"] is None
+    assert rec["custom_calls"] == []  # plain XLA: no kernel call
     reg.close()
     events = [json.loads(l) for l in open(tmp_path / "s.jsonl")
               if l.strip()]
     assert schema.validate_stream(events) == len(events)
     assert events[-1]["event"] == "program_cost"
     assert events[-1]["label"] == "test.matmul"
+
+
+def test_lowering_capture_names_custom_calls():
+    """A lowered module's custom-call targets ride the record: the way a
+    run shows its Pallas kernel lowered as a Mosaic call (chip_smoke's
+    train_pallas leg reads ``tpu_custom_call`` here) and was not
+    interpreted. On the CPU rig LAPACK's QR is the custom call at hand."""
+    reg = _reg()
+    rec = capture_program_cost(
+        reg, "test.qr", jitted=jax.jit(jnp.linalg.qr),
+        args=(jnp.ones((8, 8)),),
+    )
+    assert any(c.startswith("lapack") for c in rec["custom_calls"]), rec
+    schema.validate_event(rec)
+    with pytest.raises(ValueError):
+        schema.validate_event(dict(rec, custom_calls=["ok", ""]))
 
 
 def test_capture_from_compiled_includes_memory():

@@ -172,8 +172,6 @@ def test_parallel_sampler_trains():
     prog = r"""
 import json
 import numpy as np
-from neutronstarlite_tpu.utils.platform import honor_platform_env
-honor_platform_env()
 from neutronstarlite_tpu.graph.dataset import GNNDatum
 from neutronstarlite_tpu.graph.synthetic import planted_partition_graph
 from neutronstarlite_tpu.models.gcn_sample import GCNSampleTrainer

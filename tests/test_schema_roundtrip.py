@@ -90,10 +90,6 @@ def _emit_all(reg: registry.MetricsRegistry) -> None:
         window_count=420,
     )
     reg.event(
-        "backend_probe", attempt=1, outcome="timeout", seconds=120.0,
-        platform=None, timeout_s=120.0, error="backend init hang",
-    )
-    reg.event(
         "program_cost", label="serve.bucket_16", available=True,
         source="compiled", flops=528383.0, bytes_accessed=65580.0,
         transcendentals=None,
@@ -165,6 +161,7 @@ def _emit_all(reg: registry.MetricsRegistry) -> None:
         avg_epoch_s=0.5, epoch_times_s=[0.5], loss_history=[1.25],
         phases={}, memory={"available": False, "bytes_in_use": None,
                            "peak_bytes_in_use": None, "devices": []},
+        device={"platform": "tpu", "device_kind": "TPU v5 lite", "count": 4},
     )
 
 
@@ -192,7 +189,6 @@ RENDER_MARKERS = {
     "stream_rotated": "stream_rotated",
     "hist": "#hist_serve.latency_ms=",
     "slo_status": "slo timeline:",
-    "backend_probe": "#backend_probe=",
     "program_cost": "#program_cost=serve.bucket_16",
     "model_drift": "prediction drift:",
     "tensor_stats": "numerics:",
@@ -239,6 +235,8 @@ def test_roundtrip_construct_validate_render(tmp_path, capsys):
                 f"record kind {kind!r} left no {marker!r} in the report — "
                 "renderer support missing"
             )
+    # the run names the device it executed on, as JAX reported it
+    assert "#device=tpu TPU v5 lite x4" in out
 
 
 def test_validator_rejects_mutations_per_kind(tmp_path):
@@ -273,7 +271,6 @@ def test_validator_rejects_mutations_per_kind(tmp_path):
         "stream_rotated": {"bytes_written": "lots"},
         "hist": {"buckets": [[340, 0]]},
         "slo_status": {"state": ""},
-        "backend_probe": {"attempt": 0},
         "program_cost": {"label": ""},
         "model_drift": {"drift": "lots"},
         "tensor_stats": {"finite_fraction": 1.5},
@@ -291,6 +288,16 @@ def test_validator_rejects_mutations_per_kind(tmp_path):
         bad = dict(events[kind], **mut)
         with pytest.raises(ValueError):
             schema.validate_event(bad)
+
+    # run_summary.device is optional (synthesized summaries lack it) but
+    # typed when present
+    summ = events["run_summary"]
+    schema.validate_event({k: v for k, v in summ.items() if k != "device"})
+    for dev in ("tpu", {"platform": "", "device_kind": "x", "count": 1},
+                {"platform": "tpu", "device_kind": "x", "count": 0},
+                {"platform": "tpu", "count": 1}):
+        with pytest.raises(ValueError):
+            schema.validate_event(dict(summ, device=dev))
 
     # the span's distributed-tracing fields bite individually too: the
     # remote-parent stamps must be numbers, the lineage seqs ints
