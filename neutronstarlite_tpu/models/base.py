@@ -12,9 +12,10 @@ TPU implementation — the registry accepts all of them.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
-from typing import Callable, Dict, Optional, Type
+from typing import Callable, Dict, Iterator, Optional, Type
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,7 @@ from neutronstarlite_tpu.graph.storage import CSCGraph, build_graph, load_edges
 from neutronstarlite_tpu.ops.device_graph import DeviceGraph
 from neutronstarlite_tpu.utils.config import InputInfo
 from neutronstarlite_tpu.utils.logging import get_logger
-from neutronstarlite_tpu.utils.timing import PhaseTimers, get_time
+from neutronstarlite_tpu.utils.timing import PhaseTimers
 
 log = get_logger("models")
 
@@ -105,10 +106,8 @@ class ToolkitBase:
         # one causal tree in tools/trace_timeline.
         self.tracer = obs.Tracer(self.metrics)
         self.timers.tracer = self.tracer
-        self._run_span = self.tracer.begin(
-            "run", cat="lifecycle",
-            algorithm=cfg.algorithm or type(self).__name__,
-        )
+        self._run_span = None
+        self.open_run_root()
         self._last_epoch_span = None
         self.run_summary_record: Optional[dict] = None
         # fault/recovery records from any layer (fault injection, guard
@@ -180,20 +179,11 @@ class ToolkitBase:
                 src, dst = load_undirected_from_directed(edge_path)
             else:
                 src, dst = load_edges(edge_path)
-            self.host_graph = build_graph(
-                src, dst, cfg.vertices, weight=self.weight_mode
-            )
-            # auto-knob resolution needs only host_graph + cfg, and the
-            # _wants_fused_edge/_wants_ell upload decision below needs
-            # the RESOLVED kernel — resolving here (not in
-            # _finalize_datum, where it re-runs as a no-op) keeps
-            # KERNEL:auto from paying the O(E) DeviceGraph upload a
-            # pinned KERNEL:fused_edge skips
-            self._resolve_tune_autos()
-            if self._build_device_graph():
-                self.graph = DeviceGraph.from_host(
-                    self.host_graph, edge_chunk=cfg.edge_chunk or None
+            with self.timers.phase("host_graph_build"):
+                self.host_graph = build_graph(
+                    src, dst, cfg.vertices, weight=self.weight_mode
                 )
+            self._resolve_and_upload_graph()
         log.info(
             "loaded graph |V|=%d |E|=%d avg_deg=%.1f",
             self.host_graph.v_num,
@@ -360,7 +350,22 @@ class ToolkitBase:
         refuses."""
         from neutronstarlite_tpu.tune import select as tune_select
 
-        tune_select.resolve_auto_knobs(self)
+        with self.timers.phase("tune_resolve"):
+            tune_select.resolve_auto_knobs(self)
+
+    def _resolve_and_upload_graph(self) -> None:
+        """The funnel step both construction paths share once host_graph
+        exists. Auto-knob resolution needs only host_graph + cfg, and the
+        _wants_fused_edge/_wants_ell upload decision needs the RESOLVED
+        kernel — resolving here (not in _finalize_datum, where it re-runs
+        as a no-op) keeps KERNEL:auto from paying the O(E) DeviceGraph
+        upload a pinned KERNEL:fused_edge skips."""
+        self._resolve_tune_autos()
+        if self._build_device_graph():
+            with self.timers.phase("device_graph_upload"):
+                self.graph = DeviceGraph.from_host(
+                    self.host_graph, edge_chunk=self.cfg.edge_chunk or None
+                )
 
     def _finalize_datum(self) -> None:
         self._resolve_tune_autos()
@@ -376,15 +381,18 @@ class ToolkitBase:
     # never do, so no whole [V, f] copy lands on device 0 beside the shards.
     @functools.cached_property
     def feature(self) -> jax.Array:
-        return jnp.asarray(self.datum.feature)
+        with self.timers.phase("datum_upload"):
+            return jnp.asarray(self.datum.feature)
 
     @functools.cached_property
     def label(self) -> jax.Array:
-        return jnp.asarray(self.datum.label.astype(np.int32))
+        with self.timers.phase("datum_upload"):
+            return jnp.asarray(self.datum.label.astype(np.int32))
 
     @functools.cached_property
     def mask(self) -> jax.Array:
-        return jnp.asarray(self.datum.mask)
+        with self.timers.phase("datum_upload"):
+            return jnp.asarray(self.datum.mask)
 
     @classmethod
     def from_arrays(
@@ -408,16 +416,13 @@ class ToolkitBase:
         resident, so sharing also skips repeat HBM uploads)."""
         t = cls(cfg, seed=seed)
         t.host_ell = host_ell
-        t.host_graph = (
-            host_graph
-            if host_graph is not None
-            else build_graph(src, dst, cfg.vertices, weight=cls.weight_mode)
-        )
-        t._resolve_tune_autos()  # see init_graph: before the upload decision
-        if t._build_device_graph():
-            t.graph = DeviceGraph.from_host(
-                t.host_graph, edge_chunk=cfg.edge_chunk or None
-            )
+        if host_graph is None:
+            with t.timers.phase("host_graph_build"):
+                host_graph = build_graph(
+                    src, dst, cfg.vertices, weight=cls.weight_mode
+                )
+        t.host_graph = host_graph
+        t._resolve_and_upload_graph()
         t.datum = datum
         t._finalize_datum()
         return t
@@ -718,6 +723,52 @@ class ToolkitBase:
         log.info("%s Acc: %f %d %d", name, acc, n, correct)
         return acc
 
+    # ---- live spans of the run loops -------------------------------------
+    # One vocabulary for every run loop (docs/OBSERVABILITY.md, Tracing):
+    # the ``epoch`` span covers the whole iteration, its ``stage`` children
+    # are opened around the work itself, and each is a TraceAnnotation in
+    # any active profiler session. No span adds a device sync.
+    def open_run_root(self) -> None:
+        """Open the ``run`` root span unless one is open: at construction,
+        and again at the top of a ``run()`` that follows a finished one
+        (``finalize_metrics`` closed the root; a warm-up ``run()`` then a
+        measured one on the same trainer), so every epoch has a parent."""
+        if self._run_span is None:
+            self._run_span = self.tracer.begin(
+                "run", cat="lifecycle",
+                algorithm=self.cfg.algorithm or type(self).__name__,
+            )
+
+    def _close_run_root(self) -> None:
+        if self._run_span is not None:
+            self.tracer.end(self._run_span, epochs=len(self.epoch_times))
+            self._run_span = None
+
+    @contextlib.contextmanager
+    def epoch_span(self, epoch: int) -> Iterator["obs.trace.SpanHandle"]:
+        """The live ``epoch`` span: a run loop opens it at the top of the
+        iteration and leaves it after ``ckpt_epoch_end``. Its ``t0`` is
+        the epoch's start on the ``get_time`` clock."""
+        with self.tracer.span(
+            "epoch", cat="epoch", parent=self._run_span, epoch=int(epoch)
+        ) as span:
+            # NTS_TRACE=0 still hands out a handle (ids allocate, nothing
+            # is emitted) — a disabled tracer must not leak phantom span
+            # ids into ring_step records' epoch_span join field
+            self._last_epoch_span = span if self.tracer.enabled else None
+            yield span
+
+    def stage(self, name: str, epoch: Optional[int] = None):
+        """A live ``cat="stage"`` span under the innermost span this thread
+        has open: the ``epoch`` span inside an iteration (pass ``epoch``),
+        ``run`` (or an enclosing stage) around the loop. The handle's
+        ``dur_s`` is set when the ``with`` block ends."""
+        attrs = {} if epoch is None else {"epoch": int(epoch)}
+        return self.tracer.span(
+            name, cat="stage",
+            parent=self.tracer.current() or self._run_span, **attrs,
+        )
+
     # ---- run metrics -----------------------------------------------------
     def emit_epoch(self, epoch: int, seconds: float, loss=None,
                    stages: Optional[dict] = None, **extra):
@@ -730,10 +781,12 @@ class ToolkitBase:
         (supervised_run / NTS_GUARDS=1).
 
         ``stages``: ordered {name: seconds} sub-intervals of this epoch
-        (e.g. ``step_dispatch``/``step_device``, or the NTS_TRACE_STEP
-        split's ``forward_backward``/``optim``) — emitted as child spans
-        laid back-to-back from the epoch's start, and attached to the
-        epoch event for flat consumers."""
+        (``step_dispatch``/``step_device``, or the NTS_TRACE_STEP split's
+        ``forward_backward``/``optim``), the durations of the loop's live
+        stage spans of those names — attached to the epoch event for flat
+        consumers. The spans themselves (``epoch`` and its stages) are the
+        run loop's, opened where the work happens (``epoch_span`` /
+        ``stage``): nothing is reconstructed here."""
         if getattr(self, "_first_epoch_trained", None) is None:
             # anchor for mapping epoch numbers onto epoch_times indices
             # (a crash-resumed trainer's first trained epoch is not 0)
@@ -754,26 +807,6 @@ class ToolkitBase:
             # epoch objectives (epoch_pNN_ms) evaluate once per epoch; a
             # breach emits slo_status and snapshots the flight recorder
             self.slo.tick()
-        # the epoch (and its stages) as spans on the causal timeline —
-        # retroactive: the epoch just ended, so end ~= now and the stream's
-        # mono->wall recovery (trace.py docstring) holds
-        end = get_time()
-        span = self.tracer.complete(
-            "epoch", dur_s=seconds, end=end, cat="epoch",
-            parent=self._run_span, epoch=int(epoch),
-        )
-        # NTS_TRACE=0 still returns a handle (ids allocate, nothing is
-        # emitted) — a disabled tracer must not leak phantom span ids
-        # into ring_step records' epoch_span join field
-        self._last_epoch_span = span if self.tracer.enabled else None
-        if stages:
-            t = end - seconds
-            for name, dur in stages.items():
-                self.tracer.complete(
-                    name, dur_s=float(dur), t0=t, cat="stage",
-                    parent=span, epoch=int(epoch),
-                )
-                t += float(dur)
         res_guards.epoch_check(self, epoch, seconds, loss)
         return rec
 
@@ -825,43 +858,40 @@ class ToolkitBase:
         gauge snapshot (wire volume), device memory, and the final result.
         """
         if self.run_summary_record is not None:
+            self._close_run_root()  # a later run() reopened it
             return self.run_summary_record
-        if self.slo is not None:
-            self.slo.close()  # final forced evaluation -> last slo_status
-        # close the root lifecycle span BEFORE the summary so the span is
-        # part of the stream the summary consolidates
-        if self._run_span is not None:
-            self.tracer.end(
-                self._run_span, epochs=len(self.epoch_times),
-            )
-            self._run_span = None
         from neutronstarlite_tpu.obs import collectors
+        from neutronstarlite_tpu.tools.drift_audit import audit_registry
         from neutronstarlite_tpu.utils.platform import device_facts
 
-        fields: dict = {
-            "device": device_facts(),
-            "epochs": len(self.epoch_times),
-            "epoch_time": collectors.steady_state_stats(self.epoch_times),
-            "avg_epoch_s": self.avg_epoch_time(),
-            "epoch_times_s": [float(t) for t in self.epoch_times],
-            "loss_history": [float(v) for v in self.loss_history],
-            "phases": collectors.phase_snapshot(self.timers),
-            "memory": collectors.device_memory_stats(),
-            "compile_cache": collectors.compile_cache_info(),
-        }
-        if result is not None:
-            fields["result"] = {
-                "loss": result.get("loss"),
-                "acc": result.get("acc"),
-                "avg_epoch_s": result.get("avg_epoch_s"),
+        with self.stage("finalize_metrics"):
+            if self.slo is not None:
+                self.slo.close()  # final forced evaluation -> last slo_status
+            fields: dict = {
+                "device": device_facts(),
+                "epochs": len(self.epoch_times),
+                "epoch_time": collectors.steady_state_stats(self.epoch_times),
+                "avg_epoch_s": self.avg_epoch_time(),
+                "epoch_times_s": [float(t) for t in self.epoch_times],
+                "loss_history": [float(v) for v in self.loss_history],
+                "phases": collectors.phase_snapshot(self.timers),
+                "memory": collectors.device_memory_stats(),
+                "compile_cache": collectors.compile_cache_info(),
             }
-        # prediction-drift audit (tools/drift_audit): the analytic wire
-        # pricing vs the live counters, emitted as typed model_drift
-        # records BEFORE the summary so a drifted run's stream carries
-        # the verdict (NTS_DRIFT_AUDIT=0 disables; never raises)
-        from neutronstarlite_tpu.tools.drift_audit import audit_registry
-
-        audit_registry(self.metrics, len(self.epoch_times))
+            if result is not None:
+                fields["result"] = {
+                    "loss": result.get("loss"),
+                    "acc": result.get("acc"),
+                    "avg_epoch_s": result.get("avg_epoch_s"),
+                }
+            # prediction-drift audit (tools/drift_audit): the analytic wire
+            # pricing vs the live counters, emitted as typed model_drift
+            # records BEFORE the summary so a drifted run's stream carries
+            # the verdict (NTS_DRIFT_AUDIT=0 disables; never raises)
+            audit_registry(self.metrics, len(self.epoch_times))
+        # close the root lifecycle span BEFORE the summary so the span is
+        # part of the stream the summary consolidates
+        self._close_run_root()
         self.run_summary_record = self.metrics.run_summary(**fields)
         self._append_ledger_row()
         self.metrics.close()

@@ -87,10 +87,11 @@ class FullBatchTrainer(ToolkitBase):
             # ELL_LEVELS (cfg or the tune/ autotuner's resolved choice)
             # selects the fused tables' level ladder; "" keeps the path
             # default (binned) via the NTS_ELL_LEVELS env fallback
-            self.compute_graph = FusedEdgePair.from_host(
-                self.host_graph, vt=cfg.kernel_tile,
-                levels=getattr(cfg, "ell_levels", ""),
-            )
+            with self.timers.phase("tables_build"):
+                self.compute_graph = FusedEdgePair.from_host(
+                    self.host_graph, vt=cfg.kernel_tile,
+                    levels=getattr(cfg, "ell_levels", ""),
+                )
             log.info(
                 "KERNEL:fused_edge: blocked streaming SDDMM+softmax+SpMM "
                 "(%d src tiles of %d, %d fwd levels, %d table slots)",
@@ -110,30 +111,31 @@ class FullBatchTrainer(ToolkitBase):
             from neutronstarlite_tpu.ops.ell import EllPair
             from neutronstarlite_tpu.ops.pallas_kernels import PallasEllPair
 
-            if self.host_ell is not None:
-                self.compute_graph = self.host_ell
-            elif cfg.pallas_kernel and os.environ.get(
-                "NTS_PALLAS_RESIDENT", "0"
-            ) == "1":
-                # the resident-table kernel cannot lower to Mosaic (TPU
-                # gather restriction, ops/pallas_kernels.py docstring) —
-                # interpret-mode experiments only
-                self.compute_graph = PallasEllPair.from_host(self.host_graph)
-            elif cfg.pallas_kernel:
-                # PALLAS:1 -> the streamed block-sparse kernel at ANY
-                # scale: the one fused aggregation design Mosaic can
-                # compile (one-hot MXU combine, no gather). KERNEL_TILE:vt
-                # sets the src-tile height explicitly.
-                self.compute_graph = BspEllPair.from_host(
-                    self.host_graph,
-                    **({"vt": cfg.kernel_tile} if cfg.kernel_tile > 0 else {}),
-                )
-            elif cfg.kernel_tile > 0:
-                self.compute_graph = BlockedEllPair.from_host(
-                    self.host_graph, vt=cfg.kernel_tile
-                )
-            else:
-                self.compute_graph = EllPair.from_host(self.host_graph)
+            with self.timers.phase("tables_build"):
+                if self.host_ell is not None:
+                    self.compute_graph = self.host_ell
+                elif cfg.pallas_kernel and os.environ.get(
+                    "NTS_PALLAS_RESIDENT", "0"
+                ) == "1":
+                    # the resident-table kernel cannot lower to Mosaic (TPU
+                    # gather restriction, ops/pallas_kernels.py docstring) —
+                    # interpret-mode experiments only
+                    self.compute_graph = PallasEllPair.from_host(self.host_graph)
+                elif cfg.pallas_kernel:
+                    # PALLAS:1 -> the streamed block-sparse kernel at ANY
+                    # scale: the one fused aggregation design Mosaic can
+                    # compile (one-hot MXU combine, no gather). KERNEL_TILE:vt
+                    # sets the src-tile height explicitly.
+                    self.compute_graph = BspEllPair.from_host(
+                        self.host_graph,
+                        **({"vt": cfg.kernel_tile} if cfg.kernel_tile > 0 else {}),
+                    )
+                elif cfg.kernel_tile > 0:
+                    self.compute_graph = BlockedEllPair.from_host(
+                        self.host_graph, vt=cfg.kernel_tile
+                    )
+                else:
+                    self.compute_graph = EllPair.from_host(self.host_graph)
             if isinstance(self.compute_graph, BlockedEllPair):
                 log.info(
                     "OPTIM_KERNEL: blocked ELL aggregation (%d src tiles of "
@@ -164,19 +166,33 @@ class FullBatchTrainer(ToolkitBase):
                 )
             # trainer-specific table adaptation (e.g. GAT wraps the plain
             # EllPair with the attention slot maps); default is identity
-            self.compute_graph = self.adapt_ell_graph(self.compute_graph)
+            with self.timers.phase("tables_build"):
+                self.compute_graph = self.adapt_ell_graph(self.compute_graph)
         if getattr(type(self), "edge_family", False):
             self._emit_edge_kernel_gauges()
-        key = jax.random.PRNGKey(self.seed)
-        self.params = self.init_params(key)
-        self.adam_cfg = AdamConfig(
-            alpha=cfg.learn_rate,
-            weight_decay=cfg.weight_decay,
-            decay_rate=cfg.decay_rate,
-            decay_epoch=cfg.decay_epoch,
-        )
-        self.opt_state = adam_init(self.params)
-        train_mask01 = jnp.asarray((self.datum.mask == 0).astype(np.float32))
+        with self.timers.phase("params_init"):
+            key = jax.random.PRNGKey(self.seed)
+            self.params = self.init_params(key)
+            self.adam_cfg = AdamConfig(
+                alpha=cfg.learn_rate,
+                weight_decay=cfg.weight_decay,
+                decay_rate=cfg.decay_rate,
+                decay_epoch=cfg.decay_epoch,
+            )
+            self.opt_state = adam_init(self.params)
+        with self.timers.phase("datum_upload"):
+            train_mask01 = jnp.asarray(
+                (self.datum.mask == 0).astype(np.float32)
+            )
+        # first touch uploads the datum (the properties' own datum_upload
+        # phases), ahead of step_build, whose cost capture reads them
+        _ = self.feature, self.label
+        with self.timers.phase("step_build"):
+            self._build_steps(train_mask01)
+
+    def _build_steps(self, train_mask01) -> None:
+        """The jit wrappers run() and the tools dispatch, and the step
+        program's cost record (build_model's ``step_build`` phase)."""
         masked_nll = self.masked_nll_loss
         model_forward = self.model_forward
         adam_cfg = self.adam_cfg
@@ -405,14 +421,17 @@ class FullBatchTrainer(ToolkitBase):
 
     def run(self) -> Dict[str, Any]:
         cfg = self.cfg
-        key = jax.random.PRNGKey(self.seed + 1)
-        log.info(
-            "GNNmini::Engine[%s.%s] running [%d] Epochs",
-            jax.default_backend(),
-            type(self).__name__,
-            cfg.epochs,
-        )
-        start_epoch = self.ckpt_begin()
+        self.open_run_root()
+        with self.stage("run_begin"):
+            key = jax.random.PRNGKey(self.seed + 1)
+            log.info(
+                "GNNmini::Engine[%s.%s] running [%d] Epochs",
+                jax.default_backend(),
+                type(self).__name__,
+                cfg.epochs,
+            )
+        with self.stage("ckpt_begin"):
+            start_epoch = self.ckpt_begin()
         loss = None
         # NTS_PROFILE_DIR: emit a jax.profiler trace of the steady-state
         # epochs (from the 2nd epoch on, so compile noise stays out) — the
@@ -440,81 +459,96 @@ class FullBatchTrainer(ToolkitBase):
             if epoch == trace_from and epoch < cfg.epochs:
                 trace_cm = maybe_trace(type(self).__name__)
                 trace_cm.__enter__()
-            ekey = jax.random.fold_in(key, epoch)
-            t0 = get_time()
-            if split_step:
-                loss, grads = self._fwd_bwd(
-                    self.params, self.compute_graph, self.feature,
-                    self.label, self._train_mask01, ekey,
-                )
-                jax.block_until_ready(loss)
-                t_fb = get_time()
-                self.params, self.opt_state = self._optim_step(
-                    self.params, grads, self.opt_state
-                )
-                jax.block_until_ready(self.params)
-                logits = None  # cadence accuracies are skipped this mode
-                stages = {
-                    "forward_backward": t_fb - t0,
-                    "optim": get_time() - t_fb,
-                }
-            else:
+            with self.epoch_span(epoch):
+                with self.stage("epoch_key", epoch):
+                    ekey = jax.random.fold_in(key, epoch)
                 stats_dev = None
-                if self._train_step_stats is not None:
-                    # NTS_NUMERICS=1: the stats-fused variant — same
-                    # math, one extra all-scalar output (fetched every
-                    # NTS_NUMERICS_EVERY epochs in maybe_emit_numerics)
-                    (self.params, self.opt_state, loss, logits,
-                     stats_dev) = self._train_step_stats(
-                        self.params, self.opt_state, self.compute_graph,
-                        self.feature, self.label, self._train_mask01, ekey,
-                    )
-                else:
-                    self.params, self.opt_state, loss, logits = (
-                        self._train_step(
-                            self.params, self.opt_state, self.compute_graph,
-                            self.feature, self.label, self._train_mask01,
-                            ekey,
+                if split_step:
+                    with self.stage("forward_backward", epoch) as s_fb:
+                        loss, grads = self._fwd_bwd(
+                            self.params, self.compute_graph, self.feature,
+                            self.label, self._train_mask01, ekey,
                         )
-                    )
-                t_disp = get_time()
-                jax.block_until_ready(loss)
-                stages = {
-                    "step_dispatch": t_disp - t0,
-                    "step_device": get_time() - t_disp,
-                }
-                self.maybe_emit_numerics(epoch, stats_dev)
-            # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire here,
-            # before the loss reaches history, guards, or a checkpoint
-            loss = fault_point("epoch_loss", epoch=epoch, value=loss)
-            dt = get_time() - t0
-            self.epoch_times.append(dt)
-            self.loss_history.append(float(loss))
-            self.emit_epoch(epoch, dt, loss, stages=stages)
-            cadence = (
-                epoch % max(1, cfg.epochs // 20) == 0
-                or epoch == cfg.epochs - 1
-            )
-            if cadence and logits is not None:
-                # per-epoch Train/Eval/Test accuracy from the training
-                # forward's logits, the reference's oracle cadence
-                # (Test(0/1/2) each epoch on X[last], GCN_CPU.hpp:241-248).
-                # NOTE these cadence logits are TRAIN-mode (dropout active),
-                # so mid-training Eval/Test lines are biased low relative to
-                # the final eval-mode accuracies below — same bias as the
-                # reference's cadence, kept for log parity.
-                h = np.asarray(logits)
-                self.test(h, 0)
-                self.test(h, 1)
-                self.test(h, 2)
-            if cadence:
-                # the loss line must not depend on logits: NTS_TRACE_STEP=1
-                # skips cadence accuracies but still has loss every epoch
-                log.info("Epoch %d loss %f", epoch, float(loss))
-            self.ckpt_epoch_end(epoch)
+                        jax.block_until_ready(loss)
+                    with self.stage("optim", epoch) as s_opt:
+                        self.params, self.opt_state = self._optim_step(
+                            self.params, grads, self.opt_state
+                        )
+                        jax.block_until_ready(self.params)
+                    logits = None  # cadence accuracies are skipped this mode
+                    t0 = s_fb.t0
+                    stages = {
+                        "forward_backward": s_fb.dur_s, "optim": s_opt.dur_s,
+                    }
+                else:
+                    with self.stage("step_dispatch", epoch) as s_disp:
+                        if self._train_step_stats is not None:
+                            # NTS_NUMERICS=1: the stats-fused variant — same
+                            # math, one extra all-scalar output (fetched
+                            # every NTS_NUMERICS_EVERY epochs in
+                            # maybe_emit_numerics)
+                            (self.params, self.opt_state, loss, logits,
+                             stats_dev) = self._train_step_stats(
+                                self.params, self.opt_state,
+                                self.compute_graph, self.feature, self.label,
+                                self._train_mask01, ekey,
+                            )
+                        else:
+                            self.params, self.opt_state, loss, logits = (
+                                self._train_step(
+                                    self.params, self.opt_state,
+                                    self.compute_graph, self.feature,
+                                    self.label, self._train_mask01, ekey,
+                                )
+                            )
+                    with self.stage("step_device", epoch) as s_dev:
+                        jax.block_until_ready(loss)
+                    t0 = s_disp.t0
+                    stages = {
+                        "step_dispatch": s_disp.dur_s,
+                        "step_device": s_dev.dur_s,
+                    }
+                with self.stage("loss_fetch", epoch):
+                    self.maybe_emit_numerics(epoch, stats_dev)
+                    # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire
+                    # here, before the loss reaches history, guards, or a
+                    # checkpoint
+                    loss = fault_point("epoch_loss", epoch=epoch, value=loss)
+                    dt = get_time() - t0
+                    self.epoch_times.append(dt)
+                    self.loss_history.append(float(loss))
+                with self.stage("epoch_emit", epoch):
+                    self.emit_epoch(epoch, dt, loss, stages=stages)
+                cadence = (
+                    epoch % max(1, cfg.epochs // 20) == 0
+                    or epoch == cfg.epochs - 1
+                )
+                if cadence and logits is not None:
+                    # per-epoch Train/Eval/Test accuracy from the training
+                    # forward's logits, the reference's oracle cadence
+                    # (Test(0/1/2) each epoch on X[last],
+                    # GCN_CPU.hpp:241-248). NOTE these cadence logits are
+                    # TRAIN-mode (dropout active), so mid-training Eval/Test
+                    # lines are biased low relative to the final eval-mode
+                    # accuracies below — same bias as the reference's
+                    # cadence, kept for log parity.
+                    with self.stage("logits_copy", epoch):
+                        h = np.asarray(logits)
+                    with self.stage("host_accuracy", epoch):
+                        self.test(h, 0)
+                        self.test(h, 1)
+                        self.test(h, 2)
+                if cadence:
+                    # the loss line must not depend on logits:
+                    # NTS_TRACE_STEP=1 skips cadence accuracies but still
+                    # has loss every epoch
+                    log.info("Epoch %d loss %f", epoch, float(loss))
+                with self.stage("ckpt_epoch_end", epoch):
+                    self.ckpt_epoch_end(epoch)
         if trace_cm is not None:
             trace_cm.__exit__(None, None, None)
-        self.ckpt_final()
+        with self.stage("ckpt_final"):
+            self.ckpt_final()
 
         if os.environ.get("NTS_DEBUGINFO", "0") == "1":
             log.info("%s", self.debug_info(key))
@@ -524,14 +558,19 @@ class FullBatchTrainer(ToolkitBase):
         if self.skip_final_eval(loss):
             accs = {"train": None, "eval": None, "test": None}
         else:
-            logits = np.asarray(
-                self._eval_logits(self.params, self.compute_graph, self.feature, key)
-            )
-            accs = {
-                "train": self.test(logits, 0),
-                "eval": self.test(logits, 1),
-                "test": self.test(logits, 2),
-            }
+            with self.stage("final_eval"):
+                with self.stage("eval_forward"):
+                    logits_dev = self._eval_logits(
+                        self.params, self.compute_graph, self.feature, key
+                    )
+                with self.stage("logits_copy"):
+                    logits = np.asarray(logits_dev)
+                with self.stage("host_accuracy"):
+                    accs = {
+                        "train": self.test(logits, 0),
+                        "eval": self.test(logits, 1),
+                        "test": self.test(logits, 2),
+                    }
         avg = self.avg_epoch_time()
         log.info(
             "--avg epoch time %.4f s (first %.2f s incl. compile)",

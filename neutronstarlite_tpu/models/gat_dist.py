@@ -462,53 +462,78 @@ class DistGATTrainer(ToolkitBase):
 
     def run(self) -> Dict[str, Any]:
         cfg = self.cfg
-        key = jax.random.PRNGKey(self.seed + 1)
-        if self.mg is not None:
-            log.info(
-                "GNNmini::Engine[Dist.%s.GATimpl] %d partitions (Mb=%d El=%d), [%d] Epochs",
-                jax.default_backend(), self.mg.partitions,
-                self.mg.mb,
-                self.mg.el,
-                cfg.epochs,
-            )
-        else:  # KERNEL:fused_edge — the ring fused tables replace the mirrors
-            log.info(
-                "GNNmini::Engine[Dist.%s.GATimpl] %d partitions "
-                "(fused_edge ring, vp=%d), [%d] Epochs",
-                jax.default_backend(), self.dist.partitions, self.dist.vp, cfg.epochs,
-            )
-        start_epoch = self.ckpt_begin()
+        self.open_run_root()
+        with self.stage("run_begin"):
+            key = jax.random.PRNGKey(self.seed + 1)
+            if self.mg is not None:
+                log.info(
+                    "GNNmini::Engine[Dist.%s.GATimpl] %d partitions (Mb=%d El=%d), [%d] Epochs",
+                    jax.default_backend(), self.mg.partitions,
+                    self.mg.mb,
+                    self.mg.el,
+                    cfg.epochs,
+                )
+            else:  # KERNEL:fused_edge — the ring fused tables replace the mirrors
+                log.info(
+                    "GNNmini::Engine[Dist.%s.GATimpl] %d partitions "
+                    "(fused_edge ring, vp=%d), [%d] Epochs",
+                    jax.default_backend(), self.dist.partitions, self.dist.vp, cfg.epochs,
+                )
+        with self.stage("ckpt_begin"):
+            start_epoch = self.ckpt_begin()
         loss = None
         for epoch in range(start_epoch, cfg.epochs):
-            ekey = jax.random.fold_in(key, epoch)
-            t0 = get_time()
-            self.params, self.opt_state, loss, _ = self._train_step(
-                self.params,
-                self.opt_state,
-                self.tables,
-                self.feature_p,
-                self.label_p,
-                self.train01_p,
-                ekey,
-            )
-            jax.block_until_ready(loss)
-            # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire here,
-            # before the loss reaches history, guards, or a checkpoint
-            loss = fault_point("epoch_loss", epoch=epoch, value=loss)
-            dt = get_time() - t0
-            self.epoch_times.append(dt)
-            self.loss_history.append(float(loss))
-            self.record_epoch_wire(
-                epoch, dt, loss, self._wire_bytes_fwd_per_epoch,
-                self._wire_exchanges_per_epoch,
-            )
-            self.ckpt_epoch_end(epoch)
-            if epoch % max(1, cfg.epochs // 20) == 0 or epoch == cfg.epochs - 1:
-                log.info("Epoch %d loss %f", epoch, float(loss))
+            with self.epoch_span(epoch):
+                with self.stage("epoch_key", epoch):
+                    ekey = jax.random.fold_in(key, epoch)
+                with self.stage("step_dispatch", epoch) as s_disp:
+                    self.params, self.opt_state, loss, _ = self._train_step(
+                        self.params,
+                        self.opt_state,
+                        self.tables,
+                        self.feature_p,
+                        self.label_p,
+                        self.train01_p,
+                        ekey,
+                    )
+                with self.stage("step_device", epoch) as s_dev:
+                    jax.block_until_ready(loss)
+                with self.stage("loss_fetch", epoch):
+                    # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire
+                    # here, before the loss reaches history, guards, or a
+                    # checkpoint
+                    loss = fault_point("epoch_loss", epoch=epoch, value=loss)
+                    dt = get_time() - s_disp.t0
+                    self.epoch_times.append(dt)
+                    self.loss_history.append(float(loss))
+                with self.stage("epoch_emit", epoch):
+                    self.record_epoch_wire(
+                        epoch, dt, loss, self._wire_bytes_fwd_per_epoch,
+                        self._wire_exchanges_per_epoch,
+                        stages={
+                            "step_dispatch": s_disp.dur_s,
+                            "step_device": s_dev.dur_s,
+                        },
+                    )
+                    if (
+                        epoch % max(1, cfg.epochs // 20) == 0
+                        or epoch == cfg.epochs - 1
+                    ):
+                        log.info("Epoch %d loss %f", epoch, float(loss))
+                with self.stage("ckpt_epoch_end", epoch):
+                    self.ckpt_epoch_end(epoch)
 
-        self.ckpt_final()
-        logits_p = self._eval_logits(self.params, self.tables, self.feature_p, key)
-        accs = self.dist_eval_report(logits_p, self.label_p, self.mask_p, self.valid_p)
+        with self.stage("ckpt_final"):
+            self.ckpt_final()
+        with self.stage("final_eval"):
+            with self.stage("eval_forward"):
+                logits_p = self._eval_logits(
+                    self.params, self.tables, self.feature_p, key
+                )
+            with self.stage("host_accuracy"):
+                accs = self.dist_eval_report(
+                    logits_p, self.label_p, self.mask_p, self.valid_p
+                )
         avg = self.avg_epoch_time()
         log.info("--avg epoch time %.4f s", avg)
         import os as _os

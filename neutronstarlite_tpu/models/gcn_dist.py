@@ -352,24 +352,29 @@ class DistGCNTrainer(ToolkitBase):
                     "PALLAS:1 ignored: DIST_PATH:ring_blocked runs the "
                     "XLA blocked step tables (no Mosaic ring executor)"
                 )
-            self.dist = DistGraph.build(
-                self.host_graph, P, edge_chunk=cfg.edge_chunk or None
-            )
+            with self.timers.phase("dist_graph_build"):
+                self.dist = DistGraph.build(
+                    self.host_graph, P, edge_chunk=cfg.edge_chunk or None
+                )
             stats = self.dist.padding_stats()
             # KERNEL_TILE caps the per-gather table exactly as on the
             # all_gather blocked path; the shared default keeps whole-
             # shard-ish tiles (one definition with comm_bench)
             vt = default_ring_vt(self.dist.vp, cfg.kernel_tile)
-            pair = RingBlockedPair.build(self.dist, vt=vt)
-            est = pair.padding_stats(stats["real_edges"])
-            if self.mesh is None:
-                self.blocks = pair
-            elif self.partitioner is not None:
-                # 2D mesh: tables shard over the vertex axis, replicated
-                # across the feature axis (every slab runs the schedule)
-                self.blocks = pair.shard(self.mesh, axis=pmod.VERTEX_AXIS)
-            else:
-                self.blocks = pair.shard(self.mesh)
+            with self.timers.phase("dist_tables_build"):
+                pair = RingBlockedPair.build(self.dist, vt=vt)
+                est = pair.padding_stats(stats["real_edges"])
+                if self.mesh is None:
+                    self.blocks = pair
+                elif self.partitioner is not None:
+                    # 2D mesh: tables shard over the vertex axis,
+                    # replicated across the feature axis (every slab runs
+                    # the schedule)
+                    self.blocks = pair.shard(
+                        self.mesh, axis=pmod.VERTEX_AXIS
+                    )
+                else:
+                    self.blocks = pair.shard(self.mesh)
             self.wire_dtype = resolve_wire_dtype(cfg.wire_dtype)
             log.info(
                 "DIST_PATH ring_blocked%s: double-buffered ring (vt=%d, "
@@ -385,8 +390,10 @@ class DistGCNTrainer(ToolkitBase):
         elif layer_kind == "mirror":
             from neutronstarlite_tpu.parallel.mirror import SplitMirror
 
-            self.dist = SplitMirror.build(self.host_graph, P)
-            self.blocks = self.dist.shard(self.mesh)
+            with self.timers.phase("dist_graph_build"):
+                self.dist = SplitMirror.build(self.host_graph, P)
+            with self.timers.phase("dist_tables_build"):
+                self.blocks = self.dist.shard(self.mesh)
             log.info(
                 "COMM_LAYER mirror (split): remote-only all_to_all "
                 "(mb=%d remote slots/pair vs vp=%d shard rows; Er=%d "
@@ -394,9 +401,10 @@ class DistGCNTrainer(ToolkitBase):
                 self.dist.mb, self.dist.vp, self.dist.er, self.dist.el,
             )
         else:
-            self.dist = DistGraph.build(
-                self.host_graph, P, edge_chunk=cfg.edge_chunk or None
-            )
+            with self.timers.phase("dist_graph_build"):
+                self.dist = DistGraph.build(
+                    self.host_graph, P, edge_chunk=cfg.edge_chunk or None
+                )
             stats = self.dist.padding_stats()
             step_stats = self.dist.step_padding_stats()
             log.info(
@@ -433,11 +441,12 @@ class DistGCNTrainer(ToolkitBase):
                         DistBspPair,
                     )
 
-                    pair = DistBspPair.build(
-                        self.dist, vt=cfg.kernel_tile or DEFAULT_VT
-                    )
-                    est = pair.padding_stats(stats["real_edges"])
-                    self.blocks = pair.shard(self.mesh)
+                    with self.timers.phase("dist_tables_build"):
+                        pair = DistBspPair.build(
+                            self.dist, vt=cfg.kernel_tile or DEFAULT_VT
+                        )
+                        est = pair.padding_stats(stats["real_edges"])
+                        self.blocks = pair.shard(self.mesh)
                     log.info(
                         "OPTIM_KERNEL: dist bsp aggregation (all_gather + "
                         "[P, %d, %d, %d] stacked blocks, vt=%d, "
@@ -463,11 +472,12 @@ class DistGCNTrainer(ToolkitBase):
                         DistBlockedEllPair,
                     )
 
-                    pair = DistBlockedEllPair.build(
-                        self.dist, vt=cfg.kernel_tile
-                    )
-                    est = pair.padding_stats(stats["real_edges"])
-                    self.blocks = pair.shard(self.mesh)
+                    with self.timers.phase("dist_tables_build"):
+                        pair = DistBlockedEllPair.build(
+                            self.dist, vt=cfg.kernel_tile
+                        )
+                        est = pair.padding_stats(stats["real_edges"])
+                        self.blocks = pair.shard(self.mesh)
                     log.info(
                         "OPTIM_KERNEL: dist blocked aggregation "
                         "(all_gather + [P, %d-tile] stacked tables, "
@@ -485,9 +495,10 @@ class DistGCNTrainer(ToolkitBase):
                     # experiments (it cannot lower to Mosaic; refused on
                     # TPU above)
                     kern = "pallas" if cfg.pallas_kernel else "xla"
-                    pair = DistEllPair.build(self.dist, kernel=kern)
-                    est = pair.padding_stats(stats["real_edges"])
-                    self.blocks = pair.shard(self.mesh)
+                    with self.timers.phase("dist_tables_build"):
+                        pair = DistEllPair.build(self.dist, kernel=kern)
+                        est = pair.padding_stats(stats["real_edges"])
+                        self.blocks = pair.shard(self.mesh)
                     log.info(
                         "OPTIM_KERNEL: dist gather-only aggregation "
                         "(all_gather + %d-level ELL tables, %s per-shard "
@@ -496,7 +507,8 @@ class DistGCNTrainer(ToolkitBase):
                         est["fwd_waste_ratio"], est["bwd_waste_ratio"],
                     )
             else:
-                self.blocks = self.dist.shard(self.mesh)
+                with self.timers.phase("dist_tables_build"):
+                    self.blocks = self.dist.shard(self.mesh)
 
         # live wire counters (obs): per-epoch forward exchange volume at
         # the actual per-layer exchange widths, priced by the SAME row
@@ -606,38 +618,49 @@ class DistGCNTrainer(ToolkitBase):
         else:
             vsh = vsh1 = rsh = None
             put = lambda a, s: jax.tree.map(jnp.asarray, a)  # noqa: E731
-        feat = pad(self.datum.feature)
-        if self.partitioner is not None:
-            # zero-pad the feature width to a Pf multiple (sim too, so
-            # the twin trains the exact arrays the collective path ships)
-            feat = pmod.pad_feature_cols(feat, self.partitioner.pf)
-        self.feature_p = put(feat, vsh)
-        self.label_p = put(pad(self.datum.label.astype(np.int32)), vsh1)
-        self.valid_p = put(self.dist.valid_mask(), vsh1)
-        train01 = (self.datum.mask == 0).astype(np.float32)
-        self.train01_p = put(pad(train01), vsh1)
-        # pad fill -1 so padding rows match no mask split in the eval counters
-        self.mask_p = put(pad(self.datum.mask, fill=-1), vsh1)
+        with self.timers.phase("datum_upload"):
+            feat = pad(self.datum.feature)
+            if self.partitioner is not None:
+                # zero-pad the feature width to a Pf multiple (sim too, so
+                # the twin trains the exact arrays the collective path
+                # ships)
+                feat = pmod.pad_feature_cols(feat, self.partitioner.pf)
+            self.feature_p = put(feat, vsh)
+            self.label_p = put(pad(self.datum.label.astype(np.int32)), vsh1)
+            self.valid_p = put(self.dist.valid_mask(), vsh1)
+            train01 = (self.datum.mask == 0).astype(np.float32)
+            self.train01_p = put(pad(train01), vsh1)
+            # pad fill -1 so padding rows match no mask split in the eval
+            # counters
+            self.mask_p = put(pad(self.datum.mask, fill=-1), vsh1)
 
-        key = jax.random.PRNGKey(self.seed)
-        params = self.init_model_params(key)
-        if self.partitioner is not None:
-            # zero rows meet the zero feature columns: the padded model
-            # trains the unpadded math bit-for-bit on real coordinates
-            params = pmod.pad_params_feature_dim(
-                params, type(self).mesh_pad_keys, sizes[0],
-                self.partitioner.pf,
+        with self.timers.phase("params_init"):
+            key = jax.random.PRNGKey(self.seed)
+            params = self.init_model_params(key)
+            if self.partitioner is not None:
+                # zero rows meet the zero feature columns: the padded model
+                # trains the unpadded math bit-for-bit on real coordinates
+                params = pmod.pad_params_feature_dim(
+                    params, type(self).mesh_pad_keys, sizes[0],
+                    self.partitioner.pf,
+                )
+            self.params = put(params, rsh)
+            self.adam_cfg = AdamConfig(
+                alpha=cfg.learn_rate,
+                weight_decay=cfg.weight_decay,
+                decay_rate=cfg.decay_rate,
+                decay_epoch=cfg.decay_epoch,
             )
-        self.params = put(params, rsh)
-        self.adam_cfg = AdamConfig(
-            alpha=cfg.learn_rate,
-            weight_decay=cfg.weight_decay,
-            decay_rate=cfg.decay_rate,
-            decay_epoch=cfg.decay_epoch,
-        )
-        self.opt_state = put(adam_init(self.params), rsh)
+            self.opt_state = put(adam_init(self.params), rsh)
+        with self.timers.phase("step_build"):
+            self._build_steps()
 
+    def _build_steps(self) -> None:
+        """The jit wrappers run() and the tools dispatch, and the step
+        programs' cost records (build_model's ``step_build`` phase)."""
+        cfg, layer_kind = self.cfg, self.comm_layer
         mesh, dist, blocks = self.mesh, self.dist, self.blocks
+        P = dist.partitions
         drop_rate = cfg.drop_rate
         masked_nll = self.masked_nll_loss
         adam_cfg = self.adam_cfg
@@ -1008,15 +1031,77 @@ class DistGCNTrainer(ToolkitBase):
         entries.append((None, "logits", "logits", logits))
         return entries
 
+    def _emit_dist_epoch(self, epoch: int, dt: float, loss,
+                         dispatch_s: float, device_s: float) -> None:
+        """The ``epoch_emit`` stage of the dist loop: the epoch event with
+        its wire counters and guards, then the ring-step, straggler and
+        liveness records. Runs after the epoch's loss is in the history
+        and BEFORE ckpt_epoch_end."""
+        self.record_epoch_wire(
+            epoch, dt, loss, self._wire_bytes_fwd_per_epoch,
+            self._wire_exchanges_per_epoch,
+            stages={"step_dispatch": dispatch_s, "step_device": device_s},
+        )
+        if self._ring_plan is not None:
+            # typed per-rotation-hop records: bytes shipped per device
+            # this epoch (all layer exchanges, forward direction) and
+            # the static skip verdict. Per-hop wall time is not
+            # separable inside one XLA program — ``seconds`` is null
+            # here; parallel/comm_bench.py measures it standalone and
+            # the NTS_OVERLAP_PROBE run attributes hidden-vs-exposed
+            # hop time. ``epoch_span`` joins each hop to its epoch's
+            # span on the causal timeline.
+            espan = self._last_epoch_span
+            for hop in self._ring_plan["steps"]:
+                self.metrics.event(
+                    "ring_step", epoch=epoch, step=hop["step"],
+                    bytes=int(hop["bytes"]), skipped=hop["skipped"],
+                    seconds=None,
+                    slab_cols=int(hop["slab_cols"]),
+                    epoch_span=espan.span_id if espan else None,
+                )
+        part_seconds = None
+        if self._liveness is not None or self._straggler is not None:
+            # per-partition step attribution: the sim twin executes
+            # every partition inside ONE fused XLA step, so each
+            # partition's share of the epoch is the epoch time itself
+            # plus whatever its ``partition_step`` fault point added
+            # (slow_rank's injected sleep lands HERE, in exactly one
+            # partition's measured time — the straggler chaos oracle)
+            alive_now = elastic.alive_partitions(self.dist.partitions)
+            part_seconds = {}
+            for p in alive_now:
+                tp = get_time()
+                fault_point("partition_step", epoch=epoch, partition=p)
+                part_seconds[p] = dt + (get_time() - tp)
+            if self._straggler is not None:
+                self._straggler.observe_epoch(epoch, part_seconds)
+        if self._liveness is not None:
+            # per-partition heartbeats into the obs stream + miss-K /
+            # collective-timeout detection — after the epoch's
+            # telemetry (the loss is visible in the stream first),
+            # BEFORE ckpt_epoch_end: the raise lands at the rollback
+            # boundary the supervisor replans at, and the detection
+            # epoch never persists
+            self._liveness.epoch_end(
+                epoch,
+                alive=elastic.alive_partitions(self.dist.partitions),
+                step_seconds=device_s,
+                partition_seconds=part_seconds,
+            )
+
     def run(self) -> Dict[str, Any]:
         cfg = self.cfg
-        key = jax.random.PRNGKey(self.seed + 1)
-        log.info(
-            "GNNmini::Engine[Dist.%s.GCNimpl] %d partitions, [%d] Epochs",
-            jax.default_backend(), self.dist.partitions,
-            cfg.epochs,
-        )
-        start_epoch = self.ckpt_begin()
+        self.open_run_root()
+        with self.stage("run_begin"):
+            key = jax.random.PRNGKey(self.seed + 1)
+            log.info(
+                "GNNmini::Engine[Dist.%s.GCNimpl] %d partitions, [%d] Epochs",
+                jax.default_backend(), self.dist.partitions,
+                cfg.epochs,
+            )
+        with self.stage("ckpt_begin"):
+            start_epoch = self.ckpt_begin()
         loss = None
         # rank-health monitor (resilience/elastic): one per attempt — a
         # supervised retry (or a replan, which renumbers the survivors)
@@ -1055,108 +1140,72 @@ class DistGCNTrainer(ToolkitBase):
             if epoch == trace_from and epoch < cfg.epochs:
                 trace_cm = maybe_trace(type(self).__name__)
                 trace_cm.__enter__()
-            ekey = jax.random.fold_in(key, epoch)
-            t0 = get_time()
-            step_args = (
-                self.params,
-                self.opt_state,
-                self.blocks,
-                self.feature_p,
-                self.label_p,
-                self.train01_p,
-                self.valid_p,
-                ekey,
-            )
-            stats_dev = None
-            if self._train_step_stats is not None:
-                # NTS_NUMERICS=1: same math, one extra all-scalar output
-                (self.params, self.opt_state, loss, _,
-                 stats_dev) = self._train_step_stats(*step_args)
-            else:
-                self.params, self.opt_state, loss, _ = self._train_step(
-                    *step_args
-                )
-            t_disp = get_time()
-            jax.block_until_ready(loss)
-            t_wait = get_time()
-            self.maybe_emit_numerics(epoch, stats_dev)
-            if self._quant_probe_fn is not None:
-                self._emit_quant_probe(epoch)
-            # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire here,
-            # before the loss reaches history, guards, or a checkpoint
-            loss = fault_point("epoch_loss", epoch=epoch, value=loss)
-            dt = get_time() - t0
-            self.epoch_times.append(dt)
-            self.loss_history.append(float(loss))
-            self.record_epoch_wire(
-                epoch, dt, loss, self._wire_bytes_fwd_per_epoch,
-                self._wire_exchanges_per_epoch,
-                stages={
-                    "step_dispatch": t_disp - t0,
-                    "step_device": t_wait - t_disp,
-                },
-            )
-            if self._ring_plan is not None:
-                # typed per-rotation-hop records: bytes shipped per device
-                # this epoch (all layer exchanges, forward direction) and
-                # the static skip verdict. Per-hop wall time is not
-                # separable inside one XLA program — ``seconds`` is null
-                # here; parallel/comm_bench.py measures it standalone and
-                # the NTS_OVERLAP_PROBE run attributes hidden-vs-exposed
-                # hop time. ``epoch_span`` joins each hop to its epoch's
-                # span on the causal timeline.
-                espan = self._last_epoch_span
-                for hop in self._ring_plan["steps"]:
-                    self.metrics.event(
-                        "ring_step", epoch=epoch, step=hop["step"],
-                        bytes=int(hop["bytes"]), skipped=hop["skipped"],
-                        seconds=None,
-                        slab_cols=int(hop["slab_cols"]),
-                        epoch_span=espan.span_id if espan else None,
+            with self.epoch_span(epoch):
+                with self.stage("epoch_key", epoch):
+                    ekey = jax.random.fold_in(key, epoch)
+                with self.stage("step_dispatch", epoch) as s_disp:
+                    step_args = (
+                        self.params,
+                        self.opt_state,
+                        self.blocks,
+                        self.feature_p,
+                        self.label_p,
+                        self.train01_p,
+                        self.valid_p,
+                        ekey,
                     )
-            part_seconds = None
-            if self._liveness is not None or self._straggler is not None:
-                # per-partition step attribution: the sim twin executes
-                # every partition inside ONE fused XLA step, so each
-                # partition's share of the epoch is the epoch time itself
-                # plus whatever its ``partition_step`` fault point added
-                # (slow_rank's injected sleep lands HERE, in exactly one
-                # partition's measured time — the straggler chaos oracle)
-                alive_now = elastic.alive_partitions(self.dist.partitions)
-                part_seconds = {}
-                for p in alive_now:
-                    tp = get_time()
-                    fault_point("partition_step", epoch=epoch, partition=p)
-                    part_seconds[p] = dt + (get_time() - tp)
-                if self._straggler is not None:
-                    self._straggler.observe_epoch(epoch, part_seconds)
-            if self._liveness is not None:
-                # per-partition heartbeats into the obs stream + miss-K /
-                # collective-timeout detection — after the epoch's
-                # telemetry (the loss is visible in the stream first),
-                # BEFORE ckpt_epoch_end: the raise lands at the rollback
-                # boundary the supervisor replans at, and the detection
-                # epoch never persists
-                self._liveness.epoch_end(
-                    epoch,
-                    alive=elastic.alive_partitions(self.dist.partitions),
-                    step_seconds=t_wait - t_disp,
-                    partition_seconds=part_seconds,
-                )
-            self.ckpt_epoch_end(epoch)
-            if epoch % max(1, cfg.epochs // 20) == 0 or epoch == cfg.epochs - 1:
-                log.info("Epoch %d loss %f", epoch, float(loss))
+                    stats_dev = None
+                    if self._train_step_stats is not None:
+                        # NTS_NUMERICS=1: same math, one extra all-scalar
+                        # output
+                        (self.params, self.opt_state, loss, _,
+                         stats_dev) = self._train_step_stats(*step_args)
+                    else:
+                        self.params, self.opt_state, loss, _ = (
+                            self._train_step(*step_args)
+                        )
+                with self.stage("step_device", epoch) as s_dev:
+                    jax.block_until_ready(loss)
+                with self.stage("loss_fetch", epoch):
+                    self.maybe_emit_numerics(epoch, stats_dev)
+                    if self._quant_probe_fn is not None:
+                        self._emit_quant_probe(epoch)
+                    # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire
+                    # here, before the loss reaches history, guards, or a
+                    # checkpoint
+                    loss = fault_point("epoch_loss", epoch=epoch, value=loss)
+                    dt = get_time() - s_disp.t0
+                    self.epoch_times.append(dt)
+                    self.loss_history.append(float(loss))
+                with self.stage("epoch_emit", epoch):
+                    self._emit_dist_epoch(
+                        epoch, dt, loss, s_disp.dur_s, s_dev.dur_s
+                    )
+                    if (
+                        epoch % max(1, cfg.epochs // 20) == 0
+                        or epoch == cfg.epochs - 1
+                    ):
+                        log.info("Epoch %d loss %f", epoch, float(loss))
+                with self.stage("ckpt_epoch_end", epoch):
+                    self.ckpt_epoch_end(epoch)
 
         if trace_cm is not None:
             trace_cm.__exit__(None, None, None)
-        self.ckpt_final()
+        with self.stage("ckpt_final"):
+            self.ckpt_final()
         if self.skip_final_eval(loss):  # benchmark mode, ToolkitBase docs
             accs = {"train": None, "eval": None, "test": None}
         else:
-            logits_p = self._eval_logits(
-                self.params, self.blocks, self.feature_p, self.valid_p, key
-            )
-            accs = self.dist_eval_report(logits_p, self.label_p, self.mask_p, self.valid_p)
+            with self.stage("final_eval"):
+                with self.stage("eval_forward"):
+                    logits_p = self._eval_logits(
+                        self.params, self.blocks, self.feature_p,
+                        self.valid_p, key,
+                    )
+                with self.stage("host_accuracy"):
+                    accs = self.dist_eval_report(
+                        logits_p, self.label_p, self.mask_p, self.valid_p
+                    )
         avg = self.avg_epoch_time()
         log.info("--avg epoch time %.4f s", avg)
         import os as _os

@@ -307,57 +307,83 @@ class DistGCNCacheTrainer(ToolkitBase):
 
     def run(self) -> Dict[str, Any]:
         cfg = self.cfg
-        key = jax.random.PRNGKey(self.seed + 1)
-        use_hist = self._use_hist
-        log.info(
-            "GNNmini::Engine[Dist.%s.GCNimpl.cached] %d partitions "
-            "(mc=%d mf=%d el=%d), refresh=%d, [%d] Epochs",
-            jax.default_backend(), self.cmg.partitions, self.cmg.mc, self.cmg.mf, self.cmg.el,
-            self.cache_refresh, cfg.epochs,
-        )
-        start_epoch = self.ckpt_begin()
+        self.open_run_root()
+        with self.stage("run_begin"):
+            key = jax.random.PRNGKey(self.seed + 1)
+            use_hist = self._use_hist
+            log.info(
+                "GNNmini::Engine[Dist.%s.GCNimpl.cached] %d partitions "
+                "(mc=%d mf=%d el=%d), refresh=%d, [%d] Epochs",
+                jax.default_backend(), self.cmg.partitions, self.cmg.mc, self.cmg.mf, self.cmg.el,
+                self.cache_refresh, cfg.epochs,
+            )
+        with self.stage("ckpt_begin"):
+            start_epoch = self.ckpt_begin()
         loss = None
         for epoch in range(start_epoch, cfg.epochs):
-            ekey = jax.random.fold_in(key, epoch)
-            t0 = get_time()
-            refresh = use_hist and (
-                epoch % self.cache_refresh == 0 or self.caches is None
-            )
-            if refresh:
-                self.caches = self._refresh_caches(
-                    self.params, self.tables, self.cache_tables,
-                    self.feature_p, self.valid_p, self.cached0, ekey,
-                )
-            use_cached = use_hist and self.caches is not None
-            step = self._step_cached if use_cached else self._step_fresh
-            self.params, self.opt_state, loss = step(
-                self.params, self.opt_state, self.tables, self.cache_tables,
-                self.feature_p, self.label_p, self.train01_p, self.valid_p,
-                self.cached0, self.caches if use_cached else None, ekey,
-            )
-            jax.block_until_ready(loss)
-            # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire here,
-            # before the loss reaches history, guards, or a checkpoint
-            loss = fault_point("epoch_loss", epoch=epoch, value=loss)
-            dt = get_time() - t0
-            self.epoch_times.append(dt)
-            self.loss_history.append(float(loss))
-            self.record_epoch_wire(
-                epoch, dt, loss,
-                self._epoch_wire_bytes_fwd(use_cached, refresh),
-                len(self._wire_widths) * (2 if refresh else 1),
-                cache_refresh=bool(refresh),
-            )
-            self.ckpt_epoch_end(epoch)
-            if epoch % max(1, cfg.epochs // 20) == 0 or epoch == cfg.epochs - 1:
-                log.info("Epoch %d loss %f", epoch, float(loss))
+            with self.epoch_span(epoch):
+                with self.stage("epoch_key", epoch):
+                    ekey = jax.random.fold_in(key, epoch)
+                with self.stage("step_dispatch", epoch) as s_disp:
+                    refresh = use_hist and (
+                        epoch % self.cache_refresh == 0 or self.caches is None
+                    )
+                    if refresh:
+                        self.caches = self._refresh_caches(
+                            self.params, self.tables, self.cache_tables,
+                            self.feature_p, self.valid_p, self.cached0, ekey,
+                        )
+                    use_cached = use_hist and self.caches is not None
+                    step = (
+                        self._step_cached if use_cached else self._step_fresh
+                    )
+                    self.params, self.opt_state, loss = step(
+                        self.params, self.opt_state, self.tables,
+                        self.cache_tables, self.feature_p, self.label_p,
+                        self.train01_p, self.valid_p, self.cached0,
+                        self.caches if use_cached else None, ekey,
+                    )
+                with self.stage("step_device", epoch) as s_dev:
+                    jax.block_until_ready(loss)
+                with self.stage("loss_fetch", epoch):
+                    # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire
+                    # here, before the loss reaches history, guards, or a
+                    # checkpoint
+                    loss = fault_point("epoch_loss", epoch=epoch, value=loss)
+                    dt = get_time() - s_disp.t0
+                    self.epoch_times.append(dt)
+                    self.loss_history.append(float(loss))
+                with self.stage("epoch_emit", epoch):
+                    self.record_epoch_wire(
+                        epoch, dt, loss,
+                        self._epoch_wire_bytes_fwd(use_cached, refresh),
+                        len(self._wire_widths) * (2 if refresh else 1),
+                        cache_refresh=bool(refresh),
+                        stages={
+                            "step_dispatch": s_disp.dur_s,
+                            "step_device": s_dev.dur_s,
+                        },
+                    )
+                    if (
+                        epoch % max(1, cfg.epochs // 20) == 0
+                        or epoch == cfg.epochs - 1
+                    ):
+                        log.info("Epoch %d loss %f", epoch, float(loss))
+                with self.stage("ckpt_epoch_end", epoch):
+                    self.ckpt_epoch_end(epoch)
 
-        self.ckpt_final()
-        logits_p = self._eval_logits(
-            self.params, self.tables, self.cache_tables, self.feature_p,
-            self.valid_p, self.cached0, key,
-        )
-        accs = self.dist_eval_report(logits_p, self.label_p, self.mask_p, self.valid_p)
+        with self.stage("ckpt_final"):
+            self.ckpt_final()
+        with self.stage("final_eval"):
+            with self.stage("eval_forward"):
+                logits_p = self._eval_logits(
+                    self.params, self.tables, self.cache_tables,
+                    self.feature_p, self.valid_p, self.cached0, key,
+                )
+            with self.stage("host_accuracy"):
+                accs = self.dist_eval_report(
+                    logits_p, self.label_p, self.mask_p, self.valid_p
+                )
         avg = self.avg_epoch_time()
         log.info("--avg epoch time %.4f s", avg)
         result = {

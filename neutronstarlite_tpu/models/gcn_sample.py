@@ -343,63 +343,72 @@ class GCNSampleTrainer(ToolkitBase):
         self._last_sample_s = sample_s
 
     def _after_epoch(self, epoch: int, t0: float, losses, stats_dev,
-                     dispatch_s: float, device_s: float) -> None:
-        """Shared epoch-end bookkeeping for the per-batch and fused
-        (one-dispatch) loops: numerics/chaos hooks, loss history, the
+                     dispatch_s: float, device_s: float) -> List[float]:
+        """Shared epoch-end stages for the per-batch and fused
+        (one-dispatch) loops; returns the epoch's per-batch losses as host
+        floats. ``loss_fetch``: numerics/chaos hooks, the losses' fetch
+        (``losses`` is the scan's device vector when fused, a list of
+        device scalars otherwise), loss history. ``epoch_emit``: the
         sampling counters — ``sample.h2d_bytes`` priced per batch on the
         sync path (the wire_accounting formula), producer-MEASURED when
-        pipelined/device, and exactly 0 when fused — the typed
-        epoch/epoch_scan records, and the epoch-boundary checkpoint
-        hook (for fused runs this IS the scan boundary)."""
+        pipelined/device, and exactly 0 when fused — and the typed
+        epoch/epoch_scan records. ``ckpt_epoch_end``: the epoch-boundary
+        checkpoint hook (for fused runs this IS the scan boundary)."""
         cfg = self.cfg
         fused = self._fused is not None
-        self.maybe_emit_numerics(epoch, stats_dev)
-        # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire
-        # here, before the loss reaches history or the guards
-        epoch_loss = fault_point(
-            "epoch_loss", epoch=epoch,
-            value=float(np.mean([float(l) for l in losses])),
-        )
-        dt = get_time() - t0
-        self.epoch_times.append(dt)
-        self.loss_history.append(float(epoch_loss))
-        # fused gathers features on-device from the resident slab: the
-        # wire gather AND the per-batch H2D payload are structurally 0
-        gather_bytes = (
-            0 if fused else len(losses) * self._gather_bytes_per_batch
-        )
-        if self.sample_mode in ("sync", "fused"):
-            # pipelined/device measure this per staged batch in the
-            # producer (sample/pipeline.py); sync prices the formula
-            h2d = 0 if fused else len(losses) * self._sample_payload_bytes
-            self.metrics.counter_add("sample.h2d_bytes", h2d)
-        self.metrics.counter_add("sample.batches", len(losses))
-        self.metrics.counter_add(
-            "wire.feature_gather_bytes", gather_bytes
-        )
-        if fused:
-            self.metrics.event(
-                "epoch_scan", bucket=int(self._fused.n_batches),
-                batches=len(losses), dispatches=1, h2d_bytes=0,
-                epoch=int(epoch), seconds=round(dt, 6),
+        with self.stage("loss_fetch", epoch):
+            self.maybe_emit_numerics(epoch, stats_dev)
+            losses = [
+                float(l) for l in (np.asarray(losses) if fused else losses)
+            ]
+            # chaos hook (NTS_FAULT_SPEC): nan_loss/stall/crash fire
+            # here, before the loss reaches history or the guards
+            epoch_loss = fault_point(
+                "epoch_loss", epoch=epoch, value=float(np.mean(losses)),
             )
-        # the host-observable epoch split (the fullbatch/gcn_dist
-        # attribution from PR 5, completing the trainer family):
-        # sample_wait = host time blocked on sampling (serial
-        # sample time when sync; residual pipeline stall when
-        # pipelined; 0 when fused — sampling is inside the scan),
-        # step_dispatch = time issuing async device steps (ONE scan
-        # dispatch when fused), step_device = the epoch-end wait for
-        # the device to drain
-        stages = {
-            "sample_wait": self._last_sample_s,
-            "step_dispatch": dispatch_s,
-            "step_device": device_s,
-        }
-        self.emit_epoch(
-            epoch, dt, self.loss_history[-1], stages=stages,
-            batches=len(losses), feature_gather_bytes=gather_bytes,
-        )
+            dt = get_time() - t0
+            self.epoch_times.append(dt)
+            self.loss_history.append(float(epoch_loss))
+        with self.stage("epoch_emit", epoch):
+            # fused gathers features on-device from the resident slab: the
+            # wire gather AND the per-batch H2D payload are structurally 0
+            gather_bytes = (
+                0 if fused else len(losses) * self._gather_bytes_per_batch
+            )
+            if self.sample_mode in ("sync", "fused"):
+                # pipelined/device measure this per staged batch in the
+                # producer (sample/pipeline.py); sync prices the formula
+                h2d = (
+                    0 if fused else len(losses) * self._sample_payload_bytes
+                )
+                self.metrics.counter_add("sample.h2d_bytes", h2d)
+            self.metrics.counter_add("sample.batches", len(losses))
+            self.metrics.counter_add(
+                "wire.feature_gather_bytes", gather_bytes
+            )
+            if fused:
+                self.metrics.event(
+                    "epoch_scan", bucket=int(self._fused.n_batches),
+                    batches=len(losses), dispatches=1, h2d_bytes=0,
+                    epoch=int(epoch), seconds=round(dt, 6),
+                )
+            # the host-observable epoch split: step_dispatch = the live
+            # span over issuing the epoch's device steps (ONE scan dispatch
+            # when fused; the whole batch loop otherwise, the host's
+            # sampling included), sample_wait = the part of it blocked on
+            # sampling (serial sample time when sync; residual pipeline
+            # stall when pipelined; 0 when fused — sampling is inside the
+            # scan), step_device = the epoch-end wait for the device to
+            # drain
+            stages = {
+                "sample_wait": self._last_sample_s,
+                "step_dispatch": dispatch_s,
+                "step_device": device_s,
+            }
+            self.emit_epoch(
+                epoch, dt, self.loss_history[-1], stages=stages,
+                batches=len(losses), feature_gather_bytes=gather_bytes,
+            )
         if (
             epoch % max(1, cfg.epochs // 10) == 0
             or epoch == cfg.epochs - 1
@@ -408,22 +417,27 @@ class GCNSampleTrainer(ToolkitBase):
                 "Epoch %d loss %f (%d batches)",
                 epoch, self.loss_history[-1], len(losses),
             )
-        self.ckpt_epoch_end(epoch)
+        with self.stage("ckpt_epoch_end", epoch):
+            self.ckpt_epoch_end(epoch)
+        return losses
 
     def run(self) -> Dict[str, Any]:
         cfg = self.cfg
-        key = jax.random.PRNGKey(self.seed + 1)
-        log.info(
-            "GNNmini::Engine[%s.GCNSampleimpl] B=%d fanout=%s [%d] Epochs "
-            "(%d sample workers, sampling %s)",
-            jax.default_backend(), cfg.batch_size, self.fanouts, cfg.epochs, self.sample_workers,
-            self.sample_mode,
-        )
+        self.open_run_root()
+        with self.stage("run_begin"):
+            key = jax.random.PRNGKey(self.seed + 1)
+            log.info(
+                "GNNmini::Engine[%s.GCNSampleimpl] B=%d fanout=%s [%d] Epochs "
+                "(%d sample workers, sampling %s)",
+                jax.default_backend(), cfg.batch_size, self.fanouts, cfg.epochs, self.sample_workers,
+                self.sample_mode,
+            )
         loss = None
         # checkpoint/resume parity with the full-batch and dist trainers
         # (base.ckpt_* hooks) — also what hands trained weights to serve/:
         # the inference engine restores exactly these step dirs
-        start_epoch = self.ckpt_begin()
+        with self.stage("ckpt_begin"):
+            start_epoch = self.ckpt_begin()
         pipeline = None
         if self.sample_mode in ("pipelined", "device") \
                 and start_epoch < cfg.epochs:
@@ -437,74 +451,75 @@ class GCNSampleTrainer(ToolkitBase):
             )
         try:
             for epoch in range(start_epoch, cfg.epochs):
-                t0 = get_time()
-                losses = []
-                dispatch_s = 0.0
-                stats_dev = None
-                if self._fused is not None:
-                    # ONE dispatch: shuffle + per-batch draw/remap/
-                    # gather/train all inside the scanned program; the
-                    # epoch-end block is the only sync point and the
-                    # ckpt/numerics hooks below run at this scan boundary
-                    td = get_time()
-                    (self.params, self.opt_state, losses_dev,
-                     stats_dev) = self._fused.run_epoch(
-                        self.params, self.opt_state, self.feature,
-                        self.label, epoch, key,
-                    )
-                    dispatch_s = get_time() - td
-                    t_wait = get_time()
-                    jax.block_until_ready(losses_dev)
-                    device_s = get_time() - t_wait
-                    losses = list(np.asarray(losses_dev))
-                    loss = losses[-1]
-                    self._last_sample_s = 0.0
-                    self._after_epoch(epoch, t0, losses, stats_dev,
-                                      dispatch_s, device_s)
-                    continue
-                for bi, (nodes, hops, seed_mask, seeds) in enumerate(
-                    self._epoch_batches(epoch, pipeline)
-                ):
-                    bkey = jax.random.fold_in(key, epoch * 100003 + bi)
-                    td = get_time()
-                    if self._train_batch_stats is not None:
-                        # NTS_NUMERICS=1: same math, one extra scalar
-                        # output — the epoch keeps the LAST batch's stats
-                        (self.params, self.opt_state, loss,
-                         stats_dev) = self._train_batch_stats(
-                            self.params, self.opt_state, self.feature,
-                            self.label, nodes, hops, seed_mask, seeds, bkey,
-                        )
-                    else:
-                        self.params, self.opt_state, loss = (
-                            self._train_batch(
+                with self.epoch_span(epoch) as espan:
+                    stats_dev = None
+                    if self._fused is not None:
+                        # ONE dispatch: shuffle + per-batch draw/remap/
+                        # gather/train all inside the scanned program; the
+                        # epoch-end block is the only sync point and the
+                        # ckpt/numerics hooks run at this scan boundary
+                        with self.stage("step_dispatch", epoch) as s_disp:
+                            (self.params, self.opt_state, losses,
+                             stats_dev) = self._fused.run_epoch(
                                 self.params, self.opt_state, self.feature,
-                                self.label, nodes, hops, seed_mask, seeds,
-                                bkey,
+                                self.label, epoch, key,
                             )
-                        )
-                    dispatch_s += get_time() - td
-                    losses.append(loss)
-                t_wait = get_time()
-                jax.block_until_ready(loss)
-                device_s = get_time() - t_wait
-                self._after_epoch(epoch, t0, losses, stats_dev,
-                                  dispatch_s, device_s)
+                        with self.stage("step_device", epoch) as s_dev:
+                            jax.block_until_ready(losses)
+                        self._last_sample_s = 0.0
+                    else:
+                        losses = []
+                        with self.stage("step_dispatch", epoch) as s_disp:
+                            for bi, (nodes, hops, seed_mask, seeds) in \
+                                    enumerate(
+                                        self._epoch_batches(epoch, pipeline)
+                                    ):
+                                bkey = jax.random.fold_in(
+                                    key, epoch * 100003 + bi
+                                )
+                                if self._train_batch_stats is not None:
+                                    # NTS_NUMERICS=1: same math, one extra
+                                    # scalar output — the epoch keeps the
+                                    # LAST batch's stats
+                                    (self.params, self.opt_state, loss,
+                                     stats_dev) = self._train_batch_stats(
+                                        self.params, self.opt_state,
+                                        self.feature, self.label, nodes,
+                                        hops, seed_mask, seeds, bkey,
+                                    )
+                                else:
+                                    self.params, self.opt_state, loss = (
+                                        self._train_batch(
+                                            self.params, self.opt_state,
+                                            self.feature, self.label, nodes,
+                                            hops, seed_mask, seeds, bkey,
+                                        )
+                                    )
+                                losses.append(loss)
+                        with self.stage("step_device", epoch) as s_dev:
+                            jax.block_until_ready(loss)
+                    losses = self._after_epoch(
+                        epoch, espan.t0, losses, stats_dev,
+                        s_disp.dur_s, s_dev.dur_s,
+                    )
+                    loss = losses[-1] if losses else loss
         finally:
             # drain on ANY exit — early stop, guard trip, worker fault —
             # so no producer thread outlives its epoch loop
             if pipeline is not None:
                 pipeline.close()
-        self.ckpt_final()
+        with self.stage("ckpt_final"):
+            self.ckpt_final()
         # training is done: release the sampling worker pool (a sweep that
         # builds many trainers must not accumulate forked children; a
         # second run() on the same trainer samples inline, same batches)
         self.par_sampler.close()
-        accs = {
-            "train": self._evaluate(0, key),
-            "eval": self._evaluate(1, key),
-            "test": self._evaluate(2, key),
-        }
+        with self.stage("final_eval"):
+            accs = {
+                "train": self._evaluate(0, key),
+                "eval": self._evaluate(1, key),
+                "test": self._evaluate(2, key),
+            }
         avg = float(np.mean(self.epoch_times[1:])) if len(self.epoch_times) > 1 else 0.0
         log.info("--avg epoch time %.4f s", avg)
         # loss is None when a checkpoint restore resumed at/after cfg.epochs

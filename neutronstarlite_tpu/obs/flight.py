@@ -41,7 +41,6 @@ import os
 import signal
 import threading
 import time
-import weakref
 from collections import deque
 from typing import Any, Dict, List, Optional
 
@@ -110,6 +109,10 @@ class FlightRecorder:
     def record(self, rec: Dict[str, Any]) -> None:
         """One deque append; deque(maxlen=...) is thread-safe and O(1)."""
         self._ring.append(rec)
+
+    def records(self, event_kind: str) -> List[Dict[str, Any]]:
+        """The ring's records of one event kind, oldest first."""
+        return [r for r in list(self._ring) if r.get("event") == event_kind]
 
     def pin(self, key: str, rec: Dict[str, Any]) -> None:
         """Keep ``rec`` as the last-known record under ``key`` (latest
@@ -205,19 +208,22 @@ class FlightRecorder:
         return path
 
 
-# ---- SIGUSR2: operator-initiated snapshot of the live ring -----------------
+# ---- the process's newest ring: SIGUSR2 snapshots and in-process readers ---
 
-_active: Optional["weakref.ref[FlightRecorder]"] = None
+# held strongly (2,048 records at most): a reader that comes after the
+# trainer is gone (the benchmark's per-layer readers) still finds the run
+_active: Optional[FlightRecorder] = None
 _signal_installed = False
 
 
 def set_active(recorder: Optional[FlightRecorder]) -> None:
-    """Install ``recorder`` as the process's SIGUSR2 dump target (latest
-    registry wins — the events.set_sink convention) and hook the signal
-    once. Signal installation only works on the main thread; elsewhere
-    the recorder still rings and record-triggers still dump."""
+    """Install ``recorder`` as the process's SIGUSR2 dump target and the
+    ring ``recent_records`` reads (latest registry wins — the
+    events.set_sink convention) and hook the signal once. Signal
+    installation only works on the main thread; elsewhere the recorder
+    still rings and record-triggers still dump."""
     global _active, _signal_installed
-    _active = weakref.ref(recorder) if recorder is not None else None
+    _active = recorder
     if _signal_installed or recorder is None:
         return
     if not hasattr(signal, "SIGUSR2"):  # non-POSIX
@@ -229,7 +235,13 @@ def set_active(recorder: Optional[FlightRecorder]) -> None:
         pass
 
 
+def recent_records(event_kind: str) -> Optional[List[Dict[str, Any]]]:
+    """Records of one event kind (``"span"``, ``"epoch"``) that the
+    newest registry's ring still holds, oldest first; None where the
+    process has no ring (``NTS_FLIGHT=0``, or no registry yet)."""
+    return _active.records(event_kind) if _active is not None else None
+
+
 def _on_sigusr2(_signum, _frame) -> None:
-    rec = _active() if _active is not None else None
-    if rec is not None:
-        rec.dump("sigusr2")
+    if _active is not None:
+        _active.dump("sigusr2")

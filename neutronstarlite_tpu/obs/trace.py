@@ -18,17 +18,20 @@ Clock model (documented in docs/OBSERVABILITY.md):
   which for spans is immediately after the span ends — so per process the
   mono->wall offset is recoverable as ``median(ts - (t0 + dur_s))`` over
   its spans (tools/trace_timeline does exactly this);
-- cross-rank skew is corrected AFTER that mapping by matching per-epoch
-  spans (every rank ends epoch e at the same collective barrier), again
-  in tools/trace_timeline — the tracer itself never talks to other ranks.
+- cross-rank skew is corrected AFTER that mapping by matching the ends
+  of the per-epoch ``step_device`` spans (every rank leaves epoch e's
+  device wait at the same collective barrier), again in
+  tools/trace_timeline — the tracer itself never talks to other ranks.
 
-When ``NTS_PROFILE_DIR`` is set, LIVE spans (context-manager or
-``begin()``/``end()``) additionally open a ``jax.profiler.TraceAnnotation``
-so the same names appear inside the device trace — host causality and
-device ops land in one Perfetto view. Spans emitted retroactively via
-``complete()`` (epoch/stage/request/queue) already happened and cannot
-annotate; device-side epoch attribution comes from the profiler's own
-kernel events.
+Every LIVE span (context-manager or ``begin()``/``end()``) is also a
+``jax.profiler.TraceAnnotation`` named ``nts:<name>``, with the span's
+integer attributes (``epoch``) as event stats. Outside a profiler session
+an annotation costs well under a microsecond; inside one — whoever started
+it: ``NTS_PROFILE_DIR``, a test, an operator attaching a capture — the
+program's spans sit on the profiler's clock beside the device's
+operations. The prefix tells them from the runtime's own TraceMe events
+(``PjitFunction(train_step)``). Spans emitted retroactively via
+``complete()`` (request/queue) already happened and cannot annotate.
 
 Usage::
 
@@ -38,7 +41,7 @@ Usage::
     h = tracer.begin("run", cat="lifecycle")   # long-lived root
     ...
     tracer.end(h, outcome="ok")
-    tracer.complete("epoch", dur_s=dt, epoch=3)  # retroactive: ended just now
+    tracer.complete("queue", dur_s=dt, req_id=7)  # retroactive: ended just now
 
 Tracing is on whenever the registry exists (spans are ordinary events; a
 sink-less registry keeps them in memory only); ``NTS_TRACE=0`` disables
@@ -49,6 +52,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import Any, Optional
@@ -58,8 +62,26 @@ from neutronstarlite_tpu.utils.logging import get_logger, process_index
 log = get_logger("obs")
 
 
+ANNOTATION_PREFIX = "nts:"
+
+
 def _now() -> float:
     return time.perf_counter()
+
+
+def _annotation(name: str, attrs: dict):
+    """An entered ``TraceAnnotation`` for a live span, or None in a process
+    that never imported jax (the hub, the dashboard: no profiler session
+    can be active there, and obs/ stays importable without jax)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(
+        ANNOTATION_PREFIX + name,
+        **{k: v for k, v in attrs.items() if type(v) is int},
+    )
+    ann.__enter__()
+    return ann
 
 
 # One process-wide id source: several tracers can share one registry (the
@@ -145,8 +167,8 @@ class TraceContext:
 class SpanHandle:
     """One open (or retroactively completed) span."""
 
-    __slots__ = ("name", "cat", "span_id", "parent_id", "t0", "attrs",
-                 "trace_id", "_ann", "_ann_tid")
+    __slots__ = ("name", "cat", "span_id", "parent_id", "t0", "dur_s",
+                 "attrs", "trace_id", "_ann", "_ann_tid")
 
     def __init__(self, name: str, cat: str, span_id: str,
                  parent_id: Optional[str], t0: float, attrs: dict,
@@ -156,6 +178,7 @@ class SpanHandle:
         self.span_id = span_id
         self.parent_id = parent_id
         self.t0 = t0
+        self.dur_s: Optional[float] = None  # set when a live span ends
         self.attrs = attrs
         self.trace_id = trace_id  # per-span override (remote parenting)
         self._ann = None  # the open jax.profiler annotation, if any
@@ -190,6 +213,11 @@ class Tracer:
 
     def _next_id(self) -> str:
         return f"s{next(_SPAN_IDS):x}"
+
+    def current(self) -> Optional[SpanHandle]:
+        """The innermost span this thread has open, or None."""
+        st = self._stack()
+        return st[-1] if st else None
 
     def _resolve_parent(self, parent) -> tuple:
         """(parent_id, inherited trace override). A child belongs to its
@@ -279,19 +307,8 @@ class Tracer:
         )
         if self.enabled:
             self._stack().append(h)
-            if os.environ.get("NTS_PROFILE_DIR"):
-                # live spans also open a jax.profiler TraceAnnotation so
-                # the same name lands inside the device trace (spans
-                # emitted retroactively via complete() cannot — they
-                # already happened)
-                try:
-                    from neutronstarlite_tpu.utils.profiling import annotate
-
-                    h._ann = annotate(name)
-                    h._ann.__enter__()
-                    h._ann_tid = threading.get_ident()
-                except Exception:
-                    h._ann = None
+            h._ann = _annotation(name, attrs)
+            h._ann_tid = threading.get_ident()
         return h
 
     def end(self, h: SpanHandle, **attrs: Any) -> None:
@@ -302,10 +319,7 @@ class Tracer:
             # TraceAnnotation scopes are thread-local: only the opening
             # thread may close one (cross-thread ends just drop it)
             if h._ann_tid == threading.get_ident():
-                try:
-                    h._ann.__exit__(None, None, None)
-                except Exception:
-                    pass
+                h._ann.__exit__(None, None, None)
             h._ann = None
         st = self._stack()
         if h in st:
@@ -314,13 +328,14 @@ class Tracer:
                 st.pop()
             if st:
                 st.pop()
-        self._emit(h, _now() - h.t0, attrs)
+        h.dur_s = _now() - h.t0
+        self._emit(h, h.dur_s, attrs)
 
     # ---- context-manager form -------------------------------------------
     def span(self, name: str, cat: str = "host", parent=None,
              ctx: Optional[TraceContext] = None, **attrs: Any):
         """``with tracer.span("sample", cat="serve") as h:`` — nests via the
-        thread-local stack, annotates the device trace when profiling."""
+        thread-local stack, annotates any active profiler session."""
         return _SpanCtx(self, name, cat, parent, ctx, attrs)
 
     # ---- retroactive completion -----------------------------------------
@@ -329,7 +344,7 @@ class Tracer:
                  ctx: Optional[TraceContext] = None,
                  span_id: Optional[str] = None, **attrs: Any) -> SpanHandle:
         """Emit a span that ALREADY happened: callers that timed an interval
-        themselves (the epoch loop's ``get_time()`` bracketing) hand over
+        themselves (the serve batcher's request marks) hand over
         the duration; ``end`` defaults to now, ``t0`` to ``end - dur_s``.
         ``ctx`` joins the span into a remote caller's trace; ``span_id``
         uses a pre-allocated id (``next_id()``) so children emitted earlier

@@ -8,21 +8,23 @@ process-local monotonic clocks. This CLI reconstructs one coherent view:
    sits just past ``t0 + dur_s`` — ``median(ts - (t0 + dur_s))`` over a
    stream's spans is that process's monotonic->wall offset (robust to a
    few delayed writes; see docs/OBSERVABILITY.md for the caveats).
-2. **epoch-marker rank alignment**: every rank ends epoch *e* at the same
-   collective barrier, so per-epoch spans are cross-rank fence posts —
-   each rank is shifted by the median difference of its epoch-end times
-   against the reference (lowest) rank. Wall clocks that agree within the
-   epoch time are left essentially untouched; skewed hosts snap into
-   place.
+2. **epoch-marker rank alignment**: every rank leaves epoch *e*'s device
+   wait at the same collective barrier, so the ends of the per-epoch
+   ``step_device`` spans are cross-rank fence posts (the ``epoch`` span
+   itself ends later, after a rank-0 checkpoint write; a stream without
+   stage spans falls back to its ends) — each rank is shifted by the
+   median difference of its barrier times against the reference (lowest)
+   rank. Wall clocks that agree within the epoch time are left
+   essentially untouched; skewed hosts snap into place.
 3. **Chrome trace-event export** (``--chrome out.json``): complete ("X")
    events per span (pid = rank, tid = host thread), instant events for
    fault / recovery / shed / rank_loss / replan / tune_trial /
    tune_decision records — loadable in
-   Perfetto or chrome://tracing. When the run also wrote a ``jax.profiler`` trace
-   (``NTS_PROFILE_DIR``), the host spans were emitted as
-   ``TraceAnnotation``s inside it too, so the device-op view carries the
-   same names — open both in one Perfetto window to line host causality
-   up with kernel truth.
+   Perfetto or chrome://tracing. When a ``jax.profiler`` session was
+   active during the run (``NTS_PROFILE_DIR`` starts one), the live spans
+   are ``TraceAnnotation``s named ``nts:<name>`` inside its trace too —
+   open both in one Perfetto window to line host causality up with
+   kernel truth.
 4. **Derived metrics** printed as the timeline report (and rendered by
    tools/metrics_report as its "span timeline" block):
    - ring overlap efficiency — the NTS_OVERLAP_PROBE verdict (hop time
@@ -159,16 +161,17 @@ class Stream:
         return span["t0"] + self.offset + self.align
 
     def epoch_ends(self) -> Dict[int, float]:
-        """{epoch: aligned wall end} from this stream's epoch spans."""
-        out: Dict[int, float] = {}
+        """{epoch: aligned wall time of its barrier}: the end of the
+        epoch's ``step_device`` span, else of its ``epoch`` span."""
+        ends: Dict[str, Dict[int, float]] = {"step_device": {}, "epoch": {}}
         if self.offset is None:
-            return out
+            return {}
         for s in spans_of(self.events):
-            if s["name"] == "epoch" and isinstance(s.get("epoch"), int):
-                out[s["epoch"]] = (
+            if s["name"] in ends and isinstance(s.get("epoch"), int):
+                ends[s["name"]][s["epoch"]] = (
                     s["t0"] + s["dur_s"] + self.offset + self.align
                 )
-        return out
+        return {**ends["epoch"], **ends["step_device"]}
 
 
 def align_streams(streams: List["Stream"]) -> None:
@@ -1075,9 +1078,9 @@ def main(argv=None) -> int:
     if "chrome" in out:
         print(
             f"chrome trace: {out['chrome']['events']} events -> "
-            f"{out['chrome']['path']} (open in Perfetto; with "
-            f"NTS_PROFILE_DIR the same span names appear inside the "
-            f"jax.profiler device trace)"
+            f"{out['chrome']['path']} (open in Perfetto; a jax.profiler "
+            f"trace of the run holds the live spans as nts:<name> "
+            f"beside the device's operations)"
         )
     return 0
 

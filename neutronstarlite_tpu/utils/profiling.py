@@ -1,4 +1,4 @@
-"""Profiling hooks: jax.profiler traces + named phase annotations.
+"""Profiling hooks: jax.profiler traces of the steady-state epochs.
 
 Reference: manual MPI_Wtime accumulators and the DEBUGINFO() report
 (core/graph.hpp:210-222, toolkits/GCN.hpp:308-353). On TPU the host-side
@@ -10,7 +10,7 @@ truth this module wraps ``jax.profiler`` so a run can emit a real trace
 from __future__ import annotations
 
 import os
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from typing import Iterator, Optional
 
 import jax
@@ -32,11 +32,3 @@ def maybe_trace(label: str = "nts") -> Iterator[None]:
     os.makedirs(path, exist_ok=True)
     with jax.profiler.trace(path):
         yield
-
-
-def annotate(name: str):
-    """Named scope visible in profiler traces (device-side annotation)."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return nullcontext()

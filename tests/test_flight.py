@@ -124,6 +124,30 @@ def test_sigusr2_snapshots_the_live_ring():
     assert any(e["event"] == "epoch" for e in events)
 
 
+def test_recent_records_outlive_the_registry_that_wrote_them(monkeypatch):
+    """The in-process reader (the benchmark's per-layer readers come after
+    the trainer is gone): the newest ring is held by the module, filtered
+    by event kind, oldest first; NTS_FLIGHT=0 leaves nothing to read."""
+    import gc
+
+    reg = registry.MetricsRegistry("run-r", algorithm="A", fingerprint="f")
+    for i in range(3):
+        reg.event("span", name="epoch", cat="epoch", span_id=f"s{i}",
+                  trace_id="t", parent_id=None, t0=float(i), dur_s=0.5)
+        reg.event("epoch", epoch=i, seconds=0.5, loss=1.0)
+    del reg
+    gc.collect()
+    spans = flight.recent_records("span")
+    assert [s["span_id"] for s in spans] == ["s0", "s1", "s2"]
+    assert [e["epoch"] for e in flight.recent_records("epoch")] == [0, 1, 2]
+    assert flight.recent_records("no_such_kind") == []
+    # a newer registry takes over; with the ring off there is none to read
+    registry.MetricsRegistry("run-n", algorithm="A", fingerprint="f")
+    assert flight.recent_records("span") == []
+    flight.set_active(None)
+    assert flight.recent_records("span") is None
+
+
 # ---- e2e: injected fault -> dump -> timeline reconstruction ----------------
 
 

@@ -628,3 +628,263 @@ def test_spans_emitted_under_remote_ctx_carry_link_stamps(tmp_path):
     assert inner["parent_id"] == handler["span_id"]
     assert inner["graph_seq"] == 5 and inner["model_seq"] == 2
     assert schema.validate_stream(evs) == len(evs)
+
+
+# ---- live spans of the funnel and the run loops (ISSUE 24) ------------------
+
+# the stage vocabulary of an epoch, in the order a loop opens them
+# (docs/OBSERVABILITY.md, Tracing); logits_copy / host_accuracy only where
+# the loop has a cadence accuracy, epoch_key only where the host derives a
+# key per epoch (the sampled scan takes the run's key whole)
+EPOCH_STAGES = {
+    "fullbatch": ["epoch_key", "step_dispatch", "step_device", "loss_fetch",
+                  "epoch_emit", "logits_copy", "host_accuracy",
+                  "ckpt_epoch_end"],
+    "dist": ["epoch_key", "step_dispatch", "step_device", "loss_fetch",
+             "epoch_emit", "ckpt_epoch_end"],
+    "sampled": ["step_dispatch", "step_device", "loss_fetch", "epoch_emit",
+                "ckpt_epoch_end"],
+}
+FUNNEL_PHASES = {
+    "fullbatch": {"tune_resolve", "tables_build", "params_init",
+                  "datum_upload", "step_build"},
+    "dist": {"tune_resolve", "dist_graph_build", "dist_tables_build",
+             "datum_upload", "params_init", "step_build"},
+    "sampled": {"tune_resolve"},  # its datum uploads at the first step
+}
+
+
+def _loop_trainer(family, epochs=2):
+    """A trainer of one run-loop family through from_arrays(host_graph=),
+    large enough that an epoch is milliseconds, not span overhead."""
+    from neutronstarlite_tpu.graph.dataset import GNNDatum
+    from neutronstarlite_tpu.graph.storage import build_graph
+    from neutronstarlite_tpu.graph.synthetic import planted_partition_graph
+    from neutronstarlite_tpu.models.base import get_algorithm
+    from neutronstarlite_tpu.utils.config import InputInfo
+
+    v_num, f, classes = 6000, 192, 4
+    src, dst, feature, label = planted_partition_graph(
+        v_num, classes, avg_degree=10, feature_size=f, seed=3
+    )
+    datum = GNNDatum(
+        feature=feature, label=label.astype(np.int32),
+        mask=(np.arange(v_num) % 3).astype(np.int32),
+    )
+    cfg = InputInfo()
+    cfg.vertices = v_num
+    cfg.layer_string = f"{f}-64-{classes}"
+    cfg.epochs = epochs
+    cfg.learn_rate = 0.01
+    cfg.decay_epoch = -1
+    cfg.drop_rate = 0.1
+    if family == "fullbatch":
+        cfg.algorithm = "GCNCPU"
+        cfg.optim_kernel = True
+    elif family == "dist":
+        cfg.algorithm = "GCNDIST"
+        cfg.partitions = 2
+        cfg.optim_kernel = True
+    else:
+        cfg.algorithm = "GCNSAMPLESINGLE"
+        cfg.fanout_string = "4-4"
+        cfg.batch_size = 128
+        cfg.sample_pipeline = "fused"
+    host_graph = build_graph(src, dst, v_num, weight="gcn_norm")
+    return get_algorithm(cfg.algorithm).from_arrays(
+        cfg, None, None, datum, seed=0, host_graph=host_graph
+    )
+
+
+def _spans(trainer):
+    return trainer.metrics.flight.records("span")
+
+
+@pytest.fixture(scope="module", params=sorted(EPOCH_STAGES))
+def loop_run(request):
+    """One two-epoch run() per run-loop family: the trainer, the stages its
+    loop passed to emit_epoch, and the span / epoch records of that run
+    (snapshots: a later test runs the trainer again)."""
+    trainer = _loop_trainer(request.param)
+    passed = []
+    inner = trainer.emit_epoch
+
+    def spy(epoch, seconds, loss=None, stages=None, **extra):
+        passed.append(dict(stages))
+        return inner(epoch, seconds, loss, stages=stages, **extra)
+
+    trainer.emit_epoch = spy
+    trainer.run()
+    return {
+        "family": request.param, "trainer": trainer, "passed": list(passed),
+        "spans": _spans(trainer),
+        "events": trainer.metrics.flight.records("epoch"),
+    }
+
+
+def test_epoch_spans_hold_the_stage_vocabulary(loop_run):
+    family, spans = loop_run["family"], loop_run["spans"]
+    epochs = [s for s in spans if s["name"] == "epoch"]
+    assert [e["epoch"] for e in epochs] == [0, 1]
+    run = next(s for s in spans if s["name"] == "run")
+    for e in epochs:
+        assert e["cat"] == "epoch" and e["parent_id"] == run["span_id"]
+        kids = sorted(
+            (s for s in spans if s["parent_id"] == e["span_id"]
+             and s["cat"] == "stage"),
+            key=lambda s: s["t0"],
+        )
+        assert [k["name"] for k in kids] == EPOCH_STAGES[family]
+        assert all(k["epoch"] == e["epoch"] for k in kids)
+        end = e["t0"]
+        for k in kids:  # inside the parent, in order, never overlapping
+            assert k["t0"] >= end - 1e-9
+            end = k["t0"] + k["dur_s"]
+        assert end <= e["t0"] + e["dur_s"] + 1e-9
+        covered = sum(k["dur_s"] for k in kids)
+        assert covered == pytest.approx(e["dur_s"], rel=0.05)
+
+
+def test_stages_passed_to_emit_epoch_are_the_live_spans(loop_run):
+    passed, spans, events = (loop_run[k] for k in ("passed", "spans", "events"))
+    for name in ("step_dispatch", "step_device"):
+        live = [s["dur_s"] for s in spans if s["name"] == name]
+        assert [p[name] for p in passed] == pytest.approx(live, abs=1e-12)
+        assert [e["stages"][name] for e in events] == pytest.approx(live)
+    # the epoch event keeps its meaning (start to the loss fetch); the span
+    # covers the whole iteration
+    for e, s in zip(events, (s for s in spans if s["name"] == "epoch")):
+        assert e["seconds"] <= s["dur_s"]
+        assert e["seconds"] >= sum(
+            e["stages"][n] for n in ("step_dispatch", "step_device")
+        )
+
+
+def test_funnel_phases_are_children_of_run(loop_run):
+    family, trainer, spans = (loop_run[k] for k in ("family", "trainer", "spans"))
+    run = next(s for s in spans if s["name"] == "run")
+    first_epoch = next(s for s in spans if s["name"] == "epoch")
+    funnel = [
+        s for s in spans if s["cat"] == "phase"
+        and s["t0"] + s["dur_s"] <= first_epoch["t0"]
+    ]
+    assert {s["name"] for s in funnel} == FUNNEL_PHASES[family]
+    assert all(s["parent_id"] == run["span_id"] for s in funnel)
+    # host_graph= was handed in: the funnel built none
+    assert "host_graph_build" not in {s["name"] for s in spans}
+    phases = trainer.run_summary_record["phases"]
+    assert FUNNEL_PHASES[family] <= set(phases)
+    if family == "sampled":  # lazy: first touched inside the first dispatch
+        assert phases["datum_upload"]["count"] == 2
+
+
+def test_run_level_stages_and_a_second_run(loop_run):
+    family, trainer, spans = (loop_run[k] for k in ("family", "trainer", "spans"))
+    run = next(s for s in spans if s["name"] == "run")
+    around = [s["name"] for s in spans
+              if s["parent_id"] == run["span_id"] and s["cat"] == "stage"]
+    assert around == ["run_begin", "ckpt_begin", "ckpt_final", "final_eval",
+                      "finalize_metrics"]
+    final = next(s for s in spans if s["name"] == "final_eval")
+    kids = [s["name"] for s in spans if s["parent_id"] == final["span_id"]]
+    assert kids == {
+        "fullbatch": ["eval_forward", "logits_copy", "host_accuracy"],
+        "dist": ["eval_forward", "host_accuracy"], "sampled": [],
+    }[family]
+    # a second run() on the finished trainer (the benchmark's window after
+    # its warm-up) reopens the root: its epochs are emitted and parented
+    n_before = len(_spans(trainer))
+    trainer.run()
+    later = _spans(trainer)[n_before:]
+    roots = [s for s in later if s["name"] == "run"]
+    assert len(roots) == 1 and trainer._run_span is None
+    epochs = [s for s in later if s["name"] == "epoch"]
+    assert [e["epoch"] for e in epochs] == [0, 1]
+    assert all(e["parent_id"] == roots[0]["span_id"] for e in epochs)
+
+
+def _nts_events(trace_dir):
+    """[(name, start_ns, end_ns, stats)] of the ``nts:`` host events."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"
+    ))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("nts:"):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+@pytest.mark.parametrize("nts_trace", ["1", "0"])
+def test_live_spans_annotate_a_profiler_session_they_did_not_start(
+    tmp_path, monkeypatch, nts_trace
+):
+    """The test starts jax.profiler itself (as the benchmark and an
+    operator's capture do); NTS_PROFILE_DIR is not set. Live spans land in
+    the trace as nts:<name> with the epoch stat; NTS_TRACE=0 leaves no
+    record and no event."""
+    import jax
+
+    from tests.test_models import _planted_cfg, _planted_data
+    from neutronstarlite_tpu.models.gcn import GCNTrainer
+
+    monkeypatch.delenv("NTS_PROFILE_DIR", raising=False)
+    monkeypatch.setenv("NTS_TRACE", nts_trace)
+    src, dst, datum = _planted_data(seed=2)
+    trainer = GCNTrainer.from_arrays(_planted_cfg(epochs=2), src, dst, datum)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        trainer.run()
+    finally:
+        jax.profiler.stop_trace()
+    events = _nts_events(tmp_path)
+    if nts_trace == "0":
+        assert events == [] and _spans(trainer) == []
+        assert len(trainer.metrics.flight.records("epoch")) == 2
+        return
+    epochs = sorted(e for e in events if e[0] == "nts:epoch")
+    assert [e[3]["epoch"] for e in epochs] == [0, 1]
+    for name, lo, hi, stats in epochs:
+        inside = [e for e in events
+                  if e[0] in ("nts:step_dispatch", "nts:step_device")
+                  and lo <= e[1] and e[2] <= hi]
+        assert [e[0] for e in sorted(inside, key=lambda e: e[1])] == [
+            "nts:step_dispatch", "nts:step_device"]
+        assert all(e[3]["epoch"] == stats["epoch"] for e in inside)
+    # record names stay bare; only the annotation carries the prefix
+    assert {"epoch", "step_dispatch", "final_eval"} <= {
+        s["name"] for s in _spans(trainer)}
+    assert "nts:final_eval" in {e[0] for e in events}
+
+
+def test_alignment_anchors_on_step_device_not_the_epoch_span_end(tmp_path):
+    """Every rank leaves step_device at the same barrier; the epoch span
+    ends after a rank-0-only checkpoint write. Two ranks with the SAME
+    clock but a slow rank-0 epoch tail must not be shifted apart."""
+    paths = []
+    for rank, tail in ((0, 0.4), (1, 0.0)):
+        p = str(tmp_path / f"r{rank}.jsonl")
+        with open(p, "w") as fh:
+            for i in range(3):
+                t0 = 10.0 + i
+                for name, cat, dur in (
+                    ("step_device", "stage", 0.5),
+                    ("epoch", "epoch", 0.5 + tail),
+                ):
+                    fh.write(json.dumps({
+                        "event": "span", "run_id": f"run{rank}", "schema": 1,
+                        "ts": 1000.0 + t0 + dur, "seq": 2 * i,
+                        "name": name, "cat": cat, "span_id": f"{name}{i}",
+                        "trace_id": "t", "parent_id": None, "t0": t0,
+                        "dur_s": dur, "rank": rank, "epoch": i,
+                    }) + "\n")
+        paths.append(p)
+    streams = trace_timeline.load_streams(paths)
+    assert [s.align for s in streams] == pytest.approx([0.0, 0.0], abs=1e-9)
