@@ -86,6 +86,7 @@ class ToolkitBase:
         self.graph: Optional[DeviceGraph] = None
         self.datum: Optional[GNNDatum] = None
         self.host_ell = None  # optional prebuilt ops.ell.EllPair (shared)
+        self._raw_feature = None  # raw_feature's copy on a hoisting trainer
         self.epoch_times = []
         # per-epoch training losses, appended by every run loop — the
         # trajectory-equality oracle (two backends computing the same math
@@ -375,14 +376,65 @@ class ToolkitBase:
         self._check_elastic()
         self.build_model()
 
+    # ---- the once-aggregated input ---------------------------------------
+    # True once build_model has put the aggregated feature table in the
+    # place of the features (``feature`` / ``feature_p``); see
+    # hoists_input_aggregate
+    input_hoisted = False
+
+    def hoists_input_aggregate(self) -> bool:
+        """Whether layer 0 of this trainer's forward reads the datum
+        through the aggregation ALONE (the standard-order GCN: aggregate ->
+        bn -> dense). Nothing trained and nothing random stands before
+        that aggregation, so it is the same array in every epoch: the
+        funnel computes it once (the ``input_aggregate`` phase) and the
+        step takes it as its feature argument. A property of the model's
+        forward, answered by the class that owns the forward; the default
+        is no, and a subclass that changes the order or what layer 0 reads
+        must not inherit a yes (models/gcn.py, models/gcn_dist.py)."""
+        return False
+
+    def host_input_features(self) -> np.ndarray:
+        """The datum's features as a hoisting trainer uploads them: already
+        in the compute dtype, so that under PRECISION:bfloat16 the float32
+        table never lands on the device. numpy's cast (ml_dtypes) and XLA's
+        both round to nearest even, so the device's cast of the float32
+        table gives the same bits (pinned in tests/test_input_hoist.py)."""
+        feat = self.datum.feature
+        if self.cfg.precision == "bfloat16":
+            return feat.astype(jnp.bfloat16)
+        return feat
+
     # Single-device copies of the datum, uploaded on first use. The
     # full-batch and sampled trainers (and the serve/stream stacks on top of
     # them) read these; the dist trainers place their own sharded arrays and
     # never do, so no whole [V, f] copy lands on device 0 beside the shards.
+    # On a trainer that hoists the input aggregate, ``feature`` is the
+    # step's feature argument, the aggregated table, set by build_model.
     @functools.cached_property
     def feature(self) -> jax.Array:
         with self.timers.phase("datum_upload"):
             return jnp.asarray(self.datum.feature)
+
+    @property
+    def raw_feature(self) -> jax.Array:
+        """The raw feature rows on the device, for whoever gathers rows of
+        them beside a trainer (serve/, stream/): ``feature`` itself, except
+        where that is the aggregated table; then a copy of the datum's,
+        uploaded on first use."""
+        if not self.input_hoisted:
+            return self.feature
+        if self._raw_feature is None:
+            with self.timers.phase("datum_upload"):
+                self._raw_feature = jnp.asarray(self.datum.feature)
+        return self._raw_feature
+
+    @raw_feature.setter
+    def raw_feature(self, value: jax.Array) -> None:
+        if self.input_hoisted:
+            self._raw_feature = value
+        else:
+            self.feature = value
 
     @functools.cached_property
     def label(self) -> jax.Array:

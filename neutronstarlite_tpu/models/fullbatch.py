@@ -186,9 +186,41 @@ class FullBatchTrainer(ToolkitBase):
             )
         # first touch uploads the datum (the properties' own datum_upload
         # phases), ahead of step_build, whose cost capture reads them
-        _ = self.feature, self.label
+        self._build_feature()
+        _ = self.label
         with self.timers.phase("step_build"):
             self._build_steps(train_mask01)
+
+    def aggregate_input(self, graph, x):
+        """[V, f0] features -> layer 0's aggregate [V, f0], traceable: the
+        aggregation that ``model_forward`` then leaves out. Implemented by
+        the models that answer yes to ``hoists_input_aggregate``."""
+        raise NotImplementedError
+
+    def _build_feature(self) -> None:
+        """``self.feature``, the step's feature argument: the uploaded
+        features or, where the model hoists it, their aggregate, computed
+        here once (the ``input_aggregate`` phase). The aggregate is derived
+        from the tables and the datum and rebuilt with them, in this one
+        place; it is not checkpointed. The raw device table is released
+        before the step programs load (the host copy stays in the datum)."""
+        self.input_hoisted = self.hoists_input_aggregate()
+        self.metrics.gauge_set("agg.input_hoisted", int(self.input_hoisted))
+        if not self.input_hoisted:
+            _ = self.feature
+            return
+        self.__dict__.pop("feature", None)  # a re-entry's old aggregate
+        self._raw_feature = None
+        host = self.host_input_features()
+        with self.timers.phase(
+            "input_aggregate", width=int(host.shape[1]),
+            rows=int(host.shape[0]), bytes=int(host.nbytes),
+        ):
+            raw = jnp.asarray(host)
+            self.feature = jax.block_until_ready(
+                jax.jit(self.aggregate_input)(self.compute_graph, raw)
+            )
+            raw.delete()
 
     def _build_steps(self, train_mask01) -> None:
         """The jit wrappers run() and the tools dispatch, and the step

@@ -12,6 +12,18 @@ segment-sum with custom_vjp, ops/aggregate.py), matmuls on the MXU, jax.grad
 through the tape the reference hand-maintains (ntsContext.hpp:276-356), and
 Adam fused in. Single-chip here; the distributed version is
 models/gcn_dist.py via parallel/.
+
+Layer 1's aggregate is computed once per run, not once per epoch. In the
+standard order layer 0 is aggregate -> batch norm -> dense -> relu ->
+dropout: the features, the tables and their gcn_norm weights are constant,
+there is no input dropout and no gradient flows into the features, so
+``gather_dst_from_src(graph, x)`` at ``i == 0`` is the same ``[V, f0]``
+array in every epoch and in the closing eval. ``GCNTrainer`` computes it in
+the funnel's ``input_aggregate`` phase with the same op over the same
+tables at the same precision (``aggregate_input``), and hands it to every
+step as the feature argument (``gcn_forward(..., input_aggregated=True)``).
+That is exact: a loop invariant moved out of the loop. The eager order
+aggregates ``nn(x)``, which is trained, and has nothing to hoist.
 """
 
 from __future__ import annotations
@@ -55,8 +67,14 @@ def gcn_forward(
     compute_dtype=None,
     sublinear: bool = False,
     tap=None,
+    input_aggregated: bool = False,
 ):
     """Logits for all vertices. ``eager`` swaps aggregate/NN order.
+
+    ``input_aggregated``: ``x`` is already ``aggregate_input(graph,
+    features)``, so layer 0 feeds it to its NN as the aggregate and runs no
+    aggregation of its own (standard order only). False, every other
+    caller, leaves the traced program byte-identical.
 
     ``tap``: optional per-layer hook ``tap(i, x) -> x`` applied to each
     layer's output (outside any jax.checkpoint rematerialization). The
@@ -77,6 +95,11 @@ def gcn_forward(
     expressed as ``jax.checkpoint`` (SURVEY.md section 5: trade FLOPs for
     HBM). Gradients are bit-identical; only peak memory changes.
     """
+    if input_aggregated and eager:
+        raise ValueError(
+            "the eager order aggregates nn(x), which is trained: there is "
+            "no input aggregate to hand in"
+        )
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
 
@@ -97,7 +120,9 @@ def gcn_forward(
             h = jax.nn.relu(h @ cast(layer["W"]))
             return dropout(jax.random.fold_in(key, i), h, drop_rate, train)
 
-        def layer_step(h, nn=nn):
+        def layer_step(h, nn=nn, aggregated=input_aggregated and i == 0):
+            if aggregated:
+                return nn(h)
             return gather_dst_from_src(graph, nn(h)) if eager else nn(
                 gather_dst_from_src(graph, h)
             )
@@ -111,6 +136,16 @@ def gcn_forward(
     return x.astype(jnp.float32)
 
 
+def aggregate_input(graph, x, compute_dtype=None):
+    """Layer 1's aggregate of the standard order, ``A_hat . X``: what
+    ``gcn_forward`` computes at ``i == 0`` from the features, by the same
+    cast and the same op (f32 accumulation, result in ``x``'s compute
+    dtype)."""
+    if compute_dtype is not None:
+        x = x.astype(compute_dtype)
+    return gather_dst_from_src(graph, x)
+
+
 @register_algorithm("GCNCPU", "GCN", "GCNTPU")
 class GCNTrainer(FullBatchTrainer):
     supports_optim_kernel = True
@@ -122,12 +157,29 @@ class GCNTrainer(FullBatchTrainer):
     def init_params(self, key):
         return init_gcn_params(key, self.cfg.layer_sizes(), with_bn=self.with_bn)
 
+    def hoists_input_aggregate(self) -> bool:
+        # yes for the forward below in the standard order, and for nothing
+        # that a subclass puts in its place
+        cls = type(self)
+        return (
+            not cls.eager
+            and cls.model_forward is GCNTrainer.model_forward
+            and cls.forward_taped is GCNTrainer.forward_taped
+        )
+
+    @property
+    def _compute_dtype(self):
+        return jnp.bfloat16 if self.cfg.precision == "bfloat16" else None
+
+    def aggregate_input(self, graph, x):
+        return aggregate_input(graph, x, compute_dtype=self._compute_dtype)
+
     def model_forward(self, params, graph, x, key, train):
-        dtype = jnp.bfloat16 if self.cfg.precision == "bfloat16" else None
         return gcn_forward(
             graph, params, x, key,
             self.cfg.drop_rate if train else 0.0, train, eager=self.eager,
-            compute_dtype=dtype, sublinear=self.cfg.sublinear,
+            compute_dtype=self._compute_dtype, sublinear=self.cfg.sublinear,
+            input_aggregated=self.input_hoisted,
         )
 
     def forward_taped(self, params, graph, x, key, tap, train=True):
@@ -135,11 +187,12 @@ class GCNTrainer(FullBatchTrainer):
         forward as model_forward with the per-layer tap threaded — the
         stats-fused step collects activations through it, the provenance
         replay bisects through it."""
-        dtype = jnp.bfloat16 if self.cfg.precision == "bfloat16" else None
         return gcn_forward(
             graph, params, x, key,
             self.cfg.drop_rate if train else 0.0, train, eager=self.eager,
-            compute_dtype=dtype, sublinear=self.cfg.sublinear, tap=tap,
+            compute_dtype=self._compute_dtype, sublinear=self.cfg.sublinear,
+            tap=tap,
+            input_aggregated=self.input_hoisted,
         )
 
 

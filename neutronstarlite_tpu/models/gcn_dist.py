@@ -15,6 +15,19 @@ exchange, and Update()'s gradient allreduce, GCN.hpp:209-215). TPU design:
 
 The whole train step is one jit; on a 1-device mesh it degenerates to the
 single-chip path (ring of length 1, no collectives).
+
+Layer 1's aggregate is computed once per run, not once per epoch. In the
+standard order layer 0 is exchange -> batch norm -> dense -> relu ->
+dropout over the feature slab, which is constant (no input dropout, no
+gradient into the features, tables and weights built once), so
+``exchange(x)`` at ``i == 0`` is the same ``[P*vp, f0]`` array in every
+epoch. ``DistGCNTrainer`` computes it in the funnel's ``input_aggregate``
+phase through the same ``dist_exchange`` over its own ``blocks`` at the
+same precision (``dist_aggregate_input``), sharded as ``feature_p`` is,
+and every step takes it as ``feature_p`` (``dist_gcn_forward(...,
+input_aggregated=True)``): exact, a loop invariant moved out of the loop.
+The eager order exchanges ``nn(x)``, which is trained, and GIN / CommNet
+read the raw ``x`` beside the aggregate at layer 0: neither hoists.
 """
 
 from __future__ import annotations
@@ -43,15 +56,20 @@ from neutronstarlite_tpu.utils.timing import get_time
 log = get_logger("gcn_dist")
 
 
-def exchange_widths(eager: bool, sizes):
-    """The per-layer EXCHANGE widths of a fuse-op dist stack: standard
-    order ships each layer's INPUT width (``sizes[:-1]``); the eager
-    (NN-then-exchange) variants ship the post-matmul widths
-    (``sizes[1:]``). ONE definition shared by the live wire gauges
-    below, the tune prior (tune/runner.analytic_priors), and the
+def exchange_widths(eager: bool, sizes, input_hoisted: bool = False):
+    """The per-layer EXCHANGE widths of a fuse-op dist stack, PER EPOCH:
+    standard order ships each layer's INPUT width (``sizes[:-1]``); the
+    eager (NN-then-exchange) variants ship the post-matmul widths
+    (``sizes[1:]``). A trainer that hoists the input aggregate
+    (``ToolkitBase.hoists_input_aggregate``) ships the input width
+    ``sizes[0]`` once per run, in the ``input_aggregate`` phase, and
+    ``sizes[1:-1]`` per epoch. ONE definition shared by the live wire
+    gauges below, the tune prior (tune/runner.analytic_priors), and the
     elastic mesh reshape (resilience/elastic.replan_survivors) — three
     consumers that must never price different widths for one trainer."""
-    return list(sizes[1:] if eager else sizes[:-1])
+    if eager:
+        return list(sizes[1:])
+    return list(sizes[1:-1] if input_hoisted else sizes[:-1])
 
 
 def gcn_layer_nn(i, n_layers, layer, agg, x_in, valid_mask, key, drop_rate,
@@ -77,49 +95,17 @@ def gcn_layer_nn(i, n_layers, layer, agg, x_in, valid_mask, key, drop_rate,
     return dropout(jax.random.fold_in(key, i), h, drop_rate, train)
 
 
-def dist_gcn_forward(
-    mesh,
-    dist,
-    blocks,
-    params,
-    x,
-    valid_mask,
-    key,
-    drop_rate: float,
-    train: bool,
-    layer_nn=gcn_layer_nn,
-    eager: bool = False,
-    no_exchange: bool = False,
-    compute_dtype=None,
-    wire_dtype=None,
-    partitioner=None,
-    tap=None,
-):
-    """``blocks`` selects the exchange: the [P, P, Eb] 3-tuple is the
-    ppermute ring, a DistEllPair is the OPTIM_KERNEL gather-only path, a
+def dist_exchange(mesh, dist, blocks, v, wire_dtype=None, partitioner=None):
+    """One cross-partition aggregation of ``v`` ``[P*vp, f]``, by the
+    layout ``blocks`` selects: the [P, P, Eb] 3-tuple is the ppermute
+    ring, a DistEllPair is the OPTIM_KERNEL gather-only path, a
     RingBlockedPair is the DIST_PATH:ring_blocked pipelined ring
     (parallel/dist_ring_blocked.py — ``wire_dtype`` optionally narrows its
     ICI shipments; ``mesh=None`` selects its collective-free sim twin), the
     9-tuple is the round-5 SPLIT mirror exchange (remote-only all_to_all +
     resident local edges; ``dist`` is then the SplitMirror — what
     COMM_LAYER:mirror ships), and the legacy 5-tuple is the uniform
-    MirrorGraph all_to_all. ``layer_nn`` is the per-layer vertex
-    NN over the exchanged aggregate — the fuse-op toolkits (GCN/GIN/CommNet)
-    share the exchange engine and differ only here, exactly the reference's
-    decoupled graph-op/NN-op split (ntsContext.hpp:86-95).
-
-    ``eager`` swaps the order to NN-then-exchange (the reference's GCN_EAGER
-    distributed toolkit, GCN_CPU_EAGER.hpp:200-206): every exchange — wire
-    traffic AND aggregation — then runs at the post-matmul width, 602->128
-    on the Reddit layer stack, the bandwidth-right order for a TPU mesh when
-    d_out < d_in.
-
-    ``tap``: optional per-layer hook ``tap(i, x) -> x`` applied to each
-    layer's output — the numerics plane's seam (obs/numerics): the
-    stats-fused step collects activations through it inside jit, the
-    non-finite provenance replay walks and chaos-poisons the chain
-    through it eagerly. ``tap=None`` (every pre-existing caller) leaves
-    the traced program byte-identical."""
+    MirrorGraph all_to_all."""
     from neutronstarlite_tpu.parallel.dist_blocked import (
         DistBlockedEllPair,
         dist_blocked_gather_dst_from_src,
@@ -143,50 +129,113 @@ def dist_gcn_forward(
         dist_ring_blocked_gather_simulated,
     )
 
+    if isinstance(blocks, RingBlockedPair):
+        if partitioner is not None and mesh is not None:
+            # the partitioner's 2D (vertex x feature) mesh: the ring
+            # rotates over the vertex axis while each device works a
+            # [vp, f/Pf] feature slab (parallel/partitioner.py)
+            return dist_ring2d_gather_dst_from_src(
+                mesh, blocks, v, wire_dtype, pf=partitioner.pf
+            )
+        if mesh is None:
+            # collective-free sim twin — also the 2D layout's
+            # exchange twin: the aggregation is feature-column-
+            # independent, so the full-width sim IS bitwise the
+            # slab-sharded collective ring (the 2D-specific math,
+            # the contraction's partial-sum order, lives in
+            # partitioner.contract)
+            return dist_ring_blocked_gather_simulated(
+                blocks, v, wire_dtype
+            )
+        return dist_ring_blocked_gather_dst_from_src(
+            mesh, blocks, v, wire_dtype
+        )
+    if isinstance(blocks, DistBspPair):
+        return dist_bsp_gather_dst_from_src(mesh, blocks, v)
+    if isinstance(blocks, DistBlockedEllPair):
+        return dist_blocked_gather_dst_from_src(mesh, blocks, v)
+    if isinstance(blocks, DistEllPair):
+        return dist_ell_gather_dst_from_src(mesh, blocks, v)
+    if isinstance(blocks, tuple) and len(blocks) == 9:
+        # round 5: split layout — remote-only all_to_all + resident
+        # local edges (self-loop graphs saturate the uniform Mb at vp)
+        return dist_gather_dst_from_src_mirror_split(
+            mesh, dist, blocks, v
+        )
+    if isinstance(blocks, tuple) and len(blocks) == 5:
+        return dist_gather_dst_from_src_mirror(mesh, dist, blocks, v)
+    return dist_gather_dst_from_src(
+        mesh, dist.partitions, dist.vp, dist.edge_chunk, blocks, v
+    )
+
+
+def dist_aggregate_input(mesh, dist, blocks, x, compute_dtype=None,
+                         wire_dtype=None, partitioner=None):
+    """Layer 1's aggregate of the standard order: what ``dist_gcn_forward``
+    computes at ``i == 0`` from the feature slab, by the same cast and the
+    same exchange."""
+    return dist_exchange(
+        mesh, dist, blocks, compute_cast(compute_dtype)(x), wire_dtype,
+        partitioner,
+    )
+
+
+def dist_gcn_forward(
+    mesh,
+    dist,
+    blocks,
+    params,
+    x,
+    valid_mask,
+    key,
+    drop_rate: float,
+    train: bool,
+    layer_nn=gcn_layer_nn,
+    eager: bool = False,
+    no_exchange: bool = False,
+    compute_dtype=None,
+    wire_dtype=None,
+    partitioner=None,
+    tap=None,
+    input_aggregated: bool = False,
+):
+    """``blocks`` selects the exchange (``dist_exchange``). ``layer_nn`` is
+    the per-layer vertex NN over the exchanged aggregate — the fuse-op
+    toolkits (GCN/GIN/CommNet)
+    share the exchange engine and differ only here, exactly the reference's
+    decoupled graph-op/NN-op split (ntsContext.hpp:86-95).
+
+    ``eager`` swaps the order to NN-then-exchange (the reference's GCN_EAGER
+    distributed toolkit, GCN_CPU_EAGER.hpp:200-206): every exchange — wire
+    traffic AND aggregation — then runs at the post-matmul width, 602->128
+    on the Reddit layer stack, the bandwidth-right order for a TPU mesh when
+    d_out < d_in.
+
+    ``tap``: optional per-layer hook ``tap(i, x) -> x`` applied to each
+    layer's output — the numerics plane's seam (obs/numerics): the
+    stats-fused step collects activations through it inside jit, the
+    non-finite provenance replay walks and chaos-poisons the chain
+    through it eagerly. ``tap=None`` (every pre-existing caller) leaves
+    the traced program byte-identical.
+
+    ``input_aggregated``: ``x`` is already ``dist_aggregate_input(...)`` of
+    the feature slab, so layer 0 feeds it to ``layer_nn`` as the aggregate
+    and runs no exchange of its own (standard order, and a ``layer_nn`` that
+    reads its ``agg`` argument alone at layer 0). False, every other caller,
+    leaves the traced program byte-identical."""
+    if input_aggregated and eager:
+        raise ValueError(
+            "the eager order exchanges nn(x), which is trained: there is "
+            "no input aggregate to hand in"
+        )
+
     def exchange(v):
         if no_exchange:
             # DEBUGINFO's nn-only program: identical layer widths and
             # matmuls, the graph exchange replaced by identity — the
             # nn_time/graph_time split (models/debuginfo.py)
             return v
-        if isinstance(blocks, RingBlockedPair):
-            if partitioner is not None and mesh is not None:
-                # the partitioner's 2D (vertex x feature) mesh: the ring
-                # rotates over the vertex axis while each device works a
-                # [vp, f/Pf] feature slab (parallel/partitioner.py)
-                return dist_ring2d_gather_dst_from_src(
-                    mesh, blocks, v, wire_dtype, pf=partitioner.pf
-                )
-            if mesh is None:
-                # collective-free sim twin — also the 2D layout's
-                # exchange twin: the aggregation is feature-column-
-                # independent, so the full-width sim IS bitwise the
-                # slab-sharded collective ring (the 2D-specific math,
-                # the contraction's partial-sum order, lives in
-                # partitioner.contract below)
-                return dist_ring_blocked_gather_simulated(
-                    blocks, v, wire_dtype
-                )
-            return dist_ring_blocked_gather_dst_from_src(
-                mesh, blocks, v, wire_dtype
-            )
-        if isinstance(blocks, DistBspPair):
-            return dist_bsp_gather_dst_from_src(mesh, blocks, v)
-        if isinstance(blocks, DistBlockedEllPair):
-            return dist_blocked_gather_dst_from_src(mesh, blocks, v)
-        if isinstance(blocks, DistEllPair):
-            return dist_ell_gather_dst_from_src(mesh, blocks, v)
-        if isinstance(blocks, tuple) and len(blocks) == 9:
-            # round 5: split layout — remote-only all_to_all + resident
-            # local edges (self-loop graphs saturate the uniform Mb at vp)
-            return dist_gather_dst_from_src_mirror_split(
-                mesh, dist, blocks, v
-            )
-        if isinstance(blocks, tuple) and len(blocks) == 5:
-            return dist_gather_dst_from_src_mirror(mesh, dist, blocks, v)
-        return dist_gather_dst_from_src(
-            mesh, dist.partitions, dist.vp, dist.edge_chunk, blocks, v
-        )
+        return dist_exchange(mesh, dist, blocks, v, wire_dtype, partitioner)
 
     # PRECISION:bfloat16 — the layer_nn returns bf16 activations, so the
     # exchange (ring ppermute / all_gather / all_to_all) ships HALF the
@@ -211,7 +260,7 @@ def dist_gcn_forward(
                          contract=contract)
             )
         else:
-            h = exchange(x)
+            h = x if input_aggregated and i == 0 else exchange(x)
             x = layer_nn(i, n_layers, layer, h, x, valid_mask, key,
                          drop_rate, train, compute_dtype=compute_dtype,
                          contract=contract)
@@ -242,6 +291,13 @@ class DistGCNTrainer(ToolkitBase):
 
     def init_model_params(self, key):
         return init_gcn_params(key, self.cfg.layer_sizes(), with_bn=self.with_bn)
+
+    def hoists_input_aggregate(self) -> bool:
+        # yes for GCN's own layer_nn (which reads its aggregate alone) in
+        # the standard order, and for nothing a subclass puts in its place:
+        # GIN and CommNet read the raw x beside the aggregate at layer 0
+        cls = type(self)
+        return not cls.eager and cls.layer_nn is gcn_layer_nn
 
     @staticmethod
     def resolve_comm_layer(cfg, host_graph, P: int) -> str:
@@ -284,6 +340,9 @@ class DistGCNTrainer(ToolkitBase):
         cfg = self.cfg
         self.wire_dtype = None
         self._ring_plan = None
+        self._quant_probe_stats = None
+        self.input_hoisted = self.hoists_input_aggregate()
+        self.metrics.gauge_set("agg.input_hoisted", int(self.input_hoisted))
         spec = pmod.mesh_spec_of(cfg)
         self.mesh_spec = spec
         self.partitioner = None
@@ -524,7 +583,10 @@ class DistGCNTrainer(ToolkitBase):
         rows = exchange_rows_per_device(
             layer_kind, P, self.dist.vp, getattr(self.dist, "mb", 0)
         )
-        widths = exchange_widths(type(self).eager, sizes)
+        # a hoisting trainer ships the input width once per run (the
+        # input_aggregate phase) and the hidden widths per epoch
+        widths = exchange_widths(type(self).eager, sizes, self.input_hoisted)
+        once = sizes[:1] if self.input_hoisted else []
         itemsize = 2 if cfg.precision == "bfloat16" else 4
         if self.wire_dtype is not None:
             # WIRE_DTYPE narrows what rides the ICI independently of the
@@ -532,6 +594,7 @@ class DistGCNTrainer(ToolkitBase):
             itemsize = self.wire_dtype.itemsize
         self._wire_exchanges_per_epoch = len(widths)
         self._wire_bytes_fwd_per_epoch = rows * sum(widths) * itemsize
+        wire_bytes_once = rows * sum(once) * itemsize
         self.metrics.gauge_set("wire.comm_layer", layer_kind)
         self.metrics.gauge_set("wire.rows_per_layer", rows)
         self.metrics.gauge_set(
@@ -547,9 +610,18 @@ class DistGCNTrainer(ToolkitBase):
             # test pins against wire_accounting. A 2D mesh prices each
             # hop at its feature-slab width (slab_width(w, Pf)) — the
             # same single definition wire_accounting.predict_mesh uses
+            pf = spec.pf if spec is not None else 1
             self._ring_plan = ring_wire_plan(
-                self.blocks.fwd, widths, itemsize,
-                pf=spec.pf if spec is not None else 1,
+                self.blocks.fwd, widths, itemsize, pf=pf
+            )
+            # the one-off input exchange, by the same pricing; the
+            # residency gauges below are the run's peaks, so they take the
+            # wider of the two
+            once_plan = ring_wire_plan(self.blocks.fwd, once, itemsize, pf=pf)
+            wire_bytes_once = sum(s["bytes"] for s in once_plan["steps"])
+            self._ring_plan["peak_resident_feature_bytes"] = max(
+                self._ring_plan["peak_resident_feature_bytes"],
+                once_plan["peak_resident_feature_bytes"],
             )
             # the live counter must equal the per-hop record sum: a
             # trimmed skip SUFFIX ships fewer hops than the dense
@@ -596,6 +668,7 @@ class DistGCNTrainer(ToolkitBase):
         elif layer_kind == "ell":
             # the all_gather family materializes every shard per device
             self.metrics.gauge_set("wire.peak_resident_rows", P * self.dist.vp)
+        self.metrics.gauge_set("wire.bytes_input_aggregate", wire_bytes_once)
 
         # padded, sharded vertex-space data (the sim twin — mesh None —
         # keeps everything as single logical host-backed arrays, the
@@ -619,7 +692,12 @@ class DistGCNTrainer(ToolkitBase):
             vsh = vsh1 = rsh = None
             put = lambda a, s: jax.tree.map(jnp.asarray, a)  # noqa: E731
         with self.timers.phase("datum_upload"):
-            feat = pad(self.datum.feature)
+            # a hoisting trainer's slab is only ever read in the compute
+            # dtype: it goes up in it (host_input_features)
+            feat = pad(
+                self.host_input_features() if self.input_hoisted
+                else self.datum.feature
+            )
             if self.partitioner is not None:
                 # zero-pad the feature width to a Pf multiple (sim too, so
                 # the twin trains the exact arrays the collective path
@@ -652,8 +730,52 @@ class DistGCNTrainer(ToolkitBase):
                 decay_epoch=cfg.decay_epoch,
             )
             self.opt_state = put(adam_init(self.params), rsh)
+        if self.input_hoisted:
+            self._aggregate_input()
         with self.timers.phase("step_build"):
             self._build_steps()
+
+    def _aggregate_input(self) -> None:
+        """The ``input_aggregate`` phase: ``feature_p`` becomes layer 0's
+        aggregate of it, computed once by the exchange the step would run
+        at ``i == 0`` (same blocks, same precision), sharded as the slab
+        is. Derived from the tables and the datum, so it is rebuilt with
+        them here (every build_model: an elastic replan, a resume under
+        another partitioning) and is not checkpointed. The raw slab is
+        released before the step programs load."""
+        from neutronstarlite_tpu.obs import numerics
+
+        mesh, dist, part = self.mesh, self.dist, self.partitioner
+        wire_dtype = self.wire_dtype
+        compute_dtype = (
+            jnp.bfloat16 if self.cfg.precision == "bfloat16" else None
+        )
+        raw = self.feature_p
+        aggregate = jax.jit(
+            lambda blocks, x: dist_aggregate_input(
+                mesh, dist, blocks, x, compute_dtype, wire_dtype, part
+            ),
+            **({"out_shardings": raw.sharding} if mesh is not None else {}),
+        )
+        rows, width = raw.shape
+        with self.timers.phase(
+            "input_aggregate", width=int(width), rows=int(rows),
+            bytes=int(raw.nbytes),
+        ):
+            if wire_dtype is not None and numerics.quant_probe_enabled():
+                # NTS_QUANT_PROBE reads the layer-0 payload, which rides
+                # the wire here and nowhere else: measured while it lives
+                from neutronstarlite_tpu.parallel.ring_schedule import (
+                    payload_quant_probe,
+                )
+
+                self._quant_probe_stats = jax.device_get(
+                    payload_quant_probe(wire_dtype)(raw)
+                )
+            self.feature_p = jax.block_until_ready(
+                aggregate(self.blocks, raw)
+            )
+            raw.delete()
 
     def _build_steps(self) -> None:
         """The jit wrappers run() and the tools dispatch, and the step
@@ -671,6 +793,7 @@ class DistGCNTrainer(ToolkitBase):
         compute_dtype = jnp.bfloat16 if cfg.precision == "bfloat16" else None
         wire_dtype = self.wire_dtype
         part = self.partitioner
+        hoisted = self.input_hoisted
 
         # ``blocks`` (the O(E) sharded edge arrays) is a jit ARGUMENT, not a
         # closure: captured arrays are inlined into the HLO as constants,
@@ -683,6 +806,7 @@ class DistGCNTrainer(ToolkitBase):
                     mesh, dist, blocks, p, feature, valid, key, drop_rate,
                     True, layer_nn, eager, compute_dtype=compute_dtype,
                     wire_dtype=wire_dtype, partitioner=part,
+                    input_aggregated=hoisted,
                 )
                 return masked_nll(logits, label, train01), logits
 
@@ -696,6 +820,7 @@ class DistGCNTrainer(ToolkitBase):
                 mesh, dist, blocks, params, feature, valid, key, 0.0, False,
                 layer_nn, eager, compute_dtype=compute_dtype,
                 wire_dtype=wire_dtype, partitioner=part,
+                input_aggregated=hoisted,
             )
 
         self._train_step = train_step
@@ -728,7 +853,7 @@ class DistGCNTrainer(ToolkitBase):
                         mesh, dist, blocks, p, feature, valid, key,
                         drop_rate, True, layer_nn, eager,
                         compute_dtype=compute_dtype, wire_dtype=wire_dtype,
-                        partitioner=part, tap=tap,
+                        partitioner=part, tap=tap, input_aggregated=hoisted,
                     )
                     return masked_nll(logits, label, train01), (logits, acts)
 
@@ -741,7 +866,12 @@ class DistGCNTrainer(ToolkitBase):
                 stats = numerics.step_stats(
                     params=new_params, grads=grads, acts=acts,
                     logits=logits,
-                    wire=feature if wire_dtype is not None else None,
+                    # the layer-0 payload; a hoisted one rode the wire once,
+                    # in the input_aggregate phase, and is not in this step
+                    wire=(
+                        feature if wire_dtype is not None and not hoisted
+                        else None
+                    ),
                     wire_dtype=wire_dtype,
                 )
                 return new_params, new_opt, loss, logits, stats
@@ -770,7 +900,7 @@ class DistGCNTrainer(ToolkitBase):
                 mesh, dist, blocks, params, feature, valid, key, drop_rate,
                 True, layer_nn, eager, no_exchange=no_exchange,
                 compute_dtype=compute_dtype, wire_dtype=wire_dtype,
-                partitioner=part,
+                partitioner=part, input_aggregated=hoisted,
             )
             return masked_nll(logits, label, train01)
 
@@ -993,7 +1123,9 @@ class DistGCNTrainer(ToolkitBase):
         from neutronstarlite_tpu.obs import numerics
 
         try:
-            stats = getattr(self, "_quant_probe_stats", None)
+            # a hoisting trainer measured it in the input_aggregate phase,
+            # where the payload lived
+            stats = self._quant_probe_stats
             if stats is None:
                 stats = jax.device_get(self._quant_probe_fn(self.feature_p))
                 self._quant_probe_stats = stats
@@ -1027,6 +1159,7 @@ class DistGCNTrainer(ToolkitBase):
             type(self).layer_nn, type(self).eager,
             compute_dtype=compute_dtype, wire_dtype=self.wire_dtype,
             partitioner=self.partitioner, tap=tap,
+            input_aggregated=self.input_hoisted,
         )
         entries.append((None, "logits", "logits", logits))
         return entries

@@ -405,8 +405,9 @@ def replan_survivors(toolkit, lost_partition: int) -> int:
             sizes = toolkit.cfg.layer_sizes()
             if len(sizes) > 1:
                 widths = exchange_widths(
-                    getattr(type(toolkit), "eager", False), sizes
-                )
+                    getattr(type(toolkit), "eager", False), sizes,
+                    toolkit.hoists_input_aggregate(),
+                ) or [sizes[0]]
                 outs = sizes[1:]
             else:
                 widths = sizes or [1]

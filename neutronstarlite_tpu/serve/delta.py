@@ -361,7 +361,7 @@ def apply_to_engines(engines: Sequence, delta: GraphDelta,
         # the fine-tune worker trains over the SAME slab the engines
         # serve from — keep the shared toolkit's reference current
         for tk_id, tk in {id(e.toolkit): e.toolkit for e in engines}.items():
-            tk.feature = new_feature
+            tk.raw_feature = new_feature
     plan.rows_patched = rows_patched
     return plan
 

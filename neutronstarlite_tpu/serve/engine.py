@@ -128,7 +128,9 @@ class InferenceEngine:
         self._check_servable(toolkit.params)
         self._restore(ckpt_dir)
         self.params = toolkit.params
-        self.feature = toolkit.feature
+        # the raw rows: a full-batch GCN toolkit's ``feature`` is its
+        # aggregated table (ToolkitBase.raw_feature)
+        self.feature = toolkit.raw_feature
         fanouts = getattr(toolkit, "fanouts", None)
         if not fanouts:
             sizes = self.cfg.layer_sizes()

@@ -117,9 +117,10 @@ def reserve_feature_margin(engines: Sequence, margin: int) -> int:
         if hop is not None and hop.margin < margin:
             hop.reserve_capacity(margin)
     for tk in toolkits.values():
-        # the fine-tune worker's train step reads toolkit.feature; the
-        # padded slab keeps its aval constant across future appends too
-        tk.feature = padded
+        # the fine-tune worker's train step reads toolkit.feature (the
+        # raw rows on the sampled toolkits it trains); the padded slab
+        # keeps its aval constant across future appends too
+        tk.raw_feature = padded
     log.info(
         "stream ingest: reserved a %d-row vertex-capacity margin "
         "(feature slab %s -> %s); the AOT ladder compiled after this "

@@ -197,11 +197,16 @@ def _dist_gcn_case(cfg, base_dir, mesh, edges=None):
     # same precision binding as DistGCNTrainer.build_model
     compute_dtype = jnp.bfloat16 if cfg.precision == "bfloat16" else None
 
+    # the trainer's feature argument is the once-aggregated slab, in the
+    # compute dtype (DistGCNTrainer._aggregate_input)
+    feature_dtype = compute_dtype or jnp.float32
+
     def train_step(params, opt_state, blocks, feature, label, train01, valid, key):
         def loss_fn(p):
             logits = dist_gcn_forward(
                 mesh, dist, blocks, p, feature, valid, key, drop_rate, True,
                 compute_dtype=compute_dtype, wire_dtype=wire_dtype,
+                input_aggregated=True,
             )
             return masked_nll(logits, label, train01), logits
 
@@ -216,7 +221,7 @@ def _dist_gcn_case(cfg, base_dir, mesh, edges=None):
         jax.tree.map(rspec, params),
         jax.tree.map(rspec, adam_init(params)),
         blocks,
-        jax.ShapeDtypeStruct((vp_total, sizes[0]), jnp.float32, sharding=vsh),
+        jax.ShapeDtypeStruct((vp_total, sizes[0]), feature_dtype, sharding=vsh),
         jax.ShapeDtypeStruct((vp_total,), jnp.int32, sharding=vsh1),
         jax.ShapeDtypeStruct((vp_total,), jnp.float32, sharding=vsh1),
         jax.ShapeDtypeStruct((vp_total,), jnp.float32, sharding=vsh1),

@@ -92,6 +92,7 @@ def analytic_priors(host_graph, P: int, sizes: List[int], family: str,
                     candidates: List[Candidate], precision: str = "float32",
                     score_channels: int = 1, eager_widths: bool = False,
                     sample_cfg: Optional[dict] = None,
+                    input_hoisted: bool = False,
                     ) -> Dict[str, int]:
     """{candidate label: predicted bytes/epoch} — lower is better.
 
@@ -107,7 +108,7 @@ def analytic_priors(host_graph, P: int, sizes: List[int], family: str,
     from neutronstarlite_tpu.tools.wire_accounting import predict_all
 
     sizes = [int(s) for s in sizes] or [1]
-    widths = exchange_widths(eager_widths, sizes) or [sizes[0]]
+    widths = exchange_widths(eager_widths, sizes, input_hoisted) or [sizes[0]]
     hidden = sizes[1:] or [sizes[0]]
     base_item = 2 if precision == "bfloat16" else 4
     # ONE predict_all pass at itemsize=1 (its row/peak math is itemsize-
@@ -752,6 +753,7 @@ def score_candidates(
         score_channels=leg_kwargs.get("score_channels", 1),
         eager_widths=leg_kwargs.pop("eager_widths", False),
         sample_cfg=leg_kwargs.get("sample_cfg"),
+        input_hoisted=leg_kwargs.pop("input_hoisted", False),
     )
     rows = [
         {"candidate": c.label(), "seconds": None,

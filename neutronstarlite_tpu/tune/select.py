@@ -269,6 +269,7 @@ def _decide(toolkit, autos: Set[str], measure_allowed: bool,
         kernel_tile=cfg.kernel_tile, edge_chunk=cfg.edge_chunk,
         score_channels=C, precision=cfg.precision,
         eager_widths=bool(getattr(cls, "eager", False)),
+        input_hoisted=toolkit.hoists_input_aggregate(),
         sample_cfg=sample_cfg,
     )
     if metrics is not None and measure:

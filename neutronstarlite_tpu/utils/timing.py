@@ -64,12 +64,14 @@ class PhaseTimers:
         self.tracer = tracer
 
     @contextmanager
-    def phase(self, name: str) -> Iterator[None]:
+    def phase(self, name: str, **attrs) -> Iterator[None]:
+        """``attrs`` ride the span record (and, the integers, the profiler
+        annotation): sizes known when the phase opens."""
         t = self._timers[name]
         t.start()
         try:
             if self.tracer is not None:
-                with self.tracer.span(name, cat="phase"):
+                with self.tracer.span(name, cat="phase", **attrs):
                     yield
             else:
                 yield

@@ -504,15 +504,19 @@ assert (g_["mesh.pv"], g_["mesh.pf"]) == (2, 2)
 
 src, dst = load_edges("tests/fixtures/cora/cora.2708.edge.self")
 g = build_graph(src, dst, 2708, weight="gcn_norm")
-widths = [1433, 16]  # standard order ships each layer's INPUT width
+# standard order ships each layer's INPUT width; the input width (1433)
+# rides the ring once, in the input_aggregate phase, and sets the peak
+widths = [16]
 pred = predict_mesh(g, 2, 2, widths, itemsize=4)
+once = predict_mesh(g, 2, 2, [1433], itemsize=4)
 epochs = 2
 # live wire counters == the 2D analytic pricing (single slab_width def)
 assert summ["counters"]["wire.bytes_fwd"] == pred["bytes_per_epoch"] * epochs, (
     summ["counters"]["wire.bytes_fwd"], pred["bytes_per_epoch"], epochs)
 assert g_["wire.peak_resident_rows"] == pred["peak_resident_rows"]
-assert g_["wire.peak_resident_feature_bytes"] == pred[
+assert g_["wire.peak_resident_feature_bytes"] == once[
     "peak_resident_feature_bytes"]
+assert g_["wire.bytes_input_aggregate"] == once["bytes_per_epoch"]
 assert g_["mesh.slab_cols"] == sum(pred["slab_widths"])
 hops = [e for e in events if e["event"] == "ring_step"]
 assert hops and all(h.get("slab_cols") == sum(pred["slab_widths"])

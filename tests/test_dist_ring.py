@@ -414,7 +414,9 @@ def test_ring_smoke_cfg_obs_accounting(tmp_path, monkeypatch, capsys):
 
     summ = [e for e in events if e["event"] == "run_summary"][-1]
     P, epochs = 4, 2
-    widths = [1433, 16]  # standard order ships each layer's INPUT width
+    # standard order ships each layer's INPUT width; the input width
+    # (1433) rides the ring once, in the input_aggregate phase
+    widths, once = [16], [1433]
     rows = summ["gauges"]["wire.rows_per_layer"]
     vp = rows // (P - 1)
     assert rows == exchange_rows_per_device("ring_blocked", P, vp)
@@ -426,6 +428,7 @@ def test_ring_smoke_cfg_obs_accounting(tmp_path, monkeypatch, capsys):
     assert sum(h["bytes"] for h in hops) == predicted
     # and the live counter agrees with the same formula (single source)
     assert summ["counters"]["wire.bytes_fwd"] == predicted
+    assert summ["gauges"]["wire.bytes_input_aggregate"] == rows * sum(once) * 4
 
     # the memory envelope gauge: double buffer, not P shards
     assert summ["gauges"]["wire.peak_resident_rows"] == 2 * vp

@@ -376,7 +376,10 @@ def test_quant_probe_emits_gauge_and_record(monkeypatch, tmp_path):
 
     import ml_dtypes
 
-    x = np.asarray(t.feature_p, dtype=np.float32)
+    # the layer-0 payload is the padded feature slab, which rides the
+    # ring once (the input_aggregate phase, where the probe reads it);
+    # t.feature_p is its aggregate
+    x = t.dist.pad_vertex_array(t.datum.feature)
     xq = x.astype(ml_dtypes.bfloat16).astype(np.float32)
     exact = float(
         np.sqrt(np.mean((xq - x) ** 2)) / np.sqrt(np.mean(x ** 2))

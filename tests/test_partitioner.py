@@ -233,20 +233,25 @@ def test_2x2_end_to_end_loss_and_gauges_match_predict_mesh(rng):
     gauges, counters = snap["gauges"], snap["counters"]
     assert gauges["mesh.shape"] == "2x2"
     assert (gauges["mesh.pv"], gauges["mesh.pf"]) == (2, 2)
-    pred = predict_mesh(g, 2, 2, [11, 8], itemsize=4)
+    # the input width (11) rides the ring once, in the input_aggregate
+    # phase; an epoch ships the hidden width alone. The residency gauges
+    # are the run's peaks and hold the wider, one-off exchange
+    pred = predict_mesh(g, 2, 2, [8], itemsize=4)
+    once = predict_mesh(g, 2, 2, [11], itemsize=4)
     assert gauges["mesh.slab_cols"] == sum(pred["slab_widths"])
     assert gauges["wire.peak_resident_rows"] == pred["peak_resident_rows"]
-    assert gauges["wire.peak_resident_feature_bytes"] == pred[
+    assert gauges["wire.peak_resident_feature_bytes"] == once[
         "peak_resident_feature_bytes"
     ]
     assert counters["wire.bytes_fwd"] == pred["bytes_per_epoch"] * 3
+    assert gauges["wire.bytes_input_aggregate"] == once["bytes_per_epoch"]
     # bf16 wire rides the 2D ring too
     tb = _run_dist(src, dst, datum, g, mesh="2,2",
                    dist_path="ring_blocked_sim", kernel_tile=16,
                    wire_dtype="bf16")
     assert all(np.isfinite(tb.loss_history))
     assert tb.metrics.snapshot()["counters"]["wire.bytes_fwd"] == \
-        predict_mesh(g, 2, 2, [11, 8], itemsize=2)["bytes_per_epoch"] * 3
+        predict_mesh(g, 2, 2, [8], itemsize=2)["bytes_per_epoch"] * 3
 
 
 @multidevice

@@ -645,11 +645,11 @@ EPOCH_STAGES = {
     "sampled": ["step_dispatch", "step_device", "loss_fetch", "epoch_emit",
                 "ckpt_epoch_end"],
 }
-FUNNEL_PHASES = {
+FUNNEL_PHASES = {  # both GCN trainers hoist the input aggregate
     "fullbatch": {"tune_resolve", "tables_build", "params_init",
-                  "datum_upload", "step_build"},
+                  "datum_upload", "input_aggregate", "step_build"},
     "dist": {"tune_resolve", "dist_graph_build", "dist_tables_build",
-             "datum_upload", "params_init", "step_build"},
+             "datum_upload", "params_init", "input_aggregate", "step_build"},
     "sampled": {"tune_resolve"},  # its datum uploads at the first step
 }
 
