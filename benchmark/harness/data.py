@@ -1,12 +1,12 @@
-"""Seeded inputs: the graph of a configuration and the per-run datum.
+"""The graph of a configuration: its generator and its cache on disk.
 
 The graph's topology belongs to the configuration (its file gives the
 generator's parameters and seed): it is the data set a deployment trains
 and serves on, run after run, and the program's compiled shapes (ELL bucket
 widths, shard sizes, dedup capacities) are functions of its degree
 sequence, so a topology drawn from ``--seed`` would compile anew in every
-run. Features, labels, the split and (through the program's own
-initialiser) the weights come from ``--seed``.
+run. What comes from ``--seed`` (features, labels, the split, the weights)
+is made by the configuration's ``inputs`` module.
 
 ``power_law_edges`` is a copy of the program's
 ``graph/synthetic.py:synthetic_power_law_graph`` (same draws for the same
@@ -111,24 +111,3 @@ def load_or_build(cache_dir: str, fields: Tuple[str, ...], build) -> Tuple[Dict[
     except OSError:  # another run of the same graph finished first
         shutil.rmtree(tmp, ignore_errors=True)
     return arrays, False
-
-
-def make_datum(vertices: int, feature_size: int, classes: int, split, seed: int):
-    """(feature [V, f] float32, label [V] int32, mask [V] int32) from the
-    seed. Labels are uniform classes; a feature row is its class's
-    embedding plus unit noise, scaled by a tenth; the split (train, val,
-    test sizes) is a seeded permutation."""
-    if sum(split) != vertices:
-        raise ValueError(f"split {split} does not sum to {vertices} vertices")
-    rng = np.random.default_rng(seed)
-    label = rng.integers(0, classes, size=vertices, dtype=np.int32)
-    emb = rng.standard_normal((classes, feature_size), dtype=np.float32)
-    feature = rng.standard_normal((vertices, feature_size), dtype=np.float32)
-    feature += emb[label]
-    feature *= np.float32(0.1)
-    mask = np.empty(vertices, dtype=np.int32)
-    order = rng.permutation(vertices)
-    bounds = np.cumsum([0] + list(split))
-    for which in range(3):
-        mask[order[bounds[which]:bounds[which + 1]]] = which
-    return feature, label, mask

@@ -35,13 +35,16 @@ def require_device(chips: int, rehearse: bool) -> dict:
     return facts
 
 
-def memory_peak_bytes(chips: int) -> Optional[int]:
+def memory_peak_bytes(chips: int, rehearse: bool = False) -> Optional[int]:
     """Peak bytes on the fullest of the cell's devices, for the life of the
     process: ``peak_bytes_in_use`` plus ``peak_bytes_reserved``. On this
     libtpu the first counts the buffers programs are given and return, and
     the second the scratch memory XLA reserves for the programs themselves
     (a program with 3.2 GB of temporaries moved only the second: my chip
-    run, PR 22), so their sum is what the chip held."""
+    run, PR 22), so their sum is what the chip held. The CPU backend of a
+    rehearsal keeps no such statistics; there the process's own peak
+    resident set stands in, so that the record and its reader are driven as
+    on the chip (a rehearsal prints names, never a value)."""
     import jax
 
     peaks = []
@@ -51,6 +54,10 @@ def memory_peak_bytes(chips: int) -> Optional[int]:
             parts = (int(stats["peak_bytes_in_use"]), int(stats.get("peak_bytes_reserved", 0)))
             peaks.append((sum(parts), parts))
     if not peaks:
+        if rehearse:
+            import resource
+
+            return int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss) * 1024
         return None
     peak, (in_use, reserved) = max(peaks)
     log(f"memory peak {peak} = in use {in_use} + reserved {reserved}")

@@ -16,21 +16,21 @@ from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
-from . import correct, data, openloop, program, runtime
-from .train_cell import build_trainer
+from harness import correct, openloop, program, runtime, spec
 
 WARMUP_WAVE = 32
 SERVE_COUNTERS = ("serve.computed_seeds", "serve.padded_seeds", "serve.batches", "serve.shed")
 
 
 def build(ctx):
-    """(trainer, engine, server, feature) for the cell's configuration;
-    shared with the knee sweep."""
-    (feature, _, _), trainer = build_trainer(ctx)
+    """(inputs, trainer, engine, server, vertices) for the cell's
+    configuration; shared with the knee sweep."""
+    inputs_of = spec.config_module(ctx.config, "inputs")
+    inputs, trainer = inputs_of.build(ctx)
     t = time.perf_counter()
     engine, server = program.build_server(trainer, ctx.work_dir, ctx.seed)
     ctx.spans["server_build_s"] = time.perf_counter() - t
-    return trainer, engine, server, feature
+    return inputs, trainer, engine, server, int(inputs_of.shape(inputs, trainer)["vertices"])
 
 
 class GcLog:
@@ -55,17 +55,6 @@ class GcLog:
 
     def __exit__(self, *exc) -> None:
         gc.callbacks.remove(self._on_gc)
-
-
-def served_cases(engine, mix: dict, vertices: int, seed: int) -> List[Dict[str, Any]]:
-    """One request of every size of the mix, answered by the engine's fused
-    bucket programs (every bucket, full and part full), with the blocks
-    each drew."""
-    rng = np.random.default_rng(seed + 2)
-    return [
-        program.served_case(engine, rng.integers(0, vertices, size=int(n)))
-        for n in mix["seeds_per_request"]["values"]
-    ]
 
 
 def warm_up(server, mix: dict, vertices: int, seed: int) -> None:
@@ -121,8 +110,7 @@ def offer(server, engine, mix: dict, vertices: int, seed: int, seconds: float,
 
 def run_cell(ctx) -> Dict[str, Any]:
     mix, config = ctx.traffic, ctx.config
-    trainer, engine, server, feature = build(ctx)
-    vertices = int(trainer.host_graph.v_num)
+    inputs, trainer, engine, server, vertices = build(ctx)
     try:
         t = time.perf_counter()
         warm_up(server, mix, vertices, ctx.seed)
@@ -134,24 +122,25 @@ def run_cell(ctx) -> Dict[str, Any]:
         record = offer(server, engine, mix, vertices, ctx.seed, seconds)
         if ctx.trace:
             ctx.stop_profiler()
-        record["memory_peak_bytes"] = runtime.memory_peak_bytes(ctx.chips)
+        record["memory_peak_bytes"] = runtime.memory_peak_bytes(ctx.chips, ctx.rehearse)
         record["compile_counts"] = dict(engine.compile_counts)
     finally:
         server.close()
 
     t = time.perf_counter()
-    check = correct.check_blocks(
-        correct.ReferenceGraph(config, data.graph_params(config, ctx.rehearse), ctx.cache_root),
-        program.host_params(trainer), feature,
-        served_cases(engine, mix, vertices, ctx.seed),
-    )
-    tolerance = correct.tolerance(config, ctx.rehearse)
+    record["engine"] = engine  # the check asks it for one answer of every size
+    errors, faults = spec.config_module(config, "check").check(ctx, inputs, trainer, record)
     ctx.spans["check_s"] = time.perf_counter() - t
-    record["check"] = check
-    record["family"] = "serve"
-    record["correct"] = bool(correct.passes(check, tolerance) and record["malformed"] == 0)
+    # a served answer has no gradient: of the configuration's limits, which
+    # it shares with the training cells, the answers are held to the logits'
+    limits = correct.tolerance(config, ctx.rehearse)
+    record["compared"] = correct.compare(
+        errors, {"logits_rel": limits["logits_rel"]},
+        faults=len(faults), malformed=record["malformed"],
+    )
+    record["correct"] = correct.passes(record["compared"])
     pauses = record["gc_pauses"]
-    runtime.log(f"check {check} against {tolerance['logits_rel']}; "
+    runtime.log(f"check in {ctx.spans['check_s']:.1f}s; faults {faults}; "
                 f"{record['attempted']} requests, {record['failed']} failed, "
                 f"counters {record['counters']}; {len(pauses)} garbage collections in the "
                 f"window, by generation {[sum(g == k for _, _, g in pauses) for k in range(3)]}, "

@@ -28,7 +28,7 @@ import sys
 import numpy as np
 
 import run as bench_run
-from harness import serve_cell, spec, stats
+from harness import spec, stats
 
 
 def main(argv=None) -> int:
@@ -47,8 +47,8 @@ def main(argv=None) -> int:
         return 3
     device = ctx.device
     mix = ctx.traffic
-    trainer, engine, server, _ = serve_cell.build(ctx)
-    vertices = int(trainer.host_graph.v_num)
+    serve_cell = spec.named_module("kinds", "open_loop")
+    _, _, engine, server, vertices = serve_cell.build(ctx)
     rows = []
     try:
         serve_cell.warm_up(server, mix, vertices, args.seed)

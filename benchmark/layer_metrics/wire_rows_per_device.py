@@ -1,13 +1,13 @@
 """Exchange: feature rows one device receives per epoch, exact from the
-partitioning's shapes (harness/shapes.py)."""
+partitioning's shapes (the configuration's ``need`` module, where it has
+an exchange)."""
 
-from harness import shapes
+from harness import spec
 
 
 def read(ctx, record):
-    s = record.get("shape", {})
-    if "partitions" not in s:
+    rows_of = getattr(spec.config_module(ctx.config, "need"), "wire_rows_per_device", None)
+    if rows_of is None or "shape" not in record:
         return None
-    return float(shapes.epoch_wire_rows_per_device(
-        s["partitions"], s["vp"], len(s["layers"]) - 1
-    ))
+    rows = rows_of(record["shape"])
+    return None if rows is None else float(rows)

@@ -98,20 +98,43 @@ def aggregate(edges: Edges, x: jax.Array, v_num: int, chunk: int = EDGE_CHUNK) -
     return out[:v_num]
 
 
-@jax.jit
-def _hidden(a, layer):
+def _held_in(x, dtype):
+    """``x`` as ``dtype`` would hold it, in float32 again; ``dtype`` None:
+    as it is. Only the control (benchmark/control.py) names a dtype: the
+    reference in a precision below the configuration's, which the check
+    has to tell from the reference itself. The tensor is scaled by a power
+    of two so that its largest entry sits near the top of the dtype's
+    range, as an 8-bit path scales what it stores: unscaled, a loss
+    gradient of 1e-5 an entry is nought in fp8 and the control would fail
+    for that alone. Rounded by ``reduce_precision`` to the dtype's exponent
+    and mantissa bits: a cast there and back is one the TPU's compiler may
+    leave out inside a jitted function (it keeps the excess precision), and
+    on the chip did (my chip runs, PR 27). A gradient passes through
+    unrounded (the backward pass holds in ``dtype`` what it aggregates, by
+    a call of its own)."""
+    if dtype is None:
+        return x
+    info = jnp.finfo(dtype)
+    top = jnp.max(jnp.abs(x)) / float(2.0 ** (info.maxexp - 2))  # e4m3: entries up to 128
+    scale = jnp.exp2(jnp.ceil(jnp.log2(jnp.where(top > 0, top, 1.0))))
+    held = jax.lax.reduce_precision(x / scale, exponent_bits=info.nexp, mantissa_bits=info.nmant) * scale
+    return x + jax.lax.stop_gradient(held - x)
+
+
+@partial(jax.jit, static_argnames=("dtype",))
+def _hidden(a, layer, dtype=None):
     bn = layer["bn"]
     mean = jnp.mean(a, axis=0, keepdims=True)
     var = jnp.var(a, axis=0, keepdims=True)
     h = (a - mean) * jax.lax.rsqrt(var + BN_EPS) * bn["gamma"] + bn["beta"]
     with jax.default_matmul_precision("highest"):
-        return jax.nn.relu(h @ layer["W"])
+        return _held_in(jax.nn.relu(_held_in(h, dtype) @ _held_in(layer["W"], dtype)), dtype)
 
 
-@jax.jit
-def _last(a, layer):
+@partial(jax.jit, static_argnames=("dtype",))
+def _last(a, layer, dtype=None):
     with jax.default_matmul_precision("highest"):
-        return a @ layer["W"]
+        return _held_in(_held_in(a, dtype) @ _held_in(layer["W"], dtype), dtype)
 
 
 @jax.jit
@@ -126,34 +149,51 @@ def _as_f32(tree):
     return jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), tree)
 
 
-def full_forward(by_dst: Edges, params: List[Dict], feature: np.ndarray) -> np.ndarray:
-    """Eval-mode logits [V, classes] of the whole graph."""
+def aggregate_input(by_dst: Edges, feature: np.ndarray, dtype=None) -> jax.Array:
+    """The first layer's aggregate of the features, which no weight
+    enters: a caller that runs the model at two sets of weights makes it
+    once and hands it to both."""
+    x = _held_in(jnp.asarray(feature, jnp.float32), dtype)
+    return aggregate(by_dst, x, feature.shape[0])
+
+
+def full_forward(by_dst: Edges, params: List[Dict], feature: np.ndarray,
+                 a0: jax.Array = None, dtype=None) -> np.ndarray:
+    """Eval-mode logits [V, classes] of the whole graph; ``a0`` is
+    ``aggregate_input`` of the same edges and features, where the caller
+    has it. ``dtype`` (the control's alone): what every aggregation reads
+    and every product takes and gives, the logits among them, is held in
+    it, as the program's bfloat16 products give bfloat16."""
     v_num = feature.shape[0]
-    x = jnp.asarray(feature, jnp.float32)
+    x = a0 if a0 is not None else aggregate_input(by_dst, feature, dtype)
     for i, layer in enumerate(_as_f32(params)):
         dense = _last if i == len(params) - 1 else _hidden
-        x = dense(aggregate(by_dst, x, v_num), layer)
+        x = dense(x if i == 0 else aggregate(by_dst, x, v_num), layer, dtype)
     return np.asarray(x)
 
 
 def full_loss_and_grads(by_dst: Edges, by_src: Edges, params: List[Dict],
-                        feature: np.ndarray, label: np.ndarray, mask01: np.ndarray):
+                        feature: np.ndarray, label: np.ndarray, mask01: np.ndarray,
+                        a0: jax.Array = None, dtype=None):
     """(logits, loss, gradients in the layout of ``params``) of the
     eval-mode forward. ``by_src`` holds the edges of ``by_dst`` with the
-    roles swapped (``take`` the destination, ``into`` the source)."""
+    roles swapped (``take`` the destination, ``into`` the source); ``a0``
+    and ``dtype`` as in ``full_forward`` (the backward aggregation reads
+    in ``dtype`` too)."""
     v_num = feature.shape[0]
-    x = jnp.asarray(feature, jnp.float32)
+    x = a0 if a0 is not None else aggregate_input(by_dst, feature, dtype)
     pulls = []
     for i, layer in enumerate(_as_f32(params)):
-        dense = _last if i == len(params) - 1 else _hidden
-        x, pull = jax.vjp(dense, aggregate(by_dst, x, v_num), layer)
+        dense = partial(_last if i == len(params) - 1 else _hidden, dtype=dtype)
+        x, pull = jax.vjp(dense, x if i == 0 else aggregate(by_dst, x, v_num), layer)
         pulls.append(pull)
     loss, dx = jax.value_and_grad(masked_nll)(x, jnp.asarray(label), jnp.asarray(mask01))
     grads: List = [None] * len(pulls)
     for i in reversed(range(len(pulls))):
         da, grads[i] = pulls[i](dx)
+        grads[i] = jax.tree.map(lambda g: _held_in(g, dtype), grads[i])
         if i:  # the features are not trained: nothing flows into them
-            dx = aggregate(by_src, da, v_num)
+            dx = aggregate(by_src, _held_in(da, dtype), v_num)
     return np.asarray(x), float(loss), jax.tree.map(np.asarray, grads)
 
 

@@ -64,7 +64,6 @@ def main(argv=None) -> int:
 
     jax.config.update("jax_enable_compilation_cache", False)  # a described device reads none back
     from harness import program
-    from harness.train_cell import build_trainer
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name=args.topology)
     work_dir = os.path.join(spec.CACHE_DIR, "runs", "rehearse_aot")
@@ -89,11 +88,11 @@ def main(argv=None) -> int:
                 tree,
             )
 
-        _, trainer = build_trainer(types.SimpleNamespace(
+        _, trainer = spec.config_module(config, "inputs").build(types.SimpleNamespace(
             config=config, spans={}, rehearse=False, cache_root=spec.CACHE_DIR,
             work_dir=work_dir, seed=1,
         ))
-        family = program.trainer_family(trainer)
+        family = spec.config_module(config, "check").trainer_family(trainer)
         if cell["traffic_data"]["kind"] == "open_loop":
             from neutronstarlite_tpu.serve import engine as engine_mod
 

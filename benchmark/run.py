@@ -3,12 +3,16 @@
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Reads the cell from BENCHMARK.json, its configuration from
-benchmark/configs/, its traffic from benchmark/traffic/ and, with
-``--trace 1``, one reader per per-layer metric from
-benchmark/layer_metrics/, all found by name. Fails, with no result line,
-when JAX reports anything but a TPU with the chips the cell asks for. The
-last line of stdout is the result: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` and, when traced, ``breakdown``.
+benchmark/configs/, its traffic from benchmark/traffic/, the kind of that
+traffic from benchmark/kinds/, the modules the configuration names (its
+inputs, its check, its count) and, with ``--trace 1``, one reader per
+per-layer metric from benchmark/layer_metrics/, all found by name
+(harness/spec.py lists them). Fails, with no result line, when JAX reports
+anything but a TPU with the chips the cell asks for. The last line of
+stdout is the result: ``correct``, ``attempted``, ``failed``, ``metrics``,
+``device``, when traced ``breakdown``, and last ``compared``: every number
+the check compared, beside its limit. The same numbers are the last lines
+of stderr.
 
 ``--rehearse`` runs the same path end to end on the CPU at the
 configuration's tiny rehearsal size (a four-chip cell on four virtual
@@ -31,7 +35,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import traceback  # noqa: E402
 
-from harness import e2e, runtime, spec  # noqa: E402
+from harness import correct, e2e, runtime, spec  # noqa: E402
 
 
 class Context:
@@ -82,12 +86,6 @@ class Context:
         return spec.load_peaks(self.device["kind"])
 
 
-def traffic_kinds() -> dict:
-    from harness import serve_cell, train_cell
-
-    return {"train_epochs": train_cell.run_cell, "open_loop": serve_cell.run_cell}
-
-
 def collect_metrics(ctx: Context, record: dict) -> dict:
     out = {}
     if ctx.trace:
@@ -103,14 +101,22 @@ def collect_metrics(ctx: Context, record: dict) -> dict:
     return out
 
 
+def log_compared(compared: dict) -> None:
+    """Every number compared beside its limit: the last lines of stderr."""
+    for name, c in compared.items():
+        runtime.log(f"compared {name}: {c['value']} against the limit {c['limit']}")
+
+
 def open_context(workload: str, seed: int, seconds: float, trace: bool,
-                 rehearse: bool):
+                 rehearse: bool, chips: int = None):
     """The cell's files, its environment, the device gate and the compile
     cache, in the order they must come; None (after saying why) where JAX
-    does not report the device the cell asks for."""
+    does not report the device the cell asks for. ``chips``: what a tool
+    that runs no trainer (the control: the reference alone, on one device)
+    needs in place of the cell's own number."""
     bench = spec.load_benchmark()
     cell = spec.load_cell(bench, workload)
-    chips = int(cell["chips"])
+    cell["chips"] = chips = int(cell["chips"] if chips is None else chips)
     os.environ.update({k: str(v) for k, v in cell["config_data"].get("env", {}).items()})
     if rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -146,7 +152,7 @@ def main(argv=None) -> int:
     if ctx is None:
         return 3
     try:
-        record = traffic_kinds()[ctx.traffic["kind"]](ctx)
+        record = spec.traffic_kind(ctx.traffic["kind"])(ctx)
         t0, t1 = record["window"]
         record["compiles_in_window"] = ctx.compile_log.requests_between(t0, t1)
         metrics = collect_metrics(ctx, record)
@@ -159,12 +165,14 @@ def main(argv=None) -> int:
                 f"compile {ctx.compile_log.compile_s:.1f}s in "
                 f"{len(ctx.compile_log.requests)} requests, {ctx.compile_log.hits} cache hits; "
                 f"{record['compiles_in_window']} requests in the window")
+    compared = correct.printable(record["compared"])
     if args.rehearse:
+        log_compared(compared)
         print(json.dumps({
             "rehearsal": True, "correct": record["correct"],
             "attempted": record["attempted"], "failed": record["failed"],
             "would_report": sorted(metrics), "device": ctx.device,
-            "compiles_in_window": record["compiles_in_window"],
+            "compiles_in_window": record["compiles_in_window"], "compared": compared,
         }), flush=True)
         return 0 if record["correct"] else 1
 
@@ -179,6 +187,8 @@ def main(argv=None) -> int:
         red = ctx.reduction
         result["device"].update(busy_s=red.busy_s, window_s=red.window_s)
         result["breakdown"] = {"device_ops": red.top_ops(10), "idle_gaps": red.idle_gaps(10)}
+    result["compared"] = compared
+    log_compared(compared)
     print(json.dumps(result), flush=True)  # nothing may follow it on stdout
     return 0
 

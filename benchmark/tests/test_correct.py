@@ -5,7 +5,9 @@ go wrong is named."""
 import numpy as np
 import pytest
 
-from harness import correct, data
+from harness import correct, data, spec
+
+check = spec.named_module("checks", "vertex_graph")
 
 GRAPH = {"generator": "power_law", "vertices": 300, "edges": 6000, "seed": 5,
          "exponent": 2.0, "self_loops": True, "symmetric": False}
@@ -15,7 +17,7 @@ CAPS = (6 * 3 * 4, 6 * 3, 6)  # six seeds; each level holds the draws of the one
 
 @pytest.fixture(scope="module")
 def graph(tmp_path_factory):
-    return correct.ReferenceGraph({"reference": "gcn"}, GRAPH, str(tmp_path_factory.mktemp("cache")))
+    return check.ReferenceGraph({"reference": "gcn"}, GRAPH, str(tmp_path_factory.mktemp("cache")))
 
 
 def draw_blocks(graph, seeds, n_real, rng):
@@ -53,7 +55,7 @@ def test_sorted_edges_are_the_generators_and_are_kept(graph, tmp_path):
     by_src = graph.by_src
     assert np.all(np.diff(by_src.into.astype(np.int64)) >= 0)
     assert sorted(zip(by_src.into.tolist(), by_src.take.tolist())) == sorted(zip(src.tolist(), dst.tolist()))
-    again = correct.ReferenceGraph({"reference": "gcn"}, GRAPH, graph.cache_root)  # from the cache
+    again = check.ReferenceGraph({"reference": "gcn"}, GRAPH, graph.cache_root)  # from the cache
     assert np.array_equal(again.src, graph.src) and np.array_equal(again.dst, graph.dst)
 
 
@@ -69,7 +71,7 @@ def test_has_edges(graph):
 def test_blocks_drawn_from_the_graph_have_no_fault(graph):
     rng = np.random.default_rng(0)
     nodes, hops = draw_blocks(graph, [5, 17, 100, 250, 0, 0], 4, rng)  # two padding seeds
-    assert correct.block_faults(graph, nodes, hops, FANOUTS, 4, table_width=512) == []
+    assert check.block_faults(graph, nodes, hops, FANOUTS, 4, table_width=512) == []
 
 
 def test_each_fault_of_a_sampler_is_named(graph):
@@ -78,7 +80,7 @@ def test_each_fault_of_a_sampler_is_named(graph):
     nodes, hops = draw_blocks(graph, seeds, 6, rng)
 
     def faults(nodes, hops):
-        return correct.block_faults(graph, nodes, hops, FANOUTS, 6, table_width=512)
+        return check.block_faults(graph, nodes, hops, FANOUTS, 6, table_width=512)
 
     # a draw that is no edge: point a slot of the seed hop at a vertex that
     # is not an in-neighbour of its seed
@@ -101,7 +103,7 @@ def test_each_fault_of_a_sampler_is_named(graph):
 
     # a padding seed that drew
     assert any("another number of neighbours" in f
-               for f in correct.block_faults(graph, nodes, hops, FANOUTS, 5, table_width=512))
+               for f in check.block_faults(graph, nodes, hops, FANOUTS, 5, table_width=512))
 
 
 def test_check_blocks_holds_logits_and_gradients_to_the_reference(graph):
@@ -118,23 +120,35 @@ def test_check_blocks_holds_logits_and_gradients_to_the_reference(graph):
     _, grads = ref.block_loss_and_grads(params, feature[nodes[0]], own, CAPS, label, mask01)
     case = {"nodes": nodes, "hops": own, "caps": CAPS, "fanouts": FANOUTS, "n_real": 6,
             "table_width": 512, "logits": ref.block_forward(params, feature[nodes[0]], own, CAPS),
-            "grads": grads, "label": label, "mask01": mask01}
+            "grads": grads, "grad_params": params, "label": label, "mask01": mask01}
     tolerance = {"logits_rel": 0.01, "grads_rel": 0.01}
-    good = correct.check_blocks(graph, params, feature, [case])
-    assert good["error"] < 1e-6 and good["grad_error"] < 1e-6 and good["block_faults"] == []
-    assert correct.passes(good, tolerance)
+
+    def passes(errors, faults):
+        return correct.passes(correct.compare(errors, tolerance, faults=len(faults)))
+
+    good, faults = check.check_blocks(graph, params, feature, [case])
+    assert good["logits_rel"] < 1e-6 and good["grads_rel"] < 1e-6 and faults == []
+    assert passes(good, faults)
 
     # a program that weighs an edge wrongly shows in the logits: the
     # reference weighs the blocks itself
     heavy = [(s, d, w * np.float32(1.1)) for s, d, w in own]
     wrong = dict(case, logits=ref.block_forward(params, feature[nodes[0]], heavy, CAPS))
-    bad = correct.check_blocks(graph, params, feature, [wrong])
-    assert bad["error"] > 0.1 and not correct.passes(bad, tolerance)
+    bad, faults = check.check_blocks(graph, params, feature, [wrong])
+    assert bad["logits_rel"] > 0.1 and not passes(bad, faults)
 
     # a wrong gradient fails by itself
     flipped = dict(case, grads=[{"W": -g["W"]} for g in grads])
-    bad = correct.check_blocks(graph, params, feature, [flipped])
-    assert bad["error"] < 1e-6 and bad["grad_error"] > 1.0 and not correct.passes(bad, tolerance)
+    bad, faults = check.check_blocks(graph, params, feature, [flipped])
+    assert bad["logits_rel"] < 1e-6 and bad["grads_rel"] > 1.0 and not passes(bad, faults)
+
+    # gradients are compared at the weights they were taken at, which need
+    # not be the weights of the logits
+    other = [{"W": p["W"] * np.float32(0.5)} for p in params]
+    _, other_grads = ref.block_loss_and_grads(other, feature[nodes[0]], own, CAPS, label, mask01)
+    errors, _ = check.check_blocks(graph, params, feature,
+                                   [dict(case, grads=other_grads, grad_params=other)])
+    assert errors["logits_rel"] < 1e-6 and errors["grads_rel"] < 1e-6
 
 
 def test_errors_and_passes():
@@ -144,14 +158,41 @@ def test_errors_and_passes():
     assert correct.norm_error(np.asarray([3.0, 4.0 + 0.5]), np.asarray([3.0, 4.0])) == pytest.approx(0.1)
     assert correct.gradient_error([{"W": want * 1.02}, {"W": want}], [{"W": want}, {"W": want}]) \
         == pytest.approx(0.02)
-    tolerance = {"logits_rel": 0.02, "grads_rel": 0.05}
-    assert correct.passes({"error": 0.01, "grad_error": 0.04, "losses_finite": True}, tolerance)
-    assert not correct.passes({"error": 0.03}, tolerance)
-    assert not correct.passes({"error": 0.01, "grad_error": 0.06}, tolerance)
-    assert not correct.passes({"error": 0.01, "block_faults": ["hop 0: ..."]}, tolerance)
-    assert not correct.passes({"error": 0.01, "losses_finite": False}, tolerance)
-    config = {"tolerance": tolerance, "rehearse": {"tolerance": {"grads_rel": 0.2}}}
-    assert correct.tolerance(config, rehearse=False) == tolerance
+    limits = {"logits_rel": 0.02, "grads_rel": 0.05}
+
+    def passes(errors, **counts):
+        return correct.passes(correct.compare(errors, limits, **counts))
+
+    assert passes({"logits_rel": 0.01, "grads_rel": 0.04}, faults=0, losses_not_finite=0)
+    assert not passes({"logits_rel": 0.03, "grads_rel": 0.04})
+    assert not passes({"logits_rel": 0.01, "grads_rel": 0.06})
+    assert not passes({"logits_rel": 0.01, "grads_rel": float("nan")})
+    assert not passes({"logits_rel": 0.01, "grads_rel": 0.04}, faults=1)
+    assert not passes({"logits_rel": 0.01, "grads_rel": 0.04}, losses_not_finite=2)
+    config = {"tolerance": dict(limits, reason="..."), "rehearse": {"tolerance": {"grads_rel": 0.2}}}
+    assert correct.tolerance(config, rehearse=False) == limits
     assert correct.tolerance(config, rehearse=True) == {"logits_rel": 0.02, "grads_rel": 0.2}
-    assert correct.losses_finite([3.0, 2.5]) and not correct.losses_finite([3.0, float("nan")])
-    assert not correct.losses_finite([])
+    assert correct.losses_not_finite([3.0, 2.5]) == 0
+    assert correct.losses_not_finite([3.0, float("nan"), float("inf")]) == 2
+    assert correct.losses_not_finite([]) == 1
+
+
+@pytest.mark.parametrize("errors, limits, why", [
+    ({"logits_rel": 0.01}, {"logits_rel": 0.02, "grads_rel": 0.05}, "a limit with no error"),
+    ({"logits_rel": 0.01, "drift": 0.0}, {"logits_rel": 0.02}, "an error with no limit"),
+    ({}, {}, None),
+])
+def test_a_name_on_one_side_only_fails(errors, limits, why):
+    compared = correct.compare(errors, limits, faults=0)
+    assert correct.passes(compared) == (why is None)
+    one_sided = [k for k, c in compared.items() if c["value"] is None or c["limit"] is None]
+    assert bool(one_sided) == (why is not None)
+
+
+def test_compared_numbers_print_as_json():
+    import json
+
+    compared = correct.compare({"logits_rel": float("inf"), "grads_rel": 0.5}, {"grads_rel": 0.05})
+    line = json.dumps(correct.printable(compared), allow_nan=False)
+    assert json.loads(line) == {"logits_rel": {"value": "inf", "limit": None},
+                                "grads_rel": {"value": 0.5, "limit": 0.05}}

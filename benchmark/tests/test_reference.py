@@ -3,7 +3,7 @@
 import numpy as np
 import pytest
 
-from harness import data
+from harness import data, spec
 from reference import gcn
 
 
@@ -59,6 +59,20 @@ def test_two_layers_against_dense(small):
     got = gcn.full_forward(by_dst, params, x)
     want = dense_two_layers(a, params, x.astype(np.float64))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_the_input_aggregate_made_once_serves_both_passes(small):
+    v, by_dst, by_src, _, x, rng = small
+    first, second = two_layer_params(rng), two_layer_params(rng)
+    label = rng.integers(0, 5, v).astype(np.int32)
+    mask01 = np.ones(v, np.float32)
+    a0 = gcn.aggregate_input(by_dst, x)
+    assert np.array_equal(gcn.full_forward(by_dst, first, x, a0), gcn.full_forward(by_dst, first, x))
+    with_a0 = gcn.full_loss_and_grads(by_dst, by_src, second, x, label, mask01, a0)
+    without = gcn.full_loss_and_grads(by_dst, by_src, second, x, label, mask01)
+    assert np.array_equal(with_a0[0], without[0]) and with_a0[1] == without[1]
+    for got, want in zip(with_a0[2], without[2]):
+        assert np.array_equal(got["W"], want["W"])
 
 
 def test_loss_and_gradients_against_autodiff_of_the_dense_model(small):
@@ -159,8 +173,9 @@ def test_generator_is_seeded_and_counts_edges():
 
 
 def test_datum_is_seeded_and_split_as_asked():
-    f1, l1, m1 = data.make_datum(100, 8, 5, [60, 10, 30], seed=4)
-    f2, l2, m2 = data.make_datum(100, 8, 5, [60, 10, 30], seed=4)
+    make_datum = spec.named_module("inputs", "vertex_graph").make_datum
+    f1, l1, m1 = make_datum(100, 8, 5, [60, 10, 30], seed=4)
+    f2, l2, m2 = make_datum(100, 8, 5, [60, 10, 30], seed=4)
     assert np.array_equal(f1, f2) and np.array_equal(l1, l2) and np.array_equal(m1, m2)
     assert np.bincount(m1).tolist() == [60, 10, 30]
     assert f1.dtype == np.float32 and l1.max() < 5

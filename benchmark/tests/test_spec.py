@@ -3,17 +3,46 @@ when it adds a cell, a mix or a metric as data and entries only."""
 
 import os
 
+import pytest
+
 from harness import e2e, spec
 
 
 def test_every_cell_finds_its_config_and_traffic():
     bench = spec.load_benchmark()
-    kinds = {"train_epochs", "open_loop"}
     for w in bench["workloads"]:
         cell = spec.load_cell(bench, w["name"])
         assert cell["config_data"]["name"] == w["config"]
-        assert cell["traffic_data"]["kind"] in kinds
+        assert callable(spec.traffic_kind(cell["traffic_data"]["kind"]))
         assert cell["chips"] in (1, 4)
+
+
+@pytest.mark.parametrize("mix", sorted(os.listdir(os.path.join(spec.BENCH_DIR, "traffic"))))
+def test_every_mix_finds_its_kind_by_name(mix):
+    import json
+
+    with open(os.path.join(spec.BENCH_DIR, "traffic", mix)) as fh:
+        kind = json.load(fh)["kind"]
+    assert spec.traffic_kind(kind).__module__ == f"kinds.{kind}"
+    with pytest.raises(spec.SpecError, match="kinds/ has no module named"):
+        spec.traffic_kind(kind + "_of_another_kind")
+
+
+@pytest.mark.parametrize("name", sorted(os.listdir(os.path.join(spec.BENCH_DIR, "configs"))))
+def test_a_configuration_without_the_keys_gets_the_graph_modules(name):
+    import json
+
+    with open(os.path.join(spec.BENCH_DIR, "configs", name)) as fh:
+        config = json.load(fh)
+    assert not {"inputs", "check", "need"} & set(config)  # the files are as they were
+    inputs, check, need = (spec.config_module(config, k) for k in ("inputs", "check", "need"))
+    assert (inputs.__name__, check.__name__, need.__name__) == (
+        "inputs.vertex_graph", "checks.vertex_graph", "needs.gcn")
+    assert callable(inputs.build) and callable(inputs.shape) and callable(check.check)
+    assert callable(need.epoch_need) and callable(need.wire_rows_per_device)
+    named = dict(config, inputs="token_sequences")
+    with pytest.raises(spec.SpecError, match="inputs/ has no module named 'token_sequences'"):
+        spec.config_module(named, "inputs")
 
 
 def test_every_metric_has_its_reader():

@@ -36,17 +36,35 @@ def test_peaks_table_knows_the_v5e_and_refuses_the_rest():
         spec.load_peaks("TPU v9 imaginary")
 
 
+GCN_NEED = spec.named_module("needs", "gcn")
+REDDIT = {"vertices": 232965, "edges": 114615892, "layers": [602, 128, 41], "itemsize": 2}
+PRODUCTS = {"vertices": 2449029, "edges": 126167309, "layers": [100, 256, 256, 47],
+            "itemsize": 2, "partitions": 4, "vp": 615456}
+
+
 def test_epoch_need_by_hand():
-    # V=10, E=40, widths 8-4-2, bf16. Layer 1: one aggregation pass at 8,
-    # two products. Layer 2: two passes at 4, three products.
-    need = shapes.gcn_epoch_need(10, 40, [8, 4, 2], 2)
-    agg1 = 40 * (8 + 8 * 2) + 10 * 8 * 2
+    # V=10, E=40, widths 8-4-2, bf16. Layer 1: no aggregation pass (the
+    # features' aggregate is set-up), two products. Layer 2: two passes at
+    # 4, three products.
+    need = GCN_NEED.epoch_need({"vertices": 10, "edges": 40, "layers": [8, 4, 2], "itemsize": 2})
     agg2 = 40 * (8 + 4 * 2) + 10 * 4 * 2
     dense1 = 2 * 10 * (8 + 4) * 2
     dense2 = 3 * 10 * (4 + 2) * 2
-    assert need["bytes"] == agg1 + 2 * agg2 + dense1 + dense2
-    flops = 2 * 40 * 8 + 2 * (2 * 40 * 4) + 2 * (2 * 10 * 8 * 4) + 3 * (2 * 10 * 4 * 2)
+    assert need["bytes"] == 2 * agg2 + dense1 + dense2
+    flops = 2 * (2 * 40 * 4) + 2 * (2 * 10 * 8 * 4) + 3 * (2 * 10 * 4 * 2)
     assert need["flops"] == flops
+
+
+@pytest.mark.parametrize("shape, gigabytes, was", [(REDDIT, 61.6, 200.7), (PRODUCTS, 282.9, 309.6)])
+def test_epoch_need_of_the_cells_prices_no_pass_at_the_input_width(shape, gigabytes, was):
+    need = GCN_NEED.epoch_need(shape)
+    assert need["bytes"] / 1e9 == pytest.approx(gigabytes, abs=0.05)
+    # what the count held until PR 27: one pass at the input width more
+    hoisted = GCN_NEED.aggregation_pass(shape["vertices"], shape["edges"], shape["layers"][0],
+                                        shape["itemsize"])
+    assert (need["bytes"] + hoisted["bytes"]) / 1e9 == pytest.approx(was, abs=0.05)
+    peaks = spec.load_peaks("TPU v5 lite")
+    assert shapes.least_time(need, peaks, shape.get("partitions", 1))["bound"] == "hbm"
 
 
 def test_least_time_names_its_bound():
@@ -56,10 +74,12 @@ def test_least_time_names_its_bound():
 
 
 def test_wire_rows():
-    assert shapes.exchange_rows_per_device(1, 100) == 0
-    assert shapes.exchange_rows_per_device(4, 100) == 300
-    # three layers: 3 exchanges forward, 2 backward
-    assert shapes.epoch_wire_rows_per_device(4, 100, 3) == 5 * 300
+    assert GCN_NEED.exchange_rows_per_device(1, 100) == 0
+    assert GCN_NEED.exchange_rows_per_device(4, 100) == 300
+    # three layers: 2 exchanges forward, 2 backward; the features' is set-up
+    assert GCN_NEED.wire_rows_per_device(dict(PRODUCTS, vp=100)) == 4 * 300
+    assert GCN_NEED.wire_rows_per_device(PRODUCTS) == 7385472  # 9,231,840 until PR 27
+    assert GCN_NEED.wire_rows_per_device(REDDIT) is None
 
 
 def test_cpu_steal_is_a_running_total_or_nothing():
