@@ -24,7 +24,11 @@ Legs (the first failure exits non-zero):
 4. ``train_sampled_then_serve``: GCNSAMPLESINGLE with the fused on-device
    epoch scan, 2 epochs, checkpointed; then the server answers 64 requests
    from that checkpoint in this same process;
-5. ``dist4``, on four or more devices only: GCNDIST over four partitions at
+5. ``train_seqlm``: the token-sequence family (ALGORITHM:SEQLM) on the
+   published widths of configs/moonlight_16b_a3b.json at a small cut (the
+   dense layer and one expert layer, 8 of 64 experts, an eighth of the
+   vocabulary, two sequences of 1,024 tokens a step), 4 steps on one batch;
+6. ``dist4``, on four or more devices only: GCNDIST over four partitions at
    the default exchange and at ``DIST_PATH:ring_blocked``, one process
    driving the four chips.
 
@@ -294,6 +298,27 @@ def leg_train_sampled_then_serve(root: str, data: dict) -> dict:
     return report
 
 
+def leg_train_seqlm(root: str) -> dict:
+    t0 = time.time()
+    settings = {
+        "ALGORITHM": "SEQLM",
+        "MODEL_FILE": os.path.join(REPO, "configs", "moonlight_16b_a3b.json"),
+        "SEQ_LAYERS": 2, "EXPERT_SHARDS": 8, "EXPERT_SHARD": 0, "VOCAB_SHARDS": 8,
+        "SEQ_LENGTH": 1024, "SEQ_BATCH": 2, "SEQ_CORPUS": 1, "EPOCHS": 4,
+        "PRECISION": "bfloat16", "LEARN_RATE": 0.0003, "DECAY_EPOCH": -1,
+    }
+    cfg = os.path.join(root, "seqlm.cfg")
+    with open(cfg, "w") as fh:
+        fh.writelines(f"{k}:{v}\n" for k, v in settings.items())
+    _, summ = run_training(cfg, os.path.join(root, "m_seqlm"), 4)
+    counters = summ.get("counters") or {}
+    report = leg_report(summ, t0)
+    report["rows_routed"] = counters.get("moe.rows_routed")
+    require(counters.get("seq.tokens") == 4 * 2 * 1024, f"seq.tokens {counters.get('seq.tokens')}")
+    require((counters.get("moe.rows_routed") or 0) > 0, "no pair was routed to a held expert")
+    return report
+
+
 def leg_dist4(root: str, data: dict) -> dict:
     """Both exchanges on four partitions; the comparison with leg 2's
     losses happens in main() once leg 2 has run."""
@@ -377,6 +402,8 @@ def main() -> int:
         legs[leg] = leg_train_pallas(root, data, full_losses)
         leg = "train_sampled_then_serve"
         legs[leg] = leg_train_sampled_then_serve(root, data)
+        leg = "train_seqlm"
+        legs[leg] = leg_train_seqlm(root)
 
         leg = "dist4"
         if dist is None:

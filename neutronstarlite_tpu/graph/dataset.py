@@ -220,3 +220,58 @@ def _read_mask_table(path: str, v_num: int) -> np.ndarray:
                 continue
             out[int(parts[0])] = _MASK_NAMES.get(parts[1].strip().lower(), MASK_TEST)
     return out
+
+
+@dataclasses.dataclass
+class TokenDatum:
+    """An integer datum over an implicit graph: ``tokens`` [sequences,
+    length] int32, ids inside the vocabulary slice ``0 .. vocab`` this chip
+    holds. The vertices are the positions; vertex ``i`` of a sequence has an
+    in-edge from every ``j <= i`` of that sequence, and no table holds them
+    (ops/causal_attention.py enumerates them). An id outside the slice is an
+    error, never clamped: a clamp would train on another corpus."""
+
+    tokens: np.ndarray
+    vocab: int
+
+    def __post_init__(self) -> None:
+        tokens = np.asarray(self.tokens)
+        if tokens.ndim != 2 or not np.issubdtype(tokens.dtype, np.integer):
+            raise ValueError(
+                f"a token datum is an integer array [sequences, length], got "
+                f"{tokens.dtype} {tokens.shape}"
+            )
+        lo, hi = (int(tokens.min()), int(tokens.max())) if tokens.size else (0, 0)
+        if lo < 0 or hi >= self.vocab:
+            raise ValueError(
+                f"token ids {lo} .. {hi} leave the vocabulary slice 0 .. "
+                f"{self.vocab - 1} this chip holds"
+            )
+        self.tokens = tokens.astype(np.int32)
+
+    @property
+    def sequences(self) -> int:
+        return self.tokens.shape[0]
+
+    @property
+    def length(self) -> int:
+        return self.tokens.shape[1]
+
+    @staticmethod
+    def random_generate(sequences: int, length: int, vocab: int, seed: int = 0) -> "TokenDatum":
+        """Ids uniform over the slice, from the seed."""
+        rng = np.random.default_rng(seed)
+        return TokenDatum(rng.integers(0, vocab, size=(sequences, length), dtype=np.int32), vocab)
+
+    @staticmethod
+    def read(path: str, length: int, vocab: int) -> "TokenDatum":
+        """A ``.npy`` file of ids, [sequences, length] or flat (then cut
+        into whole sequences of ``length``; a remainder is dropped)."""
+        tokens = np.load(path)
+        if tokens.ndim == 1:
+            tokens = tokens[: tokens.shape[0] // length * length].reshape(-1, length)
+        if tokens.shape[1] != length:
+            raise ValueError(
+                f"{path} holds sequences of {tokens.shape[1]} tokens, SEQ_LENGTH is {length}"
+            )
+        return TokenDatum(tokens, vocab)

@@ -12,5 +12,6 @@ import neutronstarlite_tpu.models.commnet  # noqa: F401  (registers CommNet)
 import neutronstarlite_tpu.models.commnet_dist  # noqa: F401  (registers COMMNETDIST)
 import neutronstarlite_tpu.models.gcn_sample  # noqa: F401  (registers GCNSAMPLE)
 import neutronstarlite_tpu.models.test_getdep  # noqa: F401  (registers TEST_GETDEP*)
+import neutronstarlite_tpu.models.seqlm  # noqa: F401  (registers SEQLM)
 
 __all__ = ["ToolkitBase", "register_algorithm", "get_algorithm"]

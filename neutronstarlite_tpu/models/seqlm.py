@@ -1,0 +1,532 @@
+"""The token-sequence trainer family (ALGORITHM:SEQLM): next-token training
+of a DeepSeek-V3-style block (latent attention, routed + shared experts)
+over an integer datum and an implicit causal graph.
+
+Beside ``fullbatch``, ``dist`` and ``sampled`` this is the fourth run loop
+on ``ToolkitBase``. What differs from them is the datum and the graph: the
+datum is token ids (graph/dataset.TokenDatum), the graph is never built
+(vertex ``i`` of a sequence has an in-edge from every ``j <= i``:
+ops/causal_attention.py enumerates its tiles), and a second graph, token ->
+expert, is drawn anew inside every step (ops/moe.py). What is shared is the
+funnel (``init_graph`` / ``init_nn`` / ``_finalize_datum`` /
+``build_model``), the live spans, ``emit_epoch``, checkpoints,
+``finalize_metrics``, the optimizer (nn/param.py) and the compute cast.
+
+**An epoch is one optimizer step over one batch** of SEQ_BATCH sequences;
+batches cycle through a corpus of SEQ_CORPUS resident on the device.
+
+The model is described by the source's own ``config.json`` keys in the JSON
+file MODEL_FILE names. The cut to one chip's share is in cfg keys:
+SEQ_LAYERS (layers kept, the leading dense one first), EXPERT_SHARDS /
+EXPERT_SHARD (this chip holds ``n_routed_experts / EXPERT_SHARDS`` experts
+of every layer, routes over all of them and computes its own experts' part;
+what absent experts would add is left out and nothing stands in for their
+chips), VOCAB_SHARDS (ids, logits and loss over this chip's slice),
+SEQ_LENGTH, SEQ_BATCH.
+
+The step (one jitted program): embed; the dense layer; the identical
+expert layers under one ``lax.scan``; every layer recomputed in the
+backward (``jax.checkpoint``); the head and the loss in chunks of tokens
+(the ``[tokens, vocab]`` logits never exist whole); Adam. PRECISION:bfloat16
+computes the products in bfloat16 over the float32 masters; norms, rotary,
+the softmax state, the router (its product, scores and top-k) and the
+residual stream stay float32.
+
+Named scopes of the step (``SCOPES``): ``scope_table()`` hands out, for the
+compiled step, instruction name -> scope, read from the HLO text's
+``op_name``; the profiler's trace names device events by instruction, so a
+reader can sum device time by scope.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+import re
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from neutronstarlite_tpu.graph.dataset import TokenDatum
+from neutronstarlite_tpu.models.base import ToolkitBase, register_algorithm
+from neutronstarlite_tpu.nn import seq as nnseq
+from neutronstarlite_tpu.nn.layers import compute_cast
+from neutronstarlite_tpu.nn.param import AdamConfig, adam_init, adam_update
+from neutronstarlite_tpu.ops import moe
+from neutronstarlite_tpu.ops.causal_attention import causal_edge_attention
+from neutronstarlite_tpu.resilience.faults import fault_point
+from neutronstarlite_tpu.utils.config import InputInfo
+from neutronstarlite_tpu.utils.logging import get_logger
+from neutronstarlite_tpu.utils.timing import get_time
+
+log = get_logger("seqlm")
+
+SCOPES = (
+    "seq/embed", "seq/mla/project", "seq/mla/attend", "seq/dense_mlp",
+    "seq/moe/route", "seq/moe/dispatch", "seq/moe/experts", "seq/moe/shared",
+    "seq/moe/combine", "seq/head_loss", "seq/adam",
+)
+DEFAULT_LOSS_CHUNK = 4096
+INIT_STD = 0.02  # assumed: config.json gives no initializer_range
+
+
+@dataclasses.dataclass(frozen=True)
+class SeqSpec:
+    """The sizes the step is traced with: the published ones of
+    ``config.json`` and this chip's share."""
+
+    hidden: int
+    heads: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v_head: int
+    ffn: int
+    expert_width: int
+    shared_width: int
+    routed: int
+    per_token: int
+    route_scale: float
+    theta: float
+    eps: float
+    moe_layers: int
+    first: int  # first routed expert held here
+    held: int  # routed experts held here
+    vocab: int  # rows of the vocabulary held here
+    length: int
+    batch: int
+    block: int
+    loss_chunk: int
+
+    @property
+    def tokens(self) -> int:
+        return self.batch * self.length
+
+    @staticmethod
+    def from_cfg(model: dict, cfg: InputInfo) -> "SeqSpec":
+        for key, want in (("q_lora_rank", None), ("n_group", 1), ("topk_group", 1),
+                          ("scoring_func", "sigmoid"), ("first_k_dense_replace", 1),
+                          ("moe_layer_freq", 1), ("hidden_act", "silu")):
+            if model.get(key, want) != want:
+                raise ValueError(
+                    f"MODEL_FILE has {key}={model.get(key)!r}; the SEQLM family is written "
+                    f"for {key}={want!r} (models/seqlm.py states the block it computes)"
+                )
+        layers = cfg.seq_layers or int(model["num_hidden_layers"])
+        if not 2 <= layers <= int(model["num_hidden_layers"]):
+            raise ValueError(
+                f"SEQ_LAYERS:{layers} must keep the dense layer and at least one expert "
+                f"layer of the model's {model['num_hidden_layers']}"
+            )
+        routed, vocab = int(model["n_routed_experts"]), int(model["vocab_size"])
+        if routed % cfg.expert_shards or not 0 <= cfg.expert_shard < cfg.expert_shards:
+            raise ValueError(
+                f"EXPERT_SHARDS:{cfg.expert_shards} must divide the {routed} routed experts "
+                f"and EXPERT_SHARD:{cfg.expert_shard} name one of the shards"
+            )
+        if vocab % cfg.vocab_shards:
+            raise ValueError(f"VOCAB_SHARDS:{cfg.vocab_shards} must divide the vocabulary {vocab}")
+        length = cfg.seq_length or int(model["max_position_embeddings"])
+        if length > int(model["max_position_embeddings"]):
+            raise ValueError(
+                f"SEQ_LENGTH:{length} is beyond the model's "
+                f"{model['max_position_embeddings']} positions"
+            )
+        if cfg.attn_block and length % cfg.attn_block:
+            raise ValueError(
+                f"ATTN_BLOCK:{cfg.attn_block} does not divide the sequence length {length}")
+        held = routed // cfg.expert_shards
+        tokens = cfg.seq_batch * length
+        return SeqSpec(
+            hidden=int(model["hidden_size"]), heads=int(model["num_attention_heads"]),
+            kv_rank=int(model["kv_lora_rank"]), nope=int(model["qk_nope_head_dim"]),
+            rope=int(model["qk_rope_head_dim"]), v_head=int(model["v_head_dim"]),
+            ffn=int(model["intermediate_size"]), expert_width=int(model["moe_intermediate_size"]),
+            shared_width=int(model["n_shared_experts"]) * int(model["moe_intermediate_size"]),
+            routed=routed, per_token=int(model["num_experts_per_tok"]),
+            route_scale=float(model["routed_scaling_factor"]), theta=float(model["rope_theta"]),
+            eps=float(model["rms_norm_eps"]), moe_layers=layers - 1,
+            first=cfg.expert_shard * held, held=held, vocab=vocab // cfg.vocab_shards,
+            length=length, batch=cfg.seq_batch, block=cfg.attn_block,
+            loss_chunk=math.gcd(tokens, cfg.loss_chunk or DEFAULT_LOSS_CHUNK),
+        )
+
+
+def init_params(key: jax.Array, spec: SeqSpec) -> Dict[str, Any]:
+    """Seeded weights, normal with std ``INIT_STD``, norms at one; the
+    expert layers stacked on a leading axis (the scan's)."""
+    d, h = spec.hidden, spec.heads
+    keys = iter(jax.random.split(key, 32))
+
+    def attention(lead):
+        return {
+            "norm1": jnp.ones(lead + (d,), jnp.float32),
+            "wq": nnseq.normal_init(next(keys), lead + (d, h * (spec.nope + spec.rope)), INIT_STD),
+            "wkv_a": nnseq.normal_init(next(keys), lead + (d, spec.kv_rank + spec.rope), INIT_STD),
+            "kv_norm": jnp.ones(lead + (spec.kv_rank,), jnp.float32),
+            "wkv_b": nnseq.normal_init(
+                next(keys), lead + (spec.kv_rank, h * (spec.nope + spec.v_head)), INIT_STD),
+            "wo": nnseq.normal_init(next(keys), lead + (h * spec.v_head, d), INIT_STD),
+            "norm2": jnp.ones(lead + (d,), jnp.float32),
+        }
+
+    def glu(lead, width, names):
+        g, u, dn = names
+        return {
+            g: nnseq.normal_init(next(keys), lead + (d, width), INIT_STD),
+            u: nnseq.normal_init(next(keys), lead + (d, width), INIT_STD),
+            dn: nnseq.normal_init(next(keys), lead + (width, d), INIT_STD),
+        }
+
+    n = (spec.moe_layers,)
+    return {
+        "embed": nnseq.normal_init(next(keys), (spec.vocab, d), INIT_STD),
+        "dense": {**attention(()), **glu((), spec.ffn, ("wg", "wu", "wd"))},
+        "moe": {
+            **attention(n),
+            "router": nnseq.normal_init(next(keys), n + (d, spec.routed), INIT_STD),
+            **glu(n + (spec.held,), spec.expert_width, ("eg", "eu", "ed")),
+            **glu(n, spec.shared_width, ("sg", "su", "sd")),
+        },
+        "norm": jnp.ones((d,), jnp.float32),
+        "head": nnseq.normal_init(next(keys), (d, spec.vocab), INIT_STD),
+    }
+
+
+# ---- the forward pass
+
+def attention(lp, x, spec: SeqSpec, cast, mid):
+    """``x [T, hidden]`` (``batch`` sequences of ``length``) plus its latent
+    attention."""
+    b, s, h = spec.batch, spec.length, spec.heads
+    pos = jnp.arange(s, dtype=jnp.int32)
+    with jax.named_scope("seq/mla/project"):
+        hn = nnseq.rms_norm(x, lp["norm1"], spec.eps)
+        q = nnseq.matmul(hn, lp["wq"], cast).reshape(b, s, h, spec.nope + spec.rope)
+        q = jnp.swapaxes(q, 1, 2)  # [B, H, S, nope + rope]
+        q = jnp.concatenate(
+            [q[..., : spec.nope], nnseq.rotary(q[..., spec.nope:], pos, spec.theta)], axis=-1
+        ).astype(mid).reshape(b * h, s, -1)
+        ckr = nnseq.matmul(hn, lp["wkv_a"], cast)
+        c = nnseq.rms_norm(ckr[:, : spec.kv_rank], lp["kv_norm"], spec.eps)
+        k_rope = nnseq.rotary(ckr[:, spec.kv_rank:].reshape(b, s, spec.rope), pos, spec.theta)
+        kv = nnseq.matmul(c, lp["wkv_b"], cast, mid).reshape(b, s, h, spec.nope + spec.v_head)
+        kv = jnp.swapaxes(kv, 1, 2)
+        # the one rotary key all heads share, laid beside each head's own
+        k_rope = jnp.broadcast_to(k_rope[:, None].astype(mid), (b, h, s, spec.rope))
+        k = jnp.concatenate([kv[..., : spec.nope], k_rope], axis=-1).reshape(b * h, s, -1)
+        v = kv[..., spec.nope:].reshape(b * h, s, spec.v_head)
+    with jax.named_scope("seq/mla/attend"):
+        out = causal_edge_attention(q, k, v, 1.0 / math.sqrt(spec.nope + spec.rope), spec.block)
+    with jax.named_scope("seq/mla/project"):
+        out = jnp.swapaxes(out.reshape(b, h, s, spec.v_head), 1, 2).reshape(b * s, -1)
+        return x + nnseq.matmul(out, lp["wo"], cast)
+
+
+def dense_layer(lp, x, spec: SeqSpec, cast, mid):
+    x = attention(lp, x, spec, cast, mid)
+    with jax.named_scope("seq/dense_mlp"):
+        hn = nnseq.rms_norm(x, lp["norm2"], spec.eps)
+        return x + nnseq.swiglu(hn, lp["wg"], lp["wu"], lp["wd"], cast)
+
+
+def expert_mlp(lp, bias, x, spec: SeqSpec, cast):
+    """(``x`` plus this chip's part of the expert layer, rows per held
+    expert [held], the choice of experts [T, k])."""
+    with jax.named_scope("seq/moe/route"):
+        hn = nnseq.rms_norm(x, lp["norm2"], spec.eps)
+        # float32 at the highest precision, as the published code keeps it
+        scores = jax.nn.sigmoid(nnseq.matmul(hn, lp["router"], lambda t: t))
+        choice, weight = moe.route(scores, bias, spec.per_token, spec.route_scale)
+    with jax.named_scope("seq/moe/dispatch"):
+        plan = moe.plan_dispatch(choice, spec.first, spec.held)
+        rows = moe.dispatch_rows(cast(hn), plan)
+    with jax.named_scope("seq/moe/experts"):
+        out = moe.grouped_swiglu(rows, lp["eg"], lp["eu"], lp["ed"], plan.group_sizes, cast)
+    with jax.named_scope("seq/moe/combine"):
+        routed = moe.combine_rows(out, weight, plan)
+    with jax.named_scope("seq/moe/shared"):
+        out = x + routed + nnseq.swiglu(hn, lp["sg"], lp["su"], lp["sd"], cast)
+    return out, plan.group_sizes, choice
+
+
+def hidden_states(params, bias, tokens, spec: SeqSpec, cast, mid):
+    """(the residual stream [T, hidden] after the last layer, rows per held
+    expert [L, held], the choices [L, T, k]) of ``tokens`` [batch, length]."""
+    with jax.named_scope("seq/embed"):
+        x = params["embed"][tokens.reshape(-1)]
+    x = jax.checkpoint(lambda lp, x: dense_layer(lp, x, spec, cast, mid))(params["dense"], x)
+
+    @jax.checkpoint
+    def layer(x, lp, b):
+        x = attention(lp, x, spec, cast, mid)
+        return expert_mlp(lp, b, x, spec, cast)
+
+    def body(x, lp_b):
+        x, sizes, choice = layer(x, *lp_b)
+        return x, (sizes, choice)
+
+    x, (sizes, choice) = lax.scan(body, x, (params["moe"], bias))
+    return x, sizes, choice
+
+
+def head_loss(params, x, tokens, spec: SeqSpec, cast):
+    """Mean next-token cross-entropy; the head and the loss in chunks of
+    ``loss_chunk`` tokens, each recomputed in the backward."""
+    with jax.named_scope("seq/head_loss"):
+        targets = jnp.roll(tokens, -1, axis=1).reshape(-1)
+        weight = jnp.broadcast_to(
+            (jnp.arange(spec.length) < spec.length - 1).astype(jnp.float32), tokens.shape
+        ).reshape(-1)
+        hn = nnseq.rms_norm(x, params["norm"], spec.eps)
+        n = spec.tokens // spec.loss_chunk
+
+        @jax.checkpoint
+        def chunk(total, part):
+            h_c, t_c, w_c = part
+            logits = nnseq.matmul(h_c, params["head"], cast)
+            picked = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
+            return total + jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * w_c), None
+
+        total, _ = lax.scan(chunk, jnp.zeros((), jnp.float32), (
+            hn.reshape(n, spec.loss_chunk, -1), targets.reshape(n, -1), weight.reshape(n, -1)))
+        return total / weight.sum()
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The innermost of ``SCOPES`` an HLO ``op_name`` lies under."""
+    found = [(op_name.rfind(s), s) for s in SCOPES if s in op_name]
+    return max(found)[1] if found else None
+
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*?\b[\w\-]+\(([^\n]*)$", re.M)
+_HLO_OP_NAME = re.compile(r"op_name=\"([^\"]*)\"")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def scope_table_of(hlo_text: str, rounds: int = 4) -> Dict[str, str]:
+    """Instruction name -> scope, for the instructions of an HLO module's
+    text: the innermost of ``SCOPES`` its ``op_name`` lies under. An
+    instruction the compiler made without the scope (the TPU's rewrite of
+    ``ragged_dot`` into a custom call named ``ragged-dot-none``, a copy, a
+    get-tuple-element, a bitcast) takes the scope most of the instructions
+    that read it have, else of those it reads, over a few rounds."""
+    table: Dict[str, str] = {}
+    operands: Dict[str, list] = {}
+    for name, rest in _HLO_INSTRUCTION.findall(hlo_text):
+        op_name = _HLO_OP_NAME.search(rest)
+        scope = scope_of(op_name.group(1)) if op_name else None
+        if scope is not None:
+            table[name] = scope
+        # operands stand before the closing parenthesis of the call
+        operands[name] = _HLO_OPERAND.findall(rest.split("), ")[0])
+    users: Dict[str, list] = {}
+    for name, ops in operands.items():
+        for op in ops:
+            users.setdefault(op, []).append(name)
+    for _ in range(rounds):
+        found = {}
+        for name in operands:
+            if name in table:
+                continue
+            for near in (users.get(name, ()), operands[name]):
+                scopes = [table[n] for n in near if n in table]
+                if scopes:
+                    found[name] = max(set(scopes), key=scopes.count)
+                    break
+        if not found:
+            break
+        table.update(found)
+    return table
+
+
+@register_algorithm("SEQLM")
+class SeqLMTrainer(ToolkitBase):
+    needs_device_graph = False
+
+    # ---- the funnel ------------------------------------------------------
+    def init_graph(self) -> None:
+        """Nothing to load: the graph is implicit (every position sees the
+        positions before it in its sequence)."""
+        log.info("implicit causal graph: no edge table is read or built")
+
+    def init_nn(self) -> None:
+        cfg = self.cfg
+        spec = self._read_spec()
+        with self.timers.phase("datum_load"):
+            if cfg.token_file:
+                self.datum = TokenDatum.read(
+                    cfg.resolve_path(cfg.token_file, self.base_dir), spec.length, spec.vocab)
+            else:
+                self.datum = TokenDatum.random_generate(
+                    cfg.seq_corpus * spec.batch, spec.length, spec.vocab, seed=self.seed)
+        self._finalize_datum()
+
+    @classmethod
+    def from_tokens(cls, cfg: InputInfo, tokens: np.ndarray, seed: int = 0,
+                    base_dir: Optional[str] = None) -> "SeqLMTrainer":
+        """Construct from in-memory token ids [sequences, length] (tests,
+        the benchmark): the funnel from ``_finalize_datum`` on, as
+        ``from_arrays`` enters it for the vertex families."""
+        t = cls(cfg, base_dir=base_dir, seed=seed)
+        t.datum = TokenDatum(tokens, t._read_spec().vocab)
+        t._finalize_datum()
+        return t
+
+    def _read_spec(self) -> SeqSpec:
+        if not self.cfg.model_file:
+            raise ValueError("ALGORITHM:SEQLM needs MODEL_FILE: the model's config.json")
+        with open(self.cfg.resolve_path(self.cfg.model_file, self.base_dir)) as fh:
+            self.model = json.load(fh)
+        self.spec = SeqSpec.from_cfg(self.model, self.cfg)
+        return self.spec
+
+    def build_model(self) -> None:
+        cfg, spec = self.cfg, self.spec
+        if self.datum.length != spec.length or self.datum.sequences % spec.batch:
+            raise ValueError(
+                f"the corpus [{self.datum.sequences}, {self.datum.length}] does not cut into "
+                f"batches of SEQ_BATCH:{spec.batch} sequences of SEQ_LENGTH:{spec.length}"
+            )
+        self.compute_dtype = jnp.bfloat16 if cfg.precision == "bfloat16" else None
+        self.adam_cfg = AdamConfig(
+            alpha=cfg.learn_rate, weight_decay=cfg.weight_decay,
+            decay_rate=cfg.decay_rate, decay_epoch=cfg.decay_epoch,
+            warmup_steps=cfg.warmup_epochs,
+        )
+        with self.timers.phase("params_init"):
+            self.params, self.opt_state = self.initial_state()
+            # the noaux_tc correction bias: a buffer, no gradient; fixed at
+            # zero (config.json gives no update rate for it)
+            self.route_bias = jnp.zeros((spec.moe_layers, spec.routed), jnp.float32)
+        with self.timers.phase("datum_upload"):
+            self.n_batches = self.datum.sequences // spec.batch
+            self.corpus = jnp.asarray(
+                self.datum.tokens.reshape(self.n_batches, spec.batch, spec.length))
+            self._batch_index = [jnp.asarray(i, jnp.int32) for i in range(self.n_batches)]
+        with self.timers.phase("step_build"):
+            self._train_step = jax.jit(self._step, donate_argnums=(0, 1))
+            self._eval_logits = jax.jit(self._logits_at)
+            self._scope_table: Optional[Dict[str, str]] = None
+        self.routed_history: list = []  # pairs sent to held experts, per epoch
+        n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(self.params))
+        log.info(
+            "SEQLM: %d layers (1 dense + %d expert), experts %d..%d of %d held, vocabulary "
+            "slice %d, %d parameters; a step is %d sequences of %d tokens; corpus of %d batches",
+            spec.moe_layers + 1, spec.moe_layers, spec.first, spec.first + spec.held - 1,
+            spec.routed, spec.vocab, n_params, spec.batch, spec.length, self.n_batches,
+        )
+
+    def initial_state(self):
+        """(params, opt_state) as ``build_model`` makes them from the seed:
+        a second call gives the same bits (a check replays the first steps
+        from here)."""
+        params = jax.jit(init_params, static_argnums=1)(jax.random.PRNGKey(self.seed), self.spec)
+        return params, adam_init(params)
+
+    # ---- the step --------------------------------------------------------
+    def _casts(self):
+        cast = compute_cast(self.compute_dtype)
+        return cast, (self.compute_dtype or jnp.float32)
+
+    def _loss(self, params, bias, tokens):
+        cast, mid = self._casts()
+        x, sizes, choice = hidden_states(params, bias, tokens, self.spec, cast, mid)
+        return head_loss(params, x, tokens, self.spec, cast), (sizes, choice)
+
+    def _step(self, params, opt_state, bias, corpus, index):
+        tokens = corpus[index]
+        (loss, (sizes, choice)), grads = jax.value_and_grad(self._loss, has_aux=True)(
+            params, bias, tokens)
+        with jax.named_scope("seq/adam"):
+            params, opt_state = adam_update(params, grads, opt_state, self.adam_cfg)
+        # the step's own choice of experts [L, T, k] stays on the device: a
+        # check that follows the step reads it, the run loop does not
+        return params, opt_state, loss, sizes, choice
+
+    def _logits_at(self, params, bias, tokens, rows):
+        """(logits [len(rows), vocab] float32 at the flat positions
+        ``rows`` of ``tokens`` [batch, length], the choices [L, T, k])."""
+        cast, mid = self._casts()
+        x, _, choice = hidden_states(params, bias, tokens, self.spec, cast, mid)
+        hn = nnseq.rms_norm(x[rows], params["norm"], self.spec.eps)
+        return nnseq.matmul(hn, params["head"], cast), choice
+
+    def step_args(self, index: int = 0):
+        """The argument tuple ``run()`` passes to the jitted step."""
+        return (self.params, self.opt_state, self.route_bias, self.corpus,
+                self._batch_index[index % self.n_batches])
+
+    aot_args = step_args
+
+    def scope_table(self) -> Dict[str, str]:
+        """Instruction name -> scope of the compiled step (lowered once
+        more from the same arguments: with the compile cache a lookup)."""
+        if self._scope_table is None:
+            text = self._train_step.lower(*self.step_args()).compile().as_text()
+            self._scope_table = scope_table_of(text)
+        return self._scope_table
+
+    # ---- run -------------------------------------------------------------
+    def run(self) -> Dict[str, Any]:
+        cfg, spec = self.cfg, self.spec
+        self.open_run_root()
+        with self.stage("run_begin"):
+            log.info(
+                "GNNmini::Engine[%s.%s] running [%d] Epochs (one optimizer step each)",
+                jax.default_backend(), type(self).__name__, cfg.epochs,
+            )
+        with self.stage("ckpt_begin"):
+            start_epoch = self.ckpt_begin()
+        # epochs count on from the last run() on this trainer (a warm-up
+        # run() then a measured one): the corpus keeps cycling
+        first = start_epoch or len(self.loss_history)
+        loss = None
+        for epoch in range(first, first + max(cfg.epochs - start_epoch, 0)):
+            with self.epoch_span(epoch):
+                with self.stage("epoch_key", epoch):
+                    index = self._batch_index[epoch % self.n_batches]
+                with self.stage("step_dispatch", epoch) as s_disp:
+                    self.params, self.opt_state, loss, sizes, _ = self._train_step(
+                        self.params, self.opt_state, self.route_bias, self.corpus, index)
+                with self.stage("step_device", epoch) as s_dev:
+                    jax.block_until_ready(loss)
+                with self.stage("loss_fetch", epoch):
+                    loss = fault_point("epoch_loss", epoch=epoch, value=loss)
+                    sizes = np.asarray(sizes)
+                    dt = get_time() - s_disp.t0
+                    self.epoch_times.append(dt)
+                    self.loss_history.append(float(loss))
+                    self._count_epoch(sizes)
+                with self.stage("epoch_emit", epoch):
+                    self.emit_epoch(epoch, dt, loss, stages={
+                        "step_dispatch": s_disp.dur_s, "step_device": s_dev.dur_s})
+                if epoch % max(1, cfg.epochs // 20) == 0:
+                    log.info("Epoch %d loss %f", epoch, float(loss))
+                with self.stage("ckpt_epoch_end", epoch):
+                    self.ckpt_epoch_end(epoch)
+        with self.stage("ckpt_final"):
+            self.ckpt_final()
+        avg = self.avg_epoch_time()
+        log.info("--avg epoch time %.4f s (first %.2f s incl. compile)",
+                 avg, self.epoch_times[0] if self.epoch_times else 0.0)
+        result = {
+            "loss": float(loss) if loss is not None else float("nan"),
+            "acc": {"train": None, "eval": None, "test": None},
+            "avg_epoch_s": avg,
+        }
+        self.finalize_metrics(result)
+        return result
+
+    def _count_epoch(self, sizes: np.ndarray) -> None:
+        """``sizes`` [L, held]: rows each held expert of each layer saw."""
+        rows = int(sizes.sum())
+        self.routed_history.append(rows)
+        self.metrics.counter_add("seq.tokens", self.spec.tokens)
+        self.metrics.counter_add("moe.rows_routed", rows)
+        mean = np.maximum(sizes.mean(axis=1), 1e-9)
+        self.metrics.gauge_set("moe.load_max_over_mean", float((sizes.max(axis=1) / mean).max()))

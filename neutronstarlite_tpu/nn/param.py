@@ -43,6 +43,9 @@ class AdamConfig:
     weight_decay: float = 0.0001
     decay_rate: float = 0.97
     decay_epoch: int = 100  # -1 disables the schedule
+    warmup_steps: int = 0  # > 0: alpha rises linearly from 0 over this many
+    # updates (the token-sequence family's WARMUP_EPOCHS; 0, the default of
+    # every other trainer, leaves the step size and the program as they were)
 
 
 @jax.tree_util.register_dataclass
@@ -71,6 +74,8 @@ def adam_update(
         alpha = cfg.alpha * jnp.power(cfg.decay_rate, n_decays)
     else:
         alpha = jnp.asarray(cfg.alpha, jnp.float32)
+    if cfg.warmup_steps > 0:
+        alpha = alpha * jnp.minimum(1.0, tf / cfg.warmup_steps)
     bias1 = 1.0 - jnp.power(cfg.beta1, tf)
     bias2 = 1.0 - jnp.power(cfg.beta2, tf)
     lr_t = alpha * jnp.sqrt(bias2) / bias1

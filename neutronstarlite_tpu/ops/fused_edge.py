@@ -203,6 +203,22 @@ def _scatter_kw():
 # ---- forward: one streamed pass, online softmax ----------------------------
 
 
+def online_softmax_fold(m_old, l_old, acc_old, z, real, aggregate):
+    """One block folded into the running state of an online softmax: the
+    ONE definition of the (m, l, acc) recurrence, shared by this module's
+    table-driven blocks and ops/causal_attention.py's index-enumerated
+    tiles. ``z`` [..., n, K, C] are the block's scores (``NEG_INF`` where
+    ``real`` is False), ``m_old`` / ``l_old`` [..., n, C] and ``acc_old``
+    [..., n, f] the state of its n destination rows, ``aggregate(p)`` the
+    block's values weighted by ``p`` [..., n, K, C] -> [..., n, f]. The
+    carried state is rescaled by exp(m_old - m_new) (all-padding rows:
+    exp(0) = 1) and the block's exp-scores folded in."""
+    m_new = jnp.maximum(m_old, z.max(axis=-2))  # block max per destination row
+    p = jnp.where(real, jnp.exp(z - m_new[..., None, :]), 0.0)
+    scale = jnp.exp(m_old - m_new)
+    return m_new, l_old * scale + p.sum(axis=-2), acc_old * scale + aggregate(p)
+
+
 def fused_init_state(v_num: int, C: int, f: int):
     """(m, l, acc) — running per-destination max / normalizer / weighted
     accumulator. The distributed ring carries this tuple across hops."""
@@ -240,15 +256,11 @@ def fused_forward_into(
             z = jnp.where(
                 real, jax.nn.leaky_relu(q, negative_slope=slope), NEG_INF
             )
-            bm = z.max(axis=1)  # [n, C] block max per destination row
-            m_old = m[drc]
-            m_new = jnp.maximum(m_old, bm)
-            p = jnp.where(real, jnp.exp(z - m_new[:, None, :]), 0.0)
-            scale = jnp.exp(m_old - m_new)  # all-pad rows: exp(0) = 1
             xv = x_tile[nb].astype(jnp.float32)  # [n, K, f]
-            row_acc = (xv * p).sum(axis=1)  # C==1 broadcasts over f
-            l_new = l[drc] * scale + p.sum(axis=1)
-            acc_new = acc[drc] * scale + row_acc
+            m_new, l_new, acc_new = online_softmax_fold(
+                m[drc], l[drc], acc[drc], z, real,
+                lambda p: (xv * p).sum(axis=1),  # C==1 broadcasts over f
+            )
             kw = _scatter_kw()
             return (
                 m.at[dr].set(m_new, **kw),

@@ -206,6 +206,32 @@ class InputInfo:
     # KERNEL:auto, tune/select.py). Env override NTS_SAMPLE_PIPELINE
     # (sample.pipeline.resolve_sample_pipeline).
 
+    # The token-sequence family (ALGORITHM:SEQLM, models/seqlm.py). The
+    # model is described by the source's own ``config.json`` keys in the
+    # JSON file MODEL_FILE names; the cut to one chip's share is here.
+    model_file: str = ""  # MODEL_FILE: the published config.json, as JSON
+    token_file: str = ""  # TOKEN_FILE: a .npy of token ids; "" = drawn
+    # from the seed, uniform over the vocabulary slice
+    seq_layers: int = 0  # SEQ_LAYERS: layers kept, the leading dense ones
+    # first (0 = the model's num_hidden_layers)
+    seq_length: int = 0  # SEQ_LENGTH: tokens a sequence (0 = the model's
+    # max_position_embeddings)
+    seq_batch: int = 1  # SEQ_BATCH: sequences an optimizer step
+    seq_corpus: int = 8  # SEQ_CORPUS: batches resident on the device,
+    # cycled (with TOKEN_FILE: the file's sequences cut into batches)
+    expert_shards: int = 1  # EXPERT_SHARDS: chips that share each layer's
+    # routed experts; this chip holds n_routed_experts / EXPERT_SHARDS
+    expert_shard: int = 0  # EXPERT_SHARD: which of them this chip is
+    vocab_shards: int = 1  # VOCAB_SHARDS: chips that share the vocabulary;
+    # ids, logits and loss are over this chip's vocab_size / VOCAB_SHARDS rows
+    attn_block: int = 0  # ATTN_BLOCK: positions a tile of the streamed
+    # causal attention (0 = ops/causal_attention.DEFAULT_BLOCK)
+    loss_chunk: int = 0  # LOSS_CHUNK: tokens a chunk of the head + loss
+    # (0 = 4096): the [tokens, vocab] logits never exist whole
+    warmup_epochs: int = 0  # WARMUP_EPOCHS: optimizer steps over which the
+    # learn rate rises linearly from 0 to LEARN_RATE (0 = none), as a
+    # language-model pre-training job starts; SEQLM honours it
+
     @staticmethod
     def read_from_cfg_file(path: str) -> "InputInfo":
         """Parse a flat KEY:VALUE cfg file (GraphSegment.cpp:222-292)."""
@@ -319,6 +345,12 @@ class InputInfo:
             self.sublinear = bool(int(value))
         elif key == "EDGE_CHUNK":
             self.edge_chunk = int(value)
+        elif key in ("MODEL_FILE", "TOKEN_FILE"):
+            setattr(self, key.lower(), value)
+        elif key in ("SEQ_LAYERS", "SEQ_LENGTH", "SEQ_BATCH", "SEQ_CORPUS",
+                     "EXPERT_SHARDS", "EXPERT_SHARD", "VOCAB_SHARDS",
+                     "ATTN_BLOCK", "LOSS_CHUNK", "WARMUP_EPOCHS"):
+            setattr(self, key.lower(), int(value))
         elif key == "COMM_LAYER":
             self.comm_layer = value.strip().lower()
         elif key == "DIST_PATH":

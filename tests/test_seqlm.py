@@ -1,0 +1,410 @@
+"""The token-sequence family (models/seqlm.py, ops/causal_attention.py,
+ops/moe.py, nn/seq.py) against the plain reference the benchmark compares
+with (benchmark/reference/moonlight.py, loaded by its path: one reference,
+no second copy), on the CPU, float32, small widths, seeded weights."""
+
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neutronstarlite_tpu.graph.dataset import TokenDatum
+from neutronstarlite_tpu.graph.storage import build_graph
+from neutronstarlite_tpu.models import get_algorithm, seqlm
+from neutronstarlite_tpu.nn.layers import compute_cast
+from neutronstarlite_tpu.ops import edge, moe
+from neutronstarlite_tpu.ops.causal_attention import causal_edge_attention
+from neutronstarlite_tpu.ops.device_graph import DeviceGraph
+from neutronstarlite_tpu.utils.config import InputInfo
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_reference():
+    path = os.path.join(REPO, "benchmark", "reference", "moonlight.py")
+    spec = importlib.util.spec_from_file_location("reference_moonlight", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+ref = _load_reference()
+
+MODEL = dict(
+    hidden_size=48, num_attention_heads=3, kv_lora_rank=24, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96, moe_intermediate_size=32,
+    n_shared_experts=2, n_routed_experts=16, num_experts_per_tok=3, routed_scaling_factor=2.446,
+    rope_theta=50000, rms_norm_eps=1e-5, num_hidden_layers=6, vocab_size=128,
+    max_position_embeddings=64, q_lora_rank=None, n_group=1, topk_group=1,
+    scoring_func="sigmoid", first_k_dense_replace=1, moe_layer_freq=1, hidden_act="silu",
+)
+SHAPE = ref.Shape.of(MODEL)
+CUT = dict(SEQ_LAYERS=3, SEQ_LENGTH=32, SEQ_BATCH=2, SEQ_CORPUS=3, EXPERT_SHARDS=4,
+           EXPERT_SHARD=1, VOCAB_SHARDS=2, ATTN_BLOCK=8, LOSS_CHUNK=16, EPOCHS=2,
+           LEARN_RATE=0.0003, WEIGHT_DECAY=0.0001, DECAY_EPOCH=-1)
+
+
+def write_cfg(tmp_path, **keys):
+    """(cfg path) of a SEQLM cfg file beside its model JSON, as a user's."""
+    with open(tmp_path / "model.json", "w") as fh:
+        json.dump(MODEL, fh)
+    settings = dict(CUT, ALGORITHM="SEQLM", MODEL_FILE="model.json", **keys)
+    path = tmp_path / "seq.cfg"
+    with open(path, "w") as fh:
+        fh.writelines(f"{k}:{v}\n" for k, v in settings.items())
+    return str(path)
+
+
+def make_tokens(seed=0, sequences=6, length=32, vocab=64):
+    return np.random.default_rng(seed).integers(0, vocab, size=(sequences, length), dtype=np.int32)
+
+
+def make_trainer(tmp_path, tokens=None, seed=3, **keys):
+    cfg_path = write_cfg(tmp_path, **keys)
+    cfg = InputInfo.read_from_cfg_file(cfg_path)
+    tokens = make_tokens() if tokens is None else tokens
+    return seqlm.SeqLMTrainer.from_tokens(cfg, tokens, seed=seed, base_dir=str(tmp_path))
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    """A float32 trainer, its initial weights, and its first step's state."""
+    trainer = make_trainer(tmp_path_factory.mktemp("seq"))
+    p0 = jax.tree.map(np.asarray, trainer.params)
+    params, opt = trainer.initial_state()
+    out = trainer._train_step(params, opt, trainer.route_bias, trainer.corpus,
+                              trainer._batch_index[0])
+    return trainer, p0, out
+
+
+# ---- forward and gradients against the reference
+
+def test_eval_forward_matches_reference_logits_and_choice(trained):
+    trainer, p0, _ = trained
+    batch = trainer.datum.tokens[:2]
+    logits, choice = trainer._eval_logits(
+        trainer.initial_state()[0], trainer.route_bias, jnp.asarray(batch), jnp.arange(64))
+    share = ref.Share(trainer.spec.first, trainer.spec.held)
+    want = np.concatenate([
+        np.asarray(ref.logits_at(p0, batch[s], np.arange(32), SHAPE, share, block=16))
+        for s in range(2)])
+    assert np.abs(np.asarray(logits) - want).max() / np.abs(want).max() < 1e-5
+    _, own = ref.loss(p0, batch, SHAPE, share, block=16)
+    got = np.asarray(choice).reshape(2, 2, 32, 3).transpose(1, 0, 2, 3)
+    assert np.array_equal(np.sort(got, -1), np.sort(np.asarray(own), -1))
+
+
+@pytest.mark.parametrize("group", ["embed", "dense", "moe", "norm", "head"])
+def test_step_gradients_match_reference(trained, group):
+    """The step's own gradients, read back from Adam's first moment after
+    one step from zero state, against ``jax.grad`` of the reference."""
+    trainer, p0, (_, opt, loss, _, _) = trained
+    share = ref.Share(trainer.spec.first, trainer.spec.held)
+    want_loss, want = ref.loss_and_grads(p0, trainer.datum.tokens[:2], SHAPE, share)
+    assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
+    got = jax.tree.map(lambda m, w: np.asarray(m) / 0.1 - 1e-4 * w, opt.m[group], p0[group])
+    errors = jax.tree.map(rel, got, want[group])
+    assert max(jax.tree.leaves(errors)) < 2e-4, errors
+
+
+def test_tail_gradients_in_blocks_equal_the_whole_expression(trained):
+    trainer, p0, _ = trained
+    share = ref.Share(trainer.spec.first, trainer.spec.held)
+    batch = trainer.datum.tokens[:2]
+    loss, grads = ref.loss_and_grads(p0, batch, SHAPE, share)
+    tail_loss, tail = ref.tail_loss_and_grads(p0, batch, SHAPE, share, block=8)
+    assert abs(float(loss) - float(tail_loss)) < 1e-5
+    want = {"layer": jax.tree.map(lambda a: a[-1], grads["moe"]), "norm": grads["norm"],
+            "head": grads["head"]}
+    assert max(jax.tree.leaves(jax.tree.map(rel, tail, want))) < 1e-4
+
+
+# ---- the expert layer: shares, no dropped pair
+
+def _expert_layer(rng, held=16):
+    d, w, sw = SHAPE.hidden, MODEL["moe_intermediate_size"], 2 * MODEL["moe_intermediate_size"]
+    n = lambda *s: (rng.standard_normal(s) * 0.3).astype(np.float32)  # noqa: E731
+    return {"norm2": np.ones(d, np.float32), "router": n(d, 16), "eg": n(held, d, w),
+            "eu": n(held, d, w), "ed": n(held, w, d), "sg": n(d, sw), "su": n(d, sw),
+            "sd": n(sw, d)}
+
+
+def _spec(first, held, tokens):
+    model = dict(MODEL)
+    cfg = InputInfo()
+    cfg.seq_layers, cfg.seq_length, cfg.seq_batch = 2, tokens, 1
+    cfg.expert_shards, cfg.expert_shard = 16 // held, first // held
+    return seqlm.SeqSpec.from_cfg(model, cfg)
+
+
+def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(rng):
+    lp = _expert_layer(rng)
+    x = rng.standard_normal((40, SHAPE.hidden)).astype(np.float32)
+    bias = jnp.zeros((16,), jnp.float32)
+    whole, _ = ref.expert_mlp(lp, jnp.asarray(x), bias, SHAPE, ref.Share(0, 16))
+    cast = compute_cast(None)
+    shared = seqlm.nnseq.swiglu(seqlm.nnseq.rms_norm(x, lp["norm2"], SHAPE.eps),
+                                lp["sg"], lp["su"], lp["sd"], cast)
+    total, rows = x + np.asarray(shared), 0
+    for shard in range(8):
+        mine = dict(lp, **{k: lp[k][2 * shard: 2 * shard + 2] for k in ("eg", "eu", "ed")})
+        out, sizes, _ = seqlm.expert_mlp(mine, bias, jnp.asarray(x), _spec(2 * shard, 2, 40), cast)
+        total = total + (np.asarray(out) - x - np.asarray(shared))
+        rows += int(np.asarray(sizes).sum())
+    assert rows == 40 * 3  # every pair was computed by exactly one share
+    assert rel(total, whole) < 1e-5
+    # and one share alone is the reference told the same share
+    part, _ = ref.expert_mlp(mine, jnp.asarray(x), bias, SHAPE, ref.Share(14, 2))
+    assert rel(out, part) < 1e-5
+
+
+def test_no_pair_is_dropped_when_the_router_sends_every_token_to_one_held_expert(rng):
+    lp = _expert_layer(rng, held=4)
+    x = rng.standard_normal((64, SHAPE.hidden)).astype(np.float32)
+    bias = jnp.zeros((16,), jnp.float32).at[5].set(10.0)  # expert 5 is held (4..7)
+    out, sizes, choice = seqlm.expert_mlp(lp, bias, jnp.asarray(x), _spec(4, 4, 64), compute_cast(None))
+    choice = np.asarray(choice)
+    assert np.all(np.any(choice == 5, axis=1))
+    assert int(np.asarray(sizes)[1]) == 64  # all 64 tokens' pairs reached expert 5
+    assert int(np.asarray(sizes).sum()) == int(np.sum((choice >= 4) & (choice < 8)))
+    want, _ = ref.expert_mlp(lp, jnp.asarray(x), bias, SHAPE, ref.Share(4, 4))
+    assert rel(out, want) < 1e-5
+
+
+def test_rows_routed_counter_equals_the_pairs_sent_to_held_experts(tmp_path):
+    trainer = make_trainer(tmp_path, EPOCHS=1)
+    trainer.route_bias = trainer.route_bias.at[:, trainer.spec.first].set(10.0)
+    p0 = jax.tree.map(np.asarray, trainer.params)
+    trainer.run()
+    share = ref.Share(trainer.spec.first, trainer.spec.held)
+    _, own = ref.loss(p0, trainer.datum.tokens[:2], SHAPE, share, np.asarray(trainer.route_bias))
+    own = np.asarray(own)
+    held = int(np.sum((own >= share.first) & (own < share.first + share.held)))
+    assert held >= 2 * 2 * 32  # every token, both layers, at least the favoured expert
+    assert trainer.metrics.counter_get("moe.rows_routed") == held == trainer.routed_history[0]
+    assert trainer.metrics.counter_get("seq.tokens") == 64
+    assert trainer.metrics.snapshot()["gauges"]["moe.load_max_over_mean"] > 2.0
+
+
+def test_grouped_product_walks_the_list_in_chunks_without_changing_it(rng):
+    x = rng.standard_normal((48, 8)).astype(np.float32)
+    w = [rng.standard_normal(s).astype(np.float32) for s in ((3, 8, 6), (3, 8, 6), (3, 6, 8))]
+    sizes = jnp.asarray([5, 0, 17], jnp.int32)
+    cast = compute_cast(None)
+    whole = moe.grouped_swiglu(x, *w, sizes, cast, chunk_rows=48)
+    chunked = moe.grouped_swiglu(x, *w, sizes, cast, chunk_rows=8)
+    assert rel(np.asarray(chunked)[:22], np.asarray(whole)[:22]) < 1e-6
+
+
+# ---- the implicit causal attention against the explicit edge chain
+
+def _causal_graph(s):
+    dst, src = np.nonzero(np.tril(np.ones((s, s), bool)))
+    host = build_graph(src.astype(np.uint32), dst.astype(np.uint32), s, weight="ones")
+    return DeviceGraph.from_host(host)
+
+
+def _edge_chain(graph, q, k, v, scale):
+    """ops/edge.py's chain per (sequence, head) pair: a score per edge, a
+    softmax per destination, a weighted aggregate of the sources."""
+    def one(q, k, v):
+        score = jnp.sum(q[graph.csc_dst] * k[graph.csc_src], axis=-1) * scale
+        alpha = edge.edge_softmax(graph, score)
+        return edge.aggregate_edge_to_dst_weighted(graph, alpha, v)
+    return jax.vmap(one)(q, k, v)
+
+
+@pytest.mark.parametrize("block", [8, 16, 48])
+def test_causal_attention_equals_the_explicit_edge_chain_forward_and_backward(rng, block):
+    n, s, dk, dv = 3, 48, 12, 8
+    q, k, v, cot = (jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+                    for shape in ((n, s, dk), (n, s, dk), (n, s, dv), (n, s, dv)))
+    graph, scale = _causal_graph(s), 0.3
+    got, got_vjp = jax.vjp(lambda *a: causal_edge_attention(*a, scale, block), q, k, v)
+    want, want_vjp = jax.vjp(lambda *a: _edge_chain(graph, *a, scale), q, k, v)
+    assert rel(got, want) < 1e-5
+    for g, w in zip(got_vjp(cot), want_vjp(cot)):
+        assert rel(g, w) < 1e-5
+
+
+def test_causal_attention_never_holds_a_square_of_the_sequence():
+    n, s = 3, 64
+    q = jnp.zeros((n, s, 16))
+    v = jnp.zeros((n, s, 8))
+
+    def shapes(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            out += [tuple(var.aval.shape) for var in eqn.outvars if hasattr(var.aval, "shape")]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                shapes(sub, out)
+        return out
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: causal_edge_attention(*a, 0.25, 16).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    seen = shapes(jax.make_jaxpr(fwd_bwd)(q, q, v).jaxpr, [])
+    assert seen and not [sh for sh in seen if sum(1 for d in sh if d == s) >= 2]
+    assert any(sh[-2:] == (16, 16) for sh in seen)  # the tiles are there
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 5])
+def test_a_destination_block_sees_the_source_blocks_up_to_itself_and_no_other(blocks):
+    """Equal scores and one-hot values by source block: a destination's
+    aggregate is then the share of its in-edges that each block holds."""
+    b, s = 4, 4 * blocks
+    q = jnp.zeros((1, s, 8))
+    v = jnp.asarray(np.repeat(np.eye(blocks, dtype=np.float32), b, axis=0))[None]
+    out = np.asarray(causal_edge_attention(q, q, v, 1.0, b))[0]
+    for i in range(s):
+        want = np.bincount(np.arange(i + 1) // b, minlength=blocks) / (i + 1.0)
+        assert np.allclose(out[i], want, atol=1e-6)
+
+
+# ---- the trainer through run.py's path
+
+def test_two_steps_through_the_cli_path_follow_the_reference(tmp_path):
+    from neutronstarlite_tpu.resilience.supervisor import supervised_run
+
+    tokens = make_tokens(seed=5)
+    np.save(tmp_path / "tokens.npy", tokens)
+    cfg_path = write_cfg(tmp_path, TOKEN_FILE="tokens.npy", WARMUP_EPOCHS=4)
+    cfg = InputInfo.read_from_cfg_file(cfg_path)
+    toolkit = get_algorithm(cfg.algorithm)(cfg, base_dir=str(tmp_path), seed=7)
+    toolkit.init_graph()
+    toolkit.init_nn()
+    p = jax.tree.map(lambda a: np.asarray(a, np.float64), toolkit.params)
+    result = supervised_run(toolkit)
+    assert result["loss"] == toolkit.loss_history[-1] and toolkit.run_summary_record is not None
+    share = ref.Share(toolkit.spec.first, toolkit.spec.held)
+    zeros = jax.tree.map(np.zeros_like, p)
+    m, v = zeros, zeros
+    for step in range(2):
+        loss, grads = ref.loss_and_grads(p, tokens[2 * step: 2 * step + 2], SHAPE, share)
+        assert abs(toolkit.loss_history[step] - float(loss)) < 2e-5 * float(loss)
+        leaves, treedef = jax.tree.flatten(p)
+        out = [ref.adam_step(a, b, c, d, step + 1, 0.0003, 0.0001, warmup=4) for a, b, c, d in zip(
+            leaves, *(treedef.flatten_up_to(t) for t in (grads, m, v)))]
+        before = p
+        p, m, v = (treedef.unflatten([o[i] for o in out]) for i in range(3))
+    moved = jax.tree.map(lambda a, b, c: rel(np.asarray(a, np.float64) - c, b - c),
+                         toolkit.params, p, before)
+    assert max(jax.tree.leaves(moved)) < 2e-2, moved  # a quarter step moves a weight by 4e-3 of itself: float32's grain
+
+
+def test_the_learn_rate_warms_up_linearly_and_other_trainers_keep_their_step():
+    from neutronstarlite_tpu.nn.param import AdamConfig, adam_init, adam_update
+
+    p = {"w": jnp.ones((3,))}
+    g = {"w": jnp.full((3,), 0.5)}
+    plain, _ = adam_update(p, g, adam_init(p), AdamConfig(alpha=0.01, weight_decay=0.0))
+    warm, _ = adam_update(p, g, adam_init(p), AdamConfig(alpha=0.01, weight_decay=0.0, warmup_steps=10))
+    assert np.allclose(1.0 - np.asarray(warm["w"]), (1.0 - np.asarray(plain["w"])) / 10.0, rtol=1e-5)
+    assert AdamConfig().warmup_steps == 0
+
+
+def test_the_committed_cfg_parses_and_names_its_model():
+    cfg_path = os.path.join(REPO, "configs", "moonlight_16b_a3b_ep8.cfg")
+    cfg = InputInfo.read_from_cfg_file(cfg_path)
+    with open(cfg.resolve_path(cfg.model_file, os.path.dirname(cfg_path))) as fh:
+        spec = seqlm.SeqSpec.from_cfg(json.load(fh), cfg)
+    assert (spec.hidden, spec.heads, spec.kv_rank, spec.nope, spec.rope, spec.v_head) == (
+        2048, 16, 512, 128, 64, 128)
+    assert (spec.ffn, spec.expert_width, spec.shared_width, spec.routed, spec.per_token) == (
+        11264, 1408, 2816, 64, 6)
+    assert (spec.moe_layers, spec.held, spec.vocab, spec.tokens) == (4, 8, 20480, 32768)
+    params = jax.eval_shape(lambda k: seqlm.init_params(k, spec), jax.random.PRNGKey(0))
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) == 568484352
+
+
+def test_the_cli_runs_a_cfg(tmp_path):
+    from neutronstarlite_tpu import run
+
+    assert run.main([write_cfg(tmp_path, EPOCHS=1)]) == 0
+
+
+@pytest.mark.parametrize("bad", [64, -1])
+def test_an_id_outside_the_slice_is_refused_not_clamped(tmp_path, bad):
+    tokens = make_tokens()
+    tokens[1, 3] = bad
+    with pytest.raises(ValueError, match="leave the vocabulary slice"):
+        TokenDatum(tokens, 64)
+    with pytest.raises(ValueError, match="leave the vocabulary slice"):
+        make_trainer(tmp_path, tokens=tokens)
+
+
+@pytest.mark.parametrize("key, value, match", [
+    ("EXPERT_SHARDS", 5, "must divide"), ("SEQ_LENGTH", 128, "beyond the model"),
+    ("SEQ_LAYERS", 1, "at least one expert"), ("ATTN_BLOCK", 5, "does not divide"),
+])
+def test_a_cut_the_model_does_not_allow_is_refused(tmp_path, key, value, match):
+    with pytest.raises(ValueError, match=match):
+        make_trainer(tmp_path, **{key: value})
+
+
+def test_checkpoint_save_and_restore(tmp_path):
+    ckpt = tmp_path / "ckpt"
+    first = make_trainer(tmp_path, CHECKPOINT_DIR=str(ckpt), EPOCHS=2)
+    first.run()
+    again = make_trainer(tmp_path, CHECKPOINT_DIR=str(ckpt), EPOCHS=3)
+    assert again.restore(str(ckpt)) == 2
+    for a, b in zip(jax.tree.leaves(first.checkpoint_state()), jax.tree.leaves(again.checkpoint_state())):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    again.run()  # resumes at epoch 2 and trains the one epoch left
+    assert len(again.loss_history) == 1 and int(again.opt_state.step) == 3
+
+
+def test_a_second_run_trains_on_from_where_the_first_stopped(tmp_path):
+    trainer = make_trainer(tmp_path, EPOCHS=2)
+    trainer.run()
+    trainer.cfg.epochs = 3
+    trainer.run()
+    assert len(trainer.loss_history) == 5 and int(trainer.opt_state.step) == 5
+    one = make_trainer(tmp_path, EPOCHS=5)
+    one.run()
+    assert one.loss_history == trainer.loss_history  # the corpus kept cycling
+
+
+# ---- spans, counters, the scope table
+
+def test_spans_and_stages_of_the_funnel_and_the_run_loop(tmp_path):
+    from neutronstarlite_tpu.obs.flight import recent_records
+
+    trainer = make_trainer(tmp_path)
+    trainer.run()
+    spans = recent_records("span")
+    names = {r["name"] for r in spans}
+    assert {"params_init", "datum_upload", "step_build"} <= {r["name"] for r in spans if r["cat"] == "phase"}
+    assert {"run", "epoch", "epoch_key", "step_dispatch", "step_device", "loss_fetch",
+            "epoch_emit", "ckpt_epoch_end"} <= names
+    epochs = [r for r in spans if r["name"] == "epoch"]
+    assert len(epochs) >= 2
+    summary = trainer.run_summary_record
+    assert summary["epochs"] == 2 and summary["loss_history"] == trainer.loss_history
+
+
+def test_the_scope_table_covers_every_named_scope(trained):
+    trainer, _, _ = trained
+    table = trainer.scope_table()
+    assert set(table.values()) == set(seqlm.SCOPES)
+    assert seqlm.scope_of("jit(step)/transpose(jvp(seq/moe/experts))/ragged_dot") == "seq/moe/experts"
+    assert seqlm.scope_of("jit(step)/seq/mla/project/seq/mla/attend/while/body/dot") == "seq/mla/attend"
+    assert seqlm.scope_of("jit(step)/convert_element_type") is None
+
+
+def test_bfloat16_compute_stays_near_the_reference(tmp_path):
+    trainer = make_trainer(tmp_path, PRECISION="bfloat16")
+    p0 = jax.tree.map(np.asarray, trainer.params)
+    trainer.run()
+    share = ref.Share(trainer.spec.first, trainer.spec.held)
+    want, _ = ref.loss(p0, trainer.datum.tokens[:2], SHAPE, share, block=16)
+    assert abs(trainer.loss_history[0] - float(want)) < 1e-3 * float(want)
+    assert jax.tree.leaves(trainer.params)[0].dtype == jnp.float32  # the masters
