@@ -394,6 +394,23 @@ class ToolkitBase:
         must not inherit a yes (models/gcn.py, models/gcn_dist.py)."""
         return False
 
+    def record_table_stats(self, stats: dict) -> None:
+        """How far the aggregation's level tables are padded, where a
+        reader finds it: gauges ``agg.slots_per_edge`` (slots of both
+        directions over twice the edges; stacked tables count every
+        device's slots, so the ratio is one device's) and ``agg.levels``,
+        and the same counts on a ``tables_stats`` phase span. ``stats`` is
+        a table pair's ``padding_stats``."""
+        slots = int(stats["fwd_slots"] + stats["bwd_slots"])
+        edges = 2 * int(stats["real_edges"])
+        levels = int(stats["levels"])
+        self.metrics.gauge_set("agg.slots_per_edge", slots / max(edges, 1))
+        self.metrics.gauge_set("agg.levels", levels)
+        with self.timers.phase(
+            "tables_stats", slots=slots, edges=edges, levels=levels
+        ):
+            pass
+
     def host_input_features(self) -> np.ndarray:
         """The datum's features as a hoisting trainer uploads them: already
         in the compute dtype, so that under PRECISION:bfloat16 the float32

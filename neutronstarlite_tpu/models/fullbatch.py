@@ -160,10 +160,14 @@ class FullBatchTrainer(ToolkitBase):
                     self.compute_graph.fwd.vt,
                 )
             else:
+                est = self.compute_graph.padding_stats(self.host_graph.e_num)
                 log.info(
-                    "OPTIM_KERNEL: ELL gather-only aggregation (%d fwd buckets)",
+                    "OPTIM_KERNEL: ELL gather-only aggregation (%d fwd "
+                    "buckets, %.2fx/%.2fx fwd/bwd slot padding)",
                     len(self.compute_graph.fwd.nbr),
+                    est["fwd_waste_ratio"], est["bwd_waste_ratio"],
                 )
+                self.record_table_stats(est)
             # trainer-specific table adaptation (e.g. GAT wraps the plain
             # EllPair with the attention slot maps); default is identity
             with self.timers.phase("tables_build"):

@@ -565,6 +565,7 @@ class DistGCNTrainer(ToolkitBase):
                         len(self.blocks.fwd.nbr), kern,
                         est["fwd_waste_ratio"], est["bwd_waste_ratio"],
                     )
+                    self.record_table_stats(est)
             else:
                 with self.timers.phase("dist_tables_build"):
                     self.blocks = self.dist.shard(self.mesh)

@@ -10,10 +10,17 @@ scatter at all (XLA lowers scatter-add to a serialized update stream), while
 *gather* is vectorized and fast. So the production layout removes the
 scatter entirely:
 
-- Vertices are grouped into power-of-two in-degree buckets (K = 4, 8, 16, …,
-  next_pow2(max_degree)); each bucket stores a padded dense neighbor table
-  ``nbr [Nk, K]`` + ``wgt [Nk, K]`` (ELLPACK slices, degree-sorted so padding
-  waste is < 2x).
+- Vertices are grouped into in-degree buckets ("levels") of widths K_0 <
+  K_1 < ... (each 4, a multiple of 8 or, above 8192, a power of two; the
+  last at least the largest degree); each bucket stores a padded dense neighbor table ``nbr [Nk, K]``
+  + ``wgt [Nk, K]`` (ELLPACK slices, degree-sorted). A padding slot is
+  gathered and multiplied like a real one, so the widths are chosen per
+  graph: ``level_widths`` takes the degree histogram and returns the at
+  most ``MAX_LEVELS`` widths that hold the fewest slots (a power-of-two
+  ladder wasted up to 2x and 43-50% on the benchmark's graphs; the chosen
+  widths 16-25%, PERF.md PR 29). The stacked distributed tables
+  (parallel/dist_ell.py) call the same function with every device's
+  degrees, so a level is priced at its fullest device's rows.
 - Aggregation for a bucket is ``out[r] = sum_k wgt[r,k] * x[nbr[r,k]]`` —
   one gather plus a dense masked reduction, both native TPU operations; row
   chunks bound the [rows, K, f] gather intermediate in VMEM-friendly sizes.
@@ -30,6 +37,7 @@ pairing (GatherByDstFromSrc / GatherBySrcFromDst, NtsScheduler.hpp:151/:257).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import List, Tuple
 
@@ -46,11 +54,131 @@ from neutronstarlite_tpu.graph.storage import CSCGraph
 # one); the slot cap survives only as a table-layout knob for tests that
 # force specific chunk counts.
 DEFAULT_SLOT_CHUNK = 1 << 21
-_MIN_K = 4
+
+# Most levels one direction's tables may have: each level is one more
+# gather/reduce shape in every program that aggregates, and a shape costs
+# set-up. Sized on the two benchmark graphs (PERF.md, PR 29; my chip runs):
+# at 32 the Reddit cell's tables held 1.128 slots an edge and its warm
+# set-up rose by 2.4-3.0 s of 27-31 (0.13-0.16 s a level over the ladder's
+# 14: program load at the first step, the eval and the input aggregate),
+# against a bound of 10%; at 24 they hold 1.163 (3% more slots) for 1.3-1.7 s.
+MAX_LEVELS = 24
+# Slots of one row chunk as level_widths prices it: the default byte budget
+# at a hidden width of 128 in f32 (half of it at 256; the choice hardly
+# moves with it, the padding it prices is under 1% of the slots).
+_PRICED_CHUNK_SLOTS = (32 << 20) // (4 * 128)
+# Above this width a level's width stays a power of two. Such a level can
+# exceed the byte budget of one gather (K > 32 MiB / 4f: 13,934 at f = 602)
+# and then takes ``ell_tables_aggregate``'s K-chunked path. On the chip that
+# path, under bf16 reads inside a many-level program, gave sums that were
+# not finite where K was no power of two ([6, 227768] and [9, 161560] were
+# wrong even alone; f32 reads and every narrower level were right), and the
+# training loss with them. With K a power of two, as the ladder had it, the
+# runs are finite and pass the benchmark's check, though a throwaway script
+# found rows of such a level too large there as well, in the parent's
+# tables as in these: the fault is open (PERF.md section 7, PR 29). The
+# levels that wide hold a few dozen rows: the ladder there costs 2% of the
+# benchmark's slots.
+_POW2_WIDTHS_ABOVE = 8192
+# Above this width the candidate widths thin to one per 64th of an octave
+# (1.1% steps): finer ones save nothing measurable and the search is
+# quadratic in their number.
+_EXACT_WIDTHS_UP_TO = 512
 
 
-def _next_pow2(x: int) -> int:
-    return 1 << max(int(x) - 1, 0).bit_length()
+def _aligned_width(deg: np.ndarray) -> np.ndarray:
+    """Smallest table width that holds ``deg`` neighbours: 4, or a multiple
+    of 8 (the f32 sublane count of the [rows, K, f] slab), or above
+    ``_POW2_WIDTHS_ABOVE`` a power of two."""
+    deg = np.asarray(deg, dtype=np.int64)
+    width = np.where(deg <= 4, 4, -(-deg // 8) * 8)
+    pow2 = 1 << np.ceil(np.log2(np.maximum(width, 1))).astype(np.int64)
+    return np.where(width > _POW2_WIDTHS_ABOVE, pow2, width)
+
+
+def _chunk_rows(slots: int, K) -> np.ndarray:
+    """Rows of one scan step over a level of width ``K`` under a budget of
+    ``slots`` slots: a multiple of 8 once there are 8 (kind to the
+    compiler's tiling when K is no power of two), never below 1."""
+    rows = np.maximum(slots // np.maximum(K, 1), 1)
+    return np.where(rows >= 8, rows // 8 * 8, rows)
+
+
+def _rows_at_most(degs, widths) -> np.ndarray:
+    """[device, width] count of the device's rows with ``0 < deg <= width``."""
+    return np.stack([
+        np.searchsorted(np.sort(d[d > 0]), widths, side="right") for d in degs
+    ])
+
+
+def _priced(K, rows):
+    """Slots of one level as the program walks it: whole row chunks where
+    the level is scanned (``ell_tables_aggregate`` pads the last one)."""
+    chunk = _chunk_rows(_PRICED_CHUNK_SLOTS, K)
+    return K * np.where(rows <= chunk, rows, -(-rows // chunk) * chunk)
+
+
+def level_slots(widths, degrees_per_device) -> int:
+    """What ``level_widths`` minimises: the slots the program walks for
+    these level widths over these devices' degree arrays. Per level ``K x
+    rows``, rows the fullest device's count of ``K_prev < deg <= K``
+    (stacked tables pad every device to it), in whole row chunks. Zero
+    degrees hold no slot."""
+    widths = np.asarray(widths, dtype=np.int64)
+    degs = [np.asarray(d, dtype=np.int64) for d in degrees_per_device]
+    if widths.size == 0 or not degs:
+        return 0
+    rows = np.diff(_rows_at_most(degs, widths), axis=1, prepend=0).max(axis=0)
+    return int(_priced(widths, rows).sum())
+
+
+def level_widths(degrees_per_device, max_levels: int = MAX_LEVELS) -> np.ndarray:
+    """Level widths ``K_0 < K_1 < ...`` for the tables of the devices that
+    share one stacked layout (a list of one degree array for
+    ``EllBuckets.build``): each an ``_aligned_width`` (4, a multiple of 8,
+    a power of two above ``_POW2_WIDTHS_ABOVE``), the last at least the
+    largest degree, at most ``max_levels`` of them, and among those the set
+    that minimises ``level_slots``. Found exactly, by dynamic programming
+    over the degree histogram: ``best[l][j]`` is the cheapest way to cover
+    every degree up to candidate ``j`` with ``l`` levels, the last of width
+    ``j``. Candidates are the aligned ceilings of the degrees that occur,
+    above ``_EXACT_WIDTHS_UP_TO`` only the largest in each 64th of an
+    octave. Zero degrees are not covered (they hold no slot); every level
+    returned holds a row on some device. Empty input gives no width."""
+    degs = [np.asarray(d, dtype=np.int64) for d in degrees_per_device]
+    degs = [d[d > 0] for d in degs]
+    if not any(d.size for d in degs):
+        return np.zeros(0, dtype=np.int64)
+    cand = np.unique(_aligned_width(np.concatenate(degs)))
+    step = np.ceil(np.log2(cand) * 64)
+    last_of_step = np.append(step[1:] != step[:-1], True)
+    cand = cand[(cand <= _EXACT_WIDTHS_UP_TO) | last_of_step]
+    m = cand.size
+    # below[p, j]: device p's rows of degree <= cand[j]; column 0 is "none"
+    below = np.pad(_rows_at_most(degs, cand), ((0, 0), (1, 0)))
+    # cost[i, j]: one level of width cand[j] over the degrees above cand[i-1]
+    rows = (below[:, None, 1:] - below[:, :m, None]).max(axis=0)
+    inf = np.iinfo(np.int64).max // 4
+    cost = np.where(
+        np.arange(m)[:, None] <= np.arange(m)[None, :], _priced(cand[None, :], rows), inf
+    )
+    best = cost[0].copy()  # one level: everything up to j at width cand[j]
+    came = [np.zeros(m, dtype=np.int64)]
+    totals = [best[-1]]
+    for _ in range(1, min(max_levels, m)):
+        # the level before ends at candidate i-1, i in 1..j
+        via = best[:-1, None] + cost[1:, :]
+        came.append(via.argmin(axis=0))
+        best = np.minimum(via.min(axis=0), inf)
+        totals.append(best[-1])
+    n = int(np.argmin(totals))  # the fewest levels among the cheapest
+    picked, j = [], m - 1
+    for l in range(n, -1, -1):
+        picked.append(j)
+        j = int(came[l][j]) if l else -1
+    widths = cand[picked[::-1]]
+    held = np.diff(below[:, np.asarray(picked[::-1]) + 1], axis=1, prepend=0).max(axis=0)
+    return widths[held > 0]
 
 
 # Byte budget for one [rows, K, f] gather intermediate. At the default
@@ -154,7 +282,7 @@ def ell_tables_aggregate(x, nbrs, wgts, slot_chunk: int, out_dtype=None) -> jax.
             # rows-of-1 chunks would still breach the byte bound; chunk K
             outs.append(k_chunked_sum(nbr, wgt))
             continue
-        rows = max(min(slot_chunk, slot_budget) // K, 1)
+        rows = int(_chunk_rows(min(slot_chunk, slot_budget), K))
         if Nk <= rows:
             outs.append(row_sum(nbr, wgt))
             continue
@@ -217,10 +345,8 @@ class EllBuckets:
         if use_native:
             adj32 = np.ascontiguousarray(adj, np.int32)
             w32 = np.ascontiguousarray(weights, np.float32)
-        while i < v_num:
-            K = max(_next_pow2(max(int(sdeg[i]), 1)), _MIN_K)
+        for K in level_widths([deg]).tolist():
             j = int(np.searchsorted(sdeg, K, side="right"))
-            j = max(j, i + 1)
             ids = order[i:j]
             Nk = len(ids)
             nbr = np.zeros((Nk, K), dtype=np.int32)
@@ -291,6 +417,30 @@ class EllPair:
             slot_chunk,
         )
         return EllPair(fwd=fwd, bwd=bwd)
+
+    def padding_stats(self, real_edges: int) -> dict:
+        """Slot occupancy of both directions, as
+        ``parallel/dist_ell.DistEllPair.padding_stats`` reports it."""
+        return table_padding_stats(self.fwd.nbr, self.bwd.nbr, real_edges)
+
+
+def table_padding_stats(fwd_nbr, bwd_nbr, real_edges: int) -> dict:
+    """Slots of two directions' level tables (any leading device axis
+    counted) against the edges they hold: the only padding left is the
+    rounding of a degree up to its level's width and, stacked, the
+    fullest device's row count per level."""
+    fwd = sum(math.prod(n.shape) for n in fwd_nbr)
+    bwd = sum(math.prod(n.shape) for n in bwd_nbr)
+    return {
+        "real_edges": int(real_edges),
+        "fwd_slots": fwd,
+        "bwd_slots": bwd,
+        "fwd_waste_ratio": fwd / max(real_edges, 1),
+        "bwd_waste_ratio": bwd / max(real_edges, 1),
+        "levels": max(
+            sum(n.shape[-1] > 0 for n in nbrs) for nbrs in (fwd_nbr, bwd_nbr)
+        ),
+    }
 
 
 @jax.custom_vjp

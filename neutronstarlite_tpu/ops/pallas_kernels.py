@@ -52,7 +52,6 @@ from jax.experimental import pallas as pl
 from neutronstarlite_tpu.ops.ell import (
     EllBuckets,
     EllPair,
-    _next_pow2,
     ell_tables_aggregate,
 )
 
@@ -181,6 +180,10 @@ def merge_level_tables(nbrs, wgts, min_k: int, row_axis: int = 0):
         merged_nbr.append(jnp.concatenate(group_n, axis=row_axis))
         merged_wgt.append(jnp.concatenate(group_w, axis=row_axis))
     return merged_nbr, merged_wgt
+
+
+def _next_pow2(x: int) -> int:
+    return 1 << max(int(x) - 1, 0).bit_length()
 
 
 def effective_min_k(total_slots: int, n_rows: int, min_k: int) -> int:

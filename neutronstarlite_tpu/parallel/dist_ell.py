@@ -11,11 +11,12 @@ the OPTIM_KERNEL variant of the distributed layer trades the ring for:
      device's destination rows, with *global* (padded-space) source ids.
 
 SPMD needs identical per-device programs, so the per-device bucket tables
-are padded to a common shape: bucket levels K are global (union over
-devices), each level's row count is the max over devices, and padding rows
-carry weight 0. Each device then consumes its slice of the stacked
-``[P, Nk, K]`` tables — the same "static shapes replace variable-length
-messages" move the mirror tables make (parallel/mirror.py).
+are padded to a common shape: bucket levels K are global (one set of
+widths for all devices, chosen by ``ops/ell.level_widths`` to hold the
+fewest slots given that), each level's row count is the max over devices,
+and padding rows carry weight 0. Each device then consumes its slice of
+the stacked ``[P, Nk, K]`` tables — the same "static shapes replace
+variable-length messages" move the mirror tables make (parallel/mirror.py).
 
 The backward of (all_gather -> local gather -> inverse-perm) is generated by
 autodiff: the transpose of all_gather is reduce-scatter (psum_scatter), and
@@ -35,7 +36,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as PS
 
-from neutronstarlite_tpu.ops.ell import _next_pow2, _MIN_K, DEFAULT_SLOT_CHUNK
+from neutronstarlite_tpu.ops.ell import (
+    DEFAULT_SLOT_CHUNK,
+    level_widths,
+    table_padding_stats,
+)
 from neutronstarlite_tpu.parallel.dist_graph import DistGraph
 from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS, shard_map
 
@@ -127,15 +132,15 @@ class DistEll:
     @staticmethod
     def _build_from_blocks(dist: DistGraph, transpose: bool, slot_chunk: int):
         P, vp = dist.partitions, dist.vp
-        per_dev, max_deg = per_device_adjacency(dist, transpose)
+        per_dev, _ = per_device_adjacency(dist, transpose)
 
-        # global bucket levels (shared across devices for SPMD uniformity)
-        levels = []
-        K = _MIN_K
-        top = max(_next_pow2(max(max_deg, 1)), _MIN_K)
-        while K <= top:
-            levels.append(K)
-            K *= 2
+        # global bucket levels (shared across devices for SPMD uniformity),
+        # priced as these stacked tables pay: every level at its fullest
+        # device's rows. A zero-degree row (the padded vertex space has
+        # them) sits in the first level, so it is priced there.
+        levels = level_widths(
+            [np.maximum(dev[3], 1) for dev in per_dev]
+        ).tolist()
 
         # per device: assign vertices to levels, then pad rows to the max
         rows_per_level = [0] * len(levels)
@@ -302,16 +307,11 @@ class DistEllPair:
         padded-layout waste must be quantified and bounded, the analog of the
         reference's balanced-chunk goal, core/graph.hpp:1186-1211).
 
-        Sources of padding: next-pow2 degree rounding inside a level (< 2x)
-        and the cross-device row max per level (SPMD-uniform shapes)."""
-        fwd, bwd = self.fwd.slot_count(), self.bwd.slot_count()
-        return {
-            "real_edges": int(real_edges),
-            "fwd_slots": fwd,
-            "bwd_slots": bwd,
-            "fwd_waste_ratio": fwd / max(real_edges, 1),
-            "bwd_waste_ratio": bwd / max(real_edges, 1),
-        }
+        Sources of padding: a degree's rounding up to its level's width and
+        the cross-device row max per level (SPMD-uniform shapes);
+        ``ops/ell.level_widths`` chooses the widths that make their sum
+        least."""
+        return table_padding_stats(self.fwd.nbr, self.bwd.nbr, real_edges)
 
     def shard(self, mesh: Mesh) -> "DistEllPair":
         return DistEllPair(fwd=self.fwd.shard(mesh), bwd=self.bwd.shard(mesh))

@@ -14,6 +14,7 @@ from neutronstarlite_tpu.parallel.dist_ell import (
     DistEllPair,
     dist_ell_gather_simulated,
 )
+from neutronstarlite_tpu.ops.ell import MAX_LEVELS
 from neutronstarlite_tpu.parallel.dist_graph import DistGraph
 
 multidevice = pytest.mark.skipif(
@@ -154,8 +155,11 @@ def test_dist_ell_k_chunked_hub_under_shard_map(rng, monkeypatch):
 def test_padding_waste_bounded_on_power_law(rng):
     """VERDICT round-1 item 8: quantify and bound the padded-layout waste on
     a power-law graph at P=8. The alpha-weighted partitioning keeps the
-    [P, P, Eb] blocks under 2x; the ELL tables carry the extra next-pow2
-    degree rounding and the cross-device row max, bounded at 4x here."""
+    [P, P, Eb] blocks under 2x; the ELL tables carry each degree's rounding
+    up to its level's width and the cross-device row max. With the widths
+    chosen from the degree histogram (ops/ell.level_widths) they read 1.83x
+    and 1.82x here, where the power-of-two ladder read 2.35x (bound then:
+    4x)."""
     from neutronstarlite_tpu.graph.storage import build_graph
     from neutronstarlite_tpu.graph.synthetic import synthetic_power_law_graph
 
@@ -171,8 +175,42 @@ def test_padding_waste_bounded_on_power_law(rng):
 
     pair = DistEllPair.build(dist)
     est = pair.padding_stats(stats["real_edges"])
-    assert est["fwd_waste_ratio"] < 4.0, est
-    assert est["bwd_waste_ratio"] < 4.0, est
+    assert est["fwd_waste_ratio"] < 1.9, est
+    assert est["bwd_waste_ratio"] < 1.9, est
+    assert est["levels"] <= MAX_LEVELS
+
+
+@pytest.mark.parametrize("direction", ["forward", "transposed"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 3e-2)])
+def test_dist_ell_over_chosen_widths_matches_single_chip(rng, direction, dtype, tol):
+    """The stacked tables over widths that are no powers of two, through
+    the collective-free twin, against the single-chip EllPair on the same
+    power-law graph (both directions, f32 and bf16 reads)."""
+    from neutronstarlite_tpu.graph.storage import build_graph
+    from neutronstarlite_tpu.graph.synthetic import synthetic_power_law_graph
+    from neutronstarlite_tpu.ops.ell import (
+        EllPair,
+        ell_gather_dst_from_src,
+        ell_gather_src_from_dst,
+    )
+
+    src, dst = synthetic_power_law_graph(1200, 60_000, seed=5)
+    g = build_graph(src, dst, 1200, weight="gcn_norm")
+    dg = DistGraph.build(g, 4, edge_chunk=256)
+    pair = DistEllPair.build(dg)
+    dell = pair.fwd if direction == "forward" else pair.bwd
+    widths = [n.shape[-1] for n in dell.nbr]
+    assert any(k & (k - 1) for k in widths), widths
+    assert len(widths) <= MAX_LEVELS and widths == sorted(set(widths))
+    x = rng.standard_normal((g.v_num, 24)).astype(np.float32)
+    xp = jnp.asarray(dg.pad_vertex_array(x)).astype(dtype)
+    got = dg.unpad_vertex_array(
+        np.asarray(dist_ell_gather_simulated(dell, xp), np.float64)
+    )
+    single = EllPair.from_host(g)
+    op = ell_gather_dst_from_src if direction == "forward" else ell_gather_src_from_dst
+    want = np.asarray(op(single, jnp.asarray(x)), np.float64)
+    assert np.abs(got - want).max() <= tol * np.abs(want).max()
 
 
 @multidevice

@@ -72,11 +72,14 @@ def test_dist_ell_slot_waste_bounded():
     g, dg = _power_law_rig()
     pair = DistEllPair.build(dg)
     stats = pair.padding_stats(g.e_num)
-    # sources of padding: next-pow2 level rounding (< 2x) and cross-device
-    # row max per level; 4x absolute headroom on the power-law fixture
-    # (observed ~2.5x) — a level-assignment regression trips this
-    assert stats["fwd_waste_ratio"] <= 4.0, stats
-    assert stats["bwd_waste_ratio"] <= 4.0, stats
+    # sources of padding: a degree's rounding up to its level's width and
+    # the cross-device row max per level. Mean degree 10 over eight devices:
+    # most rows sit in the 4- and 8-wide levels, whose alignment no choice
+    # removes. Observed 1.98x / 2.01x with the widths chosen from the degree
+    # histogram (the power-of-two ladder: 3.23x / 3.13x under a 4x bound) —
+    # a level-assignment regression trips this
+    assert stats["fwd_waste_ratio"] <= 2.3, stats
+    assert stats["bwd_waste_ratio"] <= 2.3, stats
 
 
 def test_dist_blocked_slot_waste_bounded():

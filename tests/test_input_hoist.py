@@ -448,3 +448,61 @@ def test_engine_over_a_fullbatch_gcn_predicts_from_the_raw_features(
     assert toolkit.raw_feature.shape == (V + 4, F0)
     parent.raw_feature = jnp.zeros((V + 4, F0), jnp.float32)
     assert parent.feature.shape == (V + 4, F0)
+
+
+# ---- (h) how far the level tables are padded --------------------------------
+
+
+def _benchmark_generator():
+    """benchmark/harness/data.py:power_law_edges, by its path (the
+    benchmark is no package of the program's)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "harness", "data.py",
+    )
+    spec = importlib.util.spec_from_file_location("_bench_data", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.power_law_edges
+
+
+# a fiftieth of each benchmark configuration's graph (seed 7, exponent 3),
+# through the trainer that its cell runs. Measured (my CPU counts, PR 29):
+# 1.196 on one device (the power-of-two ladder: 1.464) and 1.468 stacked
+# over four (1.862). At this size the largest hub (degree 67,801) is a
+# tenth of a device's edges, every device pads to it, and its level keeps
+# the ladder's width of 131,072 (ops/ell._POW2_WIDTHS_ABOVE), so the stacked
+# bound is 1.55 here where the full-size tables read 1.25.
+@pytest.mark.parametrize("algo,kw,vertices,edges,symmetric,limit", [
+    ("GCNCPU", {}, 232965 // 50, 114615892 // 50, False, 1.25),
+    ("GCNDIST", {"partitions": 4}, 2449029 // 50,
+     (126167309 - 2449029) // 100 * 2 + 2449029 // 50, True, 1.55),
+], ids=["reddit_fiftieth_one_device", "products_fiftieth_stacked_over_four"])
+def test_slots_per_edge_counter_on_the_benchmark_graphs(
+    algo, kw, vertices, edges, symmetric, limit
+):
+    from neutronstarlite_tpu.obs import flight
+    from neutronstarlite_tpu.ops.ell import MAX_LEVELS
+
+    src, dst = _benchmark_generator()(
+        vertices, edges, 7, exponent=3.0, self_loops=True, symmetric=symmetric
+    )
+    g = build_graph(src, dst, vertices, weight="gcn_norm")
+    datum = GNNDatum.random_generate(vertices, F0, CLASSES, seed=1)
+    cfg = _cfg(algo, optim_kernel=True, **kw)
+    cfg.vertices = vertices
+    trainer = get_algorithm(algo).from_arrays(cfg, src, dst, datum, host_graph=g)
+    gauges = trainer.metrics.snapshot()["gauges"]
+    assert 1.0 <= gauges["agg.slots_per_edge"] < limit, gauges
+    assert 1 <= gauges["agg.levels"] <= MAX_LEVELS
+    span = [r for r in flight.recent_records("span") if r["name"] == "tables_stats"][-1]
+    assert span["cat"] == "phase" and span["edges"] == 2 * g.e_num
+    assert span["levels"] == gauges["agg.levels"]
+    assert span["slots"] / span["edges"] == gauges["agg.slots_per_edge"]
+    tables = trainer.compute_graph if algo == "GCNCPU" else trainer.blocks
+    assert span["slots"] == sum(
+        int(np.prod(n.shape)) for side in (tables.fwd, tables.bwd) for n in side.nbr
+    )

@@ -645,11 +645,13 @@ EPOCH_STAGES = {
     "sampled": ["step_dispatch", "step_device", "loss_fetch", "epoch_emit",
                 "ckpt_epoch_end"],
 }
-FUNNEL_PHASES = {  # both GCN trainers hoist the input aggregate
-    "fullbatch": {"tune_resolve", "tables_build", "params_init",
+FUNNEL_PHASES = {  # both GCN trainers hoist the input aggregate, and
+    # both build ELL tables, whose padding the tables_stats span carries
+    "fullbatch": {"tune_resolve", "tables_build", "tables_stats", "params_init",
                   "datum_upload", "input_aggregate", "step_build"},
     "dist": {"tune_resolve", "dist_graph_build", "dist_tables_build",
-             "datum_upload", "params_init", "input_aggregate", "step_build"},
+             "tables_stats", "datum_upload", "params_init", "input_aggregate",
+             "step_build"},
     "sampled": {"tune_resolve"},  # its datum uploads at the first step
 }
 
