@@ -147,36 +147,9 @@ def load_cached_graph(d: str):
 # ---- worker: measure ONE config in this process ----------------------------
 
 
-def build_host_tables(path, host_graph, kernel_tile):
-    """Path -> prebuilt host aggregation tables. The ONE place the
-    path-to-table mapping lives: worker_main and tools/aot_bench_path both
-    call this, so the AOT tool always compiles the exact program the
-    worker runs."""
-    if path == "ell":
-        # rebuilt per worker: ~24 s at full scale (docs/PERF.md section 3b),
-        # cheap enough that on-disk caching of the ragged bucket arrays
-        # isn't worth its complexity (isolation is the point here)
-        from neutronstarlite_tpu.ops.ell import EllPair
-
-        return EllPair.from_host(host_graph)
-    if path == "pallas":
-        # PALLAS:1 = the streamed block-sparse kernel at the DEFAULT src
-        # tile (the resident-gather design cannot lower to Mosaic —
-        # ops/pallas_kernels.py docstring); path "bsp" A/Bs an explicit
-        # KERNEL_TILE src-tile height against this default
-        from neutronstarlite_tpu.ops.bsp_ell import BspEllPair
-
-        return BspEllPair.from_host(host_graph)
-    if path == "blocked":
-        from neutronstarlite_tpu.ops.blocked_ell import BlockedEllPair
-
-        return BlockedEllPair.from_host(host_graph, vt=kernel_tile)
-    return None
-
-
 def _make_trainer(
     order, path, precision, src, dst, datum, v_num, epochs, warmup,
-    host_graph=None, host_ell=None, kernel_tile=0,
+    host_graph=None, kernel_tile=0,
 ):
     from neutronstarlite_tpu.models.gcn import GCNEagerTrainer, GCNTrainer
     from neutronstarlite_tpu.utils.config import InputInfo
@@ -195,10 +168,7 @@ def _make_trainer(
     cfg.kernel_tile = kernel_tile if path in ("blocked", "bsp") else 0
     cfg.pallas_kernel = path in ("pallas", "bsp")
     cls = GCNEagerTrainer if order == "eager" else GCNTrainer
-    return cls.from_arrays(
-        cfg, src, dst, datum, host_graph=host_graph,
-        host_ell=host_ell if path in ("ell", "pallas", "blocked") else None,
-    )
+    return cls.from_arrays(cfg, src, dst, datum, host_graph=host_graph)
 
 
 def _timed_run(trainer, warmup):
@@ -241,17 +211,16 @@ def worker_main(args) -> int:
     sizes = [int(s) for s in LAYERS.split("-")]
     datum = GNNDatum.random_generate(v_num, sizes[0], N_LABELS, seed=7)
 
-    t0 = time.time()
-    host_ell = build_host_tables(path, host_graph, args.kernel_tile)
-    tables_s = time.time() - t0
-
+    # the trainer builds the path's tables from its cfg
+    # (ops/aggregate.build_tables), in its tables_build phase
     t0 = time.time()
     trainer = _make_trainer(
         order, path, precision, src, dst, datum, v_num,
         epochs=args.epochs, warmup=args.warmup, host_graph=host_graph,
-        host_ell=host_ell, kernel_tile=args.kernel_tile,
+        kernel_tile=args.kernel_tile,
     )
-    build_s = time.time() - t0
+    tables_s = trainer.timers.total("tables_build")
+    build_s = time.time() - t0 - tables_s
     epoch_s, result = _timed_run(trainer, args.warmup)
     # the obs run_summary (epoch attribution, phase buckets, wire/memory
     # counters) rides the worker JSON so the supervisor can attach it
@@ -447,8 +416,7 @@ def main(argv=None) -> int:
                 "float32" if args.precision == "bfloat16" else "bfloat16"
             )
         # pallas = the streamed block-sparse kernel at its default src
-        # tile (the resident-gather design cannot lower to Mosaic,
-        # ops/pallas_kernels.py docstring); its one-hot-MXU cost model
+        # tile (ops/bsp_ell.py); its one-hot-MXU cost model
         # bounds the epoch ~10-100x under the XLA gather path's observed
         # time. blocked/bsp (explicit-tile A/B) stay behind --sweep full.
         # ELL FIRST (round 4): the roofline crowns eager/ell the expected

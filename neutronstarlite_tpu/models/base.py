@@ -85,7 +85,6 @@ class ToolkitBase:
         self.host_graph: Optional[CSCGraph] = None
         self.graph: Optional[DeviceGraph] = None
         self.datum: Optional[GNNDatum] = None
-        self.host_ell = None  # optional prebuilt ops.ell.EllPair (shared)
         self._raw_feature = None  # raw_feature's copy on a hoisting trainer
         self.epoch_times = []
         # per-epoch training losses, appended by every run loop — the
@@ -472,19 +471,14 @@ class ToolkitBase:
         datum: GNNDatum,
         seed: int = 0,
         host_graph=None,
-        host_ell=None,
     ) -> "ToolkitBase":
         """Construct directly from in-memory edge list + datum (tests/bench).
 
         ``host_graph``: pass a prebuilt CSCGraph (matching ``weight_mode``)
         to share one host build across many trainers — the bench sweep
         rebuilds 9 configs over the same 114M-edge graph and the host
-        CSC/CSR build dominates its wall time otherwise.
-        ``host_ell``: likewise a prebuilt ops.ell.EllPair for OPTIM_KERNEL
-        configs (the tables are precision-independent and already device-
-        resident, so sharing also skips repeat HBM uploads)."""
+        CSC/CSR build dominates its wall time otherwise."""
         t = cls(cfg, seed=seed)
-        t.host_ell = host_ell
         if host_graph is None:
             with t.timers.phase("host_graph_build"):
                 host_graph = build_graph(

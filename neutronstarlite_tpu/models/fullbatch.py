@@ -28,10 +28,10 @@ class FullBatchTrainer(ToolkitBase):
     """Template for single-mesh full-batch models (GCN/GAT/GIN/CommNet...)."""
 
     # models whose only graph op is the fused weighted aggregation run it
-    # over the gather-only ELL layout (OPTIM_KERNEL:1, ops/ell.py); GAT
-    # rides the same layout through the fused attention path (ops/ell_gat,
-    # via adapt_ell_graph); GGCN's multi-channel edge chain still needs the
-    # CSC edge arrays and keeps DeviceGraph
+    # over the table pair ops/aggregate.build_tables chooses for the cfg
+    # (OPTIM_KERNEL:1); GAT rides the ELL pair through the fused attention
+    # path (ops/ell_gat, via adapt_ell_graph); GGCN's multi-channel edge
+    # chain still needs the CSC edge arrays and keeps DeviceGraph
     supports_optim_kernel = False
 
     def init_params(self, key):
@@ -102,72 +102,17 @@ class FullBatchTrainer(ToolkitBase):
             )
         elif self._wants_ell():
             # drop the (unused on this path) DeviceGraph edge arrays BEFORE
-            # shipping the ELL tables so peak HBM never holds both O(E)
+            # shipping the tables so peak HBM never holds both O(E)
             # structures (base.init_graph also skips the device upload when
             # it sees this path coming)
             self.graph = None
-            from neutronstarlite_tpu.ops.blocked_ell import BlockedEllPair
-            from neutronstarlite_tpu.ops.bsp_ell import BspEllPair
-            from neutronstarlite_tpu.ops.ell import EllPair
-            from neutronstarlite_tpu.ops.pallas_kernels import PallasEllPair
+            from neutronstarlite_tpu.ops.aggregate import build_tables
 
             with self.timers.phase("tables_build"):
-                if self.host_ell is not None:
-                    self.compute_graph = self.host_ell
-                elif cfg.pallas_kernel and os.environ.get(
-                    "NTS_PALLAS_RESIDENT", "0"
-                ) == "1":
-                    # the resident-table kernel cannot lower to Mosaic (TPU
-                    # gather restriction, ops/pallas_kernels.py docstring) —
-                    # interpret-mode experiments only
-                    self.compute_graph = PallasEllPair.from_host(self.host_graph)
-                elif cfg.pallas_kernel:
-                    # PALLAS:1 -> the streamed block-sparse kernel at ANY
-                    # scale: the one fused aggregation design Mosaic can
-                    # compile (one-hot MXU combine, no gather). KERNEL_TILE:vt
-                    # sets the src-tile height explicitly.
-                    self.compute_graph = BspEllPair.from_host(
-                        self.host_graph,
-                        **({"vt": cfg.kernel_tile} if cfg.kernel_tile > 0 else {}),
-                    )
-                elif cfg.kernel_tile > 0:
-                    self.compute_graph = BlockedEllPair.from_host(
-                        self.host_graph, vt=cfg.kernel_tile
-                    )
-                else:
-                    self.compute_graph = EllPair.from_host(self.host_graph)
-            if isinstance(self.compute_graph, BlockedEllPair):
-                log.info(
-                    "OPTIM_KERNEL: blocked ELL aggregation (%d src tiles of "
-                    "%d vertices, %d stacked levels)",
-                    self.compute_graph.fwd.n_tiles,
-                    self.compute_graph.fwd.vt,
-                    len(self.compute_graph.fwd.nbr),
-                )
-            elif isinstance(self.compute_graph, PallasEllPair):
-                log.info(
-                    "OPTIM_KERNEL: Pallas fused ELL aggregation (%d fwd "
-                    "buckets, row_tile %d)",
-                    len(self.compute_graph.fwd.nbr),
-                    self.compute_graph.row_tile,
-                )
-            elif isinstance(self.compute_graph, BspEllPair):
-                log.info(
-                    "OPTIM_KERNEL: streamed block-sparse Pallas aggregation "
-                    "(%d fwd blocks, dt=%d vt=%d)",
-                    self.compute_graph.fwd.nbr.shape[0],
-                    self.compute_graph.fwd.dt,
-                    self.compute_graph.fwd.vt,
-                )
-            else:
-                est = self.compute_graph.padding_stats(self.host_graph.e_num)
-                log.info(
-                    "OPTIM_KERNEL: ELL gather-only aggregation (%d fwd "
-                    "buckets, %.2fx/%.2fx fwd/bwd slot padding)",
-                    len(self.compute_graph.fwd.nbr),
-                    est["fwd_waste_ratio"], est["bwd_waste_ratio"],
-                )
-                self.record_table_stats(est)
+                self.compute_graph, stats = build_tables(cfg, self.host_graph)
+            log.info("OPTIM_KERNEL: %s", self.compute_graph.describe())
+            if stats is not None:
+                self.record_table_stats(stats)
             # trainer-specific table adaptation (e.g. GAT wraps the plain
             # EllPair with the attention slot maps); default is identity
             with self.timers.phase("tables_build"):

@@ -22,7 +22,7 @@ dropout over the feature slab, which is constant (no input dropout, no
 gradient into the features, tables and weights built once), so
 ``exchange(x)`` at ``i == 0`` is the same ``[P*vp, f0]`` array in every
 epoch. ``DistGCNTrainer`` computes it in the funnel's ``input_aggregate``
-phase through the same ``dist_exchange`` over its own ``blocks`` at the
+phase through the same ``blocks.exchange`` over its own ``blocks`` at the
 same precision (``dist_aggregate_input``), sharded as ``feature_p`` is,
 and every step takes it as ``feature_p`` (``dist_gcn_forward(...,
 input_aggregated=True)``): exact, a loop invariant moved out of the loop.
@@ -47,9 +47,8 @@ from neutronstarlite_tpu.resilience.faults import fault_point
 from neutronstarlite_tpu.models.gcn import init_gcn_params
 from neutronstarlite_tpu.nn.layers import batch_norm_apply, compute_cast, dropout
 from neutronstarlite_tpu.nn.param import AdamConfig, adam_init, adam_update
-from neutronstarlite_tpu.parallel.dist_graph import DistGraph
-from neutronstarlite_tpu.parallel.dist_ops import dist_gather_dst_from_src
-from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS, make_mesh
+from neutronstarlite_tpu.parallel.layouts import build_exchange
+from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS
 from neutronstarlite_tpu.utils.logging import get_logger
 from neutronstarlite_tpu.utils.timing import get_time
 
@@ -95,94 +94,19 @@ def gcn_layer_nn(i, n_layers, layer, agg, x_in, valid_mask, key, drop_rate,
     return dropout(jax.random.fold_in(key, i), h, drop_rate, train)
 
 
-def dist_exchange(mesh, dist, blocks, v, wire_dtype=None, partitioner=None):
-    """One cross-partition aggregation of ``v`` ``[P*vp, f]``, by the
-    layout ``blocks`` selects: the [P, P, Eb] 3-tuple is the ppermute
-    ring, a DistEllPair is the OPTIM_KERNEL gather-only path, a
-    RingBlockedPair is the DIST_PATH:ring_blocked pipelined ring
-    (parallel/dist_ring_blocked.py — ``wire_dtype`` optionally narrows its
-    ICI shipments; ``mesh=None`` selects its collective-free sim twin), the
-    9-tuple is the round-5 SPLIT mirror exchange (remote-only all_to_all +
-    resident local edges; ``dist`` is then the SplitMirror — what
-    COMM_LAYER:mirror ships), and the legacy 5-tuple is the uniform
-    MirrorGraph all_to_all."""
-    from neutronstarlite_tpu.parallel.dist_blocked import (
-        DistBlockedEllPair,
-        dist_blocked_gather_dst_from_src,
-    )
-    from neutronstarlite_tpu.parallel.dist_bsp import (
-        DistBspPair,
-        dist_bsp_gather_dst_from_src,
-    )
-    from neutronstarlite_tpu.parallel.dist_edge_ops import (
-        dist_gather_dst_from_src_mirror,
-        dist_gather_dst_from_src_mirror_split,
-    )
-    from neutronstarlite_tpu.parallel.dist_ell import (
-        DistEllPair,
-        dist_ell_gather_dst_from_src,
-    )
-    from neutronstarlite_tpu.parallel.dist_ring_blocked import (
-        RingBlockedPair,
-        dist_ring2d_gather_dst_from_src,
-        dist_ring_blocked_gather_dst_from_src,
-        dist_ring_blocked_gather_simulated,
-    )
-
-    if isinstance(blocks, RingBlockedPair):
-        if partitioner is not None and mesh is not None:
-            # the partitioner's 2D (vertex x feature) mesh: the ring
-            # rotates over the vertex axis while each device works a
-            # [vp, f/Pf] feature slab (parallel/partitioner.py)
-            return dist_ring2d_gather_dst_from_src(
-                mesh, blocks, v, wire_dtype, pf=partitioner.pf
-            )
-        if mesh is None:
-            # collective-free sim twin — also the 2D layout's
-            # exchange twin: the aggregation is feature-column-
-            # independent, so the full-width sim IS bitwise the
-            # slab-sharded collective ring (the 2D-specific math,
-            # the contraction's partial-sum order, lives in
-            # partitioner.contract)
-            return dist_ring_blocked_gather_simulated(
-                blocks, v, wire_dtype
-            )
-        return dist_ring_blocked_gather_dst_from_src(
-            mesh, blocks, v, wire_dtype
-        )
-    if isinstance(blocks, DistBspPair):
-        return dist_bsp_gather_dst_from_src(mesh, blocks, v)
-    if isinstance(blocks, DistBlockedEllPair):
-        return dist_blocked_gather_dst_from_src(mesh, blocks, v)
-    if isinstance(blocks, DistEllPair):
-        return dist_ell_gather_dst_from_src(mesh, blocks, v)
-    if isinstance(blocks, tuple) and len(blocks) == 9:
-        # round 5: split layout — remote-only all_to_all + resident
-        # local edges (self-loop graphs saturate the uniform Mb at vp)
-        return dist_gather_dst_from_src_mirror_split(
-            mesh, dist, blocks, v
-        )
-    if isinstance(blocks, tuple) and len(blocks) == 5:
-        return dist_gather_dst_from_src_mirror(mesh, dist, blocks, v)
-    return dist_gather_dst_from_src(
-        mesh, dist.partitions, dist.vp, dist.edge_chunk, blocks, v
-    )
-
-
-def dist_aggregate_input(mesh, dist, blocks, x, compute_dtype=None,
+def dist_aggregate_input(mesh, blocks, x, compute_dtype=None,
                          wire_dtype=None, partitioner=None):
     """Layer 1's aggregate of the standard order: what ``dist_gcn_forward``
     computes at ``i == 0`` from the feature slab, by the same cast and the
     same exchange."""
-    return dist_exchange(
-        mesh, dist, blocks, compute_cast(compute_dtype)(x), wire_dtype,
-        partitioner,
+    return blocks.exchange(
+        mesh, compute_cast(compute_dtype)(x), wire_dtype=wire_dtype,
+        partitioner=partitioner,
     )
 
 
 def dist_gcn_forward(
     mesh,
-    dist,
     blocks,
     params,
     x,
@@ -199,7 +123,8 @@ def dist_gcn_forward(
     tap=None,
     input_aggregated: bool = False,
 ):
-    """``blocks`` selects the exchange (``dist_exchange``). ``layer_nn`` is
+    """``blocks`` is the layout's tables and runs the exchange
+    (``blocks.exchange``, parallel/layouts.py). ``layer_nn`` is
     the per-layer vertex NN over the exchanged aggregate — the fuse-op
     toolkits (GCN/GIN/CommNet)
     share the exchange engine and differ only here, exactly the reference's
@@ -235,7 +160,9 @@ def dist_gcn_forward(
             # matmuls, the graph exchange replaced by identity — the
             # nn_time/graph_time split (models/debuginfo.py)
             return v
-        return dist_exchange(mesh, dist, blocks, v, wire_dtype, partitioner)
+        return blocks.exchange(
+            mesh, v, wire_dtype=wire_dtype, partitioner=partitioner
+        )
 
     # PRECISION:bfloat16 — the layer_nn returns bf16 activations, so the
     # exchange (ring ppermute / all_gather / all_to_all) ships HALF the
@@ -299,276 +226,34 @@ class DistGCNTrainer(ToolkitBase):
         cls = type(self)
         return not cls.eager and cls.layer_nn is gcn_layer_nn
 
-    @staticmethod
-    def resolve_comm_layer(cfg, host_graph, P: int) -> str:
-        """ring | ell | mirror. Explicit COMM_LAYER wins; OPTIM_KERNEL:1
-        keeps its historical meaning (ell); auto compares the per-layer WIRE
-        rows of the two dense-feature exchanges — both ship P-1 remote
-        chunks per device per layer (the local chunk never crosses the
-        interconnect), of vp shard rows (ring) vs Mb compacted mirror rows
-        — and picks the smaller: the reference's active-mirror-only message
-        optimization (comm/network.cpp:505-518) as a build-time decision.
-        mb is priced by SplitMirror.estimate_mb_remote (pass 1 over remote
-        edges only, since round 5 the mirror layer never ships the
-        resident diagonal), so a ring verdict costs no mirror-table
-        build."""
-        from neutronstarlite_tpu.parallel.mirror import SplitMirror
-
-        if cfg.comm_layer in ("ring", "ell", "mirror"):
-            return cfg.comm_layer
-        if cfg.comm_layer not in ("", "auto"):
-            raise ValueError(f"unknown COMM_LAYER {cfg.comm_layer!r}")
-        if cfg.optim_kernel:
-            return "ell"
-        if P == 1:
-            return "ring"  # degenerate: no wire traffic either way
-        mb, vp = SplitMirror.estimate_mb_remote(host_graph, P)
-        # tie goes to mirror: at equal wire volume it ships one all_to_all
-        # instead of P-1 dependent ppermute rounds (measured faster on the
-        # 8-device rig even at mb == vp; see docs/PERF.md comm-layer table)
-        choice = "mirror" if mb <= vp else "ring"
-        log.info(
-            "COMM_LAYER auto -> %s (mirror Mb=%d vs ring vp=%d wire "
-            "rows/remote chunk/layer)",
-            choice, mb, vp,
-        )
-        return choice
-
     def build_model(self) -> None:
         from neutronstarlite_tpu.parallel import partitioner as pmod
 
         cfg = self.cfg
-        self.wire_dtype = None
         self._ring_plan = None
         self._quant_probe_stats = None
         self.input_hoisted = self.hoists_input_aggregate()
         self.metrics.gauge_set("agg.input_hoisted", int(self.input_hoisted))
-        spec = pmod.mesh_spec_of(cfg)
-        self.mesh_spec = spec
-        self.partitioner = None
-        if spec is not None:
-            # MESH:Pv,Pf — the 2D (vertex x feature) partitioner places
-            # the plane on a (Pv, Pf) mesh: the ring_blocked schedule is
-            # the layout it emits ((Pv, 1) is bitwise the 1D ring), with
-            # Pf > 1 sharding every exchange/resident buffer down to
-            # [vp, f/Pf] slabs (parallel/partitioner.py)
-            pmod.check_mesh_cfg(cfg)
-            if cfg.dist_path == "ring_blocked_sim":
-                self.simulate = True
-            part = pmod.Partitioner.build(
-                spec, simulate=self.resolve_simulate()
-            )
-            self.partitioner = part
-            self.mesh = part.mesh  # 2D Mesh, or None on the sim twin
-            P = spec.pv
-            layer_kind = "ring_blocked"
-        elif cfg.dist_path in ("ring_blocked", "ring_blocked_sim"):
-            # the pipelined ring (parallel/dist_ring_blocked.py); the _sim
-            # spelling forces the collective-free twin (single-core CI) —
-            # NTS_DIST_SIMULATE=1 does the same for the bare spelling
-            if cfg.dist_path == "ring_blocked_sim":
-                self.simulate = True
-            self.mesh, P = self.resolve_mesh()
-            layer_kind = "ring_blocked"
-        else:
-            self.mesh = make_mesh(cfg.partitions or None)
-            P = self.mesh.devices.size
-            if cfg.dist_path == "all_gather":
-                # explicit opt-out of the ring: the gather-only family
-                # (OPTIM_KERNEL ell / blocked / bsp, selected below)
-                layer_kind = "ell"
-            else:
-                layer_kind = self.resolve_comm_layer(cfg, self.host_graph, P)
-            if cfg.wire_dtype or os.environ.get("NTS_WIRE_DTYPE"):
-                # loud, not silent (the PRECISION-typo lesson): a user
-                # A/B-ing bf16 wire on the all_gather/mirror paths would
-                # otherwise measure an unchanged f32 exchange
-                log.warning(
-                    "WIRE_DTYPE/NTS_WIRE_DTYPE only applies to "
-                    "DIST_PATH:ring_blocked; the %s exchange ships the "
-                    "compute dtype (use PRECISION:bfloat16 to narrow it)",
-                    layer_kind,
-                )
-        self.comm_layer = layer_kind
+        # which layout this run aggregates over is decided in one place
+        # (parallel/layouts.py); blocks.exchange runs it
+        plan = build_exchange(
+            cfg, self.host_graph, simulate=self.resolve_simulate(),
+            timers=self.timers,
+        )
+        self.mesh, self.dist, self.blocks = plan.mesh, plan.dist, plan.blocks
+        self.partitioner = plan.partitioner
+        self.mesh_spec = spec = (
+            plan.partitioner.spec if plan.partitioner is not None else None
+        )
+        self.wire_dtype = plan.wire_dtype
+        self.comm_layer = layer_kind = plan.kind
+        P = plan.partitions
         # elastic telemetry: the currently-planned partition count — a
         # survivor replan (resilience/elastic) rebuilds through here, so
         # the gauge tracks degradation (e.g. 4 -> 3) for free
         self.metrics.gauge_set("dist.active_partitions", P)
-
-        if layer_kind == "ring_blocked":
-            from neutronstarlite_tpu.parallel.dist_ring_blocked import (
-                RingBlockedPair,
-                default_ring_vt,
-            )
-            from neutronstarlite_tpu.parallel.ring_schedule import (
-                resolve_wire_dtype,
-            )
-
-            if getattr(cfg, "pallas_kernel", False):
-                # loud, not silent: the ring's per-step compute is the
-                # XLA blocked scan only — there is no Mosaic ring body yet
-                log.warning(
-                    "PALLAS:1 ignored: DIST_PATH:ring_blocked runs the "
-                    "XLA blocked step tables (no Mosaic ring executor)"
-                )
-            with self.timers.phase("dist_graph_build"):
-                self.dist = DistGraph.build(
-                    self.host_graph, P, edge_chunk=cfg.edge_chunk or None
-                )
-            stats = self.dist.padding_stats()
-            # KERNEL_TILE caps the per-gather table exactly as on the
-            # all_gather blocked path; the shared default keeps whole-
-            # shard-ish tiles (one definition with comm_bench)
-            vt = default_ring_vt(self.dist.vp, cfg.kernel_tile)
-            with self.timers.phase("dist_tables_build"):
-                pair = RingBlockedPair.build(self.dist, vt=vt)
-                est = pair.padding_stats(stats["real_edges"])
-                if self.mesh is None:
-                    self.blocks = pair
-                elif self.partitioner is not None:
-                    # 2D mesh: tables shard over the vertex axis,
-                    # replicated across the feature axis (every slab runs
-                    # the schedule)
-                    self.blocks = pair.shard(
-                        self.mesh, axis=pmod.VERTEX_AXIS
-                    )
-                else:
-                    self.blocks = pair.shard(self.mesh)
-            self.wire_dtype = resolve_wire_dtype(cfg.wire_dtype)
-            log.info(
-                "DIST_PATH ring_blocked%s: double-buffered ring (vt=%d, "
-                "%d/%d work steps, %d hops, wire dtype %s, %.2fx/%.2fx "
-                "fwd/bwd slot padding; peak exchange residency 2*vp=%d "
-                "rows vs all_gather P*vp=%d)",
-                " (sim)" if self.mesh is None else "", vt,
-                len(pair.fwd.work_steps()), P, pair.fwd.n_transfers(),
-                self.wire_dtype or "compute",
-                est["fwd_waste_ratio"], est["bwd_waste_ratio"],
-                2 * self.dist.vp, P * self.dist.vp,
-            )
-        elif layer_kind == "mirror":
-            from neutronstarlite_tpu.parallel.mirror import SplitMirror
-
-            with self.timers.phase("dist_graph_build"):
-                self.dist = SplitMirror.build(self.host_graph, P)
-            with self.timers.phase("dist_tables_build"):
-                self.blocks = self.dist.shard(self.mesh)
-            log.info(
-                "COMM_LAYER mirror (split): remote-only all_to_all "
-                "(mb=%d remote slots/pair vs vp=%d shard rows; Er=%d "
-                "remote + El=%d resident edges)",
-                self.dist.mb, self.dist.vp, self.dist.er, self.dist.el,
-            )
-        else:
-            with self.timers.phase("dist_graph_build"):
-                self.dist = DistGraph.build(
-                    self.host_graph, P, edge_chunk=cfg.edge_chunk or None
-                )
-            stats = self.dist.padding_stats()
-            step_stats = self.dist.step_padding_stats()
-            log.info(
-                "DistGraph [P=%d vp=%d eb=%d]: %d real edges, %.2fx "
-                "step-major ring padding (uniform layout would be %.2fx; "
-                "max block %d, mean %.0f)",
-                P, self.dist.vp, self.dist.eb, stats["real_edges"],
-                step_stats["waste_ratio"], stats["waste_ratio"],
-                stats["max_block"], stats["mean_block"],
-            )
-            if layer_kind == "ell":
-                if (
-                    getattr(cfg, "pallas_kernel", False)
-                    and os.environ.get("NTS_PALLAS_RESIDENT", "0") == "1"
-                    and jax.default_backend() == "tpu"
-                ):
-                    raise ValueError(
-                        "PALLAS:1 with NTS_PALLAS_RESIDENT=1 selects the "
-                        "resident-table executor, which cannot lower to "
-                        "Mosaic (ops/pallas_kernels.py) and runs in "
-                        "interpret mode only; on a TPU the request would "
-                        "have to be swapped for XLA — unset "
-                        "NTS_PALLAS_RESIDENT to run the bsp kernel"
-                    )
-                if getattr(cfg, "pallas_kernel", False) and os.environ.get(
-                    "NTS_PALLAS_RESIDENT", "0"
-                ) != "1":
-                    # PALLAS:1 -> the rectangular Mosaic bsp kernel per
-                    # shard over the all_gathered slab (parallel/dist_bsp)
-                    # — the same fused kernel the single chip runs;
-                    # KERNEL_TILE sets its src-tile height
-                    from neutronstarlite_tpu.ops.bsp_ell import DEFAULT_VT
-                    from neutronstarlite_tpu.parallel.dist_bsp import (
-                        DistBspPair,
-                    )
-
-                    with self.timers.phase("dist_tables_build"):
-                        pair = DistBspPair.build(
-                            self.dist, vt=cfg.kernel_tile or DEFAULT_VT
-                        )
-                        est = pair.padding_stats(stats["real_edges"])
-                        self.blocks = pair.shard(self.mesh)
-                    log.info(
-                        "OPTIM_KERNEL: dist bsp aggregation (all_gather + "
-                        "[P, %d, %d, %d] stacked blocks, vt=%d, "
-                        "%.2fx/%.2fx fwd/bwd slot padding)",
-                        *self.blocks.fwd.nbr.shape[1:],
-                        self.blocks.fwd.vt,
-                        est["fwd_waste_ratio"], est["bwd_waste_ratio"],
-                    )
-                elif cfg.kernel_tile > 0:
-                    if getattr(cfg, "pallas_kernel", False):
-                        # only reachable with NTS_PALLAS_RESIDENT=1: the
-                        # resident executor has no KERNEL_TILE form, so
-                        # the pallas request is dropped — say so
-                        log.warning(
-                            "PALLAS:1 ignored: NTS_PALLAS_RESIDENT=1 has "
-                            "no KERNEL_TILE executor; running the XLA "
-                            "blocked layout"
-                        )
-                    # the gathered [P*vp, f] slab outgrows the fast gather
-                    # regime: source-tiled blocked tables per device
-                    # (parallel/dist_blocked.py, round-3 KERNEL_TILE-on-dist)
-                    from neutronstarlite_tpu.parallel.dist_blocked import (
-                        DistBlockedEllPair,
-                    )
-
-                    with self.timers.phase("dist_tables_build"):
-                        pair = DistBlockedEllPair.build(
-                            self.dist, vt=cfg.kernel_tile
-                        )
-                        est = pair.padding_stats(stats["real_edges"])
-                        self.blocks = pair.shard(self.mesh)
-                    log.info(
-                        "OPTIM_KERNEL: dist blocked aggregation "
-                        "(all_gather + [P, %d-tile] stacked tables, "
-                        "%.2fx/%.2fx fwd/bwd slot padding)",
-                        self.blocks.fwd.n_tiles,
-                        est["fwd_waste_ratio"], est["bwd_waste_ratio"],
-                    )
-                else:
-                    from neutronstarlite_tpu.parallel.dist_ell import (
-                        DistEllPair,
-                    )
-
-                    # NTS_PALLAS_RESIDENT=1 + PALLAS:1 keeps the interpret
-                    # -only per-shard resident executor for CPU-mesh
-                    # experiments (it cannot lower to Mosaic; refused on
-                    # TPU above)
-                    kern = "pallas" if cfg.pallas_kernel else "xla"
-                    with self.timers.phase("dist_tables_build"):
-                        pair = DistEllPair.build(self.dist, kernel=kern)
-                        est = pair.padding_stats(stats["real_edges"])
-                        self.blocks = pair.shard(self.mesh)
-                    log.info(
-                        "OPTIM_KERNEL: dist gather-only aggregation "
-                        "(all_gather + %d-level ELL tables, %s per-shard "
-                        "kernel, %.2fx/%.2fx fwd/bwd slot padding)",
-                        len(self.blocks.fwd.nbr), kern,
-                        est["fwd_waste_ratio"], est["bwd_waste_ratio"],
-                    )
-                    self.record_table_stats(est)
-            else:
-                with self.timers.phase("dist_tables_build"):
-                    self.blocks = self.dist.shard(self.mesh)
+        if plan.table_stats is not None:
+            self.record_table_stats(plan.table_stats)
 
         # live wire counters (obs): per-epoch forward exchange volume at
         # the actual per-layer exchange widths, priced by the SAME row
@@ -746,7 +431,7 @@ class DistGCNTrainer(ToolkitBase):
         released before the step programs load."""
         from neutronstarlite_tpu.obs import numerics
 
-        mesh, dist, part = self.mesh, self.dist, self.partitioner
+        mesh, part = self.mesh, self.partitioner
         wire_dtype = self.wire_dtype
         compute_dtype = (
             jnp.bfloat16 if self.cfg.precision == "bfloat16" else None
@@ -754,7 +439,7 @@ class DistGCNTrainer(ToolkitBase):
         raw = self.feature_p
         aggregate = jax.jit(
             lambda blocks, x: dist_aggregate_input(
-                mesh, dist, blocks, x, compute_dtype, wire_dtype, part
+                mesh, blocks, x, compute_dtype, wire_dtype, part
             ),
             **({"out_shardings": raw.sharding} if mesh is not None else {}),
         )
@@ -782,8 +467,8 @@ class DistGCNTrainer(ToolkitBase):
         """The jit wrappers run() and the tools dispatch, and the step
         programs' cost records (build_model's ``step_build`` phase)."""
         cfg, layer_kind = self.cfg, self.comm_layer
-        mesh, dist, blocks = self.mesh, self.dist, self.blocks
-        P = dist.partitions
+        mesh, blocks = self.mesh, self.blocks
+        P = self.dist.partitions
         drop_rate = cfg.drop_rate
         masked_nll = self.masked_nll_loss
         adam_cfg = self.adam_cfg
@@ -804,7 +489,7 @@ class DistGCNTrainer(ToolkitBase):
         def train_step(params, opt_state, blocks, feature, label, train01, valid, key):
             def loss_fn(p):
                 logits = dist_gcn_forward(
-                    mesh, dist, blocks, p, feature, valid, key, drop_rate,
+                    mesh, blocks, p, feature, valid, key, drop_rate,
                     True, layer_nn, eager, compute_dtype=compute_dtype,
                     wire_dtype=wire_dtype, partitioner=part,
                     input_aggregated=hoisted,
@@ -818,7 +503,7 @@ class DistGCNTrainer(ToolkitBase):
         @jax.jit
         def eval_logits(params, blocks, feature, valid, key):
             return dist_gcn_forward(
-                mesh, dist, blocks, params, feature, valid, key, 0.0, False,
+                mesh, blocks, params, feature, valid, key, 0.0, False,
                 layer_nn, eager, compute_dtype=compute_dtype,
                 wire_dtype=wire_dtype, partitioner=part,
                 input_aggregated=hoisted,
@@ -851,7 +536,7 @@ class DistGCNTrainer(ToolkitBase):
                         return h
 
                     logits = dist_gcn_forward(
-                        mesh, dist, blocks, p, feature, valid, key,
+                        mesh, blocks, p, feature, valid, key,
                         drop_rate, True, layer_nn, eager,
                         compute_dtype=compute_dtype, wire_dtype=wire_dtype,
                         partitioner=part, tap=tap, input_aggregated=hoisted,
@@ -898,7 +583,7 @@ class DistGCNTrainer(ToolkitBase):
         def _loss(params, blocks, feature, label, train01, valid, key,
                   no_exchange=False):
             logits = dist_gcn_forward(
-                mesh, dist, blocks, params, feature, valid, key, drop_rate,
+                mesh, blocks, params, feature, valid, key, drop_rate,
                 True, layer_nn, eager, no_exchange=no_exchange,
                 compute_dtype=compute_dtype, wire_dtype=wire_dtype,
                 partitioner=part, input_aggregated=hoisted,
@@ -935,37 +620,18 @@ class DistGCNTrainer(ToolkitBase):
             self.metrics, f"dist.train_step/{type(self).__name__}",
             jitted=self._train_step, args=self.aot_args(),
         )
-        if layer_kind == "ring_blocked":
-            from neutronstarlite_tpu.parallel.dist_ring_blocked import (
-                dist_ring_blocked_gather_dst_from_src,
-                dist_ring_blocked_gather_simulated,
+        if layer_kind == "ring_blocked" and (mesh is None or part is None):
+            # the 1D ring body (collective or sim twin); the 2D (Pv, Pf)
+            # body is already inside the captured step program — its
+            # shard_map needs mesh-placed inputs a bare lowering cannot stage
+            ring_fn = jax.jit(
+                lambda pair, v: pair.exchange(mesh, v, wire_dtype=wire_dtype)
             )
-
-            if mesh is None:
-                ring_fn = jax.jit(
-                    lambda pair, v: dist_ring_blocked_gather_simulated(
-                        pair, v, wire_dtype
-                    )
-                )
-                capture_program_cost(
-                    self.metrics, f"ring.body/{type(self).__name__}",
-                    jitted=ring_fn, args=(blocks, self.feature_p),
-                    partitions=int(P), simulated=True,
-                )
-            elif part is None:
-                # the 1D collective ring body; the 2D (Pv, Pf) body is
-                # already inside the captured step program — its shard_map
-                # needs mesh-placed inputs a bare lowering cannot stage
-                ring_fn = jax.jit(
-                    lambda pair, v: dist_ring_blocked_gather_dst_from_src(
-                        mesh, pair, v, wire_dtype
-                    )
-                )
-                capture_program_cost(
-                    self.metrics, f"ring.body/{type(self).__name__}",
-                    jitted=ring_fn, args=(blocks, self.feature_p),
-                    partitions=int(P), simulated=False,
-                )
+            capture_program_cost(
+                self.metrics, f"ring.body/{type(self).__name__}",
+                jitted=ring_fn, args=(blocks, self.feature_p),
+                partitions=int(P), simulated=mesh is None,
+            )
 
     # ---- checkpoint canonicalization on a 2D mesh ------------------------
     # Checkpoints store the UNPADDED parameter shapes: a 2D run's mesh
@@ -1155,7 +821,7 @@ class DistGCNTrainer(ToolkitBase):
             jnp.bfloat16 if self.cfg.precision == "bfloat16" else None
         )
         logits = dist_gcn_forward(
-            self.mesh, self.dist, self.blocks, self.params, self.feature_p,
+            self.mesh, self.blocks, self.params, self.feature_p,
             self.valid_p, key, self.cfg.drop_rate, True,
             type(self).layer_nn, type(self).eager,
             compute_dtype=compute_dtype, wire_dtype=self.wire_dtype,

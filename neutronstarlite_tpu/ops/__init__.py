@@ -14,18 +14,16 @@ from neutronstarlite_tpu.ops.edge import (
     edge_softmax,
 )
 
-# aggregation-table layouts accepted by gather_dst_from_src (the graph
-# argument picks the backend; see ops/aggregate.py)
+# aggregation-table layouts: each pair aggregates itself, and
+# gather_dst_from_src(graph, x) hands over to it (ops/aggregate.py)
 from neutronstarlite_tpu.ops.blocked_ell import BlockedEllPair
 from neutronstarlite_tpu.ops.ell import EllPair
-from neutronstarlite_tpu.ops.pallas_kernels import PallasEllPair
 from neutronstarlite_tpu.ops.ell_gat import GatEllPair, gat_ell_attention_aggregate
 
 __all__ = [
     "DeviceGraph",
     "EllPair",
     "BlockedEllPair",
-    "PallasEllPair",
     "GatEllPair",
     "gat_ell_attention_aggregate",
     "gather_dst_from_src",

@@ -173,37 +173,46 @@ def _lane_pad_width(f: int, e_pad: int) -> int:
     return _LANE_WIDTH
 
 
+def build_tables(cfg, host_graph):
+    """The single-chip ``OPTIM_KERNEL:1`` table pair for ``cfg``, and its
+    padding stats where the layout has levels to report (the ELL's; else
+    None). The ONE place a cfg becomes a single-chip table type: every
+    full-batch trainer reaches it through ``FullBatchTrainer.build_model``,
+    bench.py and the AOT tools through the trainer.
+
+    - ``PALLAS:1``: the streamed block-sparse Mosaic kernel (ops/bsp_ell),
+      ``KERNEL_TILE`` its src-tile height;
+    - ``KERNEL_TILE:vt`` alone: source-tiled blocked ELL (ops/blocked_ell);
+    - else the gather-only ELL levels (ops/ell), what the benchmark runs.
+
+    Without ``OPTIM_KERNEL:1`` a trainer aggregates over the DeviceGraph
+    scatter path and never calls this (``ToolkitBase._check_kernel``
+    refuses ``PALLAS:1`` there)."""
+    # each branch imports its own layout: the caller times this call as its
+    # tables_build phase, and ops/bsp_ell brings in Pallas (over a second)
+    if cfg.pallas_kernel:
+        from neutronstarlite_tpu.ops.bsp_ell import BspEllPair
+
+        tile = {"vt": cfg.kernel_tile} if cfg.kernel_tile > 0 else {}
+        return BspEllPair.from_host(host_graph, **tile), None
+    if cfg.kernel_tile > 0:
+        from neutronstarlite_tpu.ops.blocked_ell import BlockedEllPair
+
+        return BlockedEllPair.from_host(host_graph, vt=cfg.kernel_tile), None
+    from neutronstarlite_tpu.ops.ell import EllPair
+
+    pair = EllPair.from_host(host_graph)
+    return pair, pair.padding_stats(host_graph.e_num)
+
+
 def gather_dst_from_src(graph, x: jax.Array) -> jax.Array:
     """out[v] = sum over in-edges (u -> v) of w_uv * x[u].  [V, f] -> [V, f].
 
-    ``graph`` is a DeviceGraph (chunked sorted-scatter path), an
-    ops.ell.EllPair (gather-only ELL path, the OPTIM_KERNEL cfg flag — the
-    TPU analog of the reference's optimized aggregation kernel toggle,
-    cuda/ntsCUDAFuseKernel.cuh:154), or an ops.blocked_ell.BlockedEllPair
-    (source-tiled ELL for beyond-VMEM feature tables, OPTIM_KERNEL:1 +
-    KERNEL_TILE:vt), an ops.pallas_kernels.PallasEllPair (fused Pallas
-    kernel over the same ELL tables, OPTIM_KERNEL:1 + PALLAS:1), or an
-    ops.bsp_ell.BspEllPair (streamed block-sparse Pallas kernel for
-    V-beyond-VMEM graphs, OPTIM_KERNEL:1 + PALLAS:1 + KERNEL_TILE:vt)."""
-    from neutronstarlite_tpu.ops.blocked_ell import (
-        BlockedEllPair,
-        blocked_gather_dst_from_src,
-    )
-    from neutronstarlite_tpu.ops.bsp_ell import BspEllPair, bsp_gather_dst_from_src
-    from neutronstarlite_tpu.ops.ell import EllPair, ell_gather_dst_from_src
-    from neutronstarlite_tpu.ops.pallas_kernels import (
-        PallasEllPair,
-        pallas_gather_dst_from_src,
-    )
-
-    if isinstance(graph, BspEllPair):
-        return bsp_gather_dst_from_src(graph, x)
-    if isinstance(graph, BlockedEllPair):
-        return blocked_gather_dst_from_src(graph, x)
-    if isinstance(graph, PallasEllPair):
-        return pallas_gather_dst_from_src(graph, x)
-    if isinstance(graph, EllPair):
-        return ell_gather_dst_from_src(graph, x)
+    A table pair (``build_tables``) aggregates itself; a DeviceGraph runs
+    the chunked sorted-scatter path below, the reference every layout is
+    tested against."""
+    if not isinstance(graph, DeviceGraph):
+        return graph.gather_dst_from_src(x)
     f = x.shape[1]
     fp = _lane_pad_width(f, int(graph.csc_src.shape[0]))
     if fp != f:
@@ -225,25 +234,8 @@ def gather_dst_from_src(graph, x: jax.Array) -> jax.Array:
 def gather_src_from_dst(graph, y: jax.Array) -> jax.Array:
     """out[u] = sum over out-edges (u -> v) of w_uv * y[v] — the CSR direction
     (the reference's backward engine, exposed as a forward op)."""
-    from neutronstarlite_tpu.ops.blocked_ell import (
-        BlockedEllPair,
-        blocked_gather_src_from_dst,
-    )
-    from neutronstarlite_tpu.ops.bsp_ell import BspEllPair, bsp_gather_src_from_dst
-    from neutronstarlite_tpu.ops.ell import EllPair, ell_gather_src_from_dst
-    from neutronstarlite_tpu.ops.pallas_kernels import (
-        PallasEllPair,
-        pallas_gather_src_from_dst,
-    )
-
-    if isinstance(graph, BspEllPair):
-        return bsp_gather_src_from_dst(graph, y)
-    if isinstance(graph, BlockedEllPair):
-        return blocked_gather_src_from_dst(graph, y)
-    if isinstance(graph, PallasEllPair):
-        return pallas_gather_src_from_dst(graph, y)
-    if isinstance(graph, EllPair):
-        return ell_gather_src_from_dst(graph, y)
+    if not isinstance(graph, DeviceGraph):
+        return graph.gather_src_from_dst(y)
     # same narrow-width fence as the CSC direction (the scatter regime is
     # direction-agnostic)
     f = y.shape[1]

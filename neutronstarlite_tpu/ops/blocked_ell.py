@@ -411,6 +411,20 @@ class BlockedEllPair:
         )
         return BlockedEllPair(fwd=fwd, bwd=bwd)
 
+    def gather_dst_from_src(self, x: jax.Array) -> jax.Array:
+        """Source-tiled weighted aggregation (custom_vjp pairs the transpose)."""
+        return _blocked_aggregate(self.fwd, self.bwd, x)
+
+    def gather_src_from_dst(self, y: jax.Array) -> jax.Array:
+        """The CSR direction as a forward op."""
+        return _blocked_aggregate(self.bwd, self.fwd, y)
+
+    def describe(self) -> str:
+        return (
+            f"blocked ELL aggregation ({self.fwd.n_tiles} src tiles of "
+            f"{self.fwd.vt} vertices, {len(self.fwd.nbr)} stacked levels)"
+        )
+
 
 @jax.custom_vjp
 def _blocked_aggregate(fwd: BlockedEll, bwd: BlockedEll, x: jax.Array):
@@ -433,10 +447,8 @@ _blocked_aggregate.defvjp(_blocked_aggregate_fwd, _blocked_aggregate_bwd)
 
 
 def blocked_gather_dst_from_src(pair: BlockedEllPair, x: jax.Array) -> jax.Array:
-    """Source-tiled weighted aggregation (custom_vjp pairs the transpose)."""
-    return _blocked_aggregate(pair.fwd, pair.bwd, x)
+    return pair.gather_dst_from_src(x)
 
 
 def blocked_gather_src_from_dst(pair: BlockedEllPair, y: jax.Array) -> jax.Array:
-    """The CSR direction as a forward op."""
-    return _blocked_aggregate(pair.bwd, pair.fwd, y)
+    return pair.gather_src_from_dst(y)

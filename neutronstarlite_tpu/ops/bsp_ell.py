@@ -1,13 +1,14 @@
 """Block-sparse (dst-tile, src-tile) streamed Pallas aggregation.
 
-The regime ladder for the fused neighbor aggregation on one chip:
+The one fused aggregation kernel Mosaic can compile (``PALLAS:1``). A
+kernel that keeps the ``[V, f]`` table (or a 128-wide column chunk of it)
+resident in VMEM and gathers rows from it would be the faster design while
+the table fits, but Mosaic has no in-kernel row gather, so it cannot lower
+at any shape (docs/PERF.md section 5; removed in PR 31). This kernel needs
+no gather and no resident table, at any V:
 
-1. [V, f] fits VMEM            -> ops/pallas_kernels.py (table resident)
-2. [V, 128] fits VMEM          -> same kernel, feature-column chunked
-3. V itself is beyond VMEM     -> THIS module (V ~ 10x Reddit and up)
-
-Here neither the feature table nor a 128-wide column of it fits on-chip,
-so the kernel streams BOTH sides: vertices are cut into destination tiles
+Neither the feature table nor a 128-wide column of it has to fit on-chip,
+because the kernel streams BOTH sides: vertices are cut into destination tiles
 of ``dt`` rows and source tiles of ``vt`` rows; edges are packed into
 fixed-shape blocks, each block belonging to one (dst tile, src tile)
 pair. The pallas grid walks blocks grouped per destination tile (each
@@ -33,16 +34,11 @@ The per-block combine is scatter-free BY CONSTRUCTION: row partial sums
 ``acc`` [R, f] land in the output tile through a one-hot MXU matmul —
 ``onehot(ldst) [dt, R] @ acc [R, f]`` — the TPU-idiomatic scatter (the
 MXU is the only unit that reorders data at full bandwidth; per-row
-dynamic stores would serialize). This is the cost that makes regime 2
-preferable whenever the row count allows: the matmul spends
-``dt * f * 2`` FLOPs per packed ROW (independent of K), so at Reddit
-scale (~7M rows, dt=512, f=602) it would burn ~4.2 TFLOP per application
-— slower than keeping a 128-wide column slab resident and gathering
-from VMEM. Past ~375k-row slabs there is no resident option; the matmul
-price buys streaming locality the plain layout cannot offer, and the
-multi-chip path (parallel/dist_ell.py) re-enters regime 1/2 per shard by
-cutting V by P. Reference analog: the shared-memory tiled CUDA
-aggregation (cuda/ntsCUDAFuseKernel.cuh:154-208) — re-derived for a
+dynamic stores would serialize). This is the kernel's cost: the matmul
+spends ``dt * f * 2`` FLOPs per packed ROW (independent of K), so at
+Reddit scale (~7M rows, dt=512, f=602) it burns ~4.2 TFLOP per
+application; the price buys streaming locality the plain layout cannot
+offer. Reference analog: the shared-memory tiled CUDA aggregation (cuda/ntsCUDAFuseKernel.cuh:154-208) — re-derived for a
 memory system where the accumulator tile, not the source tile, is the
 scarce on-chip resource.
 
@@ -86,6 +82,17 @@ DEFAULT_R = 128  # rows per block (the 128-lane axis of the tables)
 # grid at dst-tile boundaries (see BspEll.build) — the compiled program
 # is then V-independent and there is no block-count ceiling at all.
 DEFAULT_MAX_BLOCKS = 224 * 1024
+
+
+def pallas_interpret_default() -> bool:
+    """interpret everywhere the default backend can't lower Mosaic — keeps
+    the CPU suite exercising the same code path the chip runs.
+    NTS_PALLAS_FORCE_COMPILED=1 overrides for AOT lowering against a TPU
+    TOPOLOGY from a CPU host (tools/aot_bench_path): tracing never executes
+    the kernel, and the topology compiler consumes the Mosaic call."""
+    if os.environ.get("NTS_PALLAS_FORCE_COMPILED", "0") == "1":
+        return False
+    return jax.default_backend() not in ("tpu",)
 
 
 def bsp_bseg_menu(cap_eff: int) -> "list[int]":
@@ -454,14 +461,9 @@ class BspEll:
     def aggregate(self, x: jax.Array, interpret: bool = None) -> jax.Array:
         """out[v] = sum over in-edges of w * x[src]; [V, f] -> [V, f]."""
         if interpret is None:
-            # shared policy incl. the NTS_PALLAS_FORCE_COMPILED override —
             # topology AOT compiles must lower real Mosaic, not the
             # interpret emulation (round-3 near-miss: an AOT "verification"
             # of this kernel silently compiled the emulation)
-            from neutronstarlite_tpu.ops.pallas_kernels import (
-                pallas_interpret_default,
-            )
-
             interpret = pallas_interpret_default()
         f = x.shape[1]
         n_src = self.src_num or self.v_num
@@ -496,8 +498,8 @@ class BspEll:
 
 def _bsp_kernel(key_ref, nbr_ref, wgt_ref, ldst_ref, x_ref, o_ref, *, dt, vt, t_src):
     """One block, gather-free BY CONSTRUCTION (Mosaic's only gather is an
-    elementwise same-shape shuffle — a row gather cannot lower, see
-    ops/pallas_kernels.py): the block's <=K*R edges are folded into a
+    elementwise same-shape shuffle — a row gather cannot lower, docs/PERF.md
+    section 5): the block's <=K*R edges are folded into a
     weights-valued one-hot matrix W [R, vt] (W[r, src_local] = w), so
     gather+scale+K-reduce is ONE bf16 MXU matmul ``W @ slab``; the row
     partial sums then land in the dst tile through the one-hot(ldst)
@@ -522,7 +524,7 @@ def _bsp_kernel(key_ref, nbr_ref, wgt_ref, ldst_ref, x_ref, o_ref, *, dt, vt, t_
     #     view: "Not implemented: Multiple source vregs along gather
     #     dimension" — tpu.dynamic_gather only shuffles WITHIN one
     #     8-sublane vreg, so any cross-slab row fetch is out.
-    # (b) the resident-table row gather (ops/pallas_kernels.py) — same
+    # (b) a resident-table row gather (the kernel removed in PR 31) — same
     #     root cause.
     # Numeric policy: W entries round to the slab dtype (bf16 in
     # production) so the main dot runs at full MXU rate; accumulation is
@@ -619,6 +621,21 @@ class BspEllPair:
         )
         return BspEllPair(fwd=fwd, bwd=bwd)
 
+    def gather_dst_from_src(self, x: jax.Array) -> jax.Array:
+        """Streamed block-sparse weighted aggregation (custom_vjp-paired)."""
+        return _bsp_aggregate(self.fwd, self.bwd, x)
+
+    def gather_src_from_dst(self, y: jax.Array) -> jax.Array:
+        """The CSR direction as a forward op."""
+        return _bsp_aggregate(self.bwd, self.fwd, y)
+
+    def describe(self) -> str:
+        return (
+            f"streamed block-sparse Pallas aggregation "
+            f"({self.fwd.nbr.shape[0]} fwd blocks, dt={self.fwd.dt} "
+            f"vt={self.fwd.vt})"
+        )
+
 
 @jax.custom_vjp
 def _bsp_aggregate(fwd: BspEll, bwd: BspEll, x: jax.Array):
@@ -641,10 +658,8 @@ _bsp_aggregate.defvjp(_bsp_aggregate_fwd, _bsp_aggregate_bwd)
 
 
 def bsp_gather_dst_from_src(pair: BspEllPair, x: jax.Array) -> jax.Array:
-    """Streamed block-sparse weighted aggregation (custom_vjp-paired)."""
-    return _bsp_aggregate(pair.fwd, pair.bwd, x)
+    return pair.gather_dst_from_src(x)
 
 
 def bsp_gather_src_from_dst(pair: BspEllPair, y: jax.Array) -> jax.Array:
-    """The CSR direction as a forward op."""
-    return _bsp_aggregate(pair.bwd, pair.fwd, y)
+    return pair.gather_src_from_dst(y)

@@ -237,8 +237,7 @@ def ell_tables_aggregate(x, nbrs, wgts, slot_chunk: int, out_dtype=None) -> jax.
     def partial_f32(nbr, wgt):
         # products AND accumulation in f32 (register-resident in the fused
         # reduce, so no extra HBM traffic; bf16 only on the gather reads) —
-        # the ONE copy of the numeric policy; keep in sync with
-        # ops/pallas_kernels._ell_level_kernel, which mirrors it in-kernel
+        # the ONE copy of the numeric policy
         vals = x[nbr].astype(jnp.float32) * wgt[:, :, None]
         return vals.sum(axis=1)
 
@@ -423,6 +422,20 @@ class EllPair:
         ``parallel/dist_ell.DistEllPair.padding_stats`` reports it."""
         return table_padding_stats(self.fwd.nbr, self.bwd.nbr, real_edges)
 
+    def gather_dst_from_src(self, x: jax.Array) -> jax.Array:
+        """Gather-only weighted aggregation (custom_vjp pairs the transpose)."""
+        return _ell_aggregate(self.fwd, self.bwd, x)
+
+    def gather_src_from_dst(self, y: jax.Array) -> jax.Array:
+        """The CSR direction as a forward op."""
+        return _ell_aggregate(self.bwd, self.fwd, y)
+
+    def describe(self) -> str:
+        return (
+            f"ELL gather-only aggregation ({len(self.fwd.nbr)} fwd / "
+            f"{len(self.bwd.nbr)} bwd levels)"
+        )
+
 
 def table_padding_stats(fwd_nbr, bwd_nbr, real_edges: int) -> dict:
     """Slots of two directions' level tables (any leading device axis
@@ -464,10 +477,8 @@ _ell_aggregate.defvjp(_ell_aggregate_fwd, _ell_aggregate_bwd)
 
 
 def ell_gather_dst_from_src(pair: EllPair, x: jax.Array) -> jax.Array:
-    """Gather-only weighted aggregation (custom_vjp pairs the transpose)."""
-    return _ell_aggregate(pair.fwd, pair.bwd, x)
+    return pair.gather_dst_from_src(x)
 
 
 def ell_gather_src_from_dst(pair: EllPair, y: jax.Array) -> jax.Array:
-    """The CSR direction as a forward op."""
-    return _ell_aggregate(pair.bwd, pair.fwd, y)
+    return pair.gather_src_from_dst(y)

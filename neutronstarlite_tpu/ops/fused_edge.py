@@ -17,7 +17,7 @@ the streamed-block regime of ops/blocked_ell.py / ops/bsp_ell.py:
   indexes a [vt, .] resident slab (the ops/ell.py on-chip-gather premise;
   a Mosaic/Pallas lowering of the same schedule would build the scores as
   one-hot MXU matmuls against these tables — the bsp_ell one-hot regime —
-  because Mosaic has no row gather, see ops/pallas_kernels.py. The XLA
+  because Mosaic has no row gather, docs/PERF.md section 5. The XLA
   blocked form ships first: it compiles everywhere, pays no dt*f FLOPs
   per row for the scatter matmul, and fixes the same HBM envelope);
 - the per-destination softmax is ONLINE (flash-attention style): a

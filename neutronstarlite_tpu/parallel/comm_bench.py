@@ -94,8 +94,7 @@ def bench_layers(v_num, avg_degree, f, partitions, steps, seed=3,
 
     paths = {
         "ring": (
-            loss_of(lambda x: dist_gather_dst_from_src(
-                mesh, dist.partitions, dist.vp, dist.edge_chunk, blocks, x)),
+            loss_of(lambda x: dist_gather_dst_from_src(mesh, blocks, x)),
             (P - 1) * dist.vp,
             peak_resident_rows("ring", P, dist.vp),
         ),

@@ -176,6 +176,16 @@ class DistBlockedEllPair:
     def shard(self, mesh: Mesh) -> "DistBlockedEllPair":
         return DistBlockedEllPair(fwd=self.fwd.shard(mesh), bwd=self.bwd.shard(mesh))
 
+    def exchange(self, mesh: Mesh, x: jax.Array, wire_dtype=None,
+                 partitioner=None) -> jax.Array:
+        return dist_blocked_gather_dst_from_src(mesh, self, x)
+
+    def describe(self) -> str:
+        return (
+            f"dist blocked aggregation (all_gather + "
+            f"[P, {self.fwd.n_tiles}-tile] stacked tables)"
+        )
+
 
 def _dist_blocked_apply(mesh: Mesh, dbl: DistBlockedEll, x: jax.Array) -> jax.Array:
     """all_gather + local blocked aggregation, as a shard_map."""

@@ -43,9 +43,9 @@ from neutronstarlite_tpu.ops.bsp_ell import (
     DEFAULT_VT,
     BspEll,
     _bsp_call,
+    pallas_interpret_default,
     resolve_bsp_knobs,
 )
-from neutronstarlite_tpu.ops.pallas_kernels import pallas_interpret_default
 from neutronstarlite_tpu.parallel.dist_ell import per_device_adjacency
 from neutronstarlite_tpu.parallel.dist_graph import DistGraph
 from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS, shard_map
@@ -382,6 +382,17 @@ class DistBspPair:
 
     def shard(self, mesh: Mesh) -> "DistBspPair":
         return DistBspPair(fwd=self.fwd.shard(mesh), bwd=self.bwd.shard(mesh))
+
+    def exchange(self, mesh: Mesh, x: jax.Array, wire_dtype=None,
+                 partitioner=None) -> jax.Array:
+        return dist_bsp_gather_dst_from_src(mesh, self, x)
+
+    def describe(self) -> str:
+        _, b, k, r = self.fwd.nbr.shape
+        return (
+            f"dist bsp aggregation (all_gather + [P, {b}, {k}, {r}] "
+            f"stacked blocks, vt={self.fwd.vt})"
+        )
 
 
 def _dist_bsp_apply(mesh: Mesh, dbsp: DistBsp, x: jax.Array) -> jax.Array:

@@ -356,8 +356,10 @@ def dist_gather_dst_from_src_mirror_split(
     the resident feature shard directly. Same semantics as
     dist_gather_dst_from_src_mirror at strictly-smaller exchange volume on
     any graph with self-loops (where the uniform layout saturates
-    Mb == vp). Autodiffs natively, like the uniform fused op."""
-    need, r_slot, r_dst, r_w, r_mask, l_src, l_dst, l_w, l_mask = tables
+    Mb == vp). Autodiffs natively, like the uniform fused op. ``tables``
+    is ``sm.shard(mesh)``; ``sm`` gives the sizes (the SplitMirror, or the
+    tables themselves, which carry them)."""
+    t = tables
     P, mb, vp = sm.partitions, sm.mb, sm.vp
 
     def body(need, r_slot, r_dst, r_w, r_mask, l_src, l_dst, l_w, l_mask, xs):
@@ -380,7 +382,8 @@ def dist_gather_dst_from_src_mirror_split(
         out_specs=PS(PARTITION_AXIS, None),
     )
     return fn(
-        need, r_slot, r_dst, r_w, r_mask, l_src, l_dst, l_w, l_mask, x
+        t.need_ids, t.r_src_slot, t.r_dst, t.r_weight, t.r_mask, t.l_src,
+        t.l_dst, t.l_weight, t.l_mask, x,
     )
 
 

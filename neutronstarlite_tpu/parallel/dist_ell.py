@@ -38,6 +38,7 @@ from jax.sharding import Mesh, PartitionSpec as PS
 
 from neutronstarlite_tpu.ops.ell import (
     DEFAULT_SLOT_CHUNK,
+    ell_tables_aggregate,
     level_widths,
     table_padding_stats,
 )
@@ -108,10 +109,6 @@ class DistEll:
     partitions: int = dataclasses.field(metadata=dict(static=True))
     vp: int = dataclasses.field(metadata=dict(static=True))
     slot_chunk: int = dataclasses.field(metadata=dict(static=True))
-    # per-shard level executor: "xla" (gather+reduce) or "pallas" (the
-    # fused VMEM kernel, ops/pallas_kernels.pallas_tables_aggregate) — the
-    # same cfg PALLAS:1 switch the single-chip trainer honors
-    kernel: str = dataclasses.field(default="xla", metadata=dict(static=True))
 
     @staticmethod
     def build(dist: DistGraph, slot_chunk: int = DEFAULT_SLOT_CHUNK) -> "DistEll":
@@ -221,7 +218,6 @@ class DistEll:
             partitions=self.partitions,
             vp=self.vp,
             slot_chunk=self.slot_chunk,
-            kernel=self.kernel,
         )
 
     # -- per-device body (collective-free given the gathered x) ------------
@@ -229,47 +225,9 @@ class DistEll:
         """p_tables: ([Nk, K] nbr, [Nk, K] wgt) per level + [vp] inv row map;
         xg [P*vp, f] fully gathered features."""
         nbrs, wgts, inv = p_tables
-        if self.kernel == "pallas":
-            from neutronstarlite_tpu.ops.pallas_kernels import (
-                pallas_interpret_default,
-                pallas_tables_aggregate,
-            )
-
-            # same interpret policy as the single-chip PallasEllPair:
-            # compiled Mosaic on TPU, interpret everywhere else (the CPU
-            # 8-virtual-device mesh exercises the identical code path)
-            return pallas_tables_aggregate(
-                xg, nbrs, wgts, self.slot_chunk,
-                interpret=pallas_interpret_default(),
-            )[inv]
-        from neutronstarlite_tpu.ops.ell import ell_tables_aggregate
-
         # shared level-reduction core (single source of the f32-accumulation
         # numeric policy across single-device and distributed paths)
         return ell_tables_aggregate(xg, nbrs, wgts, self.slot_chunk)[inv]
-
-
-def _merge_stacked_low_k(dell: DistEll, min_k: int) -> DistEll:
-    """Merge low-K levels of the stacked [P, Nk, K] tables into one
-    [P, sum Nk, min_k] level — the shared ``merge_level_tables`` with the
-    per-device row axis (1). ``inv_perm`` stays valid (row order inside
-    each device's concatenation is preserved); fewer levels = fewer
-    distinct Mosaic kernel compiles per shard (round-3 hang postmortem)."""
-    from neutronstarlite_tpu.ops.pallas_kernels import (
-        effective_min_k,
-        merge_level_tables,
-    )
-
-    rows = sum(int(n.shape[0]) * int(n.shape[1]) for n in dell.nbr)
-    nbrs, wgts = merge_level_tables(
-        dell.nbr, dell.wgt,
-        effective_min_k(dell.slot_count(), rows, min_k), row_axis=1,
-    )
-    return DistEll(
-        nbr=nbrs, wgt=wgts, inv_perm=dell.inv_perm,
-        partitions=dell.partitions, vp=dell.vp,
-        slot_chunk=dell.slot_chunk, kernel=dell.kernel,
-    )
 
 
 @jax.tree_util.register_dataclass
@@ -282,25 +240,12 @@ class DistEllPair:
 
     @staticmethod
     def build(
-        dist: DistGraph,
-        slot_chunk: int = DEFAULT_SLOT_CHUNK,
-        kernel: str = "xla",
+        dist: DistGraph, slot_chunk: int = DEFAULT_SLOT_CHUNK
     ) -> "DistEllPair":
-        if kernel not in ("xla", "pallas"):
-            # validate BEFORE the two O(E) table builds
-            raise ValueError(f"unknown dist-ELL kernel {kernel!r}")
-        fwd = DistEll.build(dist, slot_chunk)
-        bwd = DistEll.build_transposed(dist, slot_chunk)
-        if kernel == "pallas":
-            from neutronstarlite_tpu.ops.pallas_kernels import PALLAS_MIN_K
-
-            fwd = _merge_stacked_low_k(
-                dataclasses.replace(fwd, kernel="pallas"), PALLAS_MIN_K
-            )
-            bwd = _merge_stacked_low_k(
-                dataclasses.replace(bwd, kernel="pallas"), PALLAS_MIN_K
-            )
-        return DistEllPair(fwd=fwd, bwd=bwd)
+        return DistEllPair(
+            fwd=DistEll.build(dist, slot_chunk),
+            bwd=DistEll.build_transposed(dist, slot_chunk),
+        )
 
     def padding_stats(self, real_edges: int) -> dict:
         """Slot occupancy of both directions (VERDICT round-1 item 8: the
@@ -315,6 +260,16 @@ class DistEllPair:
 
     def shard(self, mesh: Mesh) -> "DistEllPair":
         return DistEllPair(fwd=self.fwd.shard(mesh), bwd=self.bwd.shard(mesh))
+
+    def exchange(self, mesh: Mesh, x: jax.Array, wire_dtype=None,
+                 partitioner=None) -> jax.Array:
+        return dist_ell_gather_dst_from_src(mesh, self, x)
+
+    def describe(self) -> str:
+        return (
+            f"dist gather-only aggregation (all_gather + "
+            f"{len(self.fwd.nbr)}-level ELL tables)"
+        )
 
 
 def _dist_ell_apply(mesh: Mesh, dell: DistEll, x: jax.Array) -> jax.Array:
@@ -337,12 +292,6 @@ def _dist_ell_apply(mesh: Mesh, dell: DistEll, x: jax.Array) -> jax.Array:
         mesh=mesh,
         in_specs=table_specs + (PS(PARTITION_AXIS, None),),
         out_specs=PS(PARTITION_AXIS, None),
-        # pallas_call inside shard_map cannot declare its output's varying
-        # mesh axes (its out_shape is a plain ShapeDtypeStruct), so the
-        # vma check must be off for the fused-kernel executor; the XLA
-        # executor keeps the stricter checking. Correctness is pinned by
-        # the pallas-vs-xla parity tests (test_dist_ell).
-        check_vma=dell.kernel != "pallas",
     )
     return fn(*dell.nbr, *dell.wgt, dell.inv_perm, x)
 

@@ -27,12 +27,13 @@ the padded [P * vp, ...] space with ``pad_vertex_array``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import numpy as np
 
 from neutronstarlite_tpu.graph.storage import CSCGraph, partition_offsets
+from neutronstarlite_tpu.parallel.mesh import shard_leading
 from neutronstarlite_tpu.parallel.vertex_space import (
     PaddedVertexSpace,
     owner_of_vertices,
@@ -51,6 +52,23 @@ class RingBlocks:
     src: list
     dst: list
     wgt: list
+    vp: int = dataclasses.field(metadata=dict(static=True))
+    edge_chunk: int = dataclasses.field(metadata=dict(static=True))
+
+    def shard(self, mesh) -> "RingBlocks":
+        """Device-put every step's arrays sharded over devices."""
+        return jax.tree.map(lambda a: shard_leading(mesh, a), self)
+
+    def exchange(self, mesh, x: jax.Array, wire_dtype=None,
+                 partitioner=None) -> jax.Array:
+        from neutronstarlite_tpu.parallel.dist_ops import (
+            dist_gather_dst_from_src,
+        )
+
+        return dist_gather_dst_from_src(mesh, self, x)
+
+    def describe(self) -> str:
+        return f"ppermute ring ({len(self.src)} steps, vp={self.vp})"
 
 
 @dataclasses.dataclass
@@ -190,7 +208,10 @@ class DistGraph(PaddedVertexSpace):
             src_l.append(bs)
             dst_l.append(bd)
             w_l.append(bw)
-        return RingBlocks(src=src_l, dst=dst_l, wgt=w_l)
+        return RingBlocks(
+            src=src_l, dst=dst_l, wgt=w_l, vp=self.vp,
+            edge_chunk=self.edge_chunk,
+        )
 
     def _step_sizes(self) -> list:
         """Per-ring-step padded block length Eb_s — the ONE source of the
@@ -221,23 +242,4 @@ class DistGraph(PaddedVertexSpace):
 
     def shard(self, mesh) -> "RingBlocks":
         """Device-put the step-major ring blocks sharded over devices."""
-        from jax.sharding import NamedSharding, PartitionSpec as PS
-
-        sh = NamedSharding(mesh, PS("p", None))
-        rb = self.step_blocks()
-        return RingBlocks(
-            src=[jax.device_put(a, sh) for a in rb.src],
-            dst=[jax.device_put(a, sh) for a in rb.dst],
-            wgt=[jax.device_put(a, sh) for a in rb.wgt],
-        )
-
-    def shard_dense(self, mesh) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        """The uniform [P, P, Eb] device layout (legacy/diagnostic path)."""
-        from jax.sharding import NamedSharding, PartitionSpec as PS
-
-        sh = NamedSharding(mesh, PS("p", None, None))
-        return (
-            jax.device_put(self.block_src, sh),
-            jax.device_put(self.block_dst, sh),
-            jax.device_put(self.block_weight, sh),
-        )
+        return self.step_blocks().shard(mesh)

@@ -25,9 +25,6 @@ Padding edges have weight 0 and index vertex 0 of their shard.
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Tuple
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -37,37 +34,15 @@ from neutronstarlite_tpu.ops.aggregate import _scatter_accumulate
 from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS, shard_map
 
 
-def _ring_aggregate_local(block_src, block_dst, block_weight, x_local, *,
-                          partitions: int, vp: int, edge_chunk: int):
-    """Per-device body. block_* are [P, Eb] (this device's dst row), x_local
-    is [vp, f] (this device's feature shard)."""
-    p = lax.axis_index(PARTITION_AXIS)
-    # accumulate WIDE regardless of the exchange dtype (bf16 ships half
-    # the ppermute bytes; the per-vertex sum must not round per term —
-    # r5 review caught the bf16 accumulator here)
-    acc = jnp.zeros((vp, x_local.shape[1]), dtype=jnp.float32)
-    cur = x_local
-    fwd_perm = [(i, (i - 1) % partitions) for i in range(partitions)]
-    for s in range(partitions):
-        q = (p + s) % partitions
-        src = lax.dynamic_index_in_dim(block_src, q, axis=0, keepdims=False)
-        dst = lax.dynamic_index_in_dim(block_dst, q, axis=0, keepdims=False)
-        w = lax.dynamic_index_in_dim(block_weight, q, axis=0, keepdims=False)
-        acc = _scatter_accumulate(
-            src, dst, w, cur, vp, edge_chunk, acc.dtype, acc=acc
-        )
-        if s != partitions - 1:
-            cur = lax.ppermute(cur, PARTITION_AXIS, fwd_perm)
-    return acc.astype(x_local.dtype)
-
-
 def _ring_aggregate_local_steps(step_blocks, x_local, *,
                                 partitions: int, vp: int, edge_chunk: int):
     """Step-major per-device body: step_blocks[s] = ([Eb_s] src, dst, w) —
     already this device's block for ring step s (row p of the stacked
     [P, Eb_s] arrays), so there is no dynamic block indexing and each step
     pays only its own diagonal's padding (DistGraph.step_blocks)."""
-    # f32 accumulator for the same reason as _ring_aggregate_local
+    # accumulate WIDE regardless of the exchange dtype (bf16 ships half
+    # the ppermute bytes; the per-vertex sum must not round per term —
+    # r5 review caught the bf16 accumulator here)
     acc = jnp.zeros((vp, x_local.shape[1]), dtype=jnp.float32)
     cur = x_local
     fwd_perm = [(i, (i - 1) % partitions) for i in range(partitions)]
@@ -80,80 +55,40 @@ def _ring_aggregate_local_steps(step_blocks, x_local, *,
     return acc.astype(x_local.dtype)
 
 
-def dist_gather_dst_from_src(
-    mesh: Mesh,
-    partitions: int,
-    vp: int,
-    edge_chunk: int,
-    blocks: Tuple[jax.Array, jax.Array, jax.Array],
-    x: jax.Array,
-) -> jax.Array:
+def dist_gather_dst_from_src(mesh: Mesh, blocks, x: jax.Array) -> jax.Array:
     """out[v] = sum over in-edges of w * x[src], vertex-sharded over the mesh.
 
-    ``x`` is the padded [P*vp, f] feature array (sharded or shardable over
-    axis 0); returns the aggregated array with the same layout. Differentiable
-    (the backward is the reverse ring).
+    ``blocks`` is the RingBlocks of ``DistGraph.shard`` (step-major per-step
+    [P, Eb_s] triples); ``x`` is the padded [P*vp, f] feature array (sharded
+    or shardable over axis 0); returns the aggregated array with the same
+    layout. Differentiable (the backward is the reverse ring)."""
+    n_steps = len(blocks.src)
 
-    ``blocks`` is either a RingBlocks (step-major per-step [P, Eb_s]
-    triples, the production layout — DistGraph.shard) or the legacy
-    uniform ([P, P, Eb] src, dst, weight) triple.
-    """
-    from neutronstarlite_tpu.parallel.dist_graph import RingBlocks
-
-    if isinstance(blocks, RingBlocks):
-        n_steps = len(blocks.src)
-
-        def local_steps(*args):
-            xs = args[-1]
-            # shard_map passes [1, Eb_s] rows; squeeze the device axis
-            steps = [
-                (args[s][0], args[n_steps + s][0], args[2 * n_steps + s][0])
-                for s in range(n_steps)
-            ]
-            return _ring_aggregate_local_steps(
-                steps, xs, partitions=partitions, vp=vp,
-                edge_chunk=edge_chunk,
-            )
-
-        fn = shard_map(
-            local_steps,
-            mesh=mesh,
-            in_specs=tuple(PS(PARTITION_AXIS, None) for _ in range(3 * n_steps))
-            + (PS(PARTITION_AXIS, None),),
-            out_specs=PS(PARTITION_AXIS, None),
+    def local_steps(*args):
+        xs = args[-1]
+        # shard_map passes [1, Eb_s] rows; squeeze the device axis
+        steps = [
+            (args[s][0], args[n_steps + s][0], args[2 * n_steps + s][0])
+            for s in range(n_steps)
+        ]
+        return _ring_aggregate_local_steps(
+            steps, xs, partitions=n_steps, vp=blocks.vp,
+            edge_chunk=blocks.edge_chunk,
         )
-        return fn(*blocks.src, *blocks.dst, *blocks.wgt, x)
-
-    block_src, block_dst, block_weight = blocks
-
-    body = partial(
-        _ring_aggregate_local,
-        partitions=partitions,
-        vp=vp,
-        edge_chunk=edge_chunk,
-    )
-
-    def local(bs, bd, bw, xs):
-        # shard_map passes [1, P, Eb] / [vp, f] blocks; squeeze the dst axis
-        return body(bs[0], bd[0], bw[0], xs)
 
     fn = shard_map(
-        local,
+        local_steps,
         mesh=mesh,
-        in_specs=(
-            PS(PARTITION_AXIS, None, None),
-            PS(PARTITION_AXIS, None, None),
-            PS(PARTITION_AXIS, None, None),
-            PS(PARTITION_AXIS, None),
-        ),
+        in_specs=tuple(PS(PARTITION_AXIS, None) for _ in range(3 * n_steps))
+        + (PS(PARTITION_AXIS, None),),
         out_specs=PS(PARTITION_AXIS, None),
     )
-    return fn(block_src, block_dst, block_weight, x)
+    return fn(*blocks.src, *blocks.dst, *blocks.wgt, x)
 
 
 def ring_aggregate_simulated(dist, x_padded: jax.Array) -> jax.Array:
     """Single-device simulation of the exact ring schedule — same blocks, same
-    per-step accumulation order as _ring_aggregate_local, with ppermute
+    per-step accumulation order as _ring_aggregate_local_steps, with ppermute
     replaced by explicit shard rotation. Used by the test rig (one-core CI
     cannot execute real cross-device collectives) to pin down the block
     construction and schedule; the shard_map path itself is exercised by the

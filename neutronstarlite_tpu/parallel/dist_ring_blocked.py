@@ -272,6 +272,31 @@ class RingBlockedPair:
             fwd=self.fwd.shard(mesh, axis), bwd=self.bwd.shard(mesh, axis)
         )
 
+    def exchange(self, mesh, x: jax.Array, wire_dtype=None,
+                 partitioner=None) -> jax.Array:
+        """``mesh=None`` is the collective-free sim twin — also the 2D
+        layout's exchange twin: the aggregation is feature-column-
+        independent, so the full-width sim IS bitwise the slab-sharded
+        collective ring (the 2D-specific math, the contraction's
+        partial-sum order, lives in partitioner.contract)."""
+        if mesh is None:
+            return dist_ring_blocked_gather_simulated(self, x, wire_dtype)
+        if partitioner is not None:
+            # the partitioner's 2D (vertex x feature) mesh: the ring
+            # rotates over the vertex axis while each device works a
+            # [vp, f/Pf] feature slab (parallel/partitioner.py)
+            return dist_ring2d_gather_dst_from_src(
+                mesh, self, x, wire_dtype, pf=partitioner.pf
+            )
+        return dist_ring_blocked_gather_dst_from_src(mesh, self, x, wire_dtype)
+
+    def describe(self) -> str:
+        return (
+            f"double-buffered ring (vt={self.fwd.vt}, "
+            f"{len(self.fwd.work_steps())}/{self.fwd.partitions} work "
+            f"steps, {self.fwd.n_transfers()} hops)"
+        )
+
 
 def _flatten_tables(rbe: RingBlockedEll, axis: str = PARTITION_AXIS):
     """(flat array list, in_specs, per-step level counts) — the shard_map

@@ -36,6 +36,15 @@ _dist_initialized = False
 shard_map = jax.shard_map
 
 
+def shard_leading(mesh: Mesh, a) -> jax.Array:
+    """Device-put one host array sharded over its leading (partition)
+    axis: the helper behind the table containers' ``shard()``."""
+    from jax.sharding import NamedSharding, PartitionSpec as PS
+
+    spec = PS(PARTITION_AXIS, *([None] * (np.ndim(a) - 1)))
+    return jax.device_put(np.asarray(a), NamedSharding(mesh, spec))
+
+
 def pcast_varying(x, axes):
     """``lax.pcast(x, axes, to="varying")``: mark a shard_map value as
     varying over ``axes`` for the VMA type system."""

@@ -35,21 +35,13 @@ import jax
 import numpy as np
 
 from neutronstarlite_tpu.graph.storage import CSCGraph, partition_offsets
+from neutronstarlite_tpu.parallel.mesh import shard_leading
 from neutronstarlite_tpu.parallel.vertex_space import PaddedVertexSpace, round_up
 
 
 def shard_tables(mesh, arrays) -> Tuple[jax.Array, ...]:
-    """Device-put each array sharded over its leading (partition) axis —
-    the one helper behind every table container's .shard() here."""
-    from jax.sharding import NamedSharding, PartitionSpec as PS
-
-    from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS
-
-    def put(a):
-        spec = PS(PARTITION_AXIS, *([None] * (np.ndim(a) - 1)))
-        return jax.device_put(np.asarray(a), NamedSharding(mesh, spec))
-
-    return tuple(put(a) for a in arrays)
+    """Device-put each array sharded over its leading (partition) axis."""
+    return tuple(shard_leading(mesh, a) for a in arrays)
 
 
 def build_local_edge_lists(P, vp, offsets, p_of_edge, slot_global, dst, w):
@@ -270,6 +262,47 @@ def chunk_edge_list(mg: "MirrorGraph", ec_target: int) -> ChunkedEdgeList:
                            base=base, dp=int(dp))
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class SplitMirrorTables:
+    """A SplitMirror's nine tables on the mesh (``SplitMirror.shard``),
+    each sharded over its leading partition axis, with the sizes the
+    exchange reads."""
+
+    need_ids: jax.Array
+    r_src_slot: jax.Array
+    r_dst: jax.Array
+    r_weight: jax.Array
+    r_mask: jax.Array
+    l_src: jax.Array
+    l_dst: jax.Array
+    l_weight: jax.Array
+    l_mask: jax.Array
+    partitions: int = dataclasses.field(metadata=dict(static=True))
+    vp: int = dataclasses.field(metadata=dict(static=True))
+    mb: int = dataclasses.field(metadata=dict(static=True))
+
+    def shard(self, mesh) -> "SplitMirrorTables":
+        """Device-put all 9 tables sharded over their leading axis."""
+        return jax.tree.map(lambda a: shard_leading(mesh, a), self)
+
+    def exchange(self, mesh, x: jax.Array, wire_dtype=None,
+                 partitioner=None) -> jax.Array:
+        from neutronstarlite_tpu.parallel.dist_edge_ops import (
+            dist_gather_dst_from_src_mirror_split,
+        )
+
+        return dist_gather_dst_from_src_mirror_split(mesh, self, self, x)
+
+    def describe(self) -> str:
+        return (
+            f"split mirror: remote-only all_to_all (mb={self.mb} remote "
+            f"slots/pair vs vp={self.vp} shard rows; "
+            f"Er={self.r_dst.shape[1]} remote + El={self.l_dst.shape[1]} "
+            f"resident edges)"
+        )
+
+
 @dataclasses.dataclass
 class SplitMirror(PaddedVertexSpace):
     """Remote-only mirror exchange + resident local edge list (round 5).
@@ -392,10 +425,15 @@ class SplitMirror(PaddedVertexSpace):
             l_mask=l_mask, e_num=g.e_num, v_num=g.v_num,
         )
 
-    def shard(self, mesh) -> Tuple[jax.Array, ...]:
-        """Device-put all 9 tables sharded over their leading axis."""
-        return shard_tables(mesh, (
-            self.need_ids, self.r_src_slot, self.r_dst, self.r_weight,
-            self.r_mask, self.l_src, self.l_dst, self.l_weight,
-            self.l_mask,
-        ))
+    def tables(self) -> SplitMirrorTables:
+        """The nine tables on the host, as the exchange takes them."""
+        return SplitMirrorTables(
+            need_ids=self.need_ids, r_src_slot=self.r_src_slot,
+            r_dst=self.r_dst, r_weight=self.r_weight, r_mask=self.r_mask,
+            l_src=self.l_src, l_dst=self.l_dst, l_weight=self.l_weight,
+            l_mask=self.l_mask, partitions=self.partitions, vp=self.vp,
+            mb=self.mb,
+        )
+
+    def shard(self, mesh) -> SplitMirrorTables:
+        return self.tables().shard(mesh)

@@ -70,7 +70,6 @@ def main(argv=None) -> int:
         N_LABELS,
         _make_trainer,
         build_and_cache_graph,
-        build_host_tables,
         load_cached_graph,
     )
     from neutronstarlite_tpu.graph.dataset import GNNDatum
@@ -83,10 +82,9 @@ def main(argv=None) -> int:
         host_graph, src, dst = load_cached_graph(d)
         sizes = [int(s) for s in LAYERS.split("-")]
         datum = GNNDatum.random_generate(v_num, sizes[0], N_LABELS, seed=7)
-        host_ell = build_host_tables(args.path, host_graph, args.kernel_tile)
         trainer = _make_trainer(
             args.order, args.path, args.precision, src, dst, datum, v_num,
-            epochs=1, warmup=0, host_graph=host_graph, host_ell=host_ell,
+            epochs=1, warmup=0, host_graph=host_graph,
             kernel_tile=args.kernel_tile,
         )
         topo = topologies.get_topology_desc(

@@ -78,9 +78,9 @@ def _synthetic_edges(cfg, scale: float):
 
 
 def _dist_gcn_case(cfg, base_dir, mesh, edges=None):
-    """The distributed GCN train step as ShapeDtypeStructs over ``mesh``
-    (mirrors DistGCNTrainer.build_model; kept in sync by
-    tests/test_aot_check.py's parity check)."""
+    """The distributed GCN train step as ShapeDtypeStructs over ``mesh``:
+    the layout is the one ``parallel/layouts.build_exchange`` gives
+    DistGCNTrainer.build_model for the same cfg, left on the host."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as PS
@@ -92,6 +92,7 @@ def _dist_gcn_case(cfg, base_dir, mesh, edges=None):
         dist_gcn_forward,
     )
     from neutronstarlite_tpu.nn.param import AdamConfig, adam_init, adam_update
+    from neutronstarlite_tpu.parallel.layouts import build_exchange
     from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS
 
     P = mesh.devices.size
@@ -102,74 +103,8 @@ def _dist_gcn_case(cfg, base_dir, mesh, edges=None):
         src, dst = edges
     host_graph = build_graph(src, dst, cfg.vertices, weight="gcn_norm")
     sizes = cfg.layer_sizes()
-
-    # same DIST_PATH resolution as DistGCNTrainer.build_model — the tool
-    # must compile the exchange the trainer ships, not a different one
-    dist_path = getattr(cfg, "dist_path", "")
-    wire_dtype = None
-    if dist_path in ("ring_blocked", "ring_blocked_sim"):
-        layer_kind = "ring_blocked"
-    elif dist_path == "all_gather":
-        layer_kind = "ell"
-    else:
-        layer_kind = DistGCNTrainer.resolve_comm_layer(cfg, host_graph, P)
-    if layer_kind == "ring_blocked":
-        from neutronstarlite_tpu.parallel.dist_graph import DistGraph
-        from neutronstarlite_tpu.parallel.dist_ring_blocked import (
-            RingBlockedPair,
-            default_ring_vt,
-        )
-        from neutronstarlite_tpu.parallel.ring_schedule import (
-            resolve_wire_dtype,
-        )
-
-        dist = DistGraph.build(host_graph, P, edge_chunk=cfg.edge_chunk or None)
-        host_blocks = RingBlockedPair.build(
-            dist, vt=default_ring_vt(dist.vp, cfg.kernel_tile)
-        )
-        wire_dtype = resolve_wire_dtype(getattr(cfg, "wire_dtype", ""))
-    elif layer_kind == "mirror":
-        # the GCN fused path ships the SPLIT layout since round 5
-        from neutronstarlite_tpu.parallel.mirror import SplitMirror
-
-        dist = SplitMirror.build(host_graph, P)
-        host_blocks = (
-            dist.need_ids, dist.r_src_slot, dist.r_dst, dist.r_weight,
-            dist.r_mask, dist.l_src, dist.l_dst, dist.l_weight,
-            dist.l_mask,
-        )
-    else:
-        from neutronstarlite_tpu.parallel.dist_graph import DistGraph
-
-        dist = DistGraph.build(host_graph, P, edge_chunk=cfg.edge_chunk or None)
-        if (
-            layer_kind == "ell"
-            and getattr(cfg, "pallas_kernel", False)
-            and os.environ.get("NTS_PALLAS_RESIDENT", "0") != "1"
-        ):
-            # PALLAS:1 -> the per-shard rectangular Mosaic bsp kernel
-            # (same gate as DistGCNTrainer.build_model; main() forces
-            # compiled-Mosaic lowering at tool entry)
-            from neutronstarlite_tpu.ops.bsp_ell import DEFAULT_VT
-            from neutronstarlite_tpu.parallel.dist_bsp import DistBspPair
-
-            host_blocks = DistBspPair.build(
-                dist, vt=cfg.kernel_tile or DEFAULT_VT
-            )
-        elif layer_kind == "ell" and cfg.kernel_tile > 0:
-            from neutronstarlite_tpu.parallel.dist_blocked import (
-                DistBlockedEllPair,
-            )
-
-            host_blocks = DistBlockedEllPair.build(dist, vt=cfg.kernel_tile)
-        elif layer_kind == "ell":
-            from neutronstarlite_tpu.parallel.dist_ell import DistEllPair
-
-            host_blocks = DistEllPair.build(dist)
-        else:
-            # step-major ring layout (DistGraph.step_blocks) — what the
-            # trainer ships since round 3
-            host_blocks = dist.step_blocks()
+    plan = build_exchange(cfg, host_graph, mesh=mesh, shard=False)
+    layer_kind, wire_dtype = plan.kind, plan.wire_dtype
 
     vsh = NamedSharding(mesh, PS(PARTITION_AXIS, None))
     vsh1 = NamedSharding(mesh, PS(PARTITION_AXIS))
@@ -181,8 +116,8 @@ def _dist_gcn_case(cfg, base_dir, mesh, edges=None):
         sh = NamedSharding(mesh, PS(PARTITION_AXIS, *([None] * (nd - 1))))
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
 
-    blocks = jax.tree.map(bspec, host_blocks)
-    vp_total = dist.vp * P
+    blocks = jax.tree.map(bspec, plan.blocks)
+    vp_total = plan.dist.vp * P
     params = init_gcn_params(
         jax.random.PRNGKey(0), sizes, with_bn=DistGCNTrainer.with_bn
     )
@@ -204,7 +139,7 @@ def _dist_gcn_case(cfg, base_dir, mesh, edges=None):
     def train_step(params, opt_state, blocks, feature, label, train01, valid, key):
         def loss_fn(p):
             logits = dist_gcn_forward(
-                mesh, dist, blocks, p, feature, valid, key, drop_rate, True,
+                mesh, blocks, p, feature, valid, key, drop_rate, True,
                 compute_dtype=compute_dtype, wire_dtype=wire_dtype,
                 input_aggregated=True,
             )

@@ -279,12 +279,12 @@ def accounting(g, P: int, f: int, refresh: int, budget_bytes: int,
     out["depcache"] = ladder
 
     # --- auto decisions vs the wire argmin --------------------------------
-    from neutronstarlite_tpu.models.gcn_dist import DistGCNTrainer
+    from neutronstarlite_tpu.parallel.layouts import resolve_comm_layer
     from neutronstarlite_tpu.utils.config import InputInfo
 
     cfg = InputInfo()
     cfg.comm_layer = "auto"
-    auto_choice = DistGCNTrainer.resolve_comm_layer(cfg, g, P)
+    auto_choice = resolve_comm_layer(cfg, g, P)
     wire_argmin = min(out["layers"], key=out["layers"].get)
     out["comm_auto"] = {
         "choice": auto_choice,

@@ -165,13 +165,9 @@ class InputInfo:
     # aggregation through the fused streamed block-sparse Pallas kernel
     # (ops/bsp_ell.py — the one fused design Mosaic can compile: one-hot
     # MXU gather + scatter, no unsupported row gathers) at any scale;
-    # KERNEL_TILE:vt sets its src-tile height (default DEFAULT_VT). The
-    # resident-gather kernel (ops/pallas_kernels.py) is interpret-only,
-    # reachable via NTS_PALLAS_RESIDENT=1 (its docstring has the analysis).
-    # On the dist path PALLAS:1 runs the compiled Mosaic bsp kernel per
-    # shard over the all_gathered slab (parallel/dist_bsp.py); only under
-    # NTS_PALLAS_RESIDENT=1 does it instead use the interpret-mode
-    # per-shard executor, which downgrades to XLA on TPU with a warning.
+    # KERNEL_TILE:vt sets its src-tile height (default DEFAULT_VT). On the
+    # dist path PALLAS:1 runs the same compiled Mosaic bsp kernel per
+    # shard over the all_gathered slab (parallel/dist_bsp.py).
     edge_chunk: int = 0  # scatter-path edge chunk size (0 = auto); applies
     # to the chunked-scatter layouts (DeviceGraph, DistGraph) — the ELL and
     # mirror-slot layouts have their own slot sizing. Tests/dryruns set it

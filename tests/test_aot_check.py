@@ -17,12 +17,21 @@ from neutronstarlite_tpu.tools.aot_check import (
 )
 from neutronstarlite_tpu.utils.config import InputInfo
 
-CFG_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "configs")
+ROOT = os.path.dirname(os.path.dirname(__file__))
+CFG_DIR = os.path.join(ROOT, "configs")
+FIXTURE = os.path.join(ROOT, "tests", "fixtures", "cora")
 
 
 def _cora_cfg(algorithm):
+    """configs/gcn_cora.cfg over the committed Cora fixture (its own paths
+    point at the reference checkout, which no machine of this round has;
+    the featuretable is not shipped: random fallback)."""
     cfg = InputInfo.read_from_cfg_file(os.path.join(CFG_DIR, "gcn_cora.cfg"))
     cfg.algorithm = algorithm
+    cfg.edge_file = os.path.join(FIXTURE, "cora.2708.edge.self")
+    cfg.feature_file = ""
+    cfg.label_file = os.path.join(FIXTURE, "cora.labeltable")
+    cfg.mask_file = os.path.join(FIXTURE, "cora.mask")
     return cfg
 
 
@@ -37,33 +46,8 @@ def test_single_device_case_compiles(algorithm):
     assert mem.argument_size_in_bytes > 0
 
 
-@pytest.mark.parametrize(
-    "comm_layer,kernel_tile",
-    [("ring", 0), ("ell", 0), ("mirror", 0), ("ell", 512)],
-)
-def test_dist_gcn_case_compiles(comm_layer, kernel_tile):
-    from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS
-
-    devs = jax.devices()
-    if len(devs) < 4:
-        pytest.skip("needs the 8-virtual-device rig")
-    mesh = Mesh(np.array(devs[:4]), (PARTITION_AXIS,))
-    cfg = _cora_cfg("GCNDIST")
-    cfg.comm_layer = comm_layer
-    cfg.partitions = 4
-    cfg.kernel_tile = kernel_tile  # 512 -> the dist blocked (KERNEL_TILE)
-    # spec path, the aot_dist_blocked plan step's shape
-    jitted, shapes, kind = _dist_gcn_case(cfg, CFG_DIR, mesh)
-    assert kind == comm_layer
-    compiled = jitted.lower(*shapes).compile()
-    assert compiled.memory_analysis().argument_size_in_bytes > 0
-
-
-def test_dist_spec_parity_with_trainer(rng):
-    """The spec builder must mirror DistGCNTrainer.build_model exactly:
-    same pytree structure, shapes, dtypes, and PartitionSpecs as the real
-    trainer's train-step arguments (the docstring's parity guarantee)."""
-    from neutronstarlite_tpu.models.gcn_dist import DistGCNTrainer
+def _tiny_dist_case(rng, comm_layer, kernel_tile=0):
+    """(cfg, mesh, edges) of a GCNDIST run over conftest's tiny graph."""
     from neutronstarlite_tpu.parallel.mesh import PARTITION_AXIS
     from tests.conftest import tiny_graph
 
@@ -71,21 +55,46 @@ def test_dist_spec_parity_with_trainer(rng):
     if len(devs) < 4:
         pytest.skip("needs the 8-virtual-device rig")
     mesh = Mesh(np.array(devs[:4]), (PARTITION_AXIS,))
-    cfg = _cora_cfg("GCNDIST")
-    cfg.comm_layer = "ring"
+    g, _ = tiny_graph(rng, v_num=97, e_num=800)
+    cfg = InputInfo()
+    cfg.algorithm = "GCNDIST"
+    cfg.vertices = g.v_num
+    cfg.layer_string = "12-8-3"
+    cfg.comm_layer = comm_layer
     cfg.partitions = 4
-    _, shapes, _ = _dist_gcn_case(cfg, CFG_DIR, mesh)
+    cfg.kernel_tile = kernel_tile
+    return cfg, mesh, (g.row_indices, g.dst_of_edge)
 
+
+@pytest.mark.parametrize(
+    "comm_layer,kernel_tile",
+    [("ring", 0), ("ell", 0), ("mirror", 0), ("ell", 512)],
+)
+def test_dist_gcn_case_compiles(rng, comm_layer, kernel_tile):
+    # 512 -> the dist blocked (KERNEL_TILE) spec path
+    cfg, mesh, edges = _tiny_dist_case(rng, comm_layer, kernel_tile)
+    jitted, shapes, kind = _dist_gcn_case(cfg, None, mesh, edges=edges)
+    assert kind == comm_layer
+    compiled = jitted.lower(*shapes).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("comm_layer", ["ring", "ell", "mirror"])
+def test_dist_spec_parity_with_trainer(rng, comm_layer):
+    """The tool and the trainer take their layout from the one builder
+    (parallel/layouts.build_exchange): the same ``blocks`` type, and the
+    same pytree structure, shapes, dtypes and PartitionSpecs in every
+    train-step argument."""
     from neutronstarlite_tpu.graph.dataset import GNNDatum
-    from neutronstarlite_tpu.graph.storage import load_edges
+    from neutronstarlite_tpu.models.gcn_dist import DistGCNTrainer
 
-    src, dst = load_edges(os.path.join(CFG_DIR, cfg.edge_file)
-                          if not os.path.isabs(cfg.edge_file)
-                          else cfg.edge_file)
+    cfg, mesh, (src, dst) = _tiny_dist_case(rng, comm_layer)
+    _, shapes, _ = _dist_gcn_case(cfg, None, mesh, edges=(src, dst))
     sizes = cfg.layer_sizes()
     datum = GNNDatum.random_generate(cfg.vertices, sizes[0], sizes[-1])
     tr = DistGCNTrainer.from_arrays(cfg, src, dst, datum)
     real = tr.aot_args()
+    assert type(shapes[2]) is type(tr.blocks)
 
     def sig(x):
         if hasattr(x, "shape"):

@@ -102,7 +102,7 @@ def test_dist_gather_matches_single_device(rng, partitions):
     x = rng.standard_normal((g.v_num, 12)).astype(np.float32)
     xp = vertex_sharded(mesh, dg.pad_vertex_array(x))
 
-    out = dist_gather_dst_from_src(mesh, partitions, dg.vp, dg.edge_chunk, blocks, xp)
+    out = dist_gather_dst_from_src(mesh, blocks, xp)
     out = dg.unpad_vertex_array(np.asarray(out))
     expected = dense @ x.astype(np.float64)
     np.testing.assert_allclose(out, expected, rtol=1e-4, atol=1e-4)
@@ -125,9 +125,7 @@ def test_dist_gather_gradient_is_reverse_ring(rng):
     cotp = jnp.asarray(dg.pad_vertex_array(cot))
 
     def loss(xp):
-        out = dist_gather_dst_from_src(
-            mesh, partitions, dg.vp, dg.edge_chunk, blocks, xp
-        )
+        out = dist_gather_dst_from_src(mesh, blocks, xp)
         return jnp.sum(out * cotp)
 
     grad = dg.unpad_vertex_array(np.asarray(jax.grad(loss)(xp)))
@@ -156,22 +154,21 @@ def test_resolve_comm_layer_rules(rng):
     """COMM_LAYER resolution: explicit wins, OPTIM_KERNEL maps to ell, auto
     compares mirror vs ring wire rows (the active-mirror-only message
     optimization as a build-time decision)."""
-    from neutronstarlite_tpu.models.gcn_dist import DistGCNTrainer
-    from neutronstarlite_tpu.utils.config import InputInfo
-
+    from neutronstarlite_tpu.parallel.layouts import resolve_comm_layer
     from neutronstarlite_tpu.parallel.mirror import MirrorGraph
+    from neutronstarlite_tpu.utils.config import InputInfo
 
     g, _ = tiny_graph(rng, v_num=97, e_num=800)
     cfg = InputInfo()
     for kind in ("ring", "ell", "mirror"):
         cfg.comm_layer = kind
-        assert DistGCNTrainer.resolve_comm_layer(cfg, g, 4) == kind
+        assert resolve_comm_layer(cfg, g, 4) == kind
     cfg.comm_layer = "auto"
     cfg.optim_kernel = True
-    assert DistGCNTrainer.resolve_comm_layer(cfg, g, 4) == "ell"
+    assert resolve_comm_layer(cfg, g, 4) == "ell"
     cfg.optim_kernel = False
-    assert DistGCNTrainer.resolve_comm_layer(cfg, g, 1) == "ring"
-    kind = DistGCNTrainer.resolve_comm_layer(cfg, g, 4)
+    assert resolve_comm_layer(cfg, g, 1) == "ring"
+    kind = resolve_comm_layer(cfg, g, 4)
     mb, vp = MirrorGraph.estimate_mb(g, 4)
     # tie -> mirror: one all_to_all beats P-1 ppermute rounds at equal
     # volume (docs/PERF.md section 3)

@@ -211,82 +211,3 @@ def test_dist_ell_over_chosen_widths_matches_single_chip(rng, direction, dtype, 
     op = ell_gather_dst_from_src if direction == "forward" else ell_gather_src_from_dst
     want = np.asarray(op(single, jnp.asarray(x)), np.float64)
     assert np.abs(got - want).max() <= tol * np.abs(want).max()
-
-
-@multidevice
-@pytest.mark.slow  # compile-heavy regime (interpret-mode / forced
-# chunking) on the CPU rig; each layer family's primary real-collective
-# parity test stays tier-1
-def test_dist_ell_pallas_kernel_matches_xla(rng):
-    """PALLAS under shard_map (round-3): the per-shard fused-kernel
-    executor over the merged stacked tables must match the XLA executor's
-    forward and custom_vjp gradient on the real 4-device mesh."""
-    from neutronstarlite_tpu.parallel.dist_ell import dist_ell_gather_dst_from_src
-    from neutronstarlite_tpu.parallel.dist_ops import vertex_sharded
-    from neutronstarlite_tpu.parallel.mesh import make_mesh
-
-    P = 4
-    g, dense, dg = _rig(rng, P)
-    mesh = make_mesh(P)
-    pair_x = DistEllPair.build(dg).shard(mesh)
-    pair_p = DistEllPair.build(dg, kernel="pallas").shard(mesh)
-    assert pair_p.fwd.kernel == "pallas"
-    # merging strictly reduces the level count on this fixture
-    assert len(pair_p.fwd.nbr) < len(pair_x.fwd.nbr)
-
-    x = rng.standard_normal((g.v_num, 6)).astype(np.float32)
-    xp = vertex_sharded(mesh, dg.pad_vertex_array(x))
-    out_x = np.asarray(dist_ell_gather_dst_from_src(mesh, pair_x, xp))
-    out_p = np.asarray(dist_ell_gather_dst_from_src(mesh, pair_p, xp))
-    np.testing.assert_allclose(out_p, out_x, rtol=1e-5, atol=1e-5)
-
-    t = jnp.asarray(rng.standard_normal(out_x.shape).astype(np.float32))
-
-    def loss(pair):
-        return lambda v: jnp.sum(
-            dist_ell_gather_dst_from_src(mesh, pair, v) * t
-        )
-
-    gx = np.asarray(jax.grad(loss(pair_x))(xp))
-    gp = np.asarray(jax.grad(loss(pair_p))(xp))
-    np.testing.assert_allclose(gp, gx, rtol=1e-4, atol=1e-4)
-
-
-@multidevice
-@pytest.mark.slow  # compile-heavy regime (interpret-mode / forced
-# chunking) on the CPU rig; each layer family's primary real-collective
-# parity test stays tier-1
-def test_dist_ell_pallas_trainer_matches_xla_trainer(rng, monkeypatch):
-    """End-to-end DistGCN with the INTERPRET-only resident per-shard
-    executor (NTS_PALLAS_RESIDENT=1 + PALLAS:1 -> DistEll kernel='pallas'):
-    must produce the same training losses as the XLA dist-ELL executor.
-    The default PALLAS:1 dist route (the Mosaic bsp kernel) is covered by
-    tests/test_dist_bsp.py."""
-    monkeypatch.setenv("NTS_PALLAS_RESIDENT", "1")
-    from neutronstarlite_tpu.graph.dataset import GNNDatum
-    from neutronstarlite_tpu.models.base import get_algorithm
-    from neutronstarlite_tpu.utils.config import InputInfo
-
-    V, E = 60, 420
-    src = rng.integers(0, V, size=E, dtype=np.uint32)
-    dst = rng.integers(0, V, size=E, dtype=np.uint32)
-    datum = GNNDatum.random_generate(V, 6, 3, seed=3)
-
-    def run(pallas: bool):
-        cfg = InputInfo()
-        cfg.algorithm = "GCNDIST"
-        cfg.vertices = V
-        cfg.layer_string = "6-8-3"
-        cfg.epochs = 3
-        cfg.learn_rate = 0.01
-        cfg.weight_decay = 1e-4
-        cfg.decay_epoch = -1
-        cfg.drop_rate = 0.0
-        cfg.partitions = 4
-        cfg.optim_kernel = True
-        cfg.kernel_tile = 0
-        cfg.pallas_kernel = pallas
-        tr = get_algorithm("GCNDIST").from_arrays(cfg, src, dst, datum)
-        return tr.run()["loss"]
-
-    np.testing.assert_allclose(run(True), run(False), rtol=1e-4, atol=1e-5)

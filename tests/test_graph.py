@@ -112,7 +112,10 @@ def test_load_cora_labels_and_masks():
 
 
 def test_cfg_parse_reference_file():
-    cfg = InputInfo.read_from_cfg_file(f"{REF}/gcn_cora.cfg")
+    # the repo's copy of the reference's gcn_cora.cfg (same keys and values)
+    cfg = InputInfo.read_from_cfg_file(
+        os.path.join(os.path.dirname(__file__), "..", "configs", "gcn_cora.cfg")
+    )
     assert cfg.algorithm == "GCNCPU"
     assert cfg.vertices == 2708
     assert cfg.layer_sizes() == [1433, 128, 7]

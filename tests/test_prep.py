@@ -1,7 +1,10 @@
 """Dataset-prep + edge-loader format tests (generate_nts_dataset equivalent)."""
 
+import os
+
 import numpy as np
 
+from neutronstarlite_tpu.graph import prep
 from neutronstarlite_tpu.graph.dataset import GNNDatum
 from neutronstarlite_tpu.graph.prep import prepare
 from neutronstarlite_tpu.graph.storage import load_edges, load_edges_binary
@@ -22,7 +25,12 @@ def test_load_edges_sniffs_text_and_binary(tmp_path):
         np.testing.assert_array_equal(d, dst)
 
 
-def test_prepare_cora_roundtrip(tmp_path):
+def test_prepare_cora_roundtrip(tmp_path, monkeypatch):
+    # the reference's Cora files, as the repo holds them
+    monkeypatch.setattr(
+        prep, "REFERENCE_DATA",
+        os.path.join(os.path.dirname(__file__), "fixtures", "cora"),
+    )
     info = prepare("cora", str(tmp_path), text_features=True)
     assert info["v_num"] == 2708
     src, dst = load_edges_binary(info["edge_file"])
