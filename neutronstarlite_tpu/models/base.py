@@ -57,15 +57,17 @@ def get_algorithm(name: str) -> Type["ToolkitBase"]:
 
 
 @jax.jit
-def _split_counts(logits_p, label_p, mask_p, valid_p):
+def _split_counts(logits_p, label_p, mask_p, valid_p=None):
     """[3] (correct, total) counts over mask splits 0/1/2, restricted to real
-    (non-padding) vertices. Inputs are padded vertex-space arrays (sharded or
-    not); the sums reduce over the sharded axis inside jit."""
-    pred = jnp.argmax(logits_p, axis=-1)
-    valid = valid_p > 0
-    ok = (pred == label_p) & valid
+    (non-padding) vertices; ``valid_p`` None where every row is a vertex
+    (the one-chip arrays). Inputs are vertex-space arrays (padded and
+    sharded, or not); the sums reduce over the sharded axis inside jit.
+    ``argmax`` takes the first largest, and a NaN as largest, as numpy's."""
+    ok = jnp.argmax(logits_p, axis=-1) == label_p
     splits = jnp.arange(3, dtype=mask_p.dtype)
-    sel = (mask_p[None, :] == splits[:, None]) & valid[None, :]  # [3, P*vp]
+    sel = mask_p[None, :] == splits[:, None]  # [3, P*vp]
+    if valid_p is not None:
+        sel = sel & (valid_p > 0)[None, :]
     correct = jnp.sum(sel & ok[None, :], axis=1)
     total = jnp.sum(sel, axis=1)
     return correct, total
@@ -739,6 +741,23 @@ class ToolkitBase:
         denom = jnp.maximum(mask01.sum(), 1.0)
         return -(picked * mask01).sum() / denom
 
+    def report_split_counts(self, counts, empty_lines: bool = True):
+        """Fetch ``_split_counts``' (correct[3], total[3]) from the device
+        (six integers: the only thing an accuracy brings to the host), log
+        the Train / Eval / Test lines (Test(0/1/2), GCN_CPU.hpp:142-171) and
+        return ``{"train": acc, "eval": acc, "test": acc}``. A split with no
+        vertex reads 0.0; the one-chip loop writes no line for it
+        (``empty_lines=False``), the sharded report does."""
+        correct, total = jax.device_get(counts)
+        accs = {}
+        for which, nm in enumerate(("Train", "Eval", "Test")):
+            n, c = int(total[which]), int(correct[which])
+            acc = c / n if n else 0.0
+            if n or empty_lines:
+                log.info("%s Acc: %f %d %d", nm, acc, n, c)
+            accs[nm.lower()] = acc
+        return accs
+
     def dist_eval_report(self, logits_p, label_p, mask_p, valid_p):
         """Accuracy for the sharded trainers: per-split (correct, total)
         counters reduced INSIDE jit over the sharded vertex axis — XLA inserts
@@ -747,15 +766,9 @@ class ToolkitBase:
         (toolkits/GCN_CPU.hpp:157-158). Never materializes global logits on
         the host, so it is multi-process safe where a
         ``np.asarray(global_sharded_logits)`` gather is not."""
-        correct, total = _split_counts(logits_p, label_p, mask_p, valid_p)
-        correct, total = np.asarray(correct), np.asarray(total)
-        accs = {}
-        for which, nm in enumerate(("Train", "Eval", "Test")):
-            n, c = int(total[which]), int(correct[which])
-            acc = c / n if n else 0.0
-            log.info("%s Acc: %f %d %d", nm, acc, n, c)
-            accs[nm.lower()] = acc
-        return accs
+        return self.report_split_counts(
+            _split_counts(logits_p, label_p, mask_p, valid_p)
+        )
 
     def avg_epoch_time(self) -> float:
         """Mean epoch time, excluding the first (compile) epoch when more
@@ -773,18 +786,6 @@ class ToolkitBase:
         when training actually ran (loss is not None) so a restore-only
         run still reports the restored model's accuracy."""
         return os.environ.get("NTS_FINAL_EVAL", "1") == "0" and loss is not None
-
-    def test(self, logits: np.ndarray, which: int) -> float:
-        """Accuracy over mask class `which` (Test(0/1/2), GCN_CPU.hpp:142-171)."""
-        sel = self.datum.mask == which
-        n = int(sel.sum())
-        if n == 0:
-            return 0.0
-        correct = int((logits[sel].argmax(axis=1) == self.datum.label[sel]).sum())
-        acc = correct / n
-        name = {0: "Train", 1: "Eval", 2: "Test"}[which]
-        log.info("%s Acc: %f %d %d", name, acc, n, correct)
-        return acc
 
     # ---- live spans of the run loops -------------------------------------
     # One vocabulary for every run loop (docs/OBSERVABILITY.md, Tracing):

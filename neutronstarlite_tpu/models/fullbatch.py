@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from neutronstarlite_tpu.models.base import ToolkitBase
+from neutronstarlite_tpu.models.base import ToolkitBase, _split_counts
 from neutronstarlite_tpu.nn.param import AdamConfig, adam_init, adam_update
 from neutronstarlite_tpu.resilience.faults import fault_point
 from neutronstarlite_tpu.utils.logging import get_logger
@@ -136,7 +136,7 @@ class FullBatchTrainer(ToolkitBase):
         # first touch uploads the datum (the properties' own datum_upload
         # phases), ahead of step_build, whose cost capture reads them
         self._build_feature()
-        _ = self.label
+        _ = self.label, self.mask  # the mask: what run() counts accuracies by
         with self.timers.phase("step_build"):
             self._build_steps(train_mask01)
 
@@ -411,6 +411,9 @@ class FullBatchTrainer(ToolkitBase):
                 type(self).__name__,
                 cfg.epochs,
             )
+            # bytes of logits brought to the host for an accuracy: the
+            # counts below are made on the device, so none
+            self.metrics.counter_add("acc.host_bytes", 0)
         with self.stage("ckpt_begin"):
             start_epoch = self.ckpt_begin()
         loss = None
@@ -444,6 +447,18 @@ class FullBatchTrainer(ToolkitBase):
                 with self.stage("epoch_key", epoch):
                     ekey = jax.random.fold_in(key, epoch)
                 stats_dev = None
+                # per-epoch Train/Eval/Test accuracy from the training
+                # forward's logits, the reference's oracle cadence
+                # (Test(0/1/2) each epoch on X[last], GCN_CPU.hpp:241-248).
+                # NOTE these cadence logits are TRAIN-mode (dropout active),
+                # so mid-training Eval/Test lines are biased low relative to
+                # the final eval-mode accuracies below — same bias as the
+                # reference's cadence, kept for log parity.
+                cadence = (
+                    epoch % max(1, cfg.epochs // 20) == 0
+                    or epoch == cfg.epochs - 1
+                )
+                counts_dev = None
                 if split_step:
                     with self.stage("forward_backward", epoch) as s_fb:
                         loss, grads = self._fwd_bwd(
@@ -456,7 +471,7 @@ class FullBatchTrainer(ToolkitBase):
                             self.params, grads, self.opt_state
                         )
                         jax.block_until_ready(self.params)
-                    logits = None  # cadence accuracies are skipped this mode
+                    # no logits: cadence accuracies are skipped this mode
                     t0 = s_fb.t0
                     stages = {
                         "forward_backward": s_fb.dur_s, "optim": s_opt.dur_s,
@@ -482,6 +497,14 @@ class FullBatchTrainer(ToolkitBase):
                                     self.label, self._train_mask01, ekey,
                                 )
                             )
+                    if cadence:
+                        # counted on the device, queued behind the step
+                        # before the host waits for it: the chip goes from
+                        # one to the other, and the logits stay where they are
+                        with self.stage("accuracy_dispatch", epoch):
+                            counts_dev = _split_counts(
+                                logits, self.label, self.mask
+                            )
                     with self.stage("step_device", epoch) as s_dev:
                         jax.block_until_ready(loss)
                     t0 = s_disp.t0
@@ -500,25 +523,10 @@ class FullBatchTrainer(ToolkitBase):
                     self.loss_history.append(float(loss))
                 with self.stage("epoch_emit", epoch):
                     self.emit_epoch(epoch, dt, loss, stages=stages)
-                cadence = (
-                    epoch % max(1, cfg.epochs // 20) == 0
-                    or epoch == cfg.epochs - 1
-                )
-                if cadence and logits is not None:
-                    # per-epoch Train/Eval/Test accuracy from the training
-                    # forward's logits, the reference's oracle cadence
-                    # (Test(0/1/2) each epoch on X[last],
-                    # GCN_CPU.hpp:241-248). NOTE these cadence logits are
-                    # TRAIN-mode (dropout active), so mid-training Eval/Test
-                    # lines are biased low relative to the final eval-mode
-                    # accuracies below — same bias as the reference's
-                    # cadence, kept for log parity.
-                    with self.stage("logits_copy", epoch):
-                        h = np.asarray(logits)
+                if counts_dev is not None:
                     with self.stage("host_accuracy", epoch):
-                        self.test(h, 0)
-                        self.test(h, 1)
-                        self.test(h, 2)
+                        self.report_split_counts(counts_dev, empty_lines=False)
+                        self.metrics.counter_add("acc.device_counts")
                 if cadence:
                     # the loss line must not depend on logits:
                     # NTS_TRACE_STEP=1 skips cadence accuracies but still
@@ -544,14 +552,11 @@ class FullBatchTrainer(ToolkitBase):
                     logits_dev = self._eval_logits(
                         self.params, self.compute_graph, self.feature, key
                     )
-                with self.stage("logits_copy"):
-                    logits = np.asarray(logits_dev)
                 with self.stage("host_accuracy"):
-                    accs = {
-                        "train": self.test(logits, 0),
-                        "eval": self.test(logits, 1),
-                        "test": self.test(logits, 2),
-                    }
+                    accs = self.report_split_counts(
+                        _split_counts(logits_dev, self.label, self.mask),
+                        empty_lines=False,
+                    )
         avg = self.avg_epoch_time()
         log.info(
             "--avg epoch time %.4f s (first %.2f s incl. compile)",

@@ -633,12 +633,13 @@ def test_spans_emitted_under_remote_ctx_carry_link_stamps(tmp_path):
 # ---- live spans of the funnel and the run loops (ISSUE 24) ------------------
 
 # the stage vocabulary of an epoch, in the order a loop opens them
-# (docs/OBSERVABILITY.md, Tracing); logits_copy / host_accuracy only where
-# the loop has a cadence accuracy, epoch_key only where the host derives a
-# key per epoch (the sampled scan takes the run's key whole)
+# (docs/OBSERVABILITY.md, Tracing); accuracy_dispatch / host_accuracy only
+# where the loop has a cadence accuracy (counted on the device, ISSUE 32: no
+# logits_copy), epoch_key only where the host derives a key per epoch (the
+# sampled scan takes the run's key whole)
 EPOCH_STAGES = {
-    "fullbatch": ["epoch_key", "step_dispatch", "step_device", "loss_fetch",
-                  "epoch_emit", "logits_copy", "host_accuracy",
+    "fullbatch": ["epoch_key", "step_dispatch", "accuracy_dispatch",
+                  "step_device", "loss_fetch", "epoch_emit", "host_accuracy",
                   "ckpt_epoch_end"],
     "dist": ["epoch_key", "step_dispatch", "step_device", "loss_fetch",
              "epoch_emit", "ckpt_epoch_end"],
@@ -790,7 +791,7 @@ def test_run_level_stages_and_a_second_run(loop_run):
     final = next(s for s in spans if s["name"] == "final_eval")
     kids = [s["name"] for s in spans if s["parent_id"] == final["span_id"]]
     assert kids == {
-        "fullbatch": ["eval_forward", "logits_copy", "host_accuracy"],
+        "fullbatch": ["eval_forward", "host_accuracy"],
         "dist": ["eval_forward", "host_accuracy"], "sampled": [],
     }[family]
     # a second run() on the finished trainer (the benchmark's window after
