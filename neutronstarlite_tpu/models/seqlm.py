@@ -1,6 +1,8 @@
 """The token-sequence trainer family (ALGORITHM:SEQLM): next-token training
-of a DeepSeek-V3-style block (latent attention, routed + shared experts)
-over an integer datum and an implicit causal graph.
+of a stack of layers, each a token mixer of one of two kinds (latent
+attention, with or without rotary positions; a gated delta-rule linear
+attention) followed by an MLP of one of two kinds (dense; routed + shared
+experts), over an integer datum and an implicit causal graph.
 
 Beside ``fullbatch``, ``dist`` and ``sampled`` this is the fourth run loop
 on ``ToolkitBase``. What differs from them is the datum and the graph: the
@@ -16,7 +18,12 @@ funnel (``init_graph`` / ``init_nn`` / ``_finalize_datum`` /
 batches cycle through a corpus of SEQ_CORPUS resident on the device.
 
 The model is described by the source's own ``config.json`` keys in the JSON
-file MODEL_FILE names. The cut to one chip's share is in cfg keys:
+file MODEL_FILE names, in either of two dialects (``SeqSpec.from_cfg``, the
+one place that tells them apart): a DeepSeek-V3 file is the stack "latent
+attention with rotary in every layer"; a ``kimi_linear`` file names the
+mixer of every layer (``linear_attn_config.kda_layers`` /
+``full_attn_layers``) and its latent attention rotates nothing. The cut to
+one chip's share is in cfg keys:
 SEQ_LAYERS (layers kept, the leading dense one first), EXPERT_SHARDS /
 EXPERT_SHARD (this chip holds ``n_routed_experts / EXPERT_SHARDS`` experts
 of every layer, routes over all of them and computes its own experts' part;
@@ -24,13 +31,16 @@ what absent experts would add is left out and nothing stands in for their
 chips), VOCAB_SHARDS (ids, logits and loss over this chip's slice),
 SEQ_LENGTH, SEQ_BATCH.
 
-The step (one jitted program): embed; the dense layer; the identical
-expert layers under one ``lax.scan``; every layer recomputed in the
-backward (``jax.checkpoint``); the head and the loss in chunks of tokens
-(the ``[tokens, vocab]`` logits never exist whole); Adam. PRECISION:bfloat16
-computes the products in bfloat16 over the float32 masters; norms, rotary,
-the softmax state, the router (its product, scores and top-k) and the
-residual stream stay float32.
+The step (one jitted program): embed; the dense layer; the expert layers
+in runs of one mixer kind, each run stacked on a leading axis under its own
+key (``moe``, then ``moe1``, ``moe2``, ...) and walked by one ``lax.scan``
+(a stack of one kind is the single run ``moe``); every layer recomputed in
+the backward (``jax.checkpoint``); the head and the loss in chunks of
+tokens (the ``[tokens, vocab]`` logits never exist whole); Adam.
+PRECISION:bfloat16 computes the products in bfloat16 over the float32
+masters; norms, rotary, the softmax state, the delta rule's decay, system
+and state, the router (its product, scores and top-k) and the residual
+stream stay float32.
 
 Named scopes of the step (``SCOPES``): ``scope_table()`` hands out, for the
 compiled step, instruction name -> scope, read from the HLO text's
@@ -56,7 +66,7 @@ from neutronstarlite_tpu.models.base import ToolkitBase, register_algorithm
 from neutronstarlite_tpu.nn import seq as nnseq
 from neutronstarlite_tpu.nn.layers import compute_cast
 from neutronstarlite_tpu.nn.param import AdamConfig, adam_init, adam_update
-from neutronstarlite_tpu.ops import moe
+from neutronstarlite_tpu.ops import delta_rule, moe
 from neutronstarlite_tpu.ops.causal_attention import causal_edge_attention
 from neutronstarlite_tpu.resilience.faults import fault_point
 from neutronstarlite_tpu.utils.config import InputInfo
@@ -66,12 +76,25 @@ from neutronstarlite_tpu.utils.timing import get_time
 log = get_logger("seqlm")
 
 SCOPES = (
-    "seq/embed", "seq/mla/project", "seq/mla/attend", "seq/dense_mlp",
+    "seq/embed", "seq/mla/project", "seq/mla/attend", "seq/kda/project", "seq/kda/conv",
+    "seq/kda/gate", "seq/kda/recur", "seq/kda/out", "seq/dense_mlp",
     "seq/moe/route", "seq/moe/dispatch", "seq/moe/experts", "seq/moe/shared",
     "seq/moe/combine", "seq/head_loss", "seq/adam",
 )
 DEFAULT_LOSS_CHUNK = 4096
 INIT_STD = 0.02  # assumed: config.json gives no initializer_range
+
+
+# the family's name of a count -> its key in (a DeepSeek-V3 file, a kimi_linear file)
+DIALECT_KEYS = {
+    "routed": ("n_routed_experts", "num_experts"),
+    "per_token": ("num_experts_per_tok", "num_experts_per_token"),
+    "shared": ("n_shared_experts", "num_shared_experts"),
+    "scoring": ("scoring_func", "moe_router_activation_func"),
+    "groups": ("n_group", "num_expert_group"),
+    "renormalised": ("norm_topk_prob", "moe_renormalize"),
+    "positions": ("max_position_embeddings", "model_max_length"),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,28 +124,63 @@ class SeqSpec:
     batch: int
     block: int
     loss_chunk: int
+    mixers: tuple = ()  # the mixer of every kept layer: "mla" or "kda"
+    rotary: bool = True  # whether the latent attention turns its shared dims by position
+    kda_heads: int = 0
+    kda_dim: int = 0  # key = value dims of a delta-rule head
+    conv_kernel: int = 0
+    kda_chunk: int = 0  # positions a chunk of the delta rule
 
     @property
     def tokens(self) -> int:
         return self.batch * self.length
 
+    @property
+    def kda_layers(self) -> int:
+        return self.mixers.count("kda")
+
+    @property
+    def runs(self) -> tuple:
+        """The expert layers in runs of one mixer kind: (key of the run in
+        the parameter tree, kind, first expert layer, layers)."""
+        out = []
+        for i, kind in enumerate(self.mixers[1:]):
+            if out and out[-1][1] == kind:
+                out[-1][3] += 1
+            else:
+                out.append([f"moe{len(out) or ''}", kind, i, 1])
+        return tuple(tuple(r) for r in out)
+
     @staticmethod
     def from_cfg(model: dict, cfg: InputInfo) -> "SeqSpec":
-        for key, want in (("q_lora_rank", None), ("n_group", 1), ("topk_group", 1),
-                          ("scoring_func", "sigmoid"), ("first_k_dense_replace", 1),
-                          ("moe_layer_freq", 1), ("hidden_act", "silu")):
-            if model.get(key, want) != want:
+        linear = model.get("linear_attn_config")
+        key = {name: keys[1 if linear else 0] for name, keys in DIALECT_KEYS.items()}
+        for name, want in (("q_lora_rank", None), (key["groups"], 1), ("topk_group", 1),
+                           (key["scoring"], "sigmoid"), (key["renormalised"], True),
+                           ("first_k_dense_replace", 1), ("moe_layer_freq", 1),
+                           ("hidden_act", "silu")) + ((("mla_use_nope", True),) if linear else ()):
+            if model.get(name, want) != want:
                 raise ValueError(
-                    f"MODEL_FILE has {key}={model.get(key)!r}; the SEQLM family is written "
-                    f"for {key}={want!r} (models/seqlm.py states the block it computes)"
+                    f"MODEL_FILE has {name}={model.get(name)!r}; the SEQLM family is written "
+                    f"for {name}={want!r} (models/seqlm.py states the block it computes)"
                 )
-        layers = cfg.seq_layers or int(model["num_hidden_layers"])
-        if not 2 <= layers <= int(model["num_hidden_layers"]):
+        published = int(model["num_hidden_layers"])
+        layers = cfg.seq_layers or published
+        if not 2 <= layers <= published:
             raise ValueError(
                 f"SEQ_LAYERS:{layers} must keep the dense layer and at least one expert "
                 f"layer of the model's {model['num_hidden_layers']}"
             )
-        routed, vocab = int(model["n_routed_experts"]), int(model["vocab_size"])
+        mixers = ("mla",) * layers
+        if linear:
+            kda, full = linear["kda_layers"], linear["full_attn_layers"]  # 1-based
+            if sorted(kda + full) != list(range(1, published + 1)):
+                raise ValueError(
+                    f"MODEL_FILE's linear_attn_config.kda_layers and full_attn_layers name "
+                    f"{sorted(kda + full)}: not each of num_hidden_layers={published} layers once"
+                )
+            mixers = tuple("kda" if i + 1 in kda else "mla" for i in range(layers))
+        routed, vocab = int(model[key["routed"]]), int(model["vocab_size"])
         if routed % cfg.expert_shards or not 0 <= cfg.expert_shard < cfg.expert_shards:
             raise ValueError(
                 f"EXPERT_SHARDS:{cfg.expert_shards} must divide the {routed} routed experts "
@@ -130,15 +188,17 @@ class SeqSpec:
             )
         if vocab % cfg.vocab_shards:
             raise ValueError(f"VOCAB_SHARDS:{cfg.vocab_shards} must divide the vocabulary {vocab}")
-        length = cfg.seq_length or int(model["max_position_embeddings"])
-        if length > int(model["max_position_embeddings"]):
+        positions = int(model[key["positions"]])
+        length = cfg.seq_length or positions
+        if length > positions:
             raise ValueError(
-                f"SEQ_LENGTH:{length} is beyond the model's "
-                f"{model['max_position_embeddings']} positions"
-            )
+                f"SEQ_LENGTH:{length} is beyond the model's {positions} positions")
         if cfg.attn_block and length % cfg.attn_block:
             raise ValueError(
                 f"ATTN_BLOCK:{cfg.attn_block} does not divide the sequence length {length}")
+        chunk = (cfg.kda_chunk or delta_rule.DEFAULT_CHUNK) if "kda" in mixers else 0
+        if chunk and length % chunk:
+            raise ValueError(f"KDA_CHUNK:{chunk} does not divide the sequence length {length}")
         held = routed // cfg.expert_shards
         tokens = cfg.seq_batch * length
         return SeqSpec(
@@ -146,77 +206,122 @@ class SeqSpec:
             kv_rank=int(model["kv_lora_rank"]), nope=int(model["qk_nope_head_dim"]),
             rope=int(model["qk_rope_head_dim"]), v_head=int(model["v_head_dim"]),
             ffn=int(model["intermediate_size"]), expert_width=int(model["moe_intermediate_size"]),
-            shared_width=int(model["n_shared_experts"]) * int(model["moe_intermediate_size"]),
-            routed=routed, per_token=int(model["num_experts_per_tok"]),
+            shared_width=int(model[key["shared"]]) * int(model["moe_intermediate_size"]),
+            routed=routed, per_token=int(model[key["per_token"]]),
             route_scale=float(model["routed_scaling_factor"]), theta=float(model["rope_theta"]),
             eps=float(model["rms_norm_eps"]), moe_layers=layers - 1,
             first=cfg.expert_shard * held, held=held, vocab=vocab // cfg.vocab_shards,
             length=length, batch=cfg.seq_batch, block=cfg.attn_block,
             loss_chunk=math.gcd(tokens, cfg.loss_chunk or DEFAULT_LOSS_CHUNK),
+            mixers=mixers, rotary=not linear,
+            kda_heads=int(linear["num_heads"]) if linear else 0,
+            kda_dim=int(linear["head_dim"]) if linear else 0,
+            conv_kernel=int(linear["short_conv_kernel_size"]) if linear else 0,
+            kda_chunk=chunk,
         )
+
+
+def _keys(key: jax.Array):
+    """Keys without end: 31 of a split of 32, the last split again."""
+    while True:
+        *batch, key = jax.random.split(key, 32)
+        yield from batch
 
 
 def init_params(key: jax.Array, spec: SeqSpec) -> Dict[str, Any]:
     """Seeded weights, normal with std ``INIT_STD``, norms at one; the
-    expert layers stacked on a leading axis (the scan's)."""
+    expert layers in runs of one mixer kind, each run stacked on a leading
+    axis (its scan's)."""
     d, h = spec.hidden, spec.heads
-    keys = iter(jax.random.split(key, 32))
+    keys = _keys(key)
 
-    def attention(lead):
+    def normal(*shape):
+        return nnseq.normal_init(next(keys), shape, INIT_STD)
+
+    def mla(lead):
         return {
             "norm1": jnp.ones(lead + (d,), jnp.float32),
-            "wq": nnseq.normal_init(next(keys), lead + (d, h * (spec.nope + spec.rope)), INIT_STD),
-            "wkv_a": nnseq.normal_init(next(keys), lead + (d, spec.kv_rank + spec.rope), INIT_STD),
+            "wq": normal(*lead, d, h * (spec.nope + spec.rope)),
+            "wkv_a": normal(*lead, d, spec.kv_rank + spec.rope),
             "kv_norm": jnp.ones(lead + (spec.kv_rank,), jnp.float32),
-            "wkv_b": nnseq.normal_init(
-                next(keys), lead + (spec.kv_rank, h * (spec.nope + spec.v_head)), INIT_STD),
-            "wo": nnseq.normal_init(next(keys), lead + (h * spec.v_head, d), INIT_STD),
+            "wkv_b": normal(*lead, spec.kv_rank, h * (spec.nope + spec.v_head)),
+            "wo": normal(*lead, h * spec.v_head, d),
+            "norm2": jnp.ones(lead + (d,), jnp.float32),
+        }
+
+    def kda(lead):
+        kh, kd, taps = spec.kda_heads, spec.kda_dim, spec.conv_kernel
+        wide = kh * kd
+
+        def uniform(lo, hi, *shape):
+            return jax.random.uniform(next(keys), lead + shape, jnp.float32, lo, hi)
+
+        # assumed (config.json gives none of them): the convolutions as
+        # torch's Conv1d starts them; exp(A_log) uniform over 1..16; a step
+        # softplus(dt_bias) log-uniform over 0.001..0.1
+        step = jnp.exp(uniform(math.log(1e-3), math.log(1e-1), wide))
+        return {
+            "norm1": jnp.ones(lead + (d,), jnp.float32),
+            "wq": normal(*lead, d, wide), "wk": normal(*lead, d, wide),
+            "wv": normal(*lead, d, wide),
+            **{c: uniform(-taps ** -0.5, taps ** -0.5, wide, taps) for c in ("cq", "ck", "cv")},
+            "wf_a": normal(*lead, d, kd), "wf_b": normal(*lead, kd, wide),
+            "a_log": jnp.log(uniform(1.0, 16.0, kh)),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "wb": normal(*lead, d, kh),
+            "wz_a": normal(*lead, d, kd), "wz_b": normal(*lead, kd, wide),
+            "o_norm": jnp.ones(lead + (kd,), jnp.float32),
+            "wo": normal(*lead, wide, d),
             "norm2": jnp.ones(lead + (d,), jnp.float32),
         }
 
     def glu(lead, width, names):
-        g, u, dn = names
-        return {
-            g: nnseq.normal_init(next(keys), lead + (d, width), INIT_STD),
-            u: nnseq.normal_init(next(keys), lead + (d, width), INIT_STD),
-            dn: nnseq.normal_init(next(keys), lead + (width, d), INIT_STD),
-        }
+        return {name: normal(*lead, *shape)
+                for name, shape in zip(names, ((d, width), (d, width), (width, d)))}
 
-    n = (spec.moe_layers,)
-    return {
-        "embed": nnseq.normal_init(next(keys), (spec.vocab, d), INIT_STD),
-        "dense": {**attention(()), **glu((), spec.ffn, ("wg", "wu", "wd"))},
-        "moe": {
-            **attention(n),
-            "router": nnseq.normal_init(next(keys), n + (d, spec.routed), INIT_STD),
+    mixer = {"mla": mla, "kda": kda}
+    params = {
+        "embed": normal(spec.vocab, d),
+        "dense": {**mixer[spec.mixers[0]](()), **glu((), spec.ffn, ("wg", "wu", "wd"))},
+    }
+    for name, kind, _, count in spec.runs:
+        n = (count,)
+        params[name] = {
+            **mixer[kind](n),
+            "router": normal(*n, d, spec.routed),
             **glu(n + (spec.held,), spec.expert_width, ("eg", "eu", "ed")),
             **glu(n, spec.shared_width, ("sg", "su", "sd")),
-        },
-        "norm": jnp.ones((d,), jnp.float32),
-        "head": nnseq.normal_init(next(keys), (d, spec.vocab), INIT_STD),
-    }
+        }
+    params["norm"] = jnp.ones((d,), jnp.float32)
+    params["head"] = normal(d, spec.vocab)
+    return params
 
 
 # ---- the forward pass
 
 def attention(lp, x, spec: SeqSpec, cast, mid):
     """``x [T, hidden]`` (``batch`` sequences of ``length``) plus its latent
-    attention."""
+    attention; the dims all heads share are turned by position where
+    ``spec.rotary``, else they enter the score as they are."""
     b, s, h = spec.batch, spec.length, spec.heads
     pos = jnp.arange(s, dtype=jnp.int32)
+
+    def turned(t):
+        return nnseq.rotary(t, pos, spec.theta) if spec.rotary else t
+
     with jax.named_scope("seq/mla/project"):
         hn = nnseq.rms_norm(x, lp["norm1"], spec.eps)
         q = nnseq.matmul(hn, lp["wq"], cast).reshape(b, s, h, spec.nope + spec.rope)
         q = jnp.swapaxes(q, 1, 2)  # [B, H, S, nope + rope]
         q = jnp.concatenate(
-            [q[..., : spec.nope], nnseq.rotary(q[..., spec.nope:], pos, spec.theta)], axis=-1
+            [q[..., : spec.nope], turned(q[..., spec.nope:])], axis=-1
         ).astype(mid).reshape(b * h, s, -1)
         ckr = nnseq.matmul(hn, lp["wkv_a"], cast)
         c = nnseq.rms_norm(ckr[:, : spec.kv_rank], lp["kv_norm"], spec.eps)
-        k_rope = nnseq.rotary(ckr[:, spec.kv_rank:].reshape(b, s, spec.rope), pos, spec.theta)
+        k_rope = turned(ckr[:, spec.kv_rank:].reshape(b, s, spec.rope))
         kv = nnseq.matmul(c, lp["wkv_b"], cast, mid).reshape(b, s, h, spec.nope + spec.v_head)
         kv = jnp.swapaxes(kv, 1, 2)
-        # the one rotary key all heads share, laid beside each head's own
+        # the one key all heads share, laid beside each head's own
         k_rope = jnp.broadcast_to(k_rope[:, None].astype(mid), (b, h, s, spec.rope))
         k = jnp.concatenate([kv[..., : spec.nope], k_rope], axis=-1).reshape(b * h, s, -1)
         v = kv[..., spec.nope:].reshape(b * h, s, spec.v_head)
@@ -227,8 +332,73 @@ def attention(lp, x, spec: SeqSpec, cast, mid):
         return x + nnseq.matmul(out, lp["wo"], cast)
 
 
+def delta_attention(lp, x, spec: SeqSpec, cast, mid):
+    """``x [T, hidden]`` plus its gated delta-rule linear attention (KDA):
+    queries, keys and values through a short causal convolution and SiLU,
+    queries and keys of unit length per head, a log-decay per head and key
+    channel and a write strength per head from the same normed stream, the
+    recurrence of ops/delta_rule.py, a sigmoid-gated RMS norm per head.
+
+    Each operand's path from the normed stream (product, convolution, SiLU,
+    norm; the decay's pair, softplus and cumulative sum) and the output's
+    path are recomputed in the backward, each under its own
+    ``jax.checkpoint`` inside the layer's: what a KDA layer's backward
+    holds at once is the operands in the compute dtype and one path's
+    float32 intermediates, not all of them (``[tokens, 4096]`` float32 is
+    0.5 GB at 32,768 tokens, and a layer has some twenty)."""
+    b, s, h, d = spec.batch, spec.length, spec.kda_heads, spec.kda_dim
+
+    def by_head(t):  # [B, S, H, ...] -> [B * H, S, ...]
+        return jnp.swapaxes(t, 1, 2).reshape(b * h, s, *t.shape[3:])
+
+    @jax.checkpoint
+    def operand(hn, w, taps, scale):
+        """One of q, k, v ``[B * H, S, d]``: ``scale`` None leaves it as
+        the SiLU gives it, else unit length per head times ``scale``."""
+        with jax.named_scope("seq/kda/project"):
+            t = nnseq.matmul(hn, w, cast, mid)
+        with jax.named_scope("seq/kda/conv"):
+            t = jax.nn.silu(nnseq.causal_conv(t.reshape(b, s, h * d), taps)).reshape(b, s, h, d)
+            if scale is not None:
+                t = nnseq.l2_norm(t) * scale
+            return by_head(t.astype(mid))
+
+    @jax.checkpoint
+    def gates(hn, wf_a, wf_b, a_log, dt_bias, wb):
+        with jax.named_scope("seq/kda/project"):
+            decay = nnseq.matmul(nnseq.matmul(hn, wf_a, cast, mid), wf_b, cast)
+            write = nnseq.matmul(hn, wb, cast)
+        with jax.named_scope("seq/kda/gate"):
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus((decay + dt_bias).reshape(b, s, h, d))
+            return (delta_rule.chunk_log_decay(by_head(g), spec.kda_chunk),
+                    by_head(jax.nn.sigmoid(write).reshape(b, s, h)))
+
+    @jax.checkpoint
+    def output(x, hn, out, wz_a, wz_b, o_norm, wo):
+        with jax.named_scope("seq/kda/project"):
+            gate = nnseq.matmul(nnseq.matmul(hn, wz_a, cast, mid), wz_b, cast)
+        with jax.named_scope("seq/kda/out"):
+            out = jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)
+            out = nnseq.gated_rms_norm(out, o_norm, gate.reshape(b, s, h, d), spec.eps)
+        with jax.named_scope("seq/kda/project"):
+            return x + nnseq.matmul(out.reshape(b * s, h * d), wo, cast)
+
+    with jax.named_scope("seq/kda/project"):
+        hn = nnseq.rms_norm(x, lp["norm1"], spec.eps)
+    q = operand(hn, lp["wq"], lp["cq"], d ** -0.5)
+    k = operand(hn, lp["wk"], lp["ck"], 1.0)
+    v = operand(hn, lp["wv"], lp["cv"], None)
+    log_decay, beta = gates(hn, lp["wf_a"], lp["wf_b"], lp["a_log"], lp["dt_bias"], lp["wb"])
+    with jax.named_scope("seq/kda/recur"):
+        out = delta_rule.chunked_delta_rule(q, k, v, log_decay, beta, cast)
+    return output(x, hn, out, lp["wz_a"], lp["wz_b"], lp["o_norm"], lp["wo"])
+
+
+MIXERS = {"mla": attention, "kda": delta_attention}
+
+
 def dense_layer(lp, x, spec: SeqSpec, cast, mid):
-    x = attention(lp, x, spec, cast, mid)
+    x = MIXERS[spec.mixers[0]](lp, x, spec, cast, mid)
     with jax.named_scope("seq/dense_mlp"):
         hn = nnseq.rms_norm(x, lp["norm2"], spec.eps)
         return x + nnseq.swiglu(hn, lp["wg"], lp["wu"], lp["wd"], cast)
@@ -260,18 +430,27 @@ def hidden_states(params, bias, tokens, spec: SeqSpec, cast, mid):
     with jax.named_scope("seq/embed"):
         x = params["embed"][tokens.reshape(-1)]
     x = jax.checkpoint(lambda lp, x: dense_layer(lp, x, spec, cast, mid))(params["dense"], x)
+    sizes, choices = [], []
+    for name, kind, start, count in spec.runs:
+        mixer = MIXERS[kind]
 
-    @jax.checkpoint
-    def layer(x, lp, b):
-        x = attention(lp, x, spec, cast, mid)
-        return expert_mlp(lp, b, x, spec, cast)
+        @jax.checkpoint
+        def layer(x, lp, b):
+            x = mixer(lp, x, spec, cast, mid)
+            return expert_mlp(lp, b, x, spec, cast)
 
-    def body(x, lp_b):
-        x, sizes, choice = layer(x, *lp_b)
-        return x, (sizes, choice)
+        def body(x, lp_b):
+            x, size, choice = layer(x, *lp_b)
+            return x, (size, choice)
 
-    x, (sizes, choice) = lax.scan(body, x, (params["moe"], bias))
-    return x, sizes, choice
+        # the run's rows of the bias; a run of all the expert layers takes it whole
+        run_bias = bias if count == spec.moe_layers else bias[start: start + count]
+        x, (size, choice) = lax.scan(body, x, (params[name], run_bias))
+        sizes.append(size)
+        choices.append(choice)
+    if len(sizes) == 1:
+        return x, sizes[0], choices[0]
+    return x, jnp.concatenate(sizes), jnp.concatenate(choices)
 
 
 def head_loss(params, x, tokens, spec: SeqSpec, cast):
@@ -414,11 +593,16 @@ class SeqLMTrainer(ToolkitBase):
             self._scope_table: Optional[Dict[str, str]] = None
         self.routed_history: list = []  # pairs sent to held experts, per epoch
         n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(self.params))
+        self.metrics.gauge_set("seq.kda_layers", spec.kda_layers)
+        self.metrics.gauge_set("seq.mla_layers", len(spec.mixers) - spec.kda_layers)
+        self.metrics.gauge_set("kda.chunk", spec.kda_chunk)
         log.info(
-            "SEQLM: %d layers (1 dense + %d expert), experts %d..%d of %d held, vocabulary "
-            "slice %d, %d parameters; a step is %d sequences of %d tokens; corpus of %d batches",
-            spec.moe_layers + 1, spec.moe_layers, spec.first, spec.first + spec.held - 1,
-            spec.routed, spec.vocab, n_params, spec.batch, spec.length, self.n_batches,
+            "SEQLM: %d layers (1 dense + %d expert; mixers %s), experts %d..%d of %d held, "
+            "vocabulary slice %d, %d parameters; a step is %d sequences of %d tokens; corpus of "
+            "%d batches",
+            spec.moe_layers + 1, spec.moe_layers, "-".join(spec.mixers), spec.first,
+            spec.first + spec.held - 1, spec.routed, spec.vocab, n_params, spec.batch,
+            spec.length, self.n_batches,
         )
 
     def initial_state(self):
@@ -528,5 +712,6 @@ class SeqLMTrainer(ToolkitBase):
         self.routed_history.append(rows)
         self.metrics.counter_add("seq.tokens", self.spec.tokens)
         self.metrics.counter_add("moe.rows_routed", rows)
+        self.metrics.counter_add("kda.token_layers", self.spec.tokens * self.spec.kda_layers)
         mean = np.maximum(sizes.mean(axis=1), 1e-9)
         self.metrics.gauge_set("moe.load_max_over_mean", float((sizes.max(axis=1) / mean).max()))

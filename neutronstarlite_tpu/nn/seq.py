@@ -1,5 +1,7 @@
 """NN pieces of the token-sequence family (models/seqlm.py): RMS norm,
-rotary positions, SwiGLU, normal initialisation.
+rotary positions, SwiGLU, normal initialisation and, for the delta-rule
+mixer, a depthwise causal convolution over positions, an L2 norm per head
+and a sigmoid-gated RMS norm.
 
 Numeric policy: norms and rotations in float32 whatever the compute dtype;
 a matrix product reads its operands through ``cast`` (nn/layers.py's
@@ -20,6 +22,28 @@ def normal_init(key: jax.Array, shape, std: float = 0.02) -> jax.Array:
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * weight
+
+
+def l2_norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    """``x / |x|`` over the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def gated_rms_norm(x: jax.Array, weight: jax.Array, gate: jax.Array, eps: float) -> jax.Array:
+    """``rms_norm(x) * sigmoid(gate)``, float32."""
+    return rms_norm(x, weight, eps) * jax.nn.sigmoid(gate.astype(jnp.float32))
+
+
+def causal_conv(x: jax.Array, weight: jax.Array) -> jax.Array:
+    """Depthwise causal convolution over the positions of ``x [B, S, C]``
+    with ``weight [C, K]``, float32: channel ``c`` of position ``t`` is
+    ``sum_i weight[c, i] * x[t - (K - 1) + i, c]``, zeros before a
+    sequence's start. A position reads itself and the ``K - 1`` before it
+    in its own sequence, nothing later and nothing of another sequence."""
+    taps, positions = weight.shape[-1], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(padded[:, i: i + positions] * weight[:, i] for i in range(taps))
 
 
 def rotary(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:

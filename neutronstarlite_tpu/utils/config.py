@@ -224,6 +224,8 @@ class InputInfo:
     # causal attention (0 = ops/causal_attention.DEFAULT_BLOCK)
     loss_chunk: int = 0  # LOSS_CHUNK: tokens a chunk of the head + loss
     # (0 = 4096): the [tokens, vocab] logits never exist whole
+    kda_chunk: int = 0  # KDA_CHUNK: positions a chunk of the delta rule
+    # (0 = ops/delta_rule.DEFAULT_CHUNK); a model without such layers ignores it
     warmup_epochs: int = 0  # WARMUP_EPOCHS: optimizer steps over which the
     # learn rate rises linearly from 0 to LEARN_RATE (0 = none), as a
     # language-model pre-training job starts; SEQLM honours it
@@ -345,7 +347,7 @@ class InputInfo:
             setattr(self, key.lower(), value)
         elif key in ("SEQ_LAYERS", "SEQ_LENGTH", "SEQ_BATCH", "SEQ_CORPUS",
                      "EXPERT_SHARDS", "EXPERT_SHARD", "VOCAB_SHARDS",
-                     "ATTN_BLOCK", "LOSS_CHUNK", "WARMUP_EPOCHS"):
+                     "ATTN_BLOCK", "LOSS_CHUNK", "KDA_CHUNK", "WARMUP_EPOCHS"):
             setattr(self, key.lower(), int(value))
         elif key == "COMM_LAYER":
             self.comm_layer = value.strip().lower()

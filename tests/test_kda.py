@@ -1,0 +1,297 @@
+"""The delta-rule mixer of the token-sequence family (ops/delta_rule.py,
+nn/seq.py's convolution and norms, models/seqlm.py's second dialect) against
+the plain reference the benchmark compares with
+(benchmark/reference/kimi_linear.py, loaded by its path: one reference, no
+second copy), on the CPU, float32, small widths, seeded weights."""
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neutronstarlite_tpu.models import seqlm
+from neutronstarlite_tpu.nn import seq as nnseq
+from neutronstarlite_tpu.nn.layers import compute_cast
+from neutronstarlite_tpu.ops import delta_rule
+from neutronstarlite_tpu.utils.config import InputInfo
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_reference():
+    path = os.path.join(REPO, "benchmark", "reference", "kimi_linear.py")
+    spec = importlib.util.spec_from_file_location("reference_kimi_linear", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+ref = _load_reference()
+
+# the keys of a kimi_linear config.json, small: eight layers, 3 KDA to 1 latent attention
+MODEL = dict(
+    model_type="kimi_linear", hidden_size=48, num_attention_heads=3, kv_lora_rank=24,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96,
+    moe_intermediate_size=32, num_shared_experts=1, num_experts=16, num_experts_per_token=3,
+    routed_scaling_factor=2.446, rope_theta=10000, rms_norm_eps=1e-5, num_hidden_layers=8,
+    vocab_size=128, model_max_length=64, q_lora_rank=None, num_expert_group=1, topk_group=1,
+    moe_router_activation_func="sigmoid", moe_renormalize=True, first_k_dense_replace=1,
+    moe_layer_freq=1, hidden_act="silu", mla_use_nope=True, use_grouped_topk=True,
+    linear_attn_config=dict(kda_layers=[1, 2, 3, 5, 6, 7], full_attn_layers=[4, 8], num_heads=4,
+                            head_dim=8, short_conv_kernel_size=4),
+)
+SHAPE = ref.Shape.of(MODEL)
+CUT = dict(SEQ_LAYERS=5, SEQ_LENGTH=32, SEQ_BATCH=2, SEQ_CORPUS=3, EXPERT_SHARDS=4,
+           EXPERT_SHARD=1, VOCAB_SHARDS=2, ATTN_BLOCK=8, LOSS_CHUNK=16, KDA_CHUNK=8, EPOCHS=2,
+           LEARN_RATE=0.0003, WEIGHT_DECAY=0.0001, DECAY_EPOCH=-1)
+
+
+def make_trainer(tmp_path, model=MODEL, seed=3, **keys):
+    """A SEQLM trainer from a cfg file beside its model JSON, as a user's."""
+    with open(tmp_path / "model.json", "w") as fh:
+        json.dump(model, fh)
+    path = tmp_path / "seq.cfg"
+    with open(path, "w") as fh:
+        fh.writelines(f"{k}:{v}\n" for k, v in
+                      dict(CUT, ALGORITHM="SEQLM", MODEL_FILE="model.json", **keys).items())
+    tokens = np.random.default_rng(0).integers(0, 64, size=(6, 32), dtype=np.int32)
+    return seqlm.SeqLMTrainer.from_tokens(InputInfo.read_from_cfg_file(str(path)), tokens,
+                                          seed=seed, base_dir=str(tmp_path))
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+# ---- the chunked delta rule against the recurrence, position by position
+
+def _recurrence_inputs(rng, rows, positions, dk, dv, gate):
+    unit = lambda t: t / np.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(rng.standard_normal((rows, positions, dk))).astype(np.float32)
+    k = unit(rng.standard_normal((rows, positions, dk))).astype(np.float32)
+    v = rng.standard_normal((rows, positions, dv)).astype(np.float32)
+    g = -rng.uniform(0.0, gate, (rows, positions, dk)).astype(np.float32)
+    beta = rng.uniform(0.0, 1.0, (rows, positions)).astype(np.float32)
+    return tuple(jnp.asarray(t) for t in (q, k, v, g, beta))
+
+
+def _by_tokens(q, k, v, g, beta):
+    """The reference's recurrence over rows ``[N, S, .]`` (it takes ``[T, H, .]``)."""
+    t = lambda a: jnp.swapaxes(a, 0, 1)  # noqa: E731
+    return t(ref.delta_rule_tokens(t(q), t(k), t(v), t(g), t(beta), segment=16))
+
+
+@pytest.mark.parametrize("chunk", [64, 32, 16, 4])
+@pytest.mark.parametrize("gate", [0.1, 3.0, 60.0])
+def test_the_chunked_delta_rule_is_the_recurrence(rng, chunk, gate):
+    """Outputs and every gradient, float32. At ``gate`` 60 a chunk's
+    cumulative decay is ``exp(-2000)``: it underflows, a quotient of two of
+    them would be 0/0, and the chunked form stays finite and equal."""
+    args = _recurrence_inputs(rng, 3, 128, 8, 6, gate)
+    weight = jnp.asarray(rng.standard_normal((3, 128, 6)).astype(np.float32))
+    with jax.default_matmul_precision("highest"):
+        got = delta_rule.gated_delta_rule(*args, chunk=chunk)
+        want = _by_tokens(*args)
+        d_got = jax.grad(lambda *a: jnp.sum(delta_rule.gated_delta_rule(*a, chunk=chunk) * weight),
+                         argnums=range(5))(*args)
+        d_want = jax.grad(lambda *a: jnp.sum(_by_tokens(*a) * weight), argnums=range(5))(*args)
+    assert np.all(np.isfinite(np.asarray(got))) and rel(got, want) < 1e-5
+    for name, a, b in zip("q k v g beta".split(), d_got, d_want):
+        assert np.all(np.isfinite(np.asarray(a))), name
+        assert rel(a, b) < 5e-5, name
+
+
+def test_the_state_is_carried_across_chunks(rng):
+    """What a later chunk gives depends on the earlier ones: cutting the
+    sequence in two and starting again from zero is another result."""
+    q, k, v, g, beta = _recurrence_inputs(rng, 2, 32, 8, 8, 0.05)
+    whole = delta_rule.gated_delta_rule(q, k, v, g, beta, chunk=16)
+    second = delta_rule.gated_delta_rule(q[:, 16:], k[:, 16:], v[:, 16:], g[:, 16:], beta[:, 16:], chunk=16)
+    assert rel(whole[:, :16], delta_rule.gated_delta_rule(
+        q[:, :16], k[:, :16], v[:, :16], g[:, :16], beta[:, :16], chunk=16)) < 1e-6
+    assert rel(whole[:, 16:], second) > 0.1
+
+
+def test_the_inverse_of_a_unit_lower_triangle(rng):
+    lower = np.tril(rng.standard_normal((5, 64, 64)), -1).astype(np.float32) * 0.3
+    with jax.default_matmul_precision("highest"):
+        got = delta_rule.unit_lower_inverse(jnp.asarray(lower))
+    want = np.linalg.inv(np.eye(64) + lower.astype(np.float64))
+    assert rel(got, want) < 1e-5
+
+
+# ---- the convolution and the norms
+
+def _conv_by_hand(x, w):
+    out = np.zeros_like(x, dtype=np.float64)
+    taps = w.shape[1]
+    for b in range(x.shape[0]):
+        for t in range(x.shape[1]):
+            for i in range(taps):
+                at = t - (taps - 1) + i
+                if at >= 0:
+                    out[b, t] += w[:, i] * x[b, at]
+    return out
+
+
+@pytest.mark.parametrize("taps", [1, 2, 4])
+def test_the_causal_convolution_is_the_explicit_sum(rng, taps):
+    x = rng.standard_normal((3, 12, 5)).astype(np.float32)
+    w = rng.standard_normal((5, taps)).astype(np.float32)
+    assert rel(nnseq.causal_conv(jnp.asarray(x), jnp.asarray(w)), _conv_by_hand(x, w)) < 1e-6
+    flat = x.reshape(36, 5)  # the reference's, one sequence at a time
+    for b in range(3):
+        assert rel(ref.short_conv(jnp.asarray(flat[12 * b: 12 * b + 12]), jnp.asarray(w)),
+                   _conv_by_hand(x, w)[b]) < 1e-6
+
+
+def test_the_convolution_reads_no_later_position_and_no_other_sequence(rng):
+    x = rng.standard_normal((2, 10, 4)).astype(np.float32)
+    w = jnp.asarray(rng.standard_normal((4, 4)).astype(np.float32))
+    base = np.asarray(nnseq.causal_conv(jnp.asarray(x), w))
+    later = x.copy()
+    later[0, 6] += 1.0
+    moved = np.asarray(nnseq.causal_conv(jnp.asarray(later), w))
+    assert np.array_equal(moved[0, :6], base[0, :6]) and not np.array_equal(moved[0, 6:], base[0, 6:])
+    assert np.array_equal(moved[1], base[1])  # the other sequence of the batch
+    last = x.copy()
+    last[0, 9] += 1.0  # the last position of sequence 0 sits before the first of sequence 1
+    assert np.array_equal(np.asarray(nnseq.causal_conv(jnp.asarray(last), w))[1], base[1])
+
+
+def test_the_l2_norm_and_the_gated_rms_norm(rng):
+    x = rng.standard_normal((7, 3, 8)).astype(np.float32)
+    gate = rng.standard_normal((7, 3, 8)).astype(np.float32)
+    w = rng.standard_normal(8).astype(np.float32)
+    assert rel(nnseq.l2_norm(jnp.asarray(x)), x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)) < 1e-6
+    want = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-5) * w / (1.0 + np.exp(-gate))
+    assert rel(nnseq.gated_rms_norm(jnp.asarray(x), jnp.asarray(w), jnp.asarray(gate), 1e-5), want) < 1e-6
+
+
+# ---- the mixer against the reference's, one layer
+
+def test_the_delta_mixer_is_the_reference_mixer(tmp_path, rng):
+    trainer = make_trainer(tmp_path)
+    lp = jax.tree.map(np.asarray, trainer.params["dense"])
+    x = rng.standard_normal((64, SHAPE.hidden)).astype(np.float32)
+    got = seqlm.delta_attention(lp, jnp.asarray(x), trainer.spec, compute_cast(None), jnp.float32)
+    want = np.concatenate([np.asarray(ref.kda_mixer(lp, jnp.asarray(x[lo: lo + 32]), SHAPE))
+                           for lo in (0, 32)])
+    assert rel(got, want) < 1e-5
+    # a sequence's rows do not depend on the other sequence of the batch
+    other = x.copy()
+    other[:32] += 1.0
+    moved = seqlm.delta_attention(lp, jnp.asarray(other), trainer.spec, compute_cast(None), jnp.float32)
+    assert np.array_equal(np.asarray(moved)[32:], np.asarray(got)[32:])
+
+
+def test_the_latent_attention_turns_nothing_without_positions(tmp_path, rng):
+    trainer = make_trainer(tmp_path)
+    assert trainer.spec.rotary is False
+    lp = jax.tree.map(lambda a: np.asarray(a[0]), trainer.params["moe1"])
+    x = rng.standard_normal((64, SHAPE.hidden)).astype(np.float32)
+    got = seqlm.attention(lp, jnp.asarray(x), trainer.spec, compute_cast(None), jnp.float32)
+    want = np.concatenate([np.asarray(ref.mixer(lp, jnp.asarray(x[lo: lo + 32]), SHAPE))
+                           for lo in (0, 32)])
+    assert rel(got, want) < 1e-5
+    turned = seqlm.attention(lp, jnp.asarray(x), dataclasses.replace(trainer.spec, rotary=True),
+                             compute_cast(None), jnp.float32)
+    assert rel(np.asarray(turned) - x, want - x) > 2e-3  # the layer's own part, without the stream
+
+
+# ---- the share, tied to the model
+
+def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(rng):
+    d, w = SHAPE.hidden, MODEL["moe_intermediate_size"]
+    n = lambda *s: (rng.standard_normal(s) * 0.3).astype(np.float32)  # noqa: E731
+    lp = {"norm2": np.ones(d, np.float32), "router": n(d, 16), "eg": n(16, d, w), "eu": n(16, d, w),
+          "ed": n(16, w, d), "sg": n(d, w), "su": n(d, w), "sd": n(w, d)}
+    x = rng.standard_normal((40, d)).astype(np.float32)
+    bias = jnp.zeros((16,), jnp.float32)
+    whole, _ = ref.expert_mlp(lp, jnp.asarray(x), bias, SHAPE, ref.Share(0, 16))
+    cast = compute_cast(None)
+    shared = np.asarray(nnseq.swiglu(nnseq.rms_norm(x, lp["norm2"], SHAPE.eps),
+                                     lp["sg"], lp["su"], lp["sd"], cast))
+    total, rows = x + shared, 0
+    for shard in range(4):
+        cfg = InputInfo()
+        cfg.seq_layers, cfg.seq_length, cfg.seq_batch = 2, 40, 1
+        cfg.expert_shards, cfg.expert_shard, cfg.kda_chunk = 4, shard, 8
+        spec = seqlm.SeqSpec.from_cfg(MODEL, cfg)
+        mine = dict(lp, **{k: lp[k][4 * shard: 4 * shard + 4] for k in ("eg", "eu", "ed")})
+        out, sizes, _ = seqlm.expert_mlp(mine, bias, jnp.asarray(x), spec, cast)
+        total = total + (np.asarray(out) - x - shared)
+        rows += int(np.asarray(sizes).sum())
+    assert rows == 40 * 3  # every pair was computed by exactly one share
+    assert rel(total, whole) < 1e-5
+
+
+# ---- the two dialects of config.json
+
+def _cfg(**keys):
+    cfg = InputInfo()
+    cfg.seq_layers, cfg.seq_length, cfg.seq_batch, cfg.kda_chunk = 5, 32, 2, 8
+    for k, v in keys.items():
+        setattr(cfg, k, v)
+    return cfg
+
+
+def test_a_kimi_linear_file_names_the_mixer_of_every_layer():
+    spec = seqlm.SeqSpec.from_cfg(MODEL, _cfg())
+    assert spec.mixers == ("kda", "kda", "kda", "mla", "kda") and spec.kda_layers == 4
+    assert spec.runs == (("moe", "kda", 0, 2), ("moe1", "mla", 2, 1), ("moe2", "kda", 3, 1))
+    assert (spec.routed, spec.per_token, spec.shared_width, spec.rotary) == (16, 3, 32, False)
+    assert (spec.kda_heads, spec.kda_dim, spec.conv_kernel, spec.kda_chunk) == (4, 8, 4, 8)
+    assert seqlm.SeqSpec.from_cfg(MODEL, _cfg(seq_layers=8)).mixers[-1] == "mla"
+    assert seqlm.SeqSpec.from_cfg(MODEL, _cfg(kda_chunk=0, seq_length=64)).kda_chunk == 64
+
+
+@pytest.mark.parametrize("key, value", [
+    ("q_lora_rank", 1536), ("num_expert_group", 8), ("topk_group", 4),
+    ("moe_router_activation_func", "softmax"), ("moe_renormalize", False),
+    ("mla_use_nope", False), ("first_k_dense_replace", 3), ("hidden_act", "gelu"),
+])
+def test_a_kimi_linear_key_the_family_does_not_compute_is_refused_by_name(key, value):
+    with pytest.raises(ValueError, match=f"MODEL_FILE has {key}="):
+        seqlm.SeqSpec.from_cfg(dict(MODEL, **{key: value}), _cfg())
+
+
+def test_a_layer_list_that_disagrees_with_the_depth_is_refused():
+    linear = dict(MODEL["linear_attn_config"], kda_layers=[1, 2, 3, 5, 6])
+    with pytest.raises(ValueError, match="kda_layers and full_attn_layers"):
+        seqlm.SeqSpec.from_cfg(dict(MODEL, linear_attn_config=linear), _cfg())
+    with pytest.raises(ValueError, match="KDA_CHUNK:5 does not divide"):
+        seqlm.SeqSpec.from_cfg(MODEL, _cfg(kda_chunk=5))
+
+
+# ---- counters, gauges, scopes
+
+def test_the_counters_and_gauges_of_the_new_layers(tmp_path):
+    trainer = make_trainer(tmp_path)
+    trainer.run()
+    gauges = trainer.metrics.snapshot()["gauges"]
+    assert (gauges["seq.kda_layers"], gauges["seq.mla_layers"], gauges["kda.chunk"]) == (4, 1, 8)
+    assert trainer.metrics.counter_get("kda.token_layers") == 2 * 64 * 4  # epochs x tokens x layers
+    assert trainer.metrics.counter_get("seq.tokens") == 2 * 64
+
+
+def test_the_scope_table_of_a_hybrid_step_covers_every_named_scope(tmp_path):
+    table = make_trainer(tmp_path).scope_table()
+    assert set(table.values()) == set(seqlm.SCOPES)
+    assert seqlm.scope_of("jit(step)/transpose(jvp(seq/kda/recur))/while/body/dot") == "seq/kda/recur"
+
+
+def test_bfloat16_compute_stays_near_the_reference(tmp_path):
+    trainer = make_trainer(tmp_path, PRECISION="bfloat16")
+    p0 = jax.tree.map(np.asarray, trainer.params)
+    trainer.run()
+    share = ref.Share(trainer.spec.first, trainer.spec.held)
+    want, _ = ref.loss(p0, trainer.datum.tokens[:2], SHAPE, share, block=16)
+    assert abs(trainer.loss_history[0] - float(want)) < 1e-3 * float(want)
+    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(trainer.params))  # the masters

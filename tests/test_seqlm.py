@@ -394,7 +394,8 @@ def test_spans_and_stages_of_the_funnel_and_the_run_loop(tmp_path):
 def test_the_scope_table_covers_every_named_scope(trained):
     trainer, _, _ = trained
     table = trainer.scope_table()
-    assert set(table.values()) == set(seqlm.SCOPES)
+    # every layer of this stack attends: the delta-rule mixer's scopes are tests/test_kda.py's
+    assert set(table.values()) == {s for s in seqlm.SCOPES if not s.startswith("seq/kda/")}
     assert seqlm.scope_of("jit(step)/transpose(jvp(seq/moe/experts))/ragged_dot") == "seq/moe/experts"
     assert seqlm.scope_of("jit(step)/seq/mla/project/seq/mla/attend/while/body/dot") == "seq/mla/attend"
     assert seqlm.scope_of("jit(step)/convert_element_type") is None
@@ -408,3 +409,110 @@ def test_bfloat16_compute_stays_near_the_reference(tmp_path):
     want, _ = ref.loss(p0, trainer.datum.tokens[:2], SHAPE, share, block=16)
     assert abs(trainer.loss_history[0] - float(want)) < 1e-3 * float(want)
     assert jax.tree.leaves(trainer.params)[0].dtype == jnp.float32  # the masters
+
+
+# ---- one code path, two dialects of config.json
+
+def test_a_deepseek_v3_file_is_what_it_was(tmp_path):
+    """The spec, the parameter tree and the first losses of this file's
+    model at seed 3, as the tree before the second dialect gave them."""
+    trainer = make_trainer(tmp_path, EPOCHS=2)
+    spec = trainer.spec
+    assert (spec.hidden, spec.heads, spec.kv_rank, spec.nope, spec.rope, spec.v_head, spec.ffn,
+            spec.expert_width, spec.shared_width, spec.routed, spec.per_token, spec.route_scale,
+            spec.theta, spec.eps, spec.moe_layers, spec.first, spec.held, spec.vocab, spec.length,
+            spec.batch, spec.block, spec.loss_chunk) == (
+        48, 3, 24, 16, 8, 16, 96, 32, 64, 16, 3, 2.446, 50000.0, 1e-05, 2, 4, 4, 64, 32, 2, 8, 16)
+    assert spec.mixers == ("mla",) * 3 and spec.rotary and spec.kda_layers == 0
+    assert spec.runs == (("moe", "mla", 0, 2),)
+    attention = {"norm1", "wq", "wkv_a", "kv_norm", "wkv_b", "wo", "norm2"}
+    assert set(trainer.params) == {"embed", "dense", "moe", "norm", "head"}
+    assert set(trainer.params["dense"]) == attention | {"wg", "wu", "wd"}
+    assert set(trainer.params["moe"]) == attention | {"router", "eg", "eu", "ed", "sg", "su", "sd"}
+    assert trainer.params["moe"]["eg"].shape == (2, 4, 48, 32)
+    sums = {"embed": 50.702041, "head": 50.029979}
+    for name, want in sums.items():
+        assert abs(float(jnp.sum(jnp.abs(trainer.params[name]))) - want) < 1e-3
+    for name, want in {"wq": 111.541246, "router": 24.085751, "ed": 195.543242}.items():
+        assert abs(float(jnp.sum(jnp.abs(trainer.params["moe"][name]))) - want) < 1e-3
+    assert abs(float(jnp.sum(jnp.abs(trainer.params["dense"]["wd"]))) - 73.618498) < 1e-3
+    trainer.run()
+    assert trainer.loss_history == pytest.approx([4.152822971343994, 4.1860880851745605], abs=2e-6)
+    assert trainer.metrics.counter_get("kda.token_layers") == 0
+
+
+@pytest.mark.parametrize("key, value", [
+    ("q_lora_rank", 1536), ("n_group", 8), ("topk_group", 4), ("scoring_func", "softmax"),
+    ("norm_topk_prob", False), ("first_k_dense_replace", 3), ("moe_layer_freq", 2),
+])
+def test_a_deepseek_v3_key_the_family_does_not_compute_is_refused_by_name(key, value):
+    cfg = InputInfo()
+    cfg.seq_layers, cfg.seq_length = 3, 32
+    with pytest.raises(ValueError, match=f"MODEL_FILE has {key}="):
+        seqlm.SeqSpec.from_cfg(dict(MODEL, **{key: value}), cfg)
+
+
+# ---- the hybrid stack (a kimi_linear file) against its reference
+
+@pytest.fixture(scope="module")
+def hybrid(tmp_path_factory):
+    """A float32 trainer over dense-KDA, KDA, KDA, latent attention, KDA
+    (tests/test_kda.py's model), its reference, its initial weights."""
+    import test_kda
+
+    trainer = test_kda.make_trainer(tmp_path_factory.mktemp("hybrid"))
+    return trainer, test_kda.ref, test_kda.SHAPE, jax.tree.map(np.asarray, trainer.params)
+
+
+def _by_sequence(choice, sequences):
+    c = np.asarray(choice)
+    return c.reshape(c.shape[0], sequences, -1, c.shape[-1]).transpose(1, 0, 2, 3)
+
+
+def test_the_hybrid_trainer_matches_the_reference(hybrid):
+    """Logits, loss, every gradient leaf of every layer (both mixers, both
+    MLPs, embedding, head), and the tail's gradients as the chip's check
+    computes them."""
+    trainer, kref, shape, p0 = hybrid
+    assert trainer.spec.mixers == ("kda", "kda", "kda", "mla", "kda")
+    batch = trainer.datum.tokens[:2]
+    share = kref.Share(trainer.spec.first, trainer.spec.held)
+    logits, choice = trainer._eval_logits(
+        trainer.initial_state()[0], trainer.route_bias, jnp.asarray(batch), jnp.arange(64))
+    want_loss, own = kref.loss(p0, batch, shape, share, block=16)
+    assert np.array_equal(np.sort(_by_sequence(choice, 2), -1), np.sort(np.asarray(own), -1))
+    want = np.concatenate([np.asarray(kref.head_logits(
+        p0, kref.hidden_states(p0, batch[s], shape, share, block=16)[0], shape)) for s in range(2)])
+    assert np.abs(np.asarray(logits) - want).max() / np.abs(want).max() < 1e-5
+    loss, grads = jax.value_and_grad(
+        lambda p: trainer._loss(p, trainer.route_bias, jnp.asarray(batch))[0])(trainer.initial_state()[0])
+    ref_loss, ref_grads = kref.loss_and_grads(p0, batch, shape, share)
+    assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
+    assert abs(float(ref_loss) - float(want_loss)) < 1e-5 * float(want_loss)
+    assert jax.tree.structure(grads) == jax.tree.structure(ref_grads)
+    errors = jax.tree.map(rel, grads, ref_grads)
+    assert max(jax.tree.leaves(errors)) < 2e-4, errors
+    tail_loss, tail = kref.tail_loss_and_grads(p0, batch, shape, share, block=16)
+    assert abs(float(tail_loss) - float(ref_loss)) < 1e-5
+    assert [("wkv_a" in lp) for lp in tail["layers"]] == [True, False]  # one of each mixer
+    assert max(jax.tree.leaves(jax.tree.map(rel, tail, kref.tail_of(ref_grads)))) < 2e-4
+
+
+def test_two_adam_steps_of_the_hybrid_trainer_follow_the_reference(hybrid):
+    trainer, kref, shape, p0 = hybrid
+    share = kref.Share(trainer.spec.first, trainer.spec.held)
+    params, opt = trainer.initial_state()
+    want = jax.tree.map(lambda a: np.asarray(a, np.float64), p0)
+    m = v = jax.tree.map(np.zeros_like, want)
+    for step in range(2):
+        batch = trainer.datum.tokens[2 * step: 2 * step + 2]
+        _, grads = kref.loss_and_grads(want, batch, shape, share)
+        params, opt, _, _, _ = trainer._train_step(params, opt, trainer.route_bias, trainer.corpus,
+                                                   trainer._batch_index[step])
+        leaves, tree = jax.tree.flatten(want)
+        out = [kref.adam_step(p, g, a, b, step + 1, 0.0003, 0.0001)
+               for p, g, a, b in zip(leaves, tree.flatten_up_to(grads), tree.flatten_up_to(m),
+                                     tree.flatten_up_to(v))]
+        want, m, v = (tree.unflatten([o[i] for o in out]) for i in range(3))
+        moved = jax.tree.map(lambda a, b, c: rel(np.asarray(a, np.float64) - c, b - c), params, want, p0)
+        assert max(jax.tree.leaves(moved)) < 5e-3, (step, moved)
