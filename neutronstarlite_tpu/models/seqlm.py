@@ -51,6 +51,7 @@ reader can sum device time by scope.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 import re
@@ -68,6 +69,7 @@ from neutronstarlite_tpu.nn.layers import compute_cast
 from neutronstarlite_tpu.nn.param import AdamConfig, adam_init, adam_update
 from neutronstarlite_tpu.ops import delta_rule, moe
 from neutronstarlite_tpu.ops.causal_attention import causal_edge_attention
+from neutronstarlite_tpu.ops.conv_operand import conv_operand
 from neutronstarlite_tpu.resilience.faults import fault_point
 from neutronstarlite_tpu.utils.config import InputInfo
 from neutronstarlite_tpu.utils.logging import get_logger
@@ -339,29 +341,28 @@ def delta_attention(lp, x, spec: SeqSpec, cast, mid):
     channel and a write strength per head from the same normed stream, the
     recurrence of ops/delta_rule.py, a sigmoid-gated RMS norm per head.
 
-    Each operand's path from the normed stream (product, convolution, SiLU,
-    norm; the decay's pair, softplus and cumulative sum) and the output's
-    path are recomputed in the backward, each under its own
-    ``jax.checkpoint`` inside the layer's: what a KDA layer's backward
-    holds at once is the operands in the compute dtype and one path's
-    float32 intermediates, not all of them (``[tokens, 4096]`` float32 is
-    0.5 GB at 32,768 tokens, and a layer has some twenty)."""
+    Each operand's path from the normed stream (the product, then
+    ops/conv_operand.py's one pass: convolution, SiLU, norm and the change
+    of layout, float32 in VMEM and nowhere else; the decay's pair, softplus
+    and cumulative sum) and the output's path are recomputed in the
+    backward, each under its own ``jax.checkpoint`` inside the layer's:
+    what a KDA layer's backward holds at once is the operands and one
+    product in the compute dtype and the gates' and the output's float32
+    intermediates (``[tokens, 4096]`` float32 is 0.5 GB at 32,768 tokens),
+    one path's at a time."""
     b, s, h, d = spec.batch, spec.length, spec.kda_heads, spec.kda_dim
 
     def by_head(t):  # [B, S, H, ...] -> [B * H, S, ...]
         return jnp.swapaxes(t, 1, 2).reshape(b * h, s, *t.shape[3:])
 
-    @jax.checkpoint
+    @functools.partial(jax.checkpoint, static_argnums=3)
     def operand(hn, w, taps, scale):
         """One of q, k, v ``[B * H, S, d]``: ``scale`` None leaves it as
         the SiLU gives it, else unit length per head times ``scale``."""
         with jax.named_scope("seq/kda/project"):
             t = nnseq.matmul(hn, w, cast, mid)
         with jax.named_scope("seq/kda/conv"):
-            t = jax.nn.silu(nnseq.causal_conv(t.reshape(b, s, h * d), taps)).reshape(b, s, h, d)
-            if scale is not None:
-                t = nnseq.l2_norm(t) * scale
-            return by_head(t.astype(mid))
+            return conv_operand(t.reshape(b, s, h * d), taps, scale, h)
 
     @jax.checkpoint
     def gates(hn, wf_a, wf_b, a_log, dt_bias, wb):

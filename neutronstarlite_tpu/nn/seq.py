@@ -3,6 +3,14 @@ rotary positions, SwiGLU, normal initialisation and, for the delta-rule
 mixer, a depthwise causal convolution over positions, an L2 norm per head
 and a sigmoid-gated RMS norm.
 
+``causal_conv`` and ``l2_norm`` are the arithmetic of the mixer's q, k and
+v and take what they are given: a whole ``[B, S, C]`` array (a test, a
+reference), or one tile of one head with the rows before it, a value in
+VMEM, which is how the mixer itself runs them (ops/conv_operand.py's
+kernels call them by these names, tile by tile, and nothing of theirs
+passes through HBM in float32). So they keep to what both XLA and Mosaic
+lower: a pad, static slices, a column of the taps, a sum over lanes.
+
 Numeric policy: norms and rotations in float32 whatever the compute dtype;
 a matrix product reads its operands through ``cast`` (nn/layers.py's
 ``compute_cast``: bfloat16 under PRECISION:bfloat16, float32 masters
@@ -36,11 +44,16 @@ def gated_rms_norm(x: jax.Array, weight: jax.Array, gate: jax.Array, eps: float)
 
 
 def causal_conv(x: jax.Array, weight: jax.Array) -> jax.Array:
-    """Depthwise causal convolution over the positions of ``x [B, S, C]``
-    with ``weight [C, K]``, float32: channel ``c`` of position ``t`` is
-    ``sum_i weight[c, i] * x[t - (K - 1) + i, c]``, zeros before a
-    sequence's start. A position reads itself and the ``K - 1`` before it
-    in its own sequence, nothing later and nothing of another sequence."""
+    """Depthwise causal convolution over the positions (axis 1) of ``x [B,
+    S, C]`` (any float dtype) with the taps ``weight [C, K]`` float32, one
+    row of ``K`` a channel; float32 ``[B, S, C]``: channel ``c`` of
+    position ``t`` is ``sum_i weight[c, i] * x[t - (K - 1) + i, c]``, zeros
+    before position 0. A position reads itself and the ``K - 1`` before it
+    in its own sequence, nothing later and nothing of another sequence.
+    The mixer gives it a tile's rows behind their halo (``[1, HALO + tile,
+    d]``, one head's ``d`` channels and their ``[d, K]`` taps) and drops
+    the halo's rows from the result, so the zeros stand only where a
+    sequence starts."""
     taps, positions = weight.shape[-1], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
     return sum(padded[:, i: i + positions] * weight[:, i] for i in range(taps))

@@ -17,7 +17,7 @@ import pytest
 from neutronstarlite_tpu.models import seqlm
 from neutronstarlite_tpu.nn import seq as nnseq
 from neutronstarlite_tpu.nn.layers import compute_cast
-from neutronstarlite_tpu.ops import delta_rule
+from neutronstarlite_tpu.ops import conv_operand, delta_rule
 from neutronstarlite_tpu.utils.config import InputInfo
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -172,6 +172,115 @@ def test_the_l2_norm_and_the_gated_rms_norm(rng):
     assert rel(nnseq.l2_norm(jnp.asarray(x)), x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)) < 1e-6
     want = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-5) * w / (1.0 + np.exp(-gate))
     assert rel(nnseq.gated_rms_norm(jnp.asarray(x), jnp.asarray(w), jnp.asarray(gate), 1e-5), want) < 1e-6
+
+
+# ---- one pass from the product to the recurrence's operand (ops/conv_operand.py)
+
+def _operand_by_formula(x, taps, scale, heads):
+    """What the pass replaces, written out: the explicit tap sum, SiLU,
+    ``x / |x|`` per head, the scale, the cast, rows by head."""
+    b, s, wide = x.shape
+    k, d = taps.shape[1], wide // heads
+    x32 = x.astype(jnp.float32)
+    y = sum(taps[:, i] * jnp.concatenate(
+        [jnp.zeros((b, k - 1 - i, wide), jnp.float32), x32[:, : s - (k - 1 - i)]], axis=1)
+        for i in range(k))
+    t = (y / (1.0 + jnp.exp(-y))).reshape(b, s, heads, d)
+    if scale is not None:
+        t = t / jnp.sqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6) * scale
+    return jnp.swapaxes(t.astype(x.dtype), 1, 2).reshape(b * heads, s, d)
+
+
+@pytest.mark.parametrize("dtype, limit", [(jnp.float32, 1e-6), (jnp.bfloat16, 1e-3)])
+@pytest.mark.parametrize("taps", [1, 2, 4])
+@pytest.mark.parametrize("form", ["q", "k", "v"])
+def test_the_operand_pass_is_the_formula_it_replaces(rng, form, taps, dtype, limit):
+    """Forward, and the gradients of the product and of the taps against
+    autodiff of the formula; three tiles of 16 positions, so that a tile
+    reads the one before it and its cotangent the one after."""
+    b, s, h, d = 2, 48, 3, 8
+    scale = {"q": d ** -0.5, "k": 1.0, "v": None}[form]
+    x = jnp.asarray(rng.standard_normal((b, s, h * d)), dtype)
+    w = jnp.asarray(rng.standard_normal((h * d, taps)).astype(np.float32))
+    weight = jnp.asarray(rng.standard_normal((b * h, s, d)).astype(np.float32))
+    got = conv_operand.conv_operand(x, w, scale, h, 16)
+    assert got.dtype == dtype and got.shape == (b * h, s, d)
+    assert rel(got, _operand_by_formula(x, w, scale, h)) < limit
+
+    def grads(f):
+        return jax.grad(lambda x, w: jnp.sum(f(x, w).astype(jnp.float32) * weight), argnums=(0, 1))(x, w)
+
+    d_got = grads(lambda x, w: conv_operand.conv_operand(x, w, scale, h, 16))
+    d_want = grads(lambda x, w: _operand_by_formula(x, w, scale, h))
+    assert d_got[0].dtype == dtype and d_got[1].dtype == jnp.float32
+    for name, a, b_ in zip(("product", "taps"), d_got, d_want):
+        assert rel(a, b_) < limit, name
+    # one tile or three: the same operand
+    assert rel(conv_operand.conv_operand(x, w, scale, h), got) < limit
+
+
+def test_an_operand_row_reads_no_later_position_no_other_head_and_no_other_sequence(rng):
+    """Row ``b * H + h`` is sequence ``b``'s head ``h``: it reads nothing
+    of row ``b * H + h - 1`` (another head, or the sequence before), and
+    nothing later than itself, across a tile's edge too."""
+    b, s, h, d = 2, 32, 3, 8
+    x = rng.standard_normal((b, s, h * d)).astype(np.float32)
+    w = jnp.asarray(rng.standard_normal((h * d, 4)).astype(np.float32))
+    run = lambda a: np.asarray(conv_operand.conv_operand(jnp.asarray(a), w, 1.0, h, 16))  # noqa: E731
+    base = run(x)
+    for at in (6, 15, 31):  # inside a tile, a tile's last position, the sequence's last
+        moved_x = x.copy()
+        moved_x[0, at, d: 2 * d] += 1.0  # sequence 0, head 1
+        moved = run(moved_x)
+        assert np.array_equal(moved[1, :at], base[1, :at])
+        assert not np.array_equal(moved[1, at: at + 4], base[1, at: at + 4])
+        assert np.array_equal(moved[1, at + 4:], base[1, at + 4:])  # four taps reach three back
+        for row in (0, 2, 3, 4, 5):  # its sequence's other heads; the other sequence
+            assert np.array_equal(moved[row], base[row]), (at, row)
+    # and the gradient of a row's loss reaches its own channels of its own sequence alone
+    dx = np.asarray(jax.grad(lambda a: jnp.sum(conv_operand.conv_operand(a, w, 1.0, h, 16)[4]))(
+        jnp.asarray(x)))
+    assert np.any(dx[1, :, d: 2 * d]) and not np.any(dx[0]) and not np.any(dx[1, :, :d])
+    assert not np.any(dx[1, :, 2 * d:])
+
+
+def test_the_operand_pass_takes_whole_tiles():
+    assert conv_operand.tile_of(8192) == 1024 and conv_operand.tile_of(48) == 48
+    assert conv_operand.tile_of(48, 16) == 16 and conv_operand.tile_of(1040) == 208
+    with pytest.raises(ValueError, match="a sequence of 40 is no multiple"):
+        conv_operand.tile_of(40)
+    with pytest.raises(ValueError, match="a convolution of 18 taps reads further back"):
+        conv_operand.conv_operand(jnp.zeros((1, 32, 8)), jnp.zeros((8, 18)), None, 1)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("scale", [1.0, None])
+def test_the_operand_kernels_compile_for_the_chip(one_chip, scale):
+    """Mosaic takes both kernels at the published head width and the
+    default tile (a compile for a described v5e: nothing runs)."""
+    b, s, h, d = 1, 2 * conv_operand.TILE, 2, 128
+    x = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((h * d, 4), jnp.float32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((b * h, s, d), jnp.bfloat16, sharding=one_chip)
+
+    def step(x, w, g):
+        out, pull = jax.vjp(lambda x, w: conv_operand.conv_operand(x, w, scale, h), x, w)
+        return out, pull(g)
+
+    text = jax.jit(step).lower(x, w, g).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "kda_conv_operand" in text and "kda_conv_operand_backward" in text
 
 
 # ---- the mixer against the reference's, one layer
