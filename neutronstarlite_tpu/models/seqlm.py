@@ -1,8 +1,11 @@
 """The token-sequence trainer family (ALGORITHM:SEQLM): next-token training
-of a stack of layers, each a token mixer of one of two kinds (latent
-attention, with or without rotary positions; a gated delta-rule linear
-attention) followed by an MLP of one of two kinds (dense; routed + shared
-experts), over an integer datum and an implicit causal graph.
+of a stack of layers, each a token mixer of one of three kinds (latent
+attention, with or without rotary positions; grouped-query softmax
+attention with an output gate; a gated delta-rule linear attention, its
+decay per key channel or per head) followed by an MLP of one of two kinds
+(dense; routed + shared experts, the router a sigmoid or a softmax, the
+shared expert gated or not), over an integer datum and an implicit causal
+graph.
 
 Beside ``fullbatch``, ``dist`` and ``sampled`` this is the fourth run loop
 on ``ToolkitBase``. What differs from them is the datum and the graph: the
@@ -18,21 +21,26 @@ funnel (``init_graph`` / ``init_nn`` / ``_finalize_datum`` /
 batches cycle through a corpus of SEQ_CORPUS resident on the device.
 
 The model is described by the source's own ``config.json`` keys in the JSON
-file MODEL_FILE names, in either of two dialects (``SeqSpec.from_cfg``, the
+file MODEL_FILE names, in one of three dialects (``SeqSpec.from_cfg``, the
 one place that tells them apart): a DeepSeek-V3 file is the stack "latent
 attention with rotary in every layer"; a ``kimi_linear`` file names the
 mixer of every layer (``linear_attn_config.kda_layers`` /
-``full_attn_layers``) and its latent attention rotates nothing. The cut to
-one chip's share is in cfg keys:
-SEQ_LAYERS (layers kept, the leading dense one first), EXPERT_SHARDS /
+``full_attn_layers``) and its latent attention rotates nothing; a
+``qwen3_next`` file has every ``full_attention_interval``-th layer attend
+(grouped queries, an output gate, rotary over a leading part of a head),
+the others run the delta rule with a decay per head and twice the value
+heads, every layer routes (softmax over the experts, a gated shared
+expert, no leading dense layer) and every norm but the delta rule's output
+norm is zero-centred. The cut to one chip's share is in cfg keys:
+SEQ_LAYERS (layers kept, a leading dense one first), EXPERT_SHARDS /
 EXPERT_SHARD (this chip holds ``n_routed_experts / EXPERT_SHARDS`` experts
 of every layer, routes over all of them and computes its own experts' part;
 what absent experts would add is left out and nothing stands in for their
 chips), VOCAB_SHARDS (ids, logits and loss over this chip's slice),
 SEQ_LENGTH, SEQ_BATCH.
 
-The step (one jitted program): embed; the dense layer; the expert layers
-in runs of one mixer kind, each run stacked on a leading axis under its own
+The step (one jitted program): embed; the dense layer where the stack
+leads with one; the expert layers in runs of one mixer kind, each run stacked on a leading axis under its own
 key (``moe``, then ``moe1``, ``moe2``, ...) and walked by one ``lax.scan``
 (a stack of one kind is the single run ``moe``); every layer recomputed in
 the backward (``jax.checkpoint``); the head and the loss in chunks of
@@ -78,7 +86,8 @@ from neutronstarlite_tpu.utils.timing import get_time
 log = get_logger("seqlm")
 
 SCOPES = (
-    "seq/embed", "seq/mla/project", "seq/mla/attend", "seq/kda/project", "seq/kda/conv",
+    "seq/embed", "seq/mla/project", "seq/mla/attend", "seq/gqa/project", "seq/gqa/attend",
+    "seq/kda/project", "seq/kda/conv",
     "seq/kda/gate", "seq/kda/recur", "seq/kda/out", "seq/dense_mlp",
     "seq/moe/route", "seq/moe/dispatch", "seq/moe/experts", "seq/moe/shared",
     "seq/moe/combine", "seq/head_loss", "seq/adam",
@@ -87,16 +96,25 @@ DEFAULT_LOSS_CHUNK = 4096
 INIT_STD = 0.02  # assumed: config.json gives no initializer_range
 
 
-# the family's name of a count -> its key in (a DeepSeek-V3 file, a kimi_linear file)
+# the family's name of a count -> its key in (a DeepSeek-V3 file, a kimi_linear file, a
+# qwen3_next file)
 DIALECT_KEYS = {
-    "routed": ("n_routed_experts", "num_experts"),
-    "per_token": ("num_experts_per_tok", "num_experts_per_token"),
-    "shared": ("n_shared_experts", "num_shared_experts"),
-    "scoring": ("scoring_func", "moe_router_activation_func"),
-    "groups": ("n_group", "num_expert_group"),
-    "renormalised": ("norm_topk_prob", "moe_renormalize"),
-    "positions": ("max_position_embeddings", "model_max_length"),
+    "routed": ("n_routed_experts", "num_experts", "num_experts"),
+    "per_token": ("num_experts_per_tok", "num_experts_per_token", "num_experts_per_tok"),
+    "renormalised": ("norm_topk_prob", "moe_renormalize", "norm_topk_prob"),
+    "positions": ("max_position_embeddings", "model_max_length", "max_position_embeddings"),
 }
+# what the family is written for, by dialect: (key, the value computed); a file that says
+# otherwise is refused by the key's name
+LATENT_ONLY = (("q_lora_rank", None), ("topk_group", 1), ("first_k_dense_replace", 1),
+               ("moe_layer_freq", 1))
+WRITTEN_FOR = (
+    LATENT_ONLY + (("n_group", 1), ("scoring_func", "sigmoid")),
+    LATENT_ONLY + (("num_expert_group", 1), ("moe_router_activation_func", "sigmoid"),
+                   ("mla_use_nope", True)),
+    (("mlp_only_layers", []), ("decoder_sparse_step", 1), ("rope_scaling", None),
+     ("use_sliding_window", False)),
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,11 +124,11 @@ class SeqSpec:
 
     hidden: int
     heads: int
-    kv_rank: int
-    nope: int
-    rope: int
+    kv_rank: int  # the latent attention's; 0 where the stack attends by grouped queries
+    nope: int  # dims of a query head that no position turns
+    rope: int  # dims that rotary turns: the latent attention's shared ones, a "gqa" head's leading ones
     v_head: int
-    ffn: int
+    ffn: int  # the dense layer's width
     expert_width: int
     shared_width: int
     routed: int
@@ -126,12 +144,22 @@ class SeqSpec:
     batch: int
     block: int
     loss_chunk: int
-    mixers: tuple = ()  # the mixer of every kept layer: "mla" or "kda"
+    mixers: tuple = ()  # the mixer of every kept layer: "mla", "gqa" or "kda"
     rotary: bool = True  # whether the latent attention turns its shared dims by position
-    kda_heads: int = 0
+    # the delta-rule mixer's sizes; the ``kda_`` names also size the per-head-gated one
+    kda_heads: int = 0  # its key (and query) heads
     kda_dim: int = 0  # key = value dims of a delta-rule head
     conv_kernel: int = 0
     kda_chunk: int = 0  # positions a chunk of the delta rule
+    kda_value_heads: int = 0  # its value heads: a key head serves value_heads / heads of them
+    decay_per_head: bool = False  # one log-decay a value head and position, not one a key channel
+    gates_low_rank: bool = True  # decay and output gate from rank-``kda_dim`` pairs, else full products
+    out_gate: str = "sigmoid"  # the delta-rule output gate's activation: "sigmoid" or "silu"
+    dense_layers: int = 1  # leading layers with a dense MLP: 1 or 0
+    kv_heads: int = 0  # key/value heads of a "gqa" layer
+    centred_norms: bool = False  # norm weights as ``1 + w`` (the delta rule's output norm excepted)
+    scoring: str = "sigmoid"  # the router's activation: "sigmoid" or "softmax"
+    shared_gate: bool = False  # the shared expert behind its own sigmoid gate
 
     @property
     def tokens(self) -> int:
@@ -146,7 +174,7 @@ class SeqSpec:
         """The expert layers in runs of one mixer kind: (key of the run in
         the parameter tree, kind, first expert layer, layers)."""
         out = []
-        for i, kind in enumerate(self.mixers[1:]):
+        for i, kind in enumerate(self.mixers[self.dense_layers:]):
             if out and out[-1][1] == kind:
                 out[-1][3] += 1
             else:
@@ -156,22 +184,22 @@ class SeqSpec:
     @staticmethod
     def from_cfg(model: dict, cfg: InputInfo) -> "SeqSpec":
         linear = model.get("linear_attn_config")
-        key = {name: keys[1 if linear else 0] for name, keys in DIALECT_KEYS.items()}
-        for name, want in (("q_lora_rank", None), (key["groups"], 1), ("topk_group", 1),
-                           (key["scoring"], "sigmoid"), (key["renormalised"], True),
-                           ("first_k_dense_replace", 1), ("moe_layer_freq", 1),
-                           ("hidden_act", "silu")) + ((("mla_use_nope", True),) if linear else ()):
+        gated = model.get("model_type") == "qwen3_next" or "full_attention_interval" in model
+        dialect = 2 if gated else 1 if linear else 0
+        key = {name: keys[dialect] for name, keys in DIALECT_KEYS.items()}
+        for name, want in WRITTEN_FOR[dialect] + ((key["renormalised"], True), ("hidden_act", "silu")):
             if model.get(name, want) != want:
                 raise ValueError(
                     f"MODEL_FILE has {name}={model.get(name)!r}; the SEQLM family is written "
                     f"for {name}={want!r} (models/seqlm.py states the block it computes)"
                 )
+        dense = 0 if gated else 1
         published = int(model["num_hidden_layers"])
         layers = cfg.seq_layers or published
-        if not 2 <= layers <= published:
+        if not dense + 1 <= layers <= published:
             raise ValueError(
-                f"SEQ_LAYERS:{layers} must keep the dense layer and at least one expert "
-                f"layer of the model's {model['num_hidden_layers']}"
+                f"SEQ_LAYERS:{layers} must keep " + ("the dense layer and " if dense else "")
+                + f"at least one expert layer of the model's {model['num_hidden_layers']}"
             )
         mixers = ("mla",) * layers
         if linear:
@@ -182,6 +210,9 @@ class SeqSpec:
                     f"{sorted(kda + full)}: not each of num_hidden_layers={published} layers once"
                 )
             mixers = tuple("kda" if i + 1 in kda else "mla" for i in range(layers))
+        elif gated:
+            every = int(model["full_attention_interval"])
+            mixers = tuple("gqa" if (i + 1) % every == 0 else "kda" for i in range(layers))
         routed, vocab = int(model[key["routed"]]), int(model["vocab_size"])
         if routed % cfg.expert_shards or not 0 <= cfg.expert_shard < cfg.expert_shards:
             raise ValueError(
@@ -203,23 +234,51 @@ class SeqSpec:
             raise ValueError(f"KDA_CHUNK:{chunk} does not divide the sequence length {length}")
         held = routed // cfg.expert_shards
         tokens = cfg.seq_batch * length
+        if gated:
+            head = int(model["head_dim"])
+            turned = int(round(head * float(model["partial_rotary_factor"])))
+            heads, kv_heads = int(model["num_attention_heads"]), int(model["num_key_value_heads"])
+            key_dim, value_dim = int(model["linear_key_head_dim"]), int(model["linear_value_head_dim"])
+            key_heads, value_heads = (int(model["linear_num_key_heads"]),
+                                      int(model["linear_num_value_heads"]))
+            if heads % kv_heads or value_heads % key_heads or key_dim != value_dim or turned % 2:
+                raise ValueError(
+                    f"MODEL_FILE's heads do not share evenly ({heads} query heads over {kv_heads} "
+                    f"key/value heads, {value_heads} delta-rule value heads over {key_heads} key "
+                    f"heads), its delta-rule key and value dims differ ({key_dim}, {value_dim}) or "
+                    f"rotary turns an odd number of dims ({turned})")
+            sizes = dict(
+                kv_rank=0, nope=head - turned, rope=turned, v_head=head,
+                ffn=int(model["intermediate_size"]),
+                shared_width=int(model["shared_expert_intermediate_size"]), route_scale=1.0,
+                kda_heads=key_heads, kda_value_heads=value_heads, kda_dim=key_dim,
+                conv_kernel=int(model["linear_conv_kernel_dim"]), decay_per_head=True,
+                gates_low_rank=False, out_gate="silu", kv_heads=kv_heads, centred_norms=True,
+                scoring="softmax", shared_gate=True,
+            )
+        else:
+            sizes = dict(
+                kv_rank=int(model["kv_lora_rank"]), nope=int(model["qk_nope_head_dim"]),
+                rope=int(model["qk_rope_head_dim"]), v_head=int(model["v_head_dim"]),
+                ffn=int(model["intermediate_size"]),
+                shared_width=(int(model["num_shared_experts" if linear else "n_shared_experts"])
+                              * int(model["moe_intermediate_size"])),
+                route_scale=float(model["routed_scaling_factor"]), rotary=not linear,
+                kda_heads=int(linear["num_heads"]) if linear else 0,
+                kda_value_heads=int(linear["num_heads"]) if linear else 0,
+                kda_dim=int(linear["head_dim"]) if linear else 0,
+                conv_kernel=int(linear["short_conv_kernel_size"]) if linear else 0,
+            )
         return SeqSpec(
             hidden=int(model["hidden_size"]), heads=int(model["num_attention_heads"]),
-            kv_rank=int(model["kv_lora_rank"]), nope=int(model["qk_nope_head_dim"]),
-            rope=int(model["qk_rope_head_dim"]), v_head=int(model["v_head_dim"]),
-            ffn=int(model["intermediate_size"]), expert_width=int(model["moe_intermediate_size"]),
-            shared_width=int(model[key["shared"]]) * int(model["moe_intermediate_size"]),
+            expert_width=int(model["moe_intermediate_size"]),
             routed=routed, per_token=int(model[key["per_token"]]),
-            route_scale=float(model["routed_scaling_factor"]), theta=float(model["rope_theta"]),
-            eps=float(model["rms_norm_eps"]), moe_layers=layers - 1,
+            theta=float(model["rope_theta"]), eps=float(model["rms_norm_eps"]),
+            moe_layers=layers - dense, dense_layers=dense,
             first=cfg.expert_shard * held, held=held, vocab=vocab // cfg.vocab_shards,
             length=length, batch=cfg.seq_batch, block=cfg.attn_block,
             loss_chunk=math.gcd(tokens, cfg.loss_chunk or DEFAULT_LOSS_CHUNK),
-            mixers=mixers, rotary=not linear,
-            kda_heads=int(linear["num_heads"]) if linear else 0,
-            kda_dim=int(linear["head_dim"]) if linear else 0,
-            conv_kernel=int(linear["short_conv_kernel_size"]) if linear else 0,
-            kda_chunk=chunk,
+            mixers=mixers, kda_chunk=chunk, **sizes,
         )
 
 
@@ -231,14 +290,18 @@ def _keys(key: jax.Array):
 
 
 def init_params(key: jax.Array, spec: SeqSpec) -> Dict[str, Any]:
-    """Seeded weights, normal with std ``INIT_STD``, norms at one; the
-    expert layers in runs of one mixer kind, each run stacked on a leading
-    axis (its scan's)."""
+    """Seeded weights, normal with std ``INIT_STD``, norms at one (at zero
+    where the spec's norms are zero-centred, the delta rule's output norm
+    at one either way); the expert layers in runs of one mixer kind, each
+    run stacked on a leading axis (its scan's)."""
     d, h = spec.hidden, spec.heads
     keys = _keys(key)
 
     def normal(*shape):
         return nnseq.normal_init(next(keys), shape, INIT_STD)
+
+    def norm(*shape):
+        return jnp.full(shape, 0.0 if spec.centred_norms else 1.0, jnp.float32)
 
     def mla(lead):
         return {
@@ -251,41 +314,59 @@ def init_params(key: jax.Array, spec: SeqSpec) -> Dict[str, Any]:
             "norm2": jnp.ones(lead + (d,), jnp.float32),
         }
 
+    def gqa(lead):
+        head = spec.v_head
+        return {
+            "norm1": norm(*lead, d),
+            "wq": normal(*lead, d, h * 2 * head),  # per head: the query, then its output gate
+            "wk": normal(*lead, d, spec.kv_heads * head),
+            "wv": normal(*lead, d, spec.kv_heads * head),
+            "q_norm": norm(*lead, head), "k_norm": norm(*lead, head),
+            "wo": normal(*lead, h * head, d),
+            "norm2": norm(*lead, d),
+        }
+
     def kda(lead):
-        kh, kd, taps = spec.kda_heads, spec.kda_dim, spec.conv_kernel
-        wide = kh * kd
+        kh, vh, kd, taps = spec.kda_heads, spec.kda_value_heads, spec.kda_dim, spec.conv_kernel
+        wide, v_wide = kh * kd, vh * kd
 
         def uniform(lo, hi, *shape):
             return jax.random.uniform(next(keys), lead + shape, jnp.float32, lo, hi)
 
+        def gate(name, out):  # a rank-``kd`` pair, or one full product
+            if spec.gates_low_rank:
+                return {name + "_a": normal(*lead, d, kd), name + "_b": normal(*lead, kd, out)}
+            return {name: normal(*lead, d, out)}
+
         # assumed (config.json gives none of them): the convolutions as
         # torch's Conv1d starts them; exp(A_log) uniform over 1..16; a step
         # softplus(dt_bias) log-uniform over 0.001..0.1
-        step = jnp.exp(uniform(math.log(1e-3), math.log(1e-1), wide))
+        decays = vh if spec.decay_per_head else wide
+        step = jnp.exp(uniform(math.log(1e-3), math.log(1e-1), decays))
         return {
-            "norm1": jnp.ones(lead + (d,), jnp.float32),
+            "norm1": norm(*lead, d),
             "wq": normal(*lead, d, wide), "wk": normal(*lead, d, wide),
-            "wv": normal(*lead, d, wide),
-            **{c: uniform(-taps ** -0.5, taps ** -0.5, wide, taps) for c in ("cq", "ck", "cv")},
-            "wf_a": normal(*lead, d, kd), "wf_b": normal(*lead, kd, wide),
-            "a_log": jnp.log(uniform(1.0, 16.0, kh)),
+            "wv": normal(*lead, d, v_wide),
+            **{c: uniform(-taps ** -0.5, taps ** -0.5, width, taps)
+               for c, width in (("cq", wide), ("ck", wide), ("cv", v_wide))},
+            **gate("wf", decays),
+            "a_log": jnp.log(uniform(1.0, 16.0, vh)),
             "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-            "wb": normal(*lead, d, kh),
-            "wz_a": normal(*lead, d, kd), "wz_b": normal(*lead, kd, wide),
+            "wb": normal(*lead, d, vh),
+            **gate("wz", v_wide),
             "o_norm": jnp.ones(lead + (kd,), jnp.float32),
-            "wo": normal(*lead, wide, d),
-            "norm2": jnp.ones(lead + (d,), jnp.float32),
+            "wo": normal(*lead, v_wide, d),
+            "norm2": norm(*lead, d),
         }
 
     def glu(lead, width, names):
         return {name: normal(*lead, *shape)
                 for name, shape in zip(names, ((d, width), (d, width), (width, d)))}
 
-    mixer = {"mla": mla, "kda": kda}
-    params = {
-        "embed": normal(spec.vocab, d),
-        "dense": {**mixer[spec.mixers[0]](()), **glu((), spec.ffn, ("wg", "wu", "wd"))},
-    }
+    mixer = {"mla": mla, "gqa": gqa, "kda": kda}
+    params = {"embed": normal(spec.vocab, d)}
+    if spec.dense_layers:
+        params["dense"] = {**mixer[spec.mixers[0]](()), **glu((), spec.ffn, ("wg", "wu", "wd"))}
     for name, kind, _, count in spec.runs:
         n = (count,)
         params[name] = {
@@ -293,13 +374,19 @@ def init_params(key: jax.Array, spec: SeqSpec) -> Dict[str, Any]:
             "router": normal(*n, d, spec.routed),
             **glu(n + (spec.held,), spec.expert_width, ("eg", "eu", "ed")),
             **glu(n, spec.shared_width, ("sg", "su", "sd")),
+            **({"sgate": normal(*n, d, 1)} if spec.shared_gate else {}),
         }
-    params["norm"] = jnp.ones((d,), jnp.float32)
+    params["norm"] = norm(d)
     params["head"] = normal(d, spec.vocab)
     return params
 
 
 # ---- the forward pass
+
+def _norm(spec: SeqSpec):
+    """The stack's RMS norm, looked up when the step is traced."""
+    return nnseq.centred_rms_norm if spec.centred_norms else nnseq.rms_norm
+
 
 def attention(lp, x, spec: SeqSpec, cast, mid):
     """``x [T, hidden]`` (``batch`` sequences of ``length``) plus its latent
@@ -334,26 +421,75 @@ def attention(lp, x, spec: SeqSpec, cast, mid):
         return x + nnseq.matmul(out, lp["wo"], cast)
 
 
+def gated_attention(lp, x, spec: SeqSpec, cast, mid):
+    """``x [T, hidden]`` plus its grouped-query softmax attention with an
+    output gate: ``heads`` query heads of ``v_head`` dims share ``kv_heads``
+    key/value heads (query head ``m`` attends head ``m // (heads /
+    kv_heads)``); queries and keys through a norm per head (one weight of
+    ``v_head`` for all heads) and rotary over their leading ``rope`` dims;
+    the attention's output times ``sigmoid(gate)``, the gate being the
+    second half of each head's query projection."""
+    b, s, h, kv, head = spec.batch, spec.length, spec.heads, spec.kv_heads, spec.v_head
+    pos = jnp.arange(s, dtype=jnp.int32)
+    norm = _norm(spec)
+
+    def by_head(t, weight):  # [T, heads * head] -> normed, turned, [B * heads, S, head]
+        t = jnp.swapaxes(norm(t.reshape(b, s, -1, head), weight, spec.eps), 1, 2)
+        return nnseq.rotary_leading(t, pos, spec.theta, spec.rope).astype(mid).reshape(-1, s, head)
+
+    with jax.named_scope("seq/gqa/project"):
+        hn = norm(x, lp["norm1"], spec.eps)
+        qg = nnseq.matmul(hn, lp["wq"], cast).reshape(b * s, h, 2 * head)
+        gate = qg[..., head:].reshape(b * s, h * head)
+        q = by_head(qg[..., :head], lp["q_norm"])
+        k = by_head(nnseq.matmul(hn, lp["wk"], cast), lp["k_norm"])
+        v = jnp.swapaxes(nnseq.matmul(hn, lp["wv"], cast, mid).reshape(b, s, kv, head), 1, 2)
+    with jax.named_scope("seq/gqa/attend"):
+        out = causal_edge_attention(q, k, v.reshape(b * kv, s, head), 1.0 / math.sqrt(head),
+                                    spec.block, h // kv)
+    with jax.named_scope("seq/gqa/project"):
+        out = jnp.swapaxes(out.reshape(b, h, s, head), 1, 2).reshape(b * s, -1)
+        return x + nnseq.matmul(nnseq.sigmoid_gate(out, gate), lp["wo"], cast)
+
+
+OUT_GATES = {"sigmoid": {}, "silu": {"activation": jax.nn.silu}}  # gated_rms_norm's default is sigmoid
+
+
 def delta_attention(lp, x, spec: SeqSpec, cast, mid):
-    """``x [T, hidden]`` plus its gated delta-rule linear attention (KDA):
-    queries, keys and values through a short causal convolution and SiLU,
-    queries and keys of unit length per head, a log-decay per head and key
-    channel and a write strength per head from the same normed stream, the
-    recurrence of ops/delta_rule.py, a sigmoid-gated RMS norm per head.
+    """``x [T, hidden]`` plus its gated delta-rule linear attention, one
+    function for both gates (KDA; the per-head-gated delta rule): queries,
+    keys and values through a short causal convolution and SiLU, queries
+    and keys of unit length per head, a log-decay (per value head and key
+    channel, or per value head: ``spec.decay_per_head``) and a write
+    strength per value head from the same normed stream, the recurrence of
+    ops/delta_rule.py (``kda_value_heads / kda_heads`` value heads share a
+    key head's q and k), an RMS norm per head gated through a sigmoid or
+    SiLU (``spec.out_gate``). The decay and the output gate come from
+    rank-``kda_dim`` pairs or from one full product each
+    (``spec.gates_low_rank``).
 
     Each operand's path from the normed stream (the product, then
     ops/conv_operand.py's one pass: convolution, SiLU, norm and the change
-    of layout, float32 in VMEM and nowhere else; the decay's pair, softplus
-    and cumulative sum) and the output's path are recomputed in the
-    backward, each under its own ``jax.checkpoint`` inside the layer's:
-    what a KDA layer's backward holds at once is the operands and one
-    product in the compute dtype and the gates' and the output's float32
-    intermediates (``[tokens, 4096]`` float32 is 0.5 GB at 32,768 tokens),
-    one path's at a time."""
-    b, s, h, d = spec.batch, spec.length, spec.kda_heads, spec.kda_dim
+    of layout, float32 in VMEM and nowhere else; the decay's products,
+    softplus and cumulative sum) and the output's path are recomputed in
+    the backward, each under its own ``jax.checkpoint`` inside the layer's:
+    what a delta-rule layer's backward holds at once is the operands and
+    one product in the compute dtype and the gates' and the output's
+    float32 intermediates (``[tokens, 4096]`` float32 is 0.5 GB at 32,768
+    tokens), one path's at a time."""
+    b, s, d = spec.batch, spec.length, spec.kda_dim
+    norm = _norm(spec)
 
     def by_head(t):  # [B, S, H, ...] -> [B * H, S, ...]
-        return jnp.swapaxes(t, 1, 2).reshape(b * h, s, *t.shape[3:])
+        return jnp.swapaxes(t, 1, 2).reshape(b * t.shape[2], s, *t.shape[3:])
+
+    def chain(hn, weights):  # hn W, or (hn Wa) Wb: the last product held in float32
+        for w in weights[:-1]:
+            hn = nnseq.matmul(hn, w, cast, mid)
+        return nnseq.matmul(hn, weights[-1], cast)
+
+    def gate_weights(name):
+        return (lp[name + "_a"], lp[name + "_b"]) if spec.gates_low_rank else (lp[name],)
 
     @functools.partial(jax.checkpoint, static_argnums=3)
     def operand(hn, w, taps, scale):
@@ -362,40 +498,43 @@ def delta_attention(lp, x, spec: SeqSpec, cast, mid):
         with jax.named_scope("seq/kda/project"):
             t = nnseq.matmul(hn, w, cast, mid)
         with jax.named_scope("seq/kda/conv"):
-            return conv_operand(t.reshape(b, s, h * d), taps, scale, h)
+            return conv_operand(t.reshape(b, s, -1), taps, scale, t.shape[-1] // d)
 
     @jax.checkpoint
-    def gates(hn, wf_a, wf_b, a_log, dt_bias, wb):
+    def gates(hn, wf, a_log, dt_bias, wb):
         with jax.named_scope("seq/kda/project"):
-            decay = nnseq.matmul(nnseq.matmul(hn, wf_a, cast, mid), wf_b, cast)
+            decay = chain(hn, wf)
             write = nnseq.matmul(hn, wb, cast)
         with jax.named_scope("seq/kda/gate"):
-            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus((decay + dt_bias).reshape(b, s, h, d))
+            # [B, S, value heads, key channels], or [B, S, value heads, 1]: a decay per head
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                (decay + dt_bias).reshape(b, s, spec.kda_value_heads, -1))
             return (delta_rule.chunk_log_decay(by_head(g), spec.kda_chunk),
-                    by_head(jax.nn.sigmoid(write).reshape(b, s, h)))
+                    by_head(jax.nn.sigmoid(write).reshape(b, s, spec.kda_value_heads)))
 
     @jax.checkpoint
-    def output(x, hn, out, wz_a, wz_b, o_norm, wo):
+    def output(x, hn, out, wz, o_norm, wo):
         with jax.named_scope("seq/kda/project"):
-            gate = nnseq.matmul(nnseq.matmul(hn, wz_a, cast, mid), wz_b, cast)
+            gate = chain(hn, wz)
         with jax.named_scope("seq/kda/out"):
-            out = jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)
-            out = nnseq.gated_rms_norm(out, o_norm, gate.reshape(b, s, h, d), spec.eps)
+            out = jnp.swapaxes(out.reshape(b, -1, s, d), 1, 2)
+            out = nnseq.gated_rms_norm(out, o_norm, gate.reshape(out.shape), spec.eps,
+                                       **OUT_GATES[spec.out_gate])
         with jax.named_scope("seq/kda/project"):
-            return x + nnseq.matmul(out.reshape(b * s, h * d), wo, cast)
+            return x + nnseq.matmul(out.reshape(b * s, -1), wo, cast)
 
     with jax.named_scope("seq/kda/project"):
-        hn = nnseq.rms_norm(x, lp["norm1"], spec.eps)
+        hn = norm(x, lp["norm1"], spec.eps)
     q = operand(hn, lp["wq"], lp["cq"], d ** -0.5)
     k = operand(hn, lp["wk"], lp["ck"], 1.0)
     v = operand(hn, lp["wv"], lp["cv"], None)
-    log_decay, beta = gates(hn, lp["wf_a"], lp["wf_b"], lp["a_log"], lp["dt_bias"], lp["wb"])
+    log_decay, beta = gates(hn, gate_weights("wf"), lp["a_log"], lp["dt_bias"], lp["wb"])
     with jax.named_scope("seq/kda/recur"):
         out = delta_rule.chunked_delta_rule(q, k, v, log_decay, beta, cast)
-    return output(x, hn, out, lp["wz_a"], lp["wz_b"], lp["o_norm"], lp["wo"])
+    return output(x, hn, out, gate_weights("wz"), lp["o_norm"], lp["wo"])
 
 
-MIXERS = {"mla": attention, "kda": delta_attention}
+MIXERS = {"mla": attention, "gqa": gated_attention, "kda": delta_attention}
 
 
 def dense_layer(lp, x, spec: SeqSpec, cast, mid):
@@ -405,13 +544,20 @@ def dense_layer(lp, x, spec: SeqSpec, cast, mid):
         return x + nnseq.swiglu(hn, lp["wg"], lp["wu"], lp["wd"], cast)
 
 
+ROUTER_SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 def expert_mlp(lp, bias, x, spec: SeqSpec, cast):
     """(``x`` plus this chip's part of the expert layer, rows per held
-    expert [held], the choice of experts [T, k])."""
+    expert [held], the choice of experts [T, k]). The router's scores are a
+    sigmoid or a softmax over all the layer's experts (``spec.scoring``),
+    the chosen ones' renormalised either way (``moe.route``); the shared
+    expert is added as it is or behind its own sigmoid gate
+    (``spec.shared_gate``)."""
     with jax.named_scope("seq/moe/route"):
-        hn = nnseq.rms_norm(x, lp["norm2"], spec.eps)
+        hn = _norm(spec)(x, lp["norm2"], spec.eps)
         # float32 at the highest precision, as the published code keeps it
-        scores = jax.nn.sigmoid(nnseq.matmul(hn, lp["router"], lambda t: t))
+        scores = ROUTER_SCORES[spec.scoring](nnseq.matmul(hn, lp["router"], lambda t: t))
         choice, weight = moe.route(scores, bias, spec.per_token, spec.route_scale)
     with jax.named_scope("seq/moe/dispatch"):
         plan = moe.plan_dispatch(choice, spec.first, spec.held)
@@ -421,7 +567,11 @@ def expert_mlp(lp, bias, x, spec: SeqSpec, cast):
     with jax.named_scope("seq/moe/combine"):
         routed = moe.combine_rows(out, weight, plan)
     with jax.named_scope("seq/moe/shared"):
-        out = x + routed + nnseq.swiglu(hn, lp["sg"], lp["su"], lp["sd"], cast)
+        out = x + routed
+        shared = nnseq.swiglu(hn, lp["sg"], lp["su"], lp["sd"], cast)
+        if spec.shared_gate:
+            shared = jax.nn.sigmoid(nnseq.matmul(hn, lp["sgate"], cast)) * shared
+        out = out + shared
     return out, plan.group_sizes, choice
 
 
@@ -430,7 +580,8 @@ def hidden_states(params, bias, tokens, spec: SeqSpec, cast, mid):
     expert [L, held], the choices [L, T, k]) of ``tokens`` [batch, length]."""
     with jax.named_scope("seq/embed"):
         x = params["embed"][tokens.reshape(-1)]
-    x = jax.checkpoint(lambda lp, x: dense_layer(lp, x, spec, cast, mid))(params["dense"], x)
+    if spec.dense_layers:
+        x = jax.checkpoint(lambda lp, x: dense_layer(lp, x, spec, cast, mid))(params["dense"], x)
     sizes, choices = [], []
     for name, kind, start, count in spec.runs:
         mixer = MIXERS[kind]
@@ -462,7 +613,7 @@ def head_loss(params, x, tokens, spec: SeqSpec, cast):
         weight = jnp.broadcast_to(
             (jnp.arange(spec.length) < spec.length - 1).astype(jnp.float32), tokens.shape
         ).reshape(-1)
-        hn = nnseq.rms_norm(x, params["norm"], spec.eps)
+        hn = _norm(spec)(x, params["norm"], spec.eps)
         n = spec.tokens // spec.loss_chunk
 
         @jax.checkpoint
@@ -594,14 +745,17 @@ class SeqLMTrainer(ToolkitBase):
             self._scope_table: Optional[Dict[str, str]] = None
         self.routed_history: list = []  # pairs sent to held experts, per epoch
         n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(self.params))
-        self.metrics.gauge_set("seq.kda_layers", spec.kda_layers)
-        self.metrics.gauge_set("seq.mla_layers", len(spec.mixers) - spec.kda_layers)
+        for kind in MIXERS:
+            self.metrics.gauge_set(f"seq.{kind}_layers", spec.mixers.count(kind))
         self.metrics.gauge_set("kda.chunk", spec.kda_chunk)
+        self.metrics.gauge_set("kda.key_heads", spec.kda_heads)
+        self.metrics.gauge_set("kda.value_heads", spec.kda_value_heads)
+        self.metrics.gauge_set("kda.decay_per_head", int(spec.decay_per_head))
         log.info(
-            "SEQLM: %d layers (1 dense + %d expert; mixers %s), experts %d..%d of %d held, "
+            "SEQLM: %d layers (%d dense + %d expert; mixers %s), experts %d..%d of %d held, "
             "vocabulary slice %d, %d parameters; a step is %d sequences of %d tokens; corpus of "
             "%d batches",
-            spec.moe_layers + 1, spec.moe_layers, "-".join(spec.mixers), spec.first,
+            len(spec.mixers), spec.dense_layers, spec.moe_layers, "-".join(spec.mixers), spec.first,
             spec.first + spec.held - 1, spec.routed, spec.vocab, n_params, spec.batch,
             spec.length, self.n_batches,
         )
@@ -638,7 +792,7 @@ class SeqLMTrainer(ToolkitBase):
         ``rows`` of ``tokens`` [batch, length], the choices [L, T, k])."""
         cast, mid = self._casts()
         x, _, choice = hidden_states(params, bias, tokens, self.spec, cast, mid)
-        hn = nnseq.rms_norm(x[rows], params["norm"], self.spec.eps)
+        hn = _norm(self.spec)(x[rows], params["norm"], self.spec.eps)
         return nnseq.matmul(hn, params["head"], cast), choice
 
     def step_args(self, index: int = 0):
@@ -714,5 +868,6 @@ class SeqLMTrainer(ToolkitBase):
         self.metrics.counter_add("seq.tokens", self.spec.tokens)
         self.metrics.counter_add("moe.rows_routed", rows)
         self.metrics.counter_add("kda.token_layers", self.spec.tokens * self.spec.kda_layers)
+        self.metrics.counter_add("gqa.token_layers", self.spec.tokens * self.spec.mixers.count("gqa"))
         mean = np.maximum(sizes.mean(axis=1), 1e-9)
         self.metrics.gauge_set("moe.load_max_over_mean", float((sizes.max(axis=1) / mean).max()))

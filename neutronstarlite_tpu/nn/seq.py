@@ -1,7 +1,8 @@
-"""NN pieces of the token-sequence family (models/seqlm.py): RMS norm,
-rotary positions, SwiGLU, normal initialisation and, for the delta-rule
-mixer, a depthwise causal convolution over positions, an L2 norm per head
-and a sigmoid-gated RMS norm.
+"""NN pieces of the token-sequence family (models/seqlm.py): RMS norm (its
+weight as it is, or zero-centred: ``1 + w``), rotary positions (over a
+whole head or its leading dims), SwiGLU, normal initialisation and, for
+the delta-rule mixer, a depthwise causal convolution over positions, an L2
+norm per head and a gated RMS norm (the gate through sigmoid or SiLU).
 
 ``causal_conv`` and ``l2_norm`` are the arithmetic of the mixer's q, k and
 v and take what they are given: a whole ``[B, S, C]`` array (a test, a
@@ -32,15 +33,28 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * weight
 
 
+def centred_rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """The zero-centred RMS norm: ``x_hat * (1 + weight)``, a weight of
+    zero being the plain normalisation (it starts there)."""
+    return rms_norm(x, 1.0 + weight, eps)
+
+
 def l2_norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
     """``x / |x|`` over the last axis, float32."""
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
 
 
-def gated_rms_norm(x: jax.Array, weight: jax.Array, gate: jax.Array, eps: float) -> jax.Array:
-    """``rms_norm(x) * sigmoid(gate)``, float32."""
-    return rms_norm(x, weight, eps) * jax.nn.sigmoid(gate.astype(jnp.float32))
+def gated_rms_norm(x: jax.Array, weight: jax.Array, gate: jax.Array, eps: float,
+                   activation=jax.nn.sigmoid) -> jax.Array:
+    """``rms_norm(x) * activation(gate)``, float32: the gate through a
+    sigmoid (KDA) or SiLU (the per-head-gated delta rule)."""
+    return rms_norm(x, weight, eps) * activation(gate.astype(jnp.float32))
+
+
+def sigmoid_gate(x: jax.Array, gate: jax.Array) -> jax.Array:
+    """``x * sigmoid(gate)``, float32: an attention's output gate."""
+    return x.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
 
 
 def causal_conv(x: jax.Array, weight: jax.Array) -> jax.Array:
@@ -69,6 +83,14 @@ def rotary(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     x = x.astype(jnp.float32)
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def rotary_leading(x: jax.Array, pos: jax.Array, theta: float, dims: int) -> jax.Array:
+    """``x [..., S, d]`` float32 with its leading ``dims`` dimensions turned
+    by ``rotary`` (``i`` pairs with ``i + dims/2``, frequencies ``theta^(-2i
+    / dims)``) and the rest as they are: a partial rotary factor."""
+    return jnp.concatenate([rotary(x[..., :dims], pos, theta),
+                            x[..., dims:].astype(jnp.float32)], axis=-1)
 
 
 def matmul(a: jax.Array, b: jax.Array, cast, out_dtype=jnp.float32) -> jax.Array:

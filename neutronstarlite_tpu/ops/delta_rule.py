@@ -32,6 +32,30 @@ raised up to ``f``; a pair inside one sub-block gets its own ``exp(G_i -
 G_j)`` (a ``[sub, sub, dk]`` value per sub-block, the only place where the
 work is not a matrix product).
 
+**A decay per head** (a log-decay whose last axis is 1: one scalar a row
+and position, the gated delta rule of a per-head gate) goes through the
+same chunk scan, state carry, inverse and checkpoints by a second, cheaper
+path to ``A`` and ``B``. The decay leaves the sum over channels,
+
+    A_ij = (k_i . k_j) exp(G_i - G_j)          B_rj = (q_r . k_j) exp(G_r - G_j)
+
+so the pairs are one product of the chunk's rows and a ``[C, C]`` mask of
+``exp(G_i - G_j)`` taken for ``i >= j`` only (an exponent ``<= 0``; never a
+quotient of two cumulative decays): no sub-blocks, no ``[sub, sub, dk]``
+value. **More value heads than key heads**: ``v``, the decay, beta and the
+state have ``r`` rows for each row of ``q`` and ``k`` (row ``n`` of the
+keys serves rows ``r n .. r n + r - 1`` of the values, each with its own
+decay, write strength and state). The keys' rows reach their value heads
+inside the scan, a chunk at a time: ``k k^T`` and ``q k^T`` are made once
+a key head and repeated under ``r`` decay masks, and the chunk's own ``[C,
+dk]`` rows of q and k are repeated where a product needs them beside a
+value head's state. That costs a copy of ``2 r C dk`` float32 a key head
+and chunk (128 KB at ``C`` 64, ``dk`` 128, ``r`` 2), which lives as long
+as its chunk; no ``[rows of v, S, dk]`` copy of q or k exists in HBM (at 2
+x 8,192 positions and 32 value heads that copy would be 134 MB each for q
+and k in bfloat16, written once forward and once more in the backward's
+recomputation, and their gradients summed over the pair again).
+
 The unit lower-triangular system is solved by its inverse, which for a
 strictly lower ``L`` (``L^C = 0``) is the finite product ``(I - L)(I +
 L^2)(I + L^4)...``: ``log2 C`` products in place of ``C`` sequential rows.
@@ -71,9 +95,9 @@ def _dot(spec: str, a, b):
 
 
 def chunk_log_decay(g: jax.Array, chunk: int) -> jax.Array:
-    """``g [N, S, dk]`` (log-decay a position) -> ``[N, S / chunk, chunk,
-    dk]`` float32: the log-decay from each chunk's start through each of
-    its rows."""
+    """``g [N, S, dk]`` (log-decay a position; ``[N, S, 1]``: one a head)
+    -> ``[N, S / chunk, chunk, dk]`` float32: the log-decay from each
+    chunk's start through each of its rows."""
     n, s, dk = g.shape
     return jnp.cumsum(g.astype(jnp.float32).reshape(n, s // chunk, chunk, dk), axis=2)
 
@@ -114,11 +138,33 @@ def _pair_decays(q, k, gc, sub: int, cast):
     return whole(k4, across[:, :, :sub], j < r), whole(q4, across[:, :, sub:], j <= r)
 
 
+def _head_pair_decays(q, k, gc, cast):
+    """``A`` and ``B`` as ``_pair_decays`` gives them, for a decay per head
+    ``gc [N, C, 1]`` and ``q, k [N / r, C, dk]``: one product a key head,
+    ``r`` masks of ``exp(G_i - G_j)``."""
+    n, c, _ = gc.shape
+    pairs = _dot("nrc,njc->nrj", cast(jnp.concatenate([k, q], axis=1)), cast(k))  # [N / r, 2 C, C]
+    pairs = jnp.repeat(pairs, n // k.shape[0], axis=0) if n != k.shape[0] else pairs
+    g = gc[..., 0]
+    decay = jnp.exp(jnp.minimum(g[:, :, None] - g[:, None, :], 0.0))  # rows i >= j are read
+    r = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return (jnp.where(j < r, pairs[:, :c] * decay, 0.0),
+            jnp.where(j <= r, pairs[:, c:] * decay, 0.0))
+
+
 def _chunk(state, q, k, v, gc, beta, sub: int, cast):
     """One chunk of every row: (the state after it, its outputs ``[N, C,
-    dv]`` float32) from the state before it ``[N, dk, dv]``."""
+    dv]`` float32) from the state before it ``[N, dk, dv]``; ``q`` and
+    ``k`` may have fewer rows (key heads) than ``v`` (value heads)."""
     q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
-    a, b = _pair_decays(q, k, gc, sub, cast)
+    per_head = gc.shape[-1] == 1
+    if per_head:
+        a, b = _head_pair_decays(q, k, gc, cast)
+    if v.shape[0] != k.shape[0]:  # a key head's rows beside each of its value heads
+        q, k = (jnp.repeat(t, v.shape[0] // k.shape[0], axis=0) for t in (q, k))
+    if not per_head:
+        a, b = _pair_decays(q, k, gc, sub, cast)
     inverse = unit_lower_inverse(beta[:, :, None] * a)
     through = jnp.exp(gc)  # the decay from the chunk's start through each row
     solved = _dot("nrs,nsd->nrd", inverse, jnp.concatenate([v, k * through], -1) * beta[:, :, None])
@@ -134,14 +180,18 @@ def _chunk(state, q, k, v, gc, beta, sub: int, cast):
 
 def chunked_delta_rule(q, k, v, log_decay, beta, cast=lambda t: t):
     """``o [N, S, dv]`` (``v``'s dtype) of the recurrence above from ``q, k
-    [N, S, dk]``, ``v [N, S, dv]``, ``log_decay [N, S / C, C, dk]``
-    (``chunk_log_decay``) and ``beta [N, S]``; every row starts from a zero
-    state."""
-    n, chunks, c, dk = log_decay.shape
+    [N / r, S, dk]``, ``v [N, S, dv]``, ``log_decay [N, S / C, C, dk]``
+    (``chunk_log_decay``; a last axis of 1 is a decay per head) and ``beta
+    [N, S]``; every row starts from a zero state."""
+    n, chunks, c, _ = log_decay.shape
+    dk = k.shape[-1]
     sub = math.gcd(c, SUB_BLOCK)
+    if n % k.shape[0] or q.shape != k.shape or v.shape[0] != n:
+        raise ValueError(f"queries {q.shape} and keys {k.shape} do not serve a whole number of "
+                         f"the {n} rows of values {v.shape} and decays")
 
     def by_chunk(t):
-        return jnp.moveaxis(t.reshape(n, chunks, c, *t.shape[2:]), 1, 0)
+        return jnp.moveaxis(t.reshape(t.shape[0], chunks, c, *t.shape[2:]), 1, 0)
 
     @jax.checkpoint
     def body(state, part):
@@ -154,5 +204,6 @@ def chunked_delta_rule(q, k, v, log_decay, beta, cast=lambda t: t):
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = DEFAULT_CHUNK, cast=lambda t: t):
-    """The recurrence from the log-decay a position ``g [N, S, dk]``."""
+    """The recurrence from the log-decay a position ``g [N, S, dk]`` (``[N,
+    S, 1]``: a decay per head)."""
     return chunked_delta_rule(q, k, v, chunk_log_decay(g, chunk), beta, cast)

@@ -88,7 +88,7 @@ def test_the_configuration_file_states_the_cut():
         bench = json.load(fh)
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("moonlight_16b_a3b_ep8", "train_epochs", 1)
-    assert sum(1 for w in bench["workloads"] if w["chips"] == 4) == 1 and len(bench["workloads"]) == 4
+    assert sum(1 for w in bench["workloads"] if w["chips"] == 4) == 1 and len(bench["workloads"]) == 5
 
 
 @pytest.fixture(scope="module")

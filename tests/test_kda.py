@@ -392,7 +392,7 @@ def test_the_counters_and_gauges_of_the_new_layers(tmp_path):
 
 def test_the_scope_table_of_a_hybrid_step_covers_every_named_scope(tmp_path):
     table = make_trainer(tmp_path).scope_table()
-    assert set(table.values()) == set(seqlm.SCOPES)
+    assert set(table.values()) == {s for s in seqlm.SCOPES if not s.startswith("seq/gqa/")}
     assert seqlm.scope_of("jit(step)/transpose(jvp(seq/kda/recur))/while/body/dot") == "seq/kda/recur"
 
 

@@ -3,6 +3,7 @@ ops/moe.py, nn/seq.py) against the plain reference the benchmark compares
 with (benchmark/reference/moonlight.py, loaded by its path: one reference,
 no second copy), on the CPU, float32, small widths, seeded weights."""
 
+import hashlib
 import importlib.util
 import json
 import os
@@ -395,7 +396,8 @@ def test_the_scope_table_covers_every_named_scope(trained):
     trainer, _, _ = trained
     table = trainer.scope_table()
     # every layer of this stack attends: the delta-rule mixer's scopes are tests/test_kda.py's
-    assert set(table.values()) == {s for s in seqlm.SCOPES if not s.startswith("seq/kda/")}
+    assert set(table.values()) == {s for s in seqlm.SCOPES
+                                   if not s.startswith(("seq/kda/", "seq/gqa/"))}
     assert seqlm.scope_of("jit(step)/transpose(jvp(seq/moe/experts))/ragged_dot") == "seq/moe/experts"
     assert seqlm.scope_of("jit(step)/seq/mla/project/seq/mla/attend/while/body/dot") == "seq/mla/attend"
     assert seqlm.scope_of("jit(step)/convert_element_type") is None
@@ -413,10 +415,23 @@ def test_bfloat16_compute_stays_near_the_reference(tmp_path):
 
 # ---- one code path, two dialects of config.json
 
+def _pinned(trainer):
+    """(sha256 of the step's lowered text, sha256 of the seeded weights' bytes)."""
+    text = trainer._train_step.lower(*trainer.step_args()).as_text()
+    weights = hashlib.sha256()
+    for leaf in jax.tree.leaves(trainer.params):
+        weights.update(np.asarray(leaf).tobytes())
+    return hashlib.sha256(text.encode()).hexdigest(), weights.hexdigest()
+
+
 def test_a_deepseek_v3_file_is_what_it_was(tmp_path):
     """The spec, the parameter tree and the first losses of this file's
-    model at seed 3, as the tree before the second dialect gave them."""
+    model at seed 3, as the tree before the second dialect gave them; its
+    seeded weights and its step's lowered text byte for byte as the tree
+    before the third dialect (PR 34's) gave them."""
     trainer = make_trainer(tmp_path, EPOCHS=2)
+    assert _pinned(trainer) == ("0391b90295a07d172d23f63fb2d39756b93b04b8f895ddc2935d907559814b28",
+                                "afabbe6eff396277eee934423cf61cc5cc1387231b808a9ecc5c882cd8a1e332")
     spec = trainer.spec
     assert (spec.hidden, spec.heads, spec.kv_rank, spec.nope, spec.rope, spec.v_head, spec.ffn,
             spec.expert_width, spec.shared_width, spec.routed, spec.per_token, spec.route_scale,
@@ -439,6 +454,26 @@ def test_a_deepseek_v3_file_is_what_it_was(tmp_path):
     trainer.run()
     assert trainer.loss_history == pytest.approx([4.152822971343994, 4.1860880851745605], abs=2e-6)
     assert trainer.metrics.counter_get("kda.token_layers") == 0
+    assert (spec.dense_layers, spec.scoring, spec.shared_gate, spec.centred_norms) == (1, "sigmoid", False, False)
+
+
+def test_a_kimi_linear_file_is_what_it_was(tmp_path):
+    """K's twin: the hybrid stack of tests/test_kda.py's model at seed 3:
+    its seeded weights, its step's lowered text and its first losses as the
+    tree before the third dialect (PR 34's) gave them, byte for byte."""
+    import test_kda
+
+    trainer = test_kda.make_trainer(tmp_path, EPOCHS=2)
+    spec = trainer.spec
+    assert spec.mixers == ("kda", "kda", "kda", "mla", "kda") and spec.dense_layers == 1
+    assert (spec.kda_heads, spec.kda_value_heads, spec.decay_per_head, spec.gates_low_rank,
+            spec.out_gate) == (4, 4, False, True, "sigmoid")
+    assert set(trainer.params) == {"embed", "dense", "moe", "moe1", "moe2", "norm", "head"}
+    assert _pinned(trainer) == ("49a121b98db207bde623ee00d796865eaf256a6c247af7b8e32450bc1202f929",
+                                "4290d11384bbbee69bde339c79da5b15038edd1de46fb7a3214c62b5a3f9b1d1")
+    trainer.run()
+    assert trainer.loss_history == pytest.approx([4.184228420257568, 4.158705711364746], abs=2e-6)
+    assert trainer.metrics.counter_get("gqa.token_layers") == 0
 
 
 @pytest.mark.parametrize("key, value", [
