@@ -561,11 +561,10 @@ def expert_mlp(lp, bias, x, spec: SeqSpec, cast):
         choice, weight = moe.route(scores, bias, spec.per_token, spec.route_scale)
     with jax.named_scope("seq/moe/dispatch"):
         plan = moe.plan_dispatch(choice, spec.first, spec.held)
-        rows = moe.dispatch_rows(cast(hn), plan)
-    with jax.named_scope("seq/moe/experts"):
-        out = moe.grouped_swiglu(rows, lp["eg"], lp["eu"], lp["ed"], plan.group_sizes, cast)
-    with jax.named_scope("seq/moe/combine"):
-        routed = moe.combine_rows(out, weight, plan)
+    # dispatch, experts and combine are one walk of the list's routed rows,
+    # each part of it under its own scope (moe.combine_rows)
+    rows = moe.ExpertRows(cast(hn), cast(lp["eg"]), cast(lp["eu"]), cast(lp["ed"]))
+    routed = moe.combine_rows(rows, weight, plan)
     with jax.named_scope("seq/moe/shared"):
         out = x + routed
         shared = nnseq.swiglu(hn, lp["sg"], lp["su"], lp["sd"], cast)
@@ -869,5 +868,10 @@ class SeqLMTrainer(ToolkitBase):
         self.metrics.counter_add("moe.rows_routed", rows)
         self.metrics.counter_add("kda.token_layers", self.spec.tokens * self.spec.kda_layers)
         self.metrics.counter_add("gqa.token_layers", self.spec.tokens * self.spec.mixers.count("gqa"))
+        # how far the walk of the sorted lists went (moe.combine_rows), of how far it could
+        list_rows = self.spec.tokens * self.spec.per_token
+        self.metrics.gauge_set("moe.rows_walked", sum(
+            moe.rows_walked(int(n), list_rows) for n in sizes.sum(axis=1)))
+        self.metrics.gauge_set("moe.list_rows", sizes.shape[0] * list_rows)
         mean = np.maximum(sizes.mean(axis=1), 1e-9)
         self.metrics.gauge_set("moe.load_max_over_mean", float((sizes.max(axis=1) / mean).max()))

@@ -7,27 +7,33 @@ graphs with random keys), the (token, expert) pairs are the edges, dispatch
 is a gather of token rows by the edge list sorted by expert, the experts are
 a grouped product over the ragged groups of that list, and combine is a
 weighted segment sum of the edges back into their tokens (ops/segment.py's
-operator; here every token has exactly ``k`` slots, so the segment sum is a
-gather by the inverse permutation and a sum over ``k``: no scatter).
+operator: a scatter-add by the list's token column).
 
 The layer is told which experts it holds (``first .. first + held``). It
 routes over ALL the layer's experts, keeps the pairs whose expert is held,
 and computes those experts' part of the result. There is no capacity
 factor and no pair is ever dropped: the sorted list has room for every
 pair a batch can send here (``tokens * k``: a token's ``k`` distinct
-choices may all be held), the held pairs first, grouped by expert, and the
-grouped product's work follows ``group_sizes``, the rows really routed.
-What absent experts would add is left out; nothing stands in for the chips
-that hold them.
+choices may all be held), the held pairs first, grouped by expert. The
+list is only ever a list of places: its rows are made a chunk of
+``GROUPED_CHUNK_ROWS`` places at a time, and every pass (dispatch, experts,
+combine, forward and backward) is one walk that stops at the routed rows,
+``sum(group_sizes)``, a number the device knows before the first pass. A
+chunk that begins at or past it is not gathered, sliced, multiplied or
+added; a batch whose every choice is held walks the whole list. What absent
+experts would add is left out; nothing stands in for the chips that hold
+them.
 
-Both gathers are hand-paired (custom_vjp): the transpose of the dispatch
-gather is the combine's gather-and-sum and the other way round, so the
-backward pass has no scatter-add either.
+The walk is hand-paired (custom_vjp): a loop whose trip count is read on
+the device has no transpose of its own. Forward and backward keep the
+token rows, the weights and the plan, and nothing ``[tokens * k, hidden]``
+exists in either.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -64,10 +70,6 @@ class Dispatch:
     held: jax.Array
     group_sizes: jax.Array
 
-    @property
-    def token_of(self) -> jax.Array:
-        return self.pair_of // self.slot_of.shape[1]
-
 
 def plan_dispatch(choice: jax.Array, first: int, n_held: int) -> Dispatch:
     tokens, per_token = choice.shape
@@ -82,73 +84,6 @@ def plan_dispatch(choice: jax.Array, first: int, n_held: int) -> Dispatch:
         key[:, None] == jnp.arange(n_held, dtype=key.dtype)[None, :], axis=0, dtype=jnp.int32
     )
     return Dispatch(pair_of=order, slot_of=slot_of, held=held, group_sizes=group_sizes)
-
-
-def _slot_sum(y, plan: Dispatch, weight=None):
-    """[T, f] float32: ``sum_k weight[t, k] * y[slot of (t, k)]`` over the
-    HELD pairs (weight None: 1), one slot at a time so that no [T, k, f]
-    value exists. The rows of pairs that are not held lie past the grouped
-    product's last group, which leaves them unwritten (on the TPU whatever
-    the buffer held, a NaN among it): they are masked, never multiplied."""
-    out = jnp.zeros((plan.slot_of.shape[0], y.shape[1]), jnp.float32)
-    for k in range(plan.slot_of.shape[1]):
-        rows = y[plan.slot_of[:, k]].astype(jnp.float32)
-        if weight is not None:
-            rows = rows * weight[:, k, None]
-        out = out + jnp.where(plan.held[:, k, None], rows, 0.0)
-    return out
-
-
-@jax.custom_vjp
-def dispatch_rows(x, plan: Dispatch):
-    """[T, f] -> [T*k, f]: the token row of every sorted pair."""
-    return x[plan.token_of]
-
-
-def _dispatch_fwd(x, plan):
-    return x[plan.token_of], plan
-
-
-def _dispatch_bwd(plan, g):
-    # the transpose of the gather by the sorted list: each token sums the
-    # cotangents of its held slots (a pair that is not held took its row
-    # too, but nothing reads that copy: its cotangent is zero)
-    return _slot_sum(g, plan).astype(g.dtype), jax.tree.map(zero_cotangent, plan)
-
-
-dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-def _combine(y, weight, plan: Dispatch):
-    return _slot_sum(y, plan, weight)
-
-
-@jax.custom_vjp
-def combine_rows(y, weight, plan: Dispatch):
-    """[T*k, f], [T, k] -> [T, f] float32: ``sum_k weight[t, k] * y[slot of
-    (t, k)]`` over the held pairs (the rows of the others are zeros: the
-    grouped product leaves rows past its last group so)."""
-    return _combine(y, weight, plan)
-
-
-def _combine_fwd(y, weight, plan):
-    return _combine(y, weight, plan), (y, weight, plan)
-
-
-def _combine_bwd(res, g):
-    y, weight, plan = res
-    d_weight = jnp.stack([
-        jnp.sum(jnp.where(plan.held[:, k, None],
-                          g * y[plan.slot_of[:, k]].astype(jnp.float32), 0.0), axis=-1)
-        for k in range(plan.slot_of.shape[1])
-    ], axis=1)
-    # each sorted pair takes its token's cotangent times its own weight
-    w_of_pair = jnp.where(plan.held, weight, 0.0).reshape(-1)[plan.pair_of]
-    d_y = g[plan.token_of] * w_of_pair[:, None]
-    return d_y.astype(y.dtype), d_weight, jax.tree.map(zero_cotangent, plan)
-
-
-combine_rows.defvjp(_combine_fwd, _combine_bwd)
 
 
 GMM_TILING = (512, 512, 512)  # rows, contracted, columns a tile of the Pallas kernel
@@ -188,34 +123,156 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array) -> jax.Ar
     return lax.platform_dependent(x, w, group_sizes, tpu=_gmm_tpu, default=_gmm_plain)
 
 
-GROUPED_CHUNK_ROWS = 32768
+# Rows a step of the walk takes. A layer's routed path, forward and backward, at the three
+# cells' shapes, milliseconds at 8,192 / 16,384 / 32,768 rows a chunk (my chip runs, PR 36):
+# 57.8 / 47.7 / 45.8 (24,577 routed rows of 196,608), 17.8 / 23.3 / 28.5 (4,223 of 131,072),
+# 26.5 / 25.2 / 29.7 (9,992 of 163,840); 107.1, 56.7 and 64.9 with every pass over the whole
+# list. A small chunk walks fewer rows past the routed ones; every chunk pays two scatter-adds
+# into [tokens, hidden] float32, which the TPU's compiler makes of a sort of the chunk's
+# tokens, a gather of its rows into that order and a sorted scatter: 1.1 ms for 4,096 rows,
+# 2.7 for 8,192, 3.2 for 16,384, 4.3 for 32,768 (a gather: 37 to 58 ns a row at any size).
+GROUPED_CHUNK_ROWS = 16384
+# the step's named scopes (models/seqlm.py ``SCOPES``) of the walk's three parts
+DISPATCH, EXPERTS, COMBINE = "seq/moe/dispatch", "seq/moe/experts", "seq/moe/combine"
 
 
-def grouped_swiglu(xs, wg, wu, wd, group_sizes, cast, chunk_rows: int = GROUPED_CHUNK_ROWS):
-    """SwiGLU of each sorted row by its own expert's weights
-    ([held, hidden, width] x2, [held, width, hidden]). The list has room
-    for every pair a batch can send here and is mostly empty (the held
-    pairs come first), so it is walked in chunks of ``chunk_rows`` rows,
-    each recomputed in the backward: the [rows, width] activations exist
-    for one chunk at a time. A chunk past the routed rows has empty groups
-    and costs the kernel its walk over an empty grid. (Keeping the first
-    chunk's activations saves a fifth of the work a routed row costs and a
-    gigabyte too many; skipping empty chunks by ``lax.cond`` cost 0.8 GB
-    and 0.6% of the step: my chip runs, PR 28.)"""
-    xs, wg, wu, wd = cast(xs), cast(wg), cast(wu), cast(wd)
-    rows = xs.shape[0]
-    chunk = math.gcd(rows, chunk_rows)
-    ends = jnp.cumsum(group_sizes)
-    starts = ends - group_sizes
+def chunk_of(list_rows: int, chunk_rows: int = GROUPED_CHUNK_ROWS) -> int:
+    """Rows a step of the walk takes of a list of ``list_rows`` places: the
+    largest divisor of the list that ``chunk_rows`` holds."""
+    return math.gcd(list_rows, chunk_rows)
 
-    @jax.checkpoint
-    def one(xs_c, offset):
-        sizes = jnp.clip(ends - offset, 0, chunk) - jnp.clip(starts - offset, 0, chunk)
-        g = grouped_matmul(xs_c, wg, sizes)
-        u = grouped_matmul(xs_c, wu, sizes)
-        return grouped_matmul(jax.nn.silu(g) * u, wd, sizes)
 
-    offsets = jnp.arange(rows // chunk, dtype=group_sizes.dtype) * chunk
-    _, out = lax.scan(lambda _, part: (None, one(*part)), None,
-                      (xs.reshape(rows // chunk, chunk, -1), offsets))
-    return out.reshape(rows, -1)
+def rows_walked(n_routed: int, list_rows: int, chunk_rows: int = GROUPED_CHUNK_ROWS) -> int:
+    """Places of the sorted list a pass touches when ``n_routed`` of them
+    are held: the chunks that begin below ``n_routed``, whole."""
+    chunk = chunk_of(list_rows, chunk_rows)
+    return -(-n_routed // chunk) * chunk
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class ExpertRows:
+    """What the sorted list's rows are made from, before any is made: the
+    token rows ``x`` [T, hidden] they are gathered from and the held
+    experts' SwiGLU matrices they go through ([held, hidden, width] x2,
+    [held, width, hidden]), all in the compute dtype."""
+
+    x: jax.Array
+    wg: jax.Array
+    wu: jax.Array
+    wd: jax.Array
+
+
+def grouped_swiglu(xs, wg, wu, wd, group_sizes):
+    """SwiGLU of each row by its own expert's weights; the rows past the
+    last group are left as ``grouped_matmul`` leaves them."""
+    g = grouped_matmul(xs, wg, group_sizes)
+    u = grouped_matmul(xs, wu, group_sizes)
+    return grouped_matmul(jax.nn.silu(g) * u, wd, group_sizes)
+
+
+def _take(x, token):
+    return x.at[token].get(mode="promise_in_bounds")
+
+
+def _add_into(out, token, rows):
+    return out.at[token].add(rows, mode="promise_in_bounds")
+
+
+class _Walk:
+    """The routed chunks of one plan's sorted list: what a pass reads of
+    the plan, and the chunk ``i`` of it. Nothing here is as long as the
+    list: a chunk's pairs, tokens and weights are taken inside its step."""
+
+    def __init__(self, plan: Dispatch, weight, chunk_rows: int):
+        self.chunk = chunk_of(plan.pair_of.shape[0], chunk_rows)
+        self.pair_of, self.per_token = plan.pair_of, plan.slot_of.shape[1]
+        self.weight = weight.reshape(-1)
+        self.ends = jnp.cumsum(plan.group_sizes)
+        self.starts = self.ends - plan.group_sizes
+        self.n_routed = self.ends[-1]
+        self.trips = (self.n_routed + self.chunk - 1) // self.chunk
+
+    def part(self, i):
+        """(pairs [chunk], tokens [chunk], weights [chunk, 1], rows per
+        expert inside the chunk [held], which of its places are routed
+        [chunk, 1]: the places past them hold pairs of absent experts)."""
+        offset = i * self.chunk
+        pair = lax.dynamic_slice(self.pair_of, (offset,), (self.chunk,))
+        sizes = (jnp.clip(self.ends - offset, 0, self.chunk)
+                 - jnp.clip(self.starts - offset, 0, self.chunk))
+        routed = offset + jnp.arange(self.chunk, dtype=offset.dtype) < self.n_routed
+        return pair, pair // self.per_token, _take(self.weight, pair)[:, None], sizes, routed[:, None]
+
+
+def _combine(rows: ExpertRows, weight, plan: Dispatch, chunk_rows: int):
+    walk = _Walk(plan, weight, chunk_rows)
+
+    def step(i, out):
+        _, token, w, sizes, routed = walk.part(i)
+        with jax.named_scope(DISPATCH):
+            xs = _take(rows.x, token)
+        with jax.named_scope(EXPERTS):
+            y = grouped_swiglu(xs, rows.wg, rows.wu, rows.wd, sizes)
+        with jax.named_scope(COMBINE):
+            # the chunk's last rows may lie past the routed ones, where the
+            # grouped product wrote nothing: masked, never multiplied
+            return _add_into(out, token, jnp.where(routed, y.astype(jnp.float32) * w, 0.0))
+
+    out = jnp.zeros((plan.slot_of.shape[0], rows.wd.shape[-1]), jnp.float32)
+    return lax.fori_loop(0, walk.trips, step, out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def combine_rows(rows: ExpertRows, weight, plan: Dispatch, chunk_rows: int = GROUPED_CHUNK_ROWS):
+    """[T, hidden] float32: ``sum_k weight[t, k] * SwiGLU_e(x[t])`` over the
+    HELD pairs (t, k), ``e`` the pair's expert; ``weight`` [T, k].
+
+    One walk of the sorted list's routed chunks, a loop whose trip count
+    the device reads from the plan: each chunk's token rows are gathered
+    (``DISPATCH``), go through their experts (``EXPERTS``: three grouped
+    products) and are added, weighted, into their tokens (``COMBINE``: a
+    scatter-add in float32). The backward is the same walk once more: the
+    chunk is gathered and multiplied again, so nothing of a chunk outlives
+    its step, and the dispatch's transpose is the other scatter-add."""
+    return _combine(rows, weight, plan, chunk_rows)
+
+
+def _combine_fwd(rows, weight, plan, chunk_rows):
+    return _combine(rows, weight, plan, chunk_rows), (rows, weight, plan)
+
+
+def _combine_bwd(chunk_rows, res, g):
+    rows, weight, plan = res
+    walk = _Walk(plan, weight, chunk_rows)
+    f32 = functools.partial(jnp.zeros_like, dtype=jnp.float32)
+
+    def step(i, carry):
+        d_x, d_weight, d_wg, d_wu, d_wd = carry
+        pair, token, w, sizes, routed = walk.part(i)
+        with jax.named_scope(DISPATCH):
+            xs = _take(rows.x, token)
+        with jax.named_scope(EXPERTS):
+            y, back = jax.vjp(lambda *a: grouped_swiglu(*a, sizes), xs, rows.wg, rows.wu, rows.wd)
+        with jax.named_scope(COMBINE):
+            g_rows = _take(g, token)
+            d_w = jnp.sum(jnp.where(routed, g_rows * y.astype(jnp.float32), 0.0), axis=-1)
+            d_weight = d_weight.at[pair].set(d_w, mode="promise_in_bounds", unique_indices=True)
+            d_y = jnp.where(routed, g_rows * w, 0.0).astype(y.dtype)
+        with jax.named_scope(EXPERTS):
+            d_xs, *d_experts = back(d_y)
+            # an expert's rows may lie in several chunks: their sum is kept in float32
+            d_wg, d_wu, d_wd = (acc + d.astype(jnp.float32)
+                                for acc, d in zip((d_wg, d_wu, d_wd), d_experts))
+        with jax.named_scope(DISPATCH):
+            # the transpose of the gather: each token sums its routed rows' cotangents
+            d_x = _add_into(d_x, token, jnp.where(routed, d_xs.astype(jnp.float32), 0.0))
+        return d_x, d_weight, d_wg, d_wu, d_wd
+
+    d_x, d_weight, *d_experts = lax.fori_loop(0, walk.trips, step, (
+        f32(rows.x), f32(walk.weight), f32(rows.wg), f32(rows.wu), f32(rows.wd)))
+    d_rows = jax.tree.map(lambda d, of: d.astype(of.dtype), ExpertRows(d_x, *d_experts), rows)
+    return d_rows, d_weight.reshape(weight.shape), jax.tree.map(zero_cotangent, plan)
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)

@@ -181,6 +181,78 @@ def test_no_pair_is_dropped_when_the_router_sends_every_token_to_one_held_expert
     assert rel(out, want) < 1e-5
 
 
+WALK_TOKENS, WALK_CHUNK = 40, 24  # a list of 120 places in five chunks
+
+
+def _steered(rng, n_routed):
+    """(layer, x [40, hidden]) whose router sends exactly ``n_routed`` of the
+    120 pairs to the held experts 4..7, the first pairs in token order: the
+    router reads the first 16 dims of a token as its scores, and the token
+    carries a large value at each expert it is to choose."""
+    lp = _expert_layer(rng, held=4)
+    lp["router"] = np.eye(SHAPE.hidden, 16, dtype=np.float32)
+    x = (rng.standard_normal((WALK_TOKENS, SHAPE.hidden)) * 0.3).astype(np.float32)
+    absent = [0, 1, 2, 3, 8, 9, 10, 11, 12, 13, 14, 15]
+    for t in range(WALK_TOKENS):
+        for slot in range(3):
+            is_held = 3 * t + slot < n_routed
+            e = 4 + (t + slot) % 4 if is_held else absent[(5 * t + slot) % 12]
+            x[t, e] = 4.0 + rng.random()
+    return lp, x
+
+
+@pytest.mark.parametrize("n_routed", [0, 10, 24, 25, 120],
+                         ids=["none", "inside_a_chunk", "on_the_boundary", "one_past_it", "all_held"])
+def test_the_walk_stops_at_the_routed_rows_and_the_layer_is_the_reference(rng, monkeypatch, n_routed):
+    walk = moe.combine_rows
+    monkeypatch.setattr(moe, "combine_rows",
+                        lambda rows, weight, plan: walk(rows, weight, plan, chunk_rows=WALK_CHUNK))
+    lp, x = _steered(rng, n_routed)
+    bias = jnp.zeros((16,), jnp.float32)
+    spec, cast = _spec(4, 4, WALK_TOKENS), compute_cast(None)
+    ct = rng.standard_normal(x.shape).astype(np.float32)
+
+    out, sizes, _ = seqlm.expert_mlp(lp, bias, jnp.asarray(x), spec, cast)
+    assert int(np.asarray(sizes).sum()) == n_routed
+    assert moe.rows_walked(n_routed, 120, WALK_CHUNK) == {0: 0, 10: 24, 24: 24, 25: 48, 120: 120}[n_routed]
+    want, _ = ref.expert_mlp(lp, jnp.asarray(x), bias, SHAPE, ref.Share(4, 4))
+    assert rel(out, want) < 1e-5
+
+    got = jax.grad(lambda lp, x: jnp.sum(seqlm.expert_mlp(lp, bias, x, spec, cast)[0] * ct),
+                   argnums=(0, 1))(lp, jnp.asarray(x))
+    want = jax.grad(lambda lp, x: jnp.sum(ref.expert_mlp(lp, x, bias, SHAPE, ref.Share(4, 4))[0] * ct),
+                    argnums=(0, 1))(lp, jnp.asarray(x))
+    errors = jax.tree.map(lambda g, w: rel(g, w) if np.any(np.asarray(w)) else float(np.abs(g).max()),
+                          got, want)
+    assert max(jax.tree.leaves(errors)) < 1e-5, errors
+    # an expert no routed row reached has a gradient of exact zeros, not what a chunk left behind
+    for name in ("eg", "eu", "ed"):
+        g = np.asarray(got[0][name])
+        assert np.all(np.isfinite(g))
+        assert not np.any(g[np.asarray(sizes) == 0])
+
+
+@pytest.mark.parametrize("n_routed", [0, 25, 120])
+def test_the_weights_gradient_follows_the_routed_rows(rng, n_routed):
+    """``d weight`` of the walk itself, pair by pair: the held pairs' is the
+    cotangent times the expert's row, every other pair's exactly zero."""
+    lp, x = _steered(rng, n_routed)
+    scores = jax.nn.sigmoid(x[:, :16])
+    choice, weight = moe.route(scores, jnp.zeros((16,)), 3, 1.0)
+    plan = moe.plan_dispatch(choice, 4, 4)
+    rows = moe.ExpertRows(*(jnp.asarray(a) for a in (x, lp["eg"], lp["eu"], lp["ed"])))
+    ct = rng.standard_normal(x.shape).astype(np.float32)
+    got = jax.grad(lambda w: jnp.sum(moe.combine_rows(rows, w, plan, chunk_rows=WALK_CHUNK) * ct))(weight)
+    want = np.zeros((WALK_TOKENS, 3), np.float32)
+    for t, slot in zip(*np.nonzero(np.asarray(plan.held))):
+        e = int(choice[t, slot]) - 4
+        row = seqlm.nnseq.swiglu(x[t][None], lp["eg"][e], lp["eu"][e], lp["ed"][e], compute_cast(None))
+        want[t, slot] = float(np.asarray(row)[0] @ ct[t])
+    assert int(np.asarray(plan.held).sum()) == n_routed
+    assert rel(got, want) < 1e-5 if n_routed else not np.any(np.asarray(got))
+    assert not np.any(np.asarray(got)[~np.asarray(plan.held)])
+
+
 def test_rows_routed_counter_equals_the_pairs_sent_to_held_experts(tmp_path):
     trainer = make_trainer(tmp_path, EPOCHS=1)
     trainer.route_bias = trainer.route_bias.at[:, trainer.spec.first].set(10.0)
@@ -193,17 +265,37 @@ def test_rows_routed_counter_equals_the_pairs_sent_to_held_experts(tmp_path):
     assert held >= 2 * 2 * 32  # every token, both layers, at least the favoured expert
     assert trainer.metrics.counter_get("moe.rows_routed") == held == trainer.routed_history[0]
     assert trainer.metrics.counter_get("seq.tokens") == 64
-    assert trainer.metrics.snapshot()["gauges"]["moe.load_max_over_mean"] > 2.0
+    gauges = trainer.metrics.snapshot()["gauges"]
+    assert gauges["moe.load_max_over_mean"] > 2.0
+    # the walk's gauges: two expert layers' lists of 64 x 3 places, each walked to the end
+    # of the chunk its routed rows end in
+    per_layer = [int(np.sum((own[layer] >= share.first) & (own[layer] < share.first + share.held)))
+                 for layer in range(2)]
+    chunk = moe.chunk_of(64 * 3)
+    assert gauges["moe.list_rows"] == 2 * 64 * 3
+    assert gauges["moe.rows_walked"] == sum(-(-n // chunk) * chunk for n in per_layer)
 
 
-def test_grouped_product_walks_the_list_in_chunks_without_changing_it(rng):
-    x = rng.standard_normal((48, 8)).astype(np.float32)
-    w = [rng.standard_normal(s).astype(np.float32) for s in ((3, 8, 6), (3, 8, 6), (3, 6, 8))]
-    sizes = jnp.asarray([5, 0, 17], jnp.int32)
-    cast = compute_cast(None)
-    whole = moe.grouped_swiglu(x, *w, sizes, cast, chunk_rows=48)
-    chunked = moe.grouped_swiglu(x, *w, sizes, cast, chunk_rows=8)
-    assert rel(np.asarray(chunked)[:22], np.asarray(whole)[:22]) < 1e-6
+@pytest.mark.parametrize("chunk_rows", [8, 16, 24])
+def test_grouped_product_walks_the_list_in_chunks_without_changing_it(rng, chunk_rows):
+    x = rng.standard_normal((16, 8)).astype(np.float32)
+    rows = moe.ExpertRows(jnp.asarray(x), *(jnp.asarray(rng.standard_normal(s), jnp.float32)
+                                            for s in ((3, 8, 6), (3, 8, 6), (3, 6, 8))))
+    choice = jnp.asarray(rng.permuted(np.tile(np.arange(6), (16, 1)), axis=1)[:, :3], jnp.int32)
+    weight = jnp.asarray(rng.random((16, 3)), jnp.float32)
+    plan = moe.plan_dispatch(choice, 1, 3)  # experts 1..3 of 6 are held
+    assert 0 < int(plan.group_sizes.sum()) < 48 and moe.chunk_of(48, chunk_rows) == chunk_rows
+    whole = moe.combine_rows(rows, weight, plan, chunk_rows=48)
+    chunked = moe.combine_rows(rows, weight, plan, chunk_rows=chunk_rows)
+    assert rel(chunked, whole) < 1e-6
+    # and the list's rows are what a grouped product over the held pairs gives
+    sorted_x = x[np.asarray(plan.pair_of) // 3]
+    want = np.zeros_like(x)
+    y = np.asarray(moe.grouped_swiglu(sorted_x, rows.wg, rows.wu, rows.wd, plan.group_sizes))
+    for place in range(int(plan.group_sizes.sum())):
+        pair = int(plan.pair_of[place])
+        want[pair // 3] += float(weight[pair // 3, pair % 3]) * y[place]
+    assert rel(whole, want) < 1e-6
 
 
 # ---- the implicit causal attention against the explicit edge chain
@@ -427,10 +519,12 @@ def _pinned(trainer):
 def test_a_deepseek_v3_file_is_what_it_was(tmp_path):
     """The spec, the parameter tree and the first losses of this file's
     model at seed 3, as the tree before the second dialect gave them; its
-    seeded weights and its step's lowered text byte for byte as the tree
-    before the third dialect (PR 34's) gave them."""
+    seeded weights byte for byte as the tree before the third dialect (PR
+    34's) gave them; its step's lowered text as PR 36 left it (the routed
+    path became one walk of the routed rows: the text changed by design,
+    the weights and the first losses did not)."""
     trainer = make_trainer(tmp_path, EPOCHS=2)
-    assert _pinned(trainer) == ("0391b90295a07d172d23f63fb2d39756b93b04b8f895ddc2935d907559814b28",
+    assert _pinned(trainer) == ("21620a1d0565a9429f4bb64f51d3bee2aac9fc39c294fed35de750da06bb00c0",
                                 "afabbe6eff396277eee934423cf61cc5cc1387231b808a9ecc5c882cd8a1e332")
     spec = trainer.spec
     assert (spec.hidden, spec.heads, spec.kv_rank, spec.nope, spec.rope, spec.v_head, spec.ffn,
@@ -459,8 +553,9 @@ def test_a_deepseek_v3_file_is_what_it_was(tmp_path):
 
 def test_a_kimi_linear_file_is_what_it_was(tmp_path):
     """K's twin: the hybrid stack of tests/test_kda.py's model at seed 3:
-    its seeded weights, its step's lowered text and its first losses as the
-    tree before the third dialect (PR 34's) gave them, byte for byte."""
+    its seeded weights and its first losses as the tree before the third
+    dialect (PR 34's) gave them, its step's lowered text as PR 36 left it
+    (as the test above), byte for byte."""
     import test_kda
 
     trainer = test_kda.make_trainer(tmp_path, EPOCHS=2)
@@ -469,7 +564,7 @@ def test_a_kimi_linear_file_is_what_it_was(tmp_path):
     assert (spec.kda_heads, spec.kda_value_heads, spec.decay_per_head, spec.gates_low_rank,
             spec.out_gate) == (4, 4, False, True, "sigmoid")
     assert set(trainer.params) == {"embed", "dense", "moe", "moe1", "moe2", "norm", "head"}
-    assert _pinned(trainer) == ("49a121b98db207bde623ee00d796865eaf256a6c247af7b8e32450bc1202f929",
+    assert _pinned(trainer) == ("78e06e12163e7548c48f20c4fdaf458ba53b61eb4fd9f8f65c71662f9fd0176e",
                                 "4290d11384bbbee69bde339c79da5b15038edd1de46fb7a3214c62b5a3f9b1d1")
     trainer.run()
     assert trainer.loss_history == pytest.approx([4.184228420257568, 4.158705711364746], abs=2e-6)
