@@ -23,6 +23,13 @@ TPU-first:
                        files (reference: toolkits/, GraphSegment.cpp:222)
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()  # the first line the package runs
+
 __version__ = "0.1.0"
 
-from neutronstarlite_tpu.utils.config import InputInfo  # noqa: F401
+from neutronstarlite_tpu.utils.config import InputInfo  # noqa: E402,F401
+from neutronstarlite_tpu.utils.platform import note_package_import as _note  # noqa: E402
+
+_note(_T_IMPORT)  # the ``process_prelude`` span, for the process's first tracer

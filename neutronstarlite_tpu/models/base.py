@@ -917,8 +917,9 @@ class ToolkitBase:
 
     def finalize_metrics(self, result: Optional[dict] = None) -> dict:
         """Emit the consolidated run_summary record (idempotent: a second
-        call returns the first record). Aggregates epoch timings,
-        compile-vs-steady-state attribution, phase buckets, the counter/
+        call returns the first record). Aggregates epoch timings, the
+        first epoch beside the warm ones, the compiler's counters
+        (obs/compiles), phase buckets, the counter/
         gauge snapshot (wire volume), device memory, and the final result.
         """
         if self.run_summary_record is not None:
@@ -940,7 +941,7 @@ class ToolkitBase:
                 "loss_history": [float(v) for v in self.loss_history],
                 "phases": collectors.phase_snapshot(self.timers),
                 "memory": collectors.device_memory_stats(),
-                "compile_cache": collectors.compile_cache_info(),
+                "compile_cache": collectors.compile_cache_info(self.metrics),
             }
             if result is not None:
                 fields["result"] = {

@@ -803,10 +803,16 @@ class SeqLMTrainer(ToolkitBase):
 
     def scope_table(self) -> Dict[str, str]:
         """Instruction name -> scope of the compiled step (lowered once
-        more from the same arguments: with the compile cache a lookup)."""
+        more from the same arguments: with the compile cache a lookup).
+        That program's size, where the backend analyses it, goes to the
+        gauge ``step.generated_code_bytes``."""
         if self._scope_table is None:
-            text = self._train_step.lower(*self.step_args()).compile().as_text()
-            self._scope_table = scope_table_of(text)
+            compiled = self._train_step.lower(*self.step_args()).compile()
+            self._scope_table = scope_table_of(compiled.as_text())
+            memory = compiled.memory_analysis()
+            if memory is not None:
+                self.metrics.gauge_set(
+                    "step.generated_code_bytes", int(memory.generated_code_size_in_bytes))
         return self._scope_table
 
     # ---- run -------------------------------------------------------------

@@ -9,13 +9,16 @@ volume) and ad-hoc bench prints:
 
 - :class:`MetricsRegistry` — counters, gauges, timing summaries, plus a
   structured per-epoch JSONL event stream written under ``NTS_METRICS_DIR``;
-- :mod:`collectors` — device memory, compile-vs-steady-state step
-  attribution, phase-timer snapshots;
+- :mod:`collectors` — device memory, first and warm epoch times, the
+  compiler's counters, phase-timer snapshots;
 - :mod:`schema` — the JSONL event schema and its validator (tests and
   tools/metrics_report consume it);
 - :mod:`trace` — hierarchical span tracing (trace_id / span_id /
   parent_id) over the same JSONL stream; tools/trace_timeline merges the
   per-rank span files into one causal timeline and a Chrome trace;
+- :mod:`compiles` — every compile JAX reports as one ``compile`` span
+  (function, trace / lower / backend seconds, cache hit) under the span
+  that caused it, and the ``compile.*`` counters;
 - :mod:`hist` — log-bucketed mergeable latency histograms (bounded
   relative quantile error, fixed memory) serialized as typed ``hist``
   records so tail quantiles survive rotation and multi-rank runs;

@@ -1,9 +1,14 @@
-"""Collectors: device memory, compile-vs-steady-state attribution, phases.
+"""Collectors: device memory, first and warm epochs, the compiler, phases.
 
 Each collector returns plain JSON-serializable dicts for the run_summary
-record. All of them degrade gracefully: a CPU backend with no
-``memory_stats()`` reports explicit nulls, a 1-epoch run reports null warm
-statistics — telemetry never fails a run.
+record. Measured: the device's memory statistics, the epochs' times, and
+what JAX reported of every compile under a span of the run (obs/compiles:
+requests, cache hits and misses, seconds tracing, lowering and in the
+backend). Inferred: ``compile_overhead_s``, the first epoch less the warm
+median, which also holds the program's load, lazy uploads and whatever
+else only the first epoch does. All of them degrade gracefully: a CPU
+backend with no ``memory_stats()`` reports explicit nulls, a 1-epoch run
+reports null warm statistics — telemetry never fails a run.
 """
 
 from __future__ import annotations
@@ -53,10 +58,10 @@ def device_memory_stats() -> Dict[str, Any]:
 
 
 def steady_state_stats(epoch_times: Sequence[float]) -> Dict[str, Any]:
-    """First-step vs warm attribution: the first epoch carries the jit
-    compile (or its AOT/persistent-cache hit), the rest are steady state.
-    ``first_to_warm_ratio`` near 1.0 is the compile-cache-hit signature;
-    a large ratio means the first step paid a cold compile."""
+    """The first epoch beside the warm ones: the first carries the jit
+    compile (or its cache read) and the program's load, the rest are
+    steady state. ``compile_overhead_s`` is their difference, an inference;
+    what the compiler took is measured (``compile_cache_info``)."""
     times = [float(t) for t in epoch_times]
     out: Dict[str, Any] = {
         "epochs": len(times),
@@ -64,7 +69,6 @@ def steady_state_stats(epoch_times: Sequence[float]) -> Dict[str, Any]:
         "warm_median_s": None,
         "warm_mean_s": None,
         "compile_overhead_s": None,
-        "first_to_warm_ratio": None,
     }
     if len(times) >= 2:
         warm = sorted(times[1:])
@@ -75,15 +79,17 @@ def steady_state_stats(epoch_times: Sequence[float]) -> Dict[str, Any]:
         out["warm_median_s"] = med
         out["warm_mean_s"] = sum(warm) / n
         out["compile_overhead_s"] = max(times[0] - med, 0.0)
-        if med > 0:
-            out["first_to_warm_ratio"] = times[0] / med
     return out
 
 
-def compile_cache_info() -> Dict[str, Any]:
-    """Whether a persistent (AOT-style) compilation cache backs this run —
-    paired with ``first_to_warm_ratio`` it attributes the first step to a
-    cold compile vs a cache hit."""
+def compile_cache_info(registry) -> Dict[str, Any]:
+    """Where the persistent compilation cache of this run is, and what
+    the run's compiles took: the ``compile.*`` counters of ``registry``
+    (obs/compiles: ``requests``, ``hits``, ``misses``, ``trace_s``,
+    ``lower_s``, ``backend_s``, ``retrieve_s``; zeros in a process whose
+    entry point never placed the cache, where nothing listens)."""
+    from neutronstarlite_tpu.obs import compiles
+
     cache_dir: Optional[str] = None
     try:
         import jax
@@ -91,7 +97,8 @@ def compile_cache_info() -> Dict[str, Any]:
         cache_dir = jax.config.jax_compilation_cache_dir
     except Exception:
         cache_dir = None
-    return {"persistent_cache_dir": cache_dir, "enabled": bool(cache_dir)}
+    return {"persistent_cache_dir": cache_dir, "enabled": bool(cache_dir),
+            **compiles.snapshot(registry)}
 
 
 def phase_snapshot(timers) -> Dict[str, Dict[str, float]]:

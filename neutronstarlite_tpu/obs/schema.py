@@ -177,9 +177,14 @@ tune_decision (tune/select.py): the resolved auto-knob tuple a trainer
 
 span (obs/trace.py): one completed interval on the causal timeline
   name: str (non-empty), cat: str (phase | lifecycle | epoch | stage |
-  serve | ring | resilience | probe | sample, open set; cat=sample spans
-  are the async sampling pipeline's sample_produce / h2d_copy /
-  sample_wait intervals, sample/pipeline.py),
+  serve | ring | resilience | probe | sample | startup | compile, open
+  set; cat=sample spans are the async sampling pipeline's sample_produce
+  / h2d_copy / sample_wait intervals, sample/pipeline.py; cat=startup
+  spans are process_prelude {backend_live: int} and backend_init
+  {platform: str, count: int}, utils/platform.py; cat=compile spans are
+  obs/compiles.py's, one per request of the compiler: fun: str,
+  trace_s / lower_s / backend_s / retrieve_s: number >= 0, cache: str
+  (hit | miss | off), dur_s = trace_s + lower_s + backend_s),
   span_id: str (non-empty, unique within the stream),
   trace_id: str (non-empty; defaults to the run_id),
   parent_id: str | null (the enclosing span),
@@ -359,6 +364,11 @@ run_summary:
   device: object | absent  {platform: str, device_kind: str, count: int > 0}
           as JAX reports the device the run executed on (absent on
           summaries synthesized from a stream that died before its own)
+  compile_cache: object | absent  {persistent_cache_dir: str | null,
+          enabled: bool, requests / hits / misses: number >= 0,
+          trace_s / lower_s / backend_s / retrieve_s: number >= 0}: the
+          run's compile.* counters (obs/compiles.py) beside where the
+          persistent cache is
 """
 
 from __future__ import annotations
@@ -468,6 +478,20 @@ def validate_event(obj: Any) -> None:
         ):
             _fail("run_summary.memory must be an object with an "
                   "'available' bool")
+        cc = obj.get("compile_cache")
+        if cc is not None:
+            if not isinstance(cc, dict) or not isinstance(
+                cc.get("enabled"), bool
+            ):
+                _fail("run_summary.compile_cache must be an object with "
+                      "an 'enabled' bool")
+            for key in ("requests", "hits", "misses", "trace_s", "lower_s",
+                        "backend_s", "retrieve_s"):
+                if key in cc:  # a stream from before the counters has none
+                    _require_number(cc, key)
+                    if cc[key] < 0:
+                        _fail(f"run_summary.compile_cache.{key} must be "
+                              ">= 0")
         dev = obj.get("device")
         if dev is not None:
             if not isinstance(dev, dict):

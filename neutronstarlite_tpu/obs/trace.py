@@ -31,7 +31,15 @@ it: ``NTS_PROFILE_DIR``, a test, an operator attaching a capture — the
 program's spans sit on the profiler's clock beside the device's
 operations. The prefix tells them from the runtime's own TraceMe events
 (``PjitFunction(train_step)``). Spans emitted retroactively via
-``complete()`` (request/queue) already happened and cannot annotate.
+``complete()`` (request/queue, the ``startup`` spans that ended before the
+process had a tracer, obs/compiles' ``compile`` spans) already happened
+and cannot annotate.
+
+The process's tracers are known to this module, newest last
+(``newest()``, ``on_thread()``): what happens outside any trainer's call
+stack (a compile JAX reports through ``jax.monitoring``, the entry
+point's ``start_runtime()``) finds the tracer to emit through, and a span
+that ended before any tracer existed waits in ``defer()`` for the first.
 
 Usage::
 
@@ -55,6 +63,7 @@ import os
 import sys
 import threading
 import time
+import weakref
 from typing import Any, Optional
 
 from neutronstarlite_tpu.utils.logging import get_logger, process_index
@@ -82,6 +91,38 @@ def _annotation(name: str, attrs: dict):
     )
     ann.__enter__()
     return ann
+
+
+# The process's tracers, newest last (weakly: a tracer lives as long as its
+# trainer or server), the newest of them held strongly (as obs/flight holds
+# the newest ring: a reader that comes after the trainer is gone still finds
+# its registry; a tracer holds no trainer), and the spans that ended before
+# the first of them.
+_tracers: list = []
+_newest: Optional["Tracer"] = None
+_deferred: list = []
+_tracers_lock = threading.Lock()
+
+
+def newest() -> Optional["Tracer"]:
+    """The newest tracer of the process, enabled or not; None before the
+    first."""
+    return _newest
+
+
+def on_thread() -> Optional["Tracer"]:
+    """The newest tracer under which the calling thread has a span open
+    (a train-then-serve process has two over one registry), or None."""
+    with _tracers_lock:
+        live = [ref() for ref in reversed(_tracers)]
+    return next((t for t in live if t is not None and t.current() is not None), None)
+
+
+def defer(name: str, t0: float, dur_s: float, cat: str = "host", **attrs: Any) -> None:
+    """A span that ended before the process had a tracer (the runtime's
+    start): the first tracer made emits it, as a root, when it is made."""
+    with _tracers_lock:
+        _deferred.append(dict(attrs, name=name, t0=t0, dur_s=dur_s, cat=cat))
 
 
 # One process-wide id source: several tracers can share one registry (the
@@ -203,6 +244,15 @@ class Tracer:
             registry is not None
             and os.environ.get("NTS_TRACE", "1") != "0"
         )
+        if registry is not None:
+            global _newest
+            with _tracers_lock:
+                _newest = self
+                _tracers[:] = [r for r in _tracers if r() is not None]
+                _tracers.append(weakref.ref(self))
+                waiting, _deferred[:] = list(_deferred), []
+            for span in waiting:  # NTS_TRACE=0: dropped, as every span is
+                self.complete(**span)
 
     # ---- internals -------------------------------------------------------
     def _stack(self) -> list:

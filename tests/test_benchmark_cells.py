@@ -41,8 +41,10 @@ def over_limit(compared):
 @pytest.mark.parametrize("trace, reports", [
     (0, ["epoch_s", "peak_device_bytes", "setup_s"]),
     # a CPU rehearsal's trace has no device plane: the device's readers find nothing
-    (1, ["compile_s", "compiles_in_window", "datum_upload_s", "first_step_s",
-         "funnel_unspanned_s", "graph_build_s", "moe_load_max_over_mean", "step_dispatch_ms"]),
+    (1, ["compile_s", "compiles_in_window", "datum_upload_s", "first_step_backend_s",
+         "first_step_s", "first_step_trace_s", "funnel_unspanned_s", "graph_build_s",
+         "moe_load_max_over_mean", "runtime_start_s", "setup_cache_misses", "setup_compile_s",
+         "setup_unspanned_s", "step_dispatch_ms", "step_program_mb"]),
 ])
 def test_the_token_sequence_cell_rehearses(trace, reports):
     out = rehearse(REPO, CELL, trace)

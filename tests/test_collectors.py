@@ -3,8 +3,9 @@
 The collectors were previously exercised only incidentally through trainer
 smokes; these tests pin their contracts standalone: graceful degradation
 (CPU backends expose no memory_stats -> explicit nulls, 0/1-epoch runs ->
-null warm statistics), the compile-attribution arithmetic, and the
-persistent-cache probe — so a collector regression fails HERE with a
+null warm statistics), the first-against-warm arithmetic, and the
+compiler's snapshot (where the persistent cache is, and the run's
+``compile.*`` counters) — so a collector regression fails HERE with a
 named cause instead of somewhere inside a 40-second smoke.
 """
 
@@ -12,7 +13,7 @@ from __future__ import annotations
 
 import pytest
 
-from neutronstarlite_tpu.obs import collectors
+from neutronstarlite_tpu.obs import collectors, compiles, registry
 from neutronstarlite_tpu.utils.timing import PhaseTimers
 
 
@@ -63,7 +64,10 @@ def test_steady_state_stats_empty_and_single():
     assert one["epochs"] == 1 and one["first_s"] == 2.5
     # a 1-epoch run has no warm window: nulls, not fictitious zeros
     assert one["warm_median_s"] is None
-    assert one["first_to_warm_ratio"] is None
+    # the ratio of the two is no field any more: what the compiler took is
+    # measured (compile_cache_info), not read off the first epoch
+    assert set(one) == {"epochs", "first_s", "warm_median_s", "warm_mean_s",
+                        "compile_overhead_s"}
 
 
 def test_steady_state_stats_attribution_math():
@@ -72,7 +76,14 @@ def test_steady_state_stats_attribution_math():
     assert s["warm_median_s"] == 2.0  # median of [1, 2, 3]
     assert s["warm_mean_s"] == pytest.approx(2.0)
     assert s["compile_overhead_s"] == pytest.approx(3.0)  # 5 - 2
-    assert s["first_to_warm_ratio"] == pytest.approx(2.5)
+    # ... an inference; beside it the snapshot of what was measured: 2.5 s
+    # of the 3.0 were the compiler's, one program of two read from the cache
+    reg = _registry_with(requests=2, cache_hits=1, cache_misses=1, trace_s=0.5,
+                         lower_s=0.25, backend_s=1.75, retrieve_s=0.125)
+    info = collectors.compile_cache_info(reg)
+    assert info["trace_s"] + info["lower_s"] + info["backend_s"] == pytest.approx(2.5)
+    assert (info["requests"], info["hits"], info["misses"]) == (2, 1, 1)
+    assert info["retrieve_s"] == 0.125 <= info["backend_s"]
     # even warm count: midpoint interpolation
     s = collectors.steady_state_stats([4.0, 1.0, 3.0])
     assert s["warm_median_s"] == pytest.approx(2.0)
@@ -83,23 +94,39 @@ def test_steady_state_stats_clamps_negative_overhead():
     report negative compile overhead."""
     s = collectors.steady_state_stats([1.0, 2.0, 2.0])
     assert s["compile_overhead_s"] == 0.0
-    assert s["first_to_warm_ratio"] == pytest.approx(0.5)
+    # a run that compiled nothing (every program already loaded) says so
+    info = collectors.compile_cache_info(_registry_with())
+    assert {info[k] for k in SNAPSHOT_KEYS} == {0.0}
 
 
 # ---- compile_cache_info -----------------------------------------------------
+
+SNAPSHOT_KEYS = ("requests", "hits", "misses", "trace_s", "lower_s", "backend_s",
+                 "retrieve_s")
+
+
+def _registry_with(**counters):
+    """A registry whose ``compile.*`` counters stand as obs/compiles would
+    have left them."""
+    reg = registry.MetricsRegistry("t", algorithm="A", fingerprint="f")
+    for key, value in counters.items():
+        assert key in compiles.COUNTERS
+        reg.counter_add("compile." + key, value)
+    return reg
 
 
 def test_compile_cache_info_reports_the_configured_dir(tmp_path):
     import jax
 
-    info = collectors.compile_cache_info()
-    assert set(info) == {"persistent_cache_dir", "enabled"}
+    info = collectors.compile_cache_info(_registry_with())
+    assert set(info) == {"persistent_cache_dir", "enabled", *SNAPSHOT_KEYS}
     assert isinstance(info["enabled"], bool)
 
     before = jax.config.jax_compilation_cache_dir
     try:
         jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-        on = collectors.compile_cache_info()
+        on = collectors.compile_cache_info(_registry_with(requests=3))
+        assert on["requests"] == 3
         assert on["enabled"] is True
         assert on["persistent_cache_dir"] == str(tmp_path)
     finally:

@@ -493,6 +493,9 @@ def test_the_scope_table_covers_every_named_scope(trained):
     assert seqlm.scope_of("jit(step)/transpose(jvp(seq/moe/experts))/ragged_dot") == "seq/moe/experts"
     assert seqlm.scope_of("jit(step)/seq/mla/project/seq/mla/attend/while/body/dot") == "seq/mla/attend"
     assert seqlm.scope_of("jit(step)/convert_element_type") is None
+    # the same compile gave the step's size, where the backend analyses it
+    gauges = trainer.metrics.snapshot(include_hists=False)["gauges"]
+    assert gauges["step.generated_code_bytes"] >= 0
 
 
 def test_bfloat16_compute_stays_near_the_reference(tmp_path):

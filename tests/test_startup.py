@@ -9,6 +9,7 @@ import subprocess
 import sys
 
 import jax
+import pytest
 
 from neutronstarlite_tpu.utils import platform as nts_platform
 
@@ -105,3 +106,108 @@ def test_chip_smoke_refuses_the_cpu(tmp_path):
     assert "chip_smoke device: platform=cpu device_kind='cpu'" in r.stdout
     assert "JAX reports 'cpu'" in r.stderr
     assert not any(line.startswith("{") for line in r.stdout.splitlines())
+
+
+# ---- the runtime's start as spans (cat ``startup``) -------------------------
+
+PRELUDE_SCRIPT = """
+import json, os, sys, time
+{before_import}
+import neutronstarlite_tpu
+from neutronstarlite_tpu import obs
+with open("/proc/self/stat") as fh:
+    ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+suspended = time.clock_gettime(time.CLOCK_BOOTTIME) - time.clock_gettime(time.CLOCK_MONOTONIC)
+reg = obs.open_run("T")
+obs.Tracer(reg)
+obs.Tracer(reg)  # a second tracer: the span is the first one's alone
+obs.Tracer(obs.open_run("U"))
+spans = [r for r in reg.flight.records("span")]
+print(json.dumps({{"spans": spans, "proc_start": ticks / os.sysconf("SC_CLK_TCK") - suspended,
+                  "t_import": neutronstarlite_tpu._T_IMPORT, "now": time.perf_counter()}}))
+"""
+
+
+@pytest.mark.parametrize("before_import, backend_live", [
+    ("", 0),
+    ("import jax; jax.devices()", 1),  # as benchmark/run.py's device gate does
+])
+def test_process_prelude_runs_from_the_kernels_start_to_the_packages_import(
+        before_import, backend_live):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    r = subprocess.run(
+        [sys.executable, "-c", PRELUDE_SCRIPT.format(before_import=before_import)],
+        capture_output=True, text=True, timeout=120, env=env,
+    )
+    assert r.returncode == 0, r.stderr[-1500:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    (span,) = out["spans"]  # once a process, whatever the number of tracers
+    assert (span["name"], span["cat"], span["parent_id"]) == ("process_prelude", "startup", None)
+    assert span["backend_live"] == backend_live
+    assert abs(span["t0"] - out["proc_start"]) <= 0.02
+    assert span["t0"] + span["dur_s"] == pytest.approx(out["t_import"], abs=1e-9)
+    # on the clock of every other span: it ended before the script's last
+    # line, and an interpreter does not take a minute to reach its first
+    assert 0.0 < span["dur_s"] < 60.0 and span["t0"] + span["dur_s"] < out["now"]
+    if backend_live:  # the prelude holds JAX's import and the backend's start
+        assert span["dur_s"] > 0.2
+
+
+def test_start_runtime_times_the_backend_under_the_newest_tracer():
+    from neutronstarlite_tpu import obs
+
+    reg = obs.open_run("T")
+    tracer = obs.Tracer(reg)
+    with tracer.span("run", cat="lifecycle") as root:
+        facts = nts_platform.start_runtime()
+    # (the first tracer of a process also gets what waited for it)
+    (span,) = [r for r in reg.flight.records("span") if r["parent_id"] == root.span_id]
+    assert (span["name"], span["cat"]) == ("backend_init", "startup")
+    assert (span["platform"], span["count"]) == (facts["platform"], facts["count"])
+
+
+def test_a_backend_named_before_any_tracer_waits_for_the_first(monkeypatch):
+    from neutronstarlite_tpu import obs
+    from neutronstarlite_tpu.obs import trace
+
+    monkeypatch.setattr(trace, "_tracers", [])
+    monkeypatch.setattr(trace, "_newest", None)
+    monkeypatch.setattr(trace, "_deferred", [])
+    facts = nts_platform.start_runtime()  # a tool: the device first, a toolkit later
+    reg = obs.open_run("T")
+    obs.Tracer(reg)
+    (span,) = reg.flight.records("span")
+    assert (span["name"], span["cat"], span["parent_id"]) == ("backend_init", "startup", None)
+    assert (span["platform"], span["count"]) == ("cpu", facts["count"])
+
+
+def test_process_start_leaves_out_the_time_the_host_was_suspended(monkeypatch):
+    """``/proc`` counts from boot on a clock that runs through a suspend;
+    ``perf_counter`` reads one that stops: after 100 s of suspend the
+    process's start is 100 s earlier on the spans' clock."""
+    import time
+
+    awake = nts_platform.process_start()
+    real = time.clock_gettime
+    monkeypatch.setattr(
+        time, "clock_gettime",
+        lambda clock: real(clock) + (100.0 if clock == time.CLOCK_BOOTTIME else 0.0))
+    assert nts_platform.process_start() == pytest.approx(awake - 100.0, abs=1e-3)
+
+
+def test_process_start_is_none_where_proc_does_not_say(monkeypatch):
+    assert nts_platform.process_start() is not None  # Linux: the tests' machine
+    real_open = open
+
+    def no_proc(path, *a, **k):
+        if path == "/proc/self/stat":
+            raise OSError("no /proc here")
+        return real_open(path, *a, **k)
+
+    monkeypatch.setattr("builtins.open", no_proc)
+    assert nts_platform.process_start() is None
+    from neutronstarlite_tpu.obs import trace
+
+    monkeypatch.setattr(trace, "_deferred", [])
+    nts_platform.note_package_import(0.0)  # and then the span is not emitted
+    assert trace._deferred == []

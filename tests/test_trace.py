@@ -789,7 +789,8 @@ def test_run_level_stages_and_a_second_run(loop_run):
     assert around == ["run_begin", "ckpt_begin", "ckpt_final", "final_eval",
                       "finalize_metrics"]
     final = next(s for s in spans if s["name"] == "final_eval")
-    kids = [s["name"] for s in spans if s["parent_id"] == final["span_id"]]
+    kids = [s["name"] for s in spans
+            if s["parent_id"] == final["span_id"] and s["cat"] != "compile"]
     assert kids == {
         "fullbatch": ["eval_forward", "host_accuracy"],
         "dist": ["eval_forward", "host_accuracy"], "sampled": [],
@@ -891,3 +892,323 @@ def test_alignment_anchors_on_step_device_not_the_epoch_span_end(tmp_path):
         paths.append(p)
     streams = trace_timeline.load_streams(paths)
     assert [s.align for s in streams] == pytest.approx([0.0, 0.0], abs=1e-9)
+
+
+# ---- every compile as a span (obs/compiles) --------------------------------
+# The listener is process-wide and stays once installed (an entry point's
+# configure_compile_cache() installs it), so every test here and above holds
+# with it on.
+
+
+@pytest.fixture
+def traced():
+    """(registry, tracer) with the compile listener installed."""
+    from neutronstarlite_tpu.obs import compiles
+
+    compiles.install()
+    reg = registry.MetricsRegistry("t", algorithm="A", fingerprint="f")
+    return reg, Tracer(reg)
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """JAX's persistent compile cache on, in a directory of this test."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = {
+        name: getattr(jax.config, name) for name in (
+            "jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    }
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    yield str(tmp_path / "cache")
+    for name, value in before.items():
+        jax.config.update(name, value)
+    cc.reset_cache()
+
+
+def _fresh_step(tag):
+    """A jitted function no test has compiled yet (its name and its
+    constant make the program new), which calls a jitted function."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        return x @ x
+
+    def _step(x):
+        return jnp.sum(inner(x)) + float(len(tag))
+
+    _step.__name__ = f"_step_{tag}"
+    return jax.jit(_step), jnp.ones((32, 32)), f"jit(_step_{tag})"
+
+
+def _compile_spans(reg, fun=None):
+    return [s for s in reg.flight.records("span") if s["cat"] == "compile"
+            and (fun is None or s["fun"] == fun)]
+
+
+def test_a_first_call_under_an_open_span_is_one_compile_span(traced, persistent_cache):
+    import jax
+
+    reg, tr = traced
+    f, x, fun = _fresh_step("miss_then_hit")
+    with tr.span("step_dispatch", cat="stage") as parent:
+        f(x).block_until_ready()
+    (miss,) = _compile_spans(reg, fun)
+    assert miss["name"] == "compile" and miss["parent_id"] == parent.span_id
+    assert miss["cache"] == "miss" and miss["retrieve_s"] == 0.0
+    assert miss["dur_s"] == pytest.approx(
+        miss["trace_s"] + miss["lower_s"] + miss["backend_s"], abs=1e-12)
+    assert min(miss["trace_s"], miss["lower_s"], miss["backend_s"]) > 0.0
+    # on the clock of the live spans, and inside its parent but for its
+    # start: a retroactive span starts at its end less the three durations
+    assert miss["t0"] + miss["dur_s"] <= parent.t0 + parent.dur_s
+    assert miss["t0"] + miss["dur_s"] >= parent.t0
+    assert schema.validate_event(miss) is None
+
+    f(x)  # compiled: JAX reports nothing, so there is nothing to add
+    assert len(_compile_spans(reg, fun)) == 1
+
+    jax.clear_caches()  # the same program again: the persistent cache has it
+    with tr.span("step_dispatch", cat="stage"):
+        f(x).block_until_ready()
+    _, hit = _compile_spans(reg, fun)
+    assert hit["cache"] == "hit" and 0.0 < hit["retrieve_s"] <= hit["backend_s"]
+    assert hit["dur_s"] == pytest.approx(
+        hit["trace_s"] + hit["lower_s"] + hit["backend_s"], abs=1e-12)
+    got = reg.snapshot()["counters"]
+    assert got["compile.cache_hits"] >= 1 and got["compile.cache_misses"] >= 1
+    assert got["compile.requests"] == got["compile.cache_hits"] + got["compile.cache_misses"]
+    assert got["compile.backend_s"] >= miss["backend_s"] + hit["backend_s"] - 1e-9
+    assert got["compile.retrieve_s"] >= hit["retrieve_s"] - 1e-9
+
+
+def test_the_trace_seconds_are_the_lowered_functions_not_a_sum(traced):
+    """``inner`` reports a trace duration of its own before ``_step``'s:
+    the span is ``_step``'s alone, and with the cache off says ``off``."""
+    from jax import monitoring
+    from jax._src import monitoring as monitoring_src
+
+    from neutronstarlite_tpu.obs import compiles
+
+    reg, tr = traced
+    f, x, fun = _fresh_step("nested")
+    seen = []
+
+    def spy(name, seconds, fun_name="", **_):
+        if name == compiles.TRACE:
+            seen.append((fun_name, seconds))
+
+    monitoring.register_event_duration_secs_listener(spy)
+    try:
+        with tr.span("step_dispatch", cat="stage"):
+            f(x).block_until_ready()
+    finally:
+        monitoring_src.unregister_event_duration_listener(spy)
+    (span,) = _compile_spans(reg, fun)
+    assert span["cache"] == "off"
+    assert "inner" in [name for name, _ in seen]
+    assert span["trace_s"] == dict(seen)["_step_nested"]
+    assert not [s for s in _compile_spans(reg) if s["fun"] == "jit(inner)"]
+
+
+def test_a_compile_with_no_span_open_is_neither_spanned_nor_counted(traced):
+    """A caller's own programs (the benchmark's check, after ``run()`` has
+    closed its root) are not the run's: the ring and the counters hold none."""
+    reg, tr = traced
+    f, x, fun = _fresh_step("outside")
+    f(x).block_until_ready()
+    assert not _compile_spans(reg)
+    assert not [k for k in reg.snapshot()["counters"] if k.startswith("compile.")]
+
+
+def test_nts_trace_0_gives_no_compile_span_and_keeps_the_counters(monkeypatch):
+    from neutronstarlite_tpu.obs import compiles
+
+    monkeypatch.setenv("NTS_TRACE", "0")
+    compiles.install()
+    reg = registry.MetricsRegistry("t", algorithm="A", fingerprint="f")
+    tr = Tracer(reg)
+    f, x, fun = _fresh_step("untraced")
+    with tr.span("step_dispatch", cat="stage"):
+        f(x).block_until_ready()
+    assert not reg.flight.records("span")
+    got = reg.snapshot()["counters"]
+    assert got["compile.requests"] >= 1 and got["compile.backend_s"] > 0.0
+
+
+def test_a_compile_inside_an_outer_trace_leaves_the_outer_ones_seconds(traced):
+    """``jax.ensure_compile_time_eval`` compiles and runs ``inner`` while
+    ``_step`` is traced: that request ends before ``_step``'s trace does,
+    and ``_step``'s span still carries its own trace seconds."""
+    import jax
+    import jax.numpy as jnp
+
+    reg, tr = traced
+
+    @jax.jit
+    def _eager_inner(x):
+        return x * 3.0 + 1.0
+
+    def _step_around(x):
+        with jax.ensure_compile_time_eval():
+            c = _eager_inner(jnp.ones((4,)))
+        return jnp.sum(x) + c[0]
+
+    with tr.span("step_dispatch", cat="stage") as parent:
+        jax.jit(_step_around)(jnp.ones((8,))).block_until_ready()
+    by_fun = {s["fun"]: s for s in _compile_spans(reg)}
+    inner, outer = by_fun["jit(_eager_inner)"], by_fun["jit(_step_around)"]
+    assert inner["parent_id"] == outer["parent_id"] == parent.span_id
+    assert inner["t0"] + inner["dur_s"] <= outer["t0"] + outer["dur_s"]
+    assert outer["trace_s"] > inner["trace_s"] > 0.0  # the outer trace holds the whole inner request
+    assert outer["trace_s"] >= inner["dur_s"]
+
+
+def test_the_listener_pairs_a_lowering_with_its_trace_across_a_nested_request(traced):
+    """The events as JAX sends them when a program compiles while an outer
+    one is being lowered: the inner request ends first and does not take
+    the outer function's trace with it; a trace no lowering claims (an
+    ``eval_shape``) is dropped once ``TRACES_KEPT`` newer ones came."""
+    from neutronstarlite_tpu.obs import compiles
+
+    reg, tr = traced
+    with tr.span("step_dispatch", cat="stage"):
+        compiles._on_duration(compiles.TRACE, 0.5, fun_name="outer_fn")
+        compiles._on_duration(compiles.TRACE, 0.001, fun_name="inner_fn")  # inside the lowering
+        compiles._on_duration(compiles.LOWER, 5.0, fun_name="jit(inner_fn)")
+        compiles._on_duration(compiles.BACKEND, 0.25, fun_name="jit(inner_fn)")
+        compiles._on_duration(compiles.LOWER, 0.0, fun_name="jit(outer_fn)")
+        compiles._on_duration(compiles.BACKEND, 2.0, fun_name="jit(outer_fn)")
+    by_fun = {s["fun"]: s for s in _compile_spans(reg)}
+    assert by_fun["jit(outer_fn)"]["trace_s"] == 0.5
+    assert by_fun["jit(outer_fn)"]["dur_s"] == pytest.approx(2.5)
+    assert by_fun["jit(inner_fn)"]["trace_s"] == 0.0  # traced after its lowering's start: not its own
+    for i in range(3 * compiles.TRACES_KEPT):
+        compiles._on_duration(compiles.TRACE, 0.1, fun_name=f"shape_only_{i}")
+    assert len(compiles._pending.traced) == compiles.TRACES_KEPT
+    compiles._pending.traced.clear()
+
+
+def test_a_listener_that_raises_is_logged_and_never_fails_the_compile(traced, monkeypatch):
+    from neutronstarlite_tpu.obs import compiles
+
+    reg, tr = traced
+
+    def broken():
+        raise RuntimeError("no tracer today")
+
+    monkeypatch.setattr(compiles.trace, "on_thread", broken)
+    f, x, fun = _fresh_step("listener_raises")
+    with tr.span("step_dispatch", cat="stage"):
+        assert float(f(x)) == 32.0 * 32.0 * 32.0 + len("listener_raises")
+    assert not _compile_spans(reg)
+    compiles._on_duration(compiles.TRACE, "not a number", fun_name="f")  # every branch is guarded
+    compiles._on_event(compiles.HIT, unexpected=object())
+
+
+def test_the_span_goes_through_the_tracer_the_thread_has_a_span_open_under(traced):
+    """A train-then-serve process has two tracers over one registry: the
+    newer one does not take the older one's compiles from it."""
+    reg, older = traced
+    newer = Tracer(reg)
+    f, x, fun = _fresh_step("two_tracers")
+    with older.span("step_dispatch", cat="stage") as parent:
+        f(x).block_until_ready()
+    (span,) = _compile_spans(reg, fun)
+    assert span["parent_id"] == parent.span_id and newer.current() is None
+
+
+def test_a_span_deferred_before_the_first_tracer_is_emitted_once_as_a_root(monkeypatch):
+    from neutronstarlite_tpu.obs import trace
+
+    monkeypatch.setattr(trace, "_tracers", [])
+    monkeypatch.setattr(trace, "_newest", None)
+    monkeypatch.setattr(trace, "_deferred", [])
+    assert trace.newest() is None and trace.on_thread() is None
+    trace.defer("process_prelude", 10.0, 2.5, cat="startup", backend_live=1)
+    reg = registry.MetricsRegistry("t", algorithm="A", fingerprint="f")
+    first = Tracer(reg)
+    assert trace.newest() is first
+    Tracer(registry.MetricsRegistry("u", algorithm="A", fingerprint="f"))
+    (span,) = reg.flight.records("span")
+    assert (span["name"], span["cat"], span["t0"], span["dur_s"], span["parent_id"],
+            span["backend_live"]) == ("process_prelude", "startup", 10.0, 2.5, None, 1)
+    # NTS_TRACE=0: dropped with every other span, not kept for a later tracer
+    trace.defer("process_prelude", 10.0, 2.5, cat="startup")
+    monkeypatch.setenv("NTS_TRACE", "0")
+    off = registry.MetricsRegistry("v", algorithm="A", fingerprint="f")
+    Tracer(off)
+    assert not off.flight.records("span") and not trace._deferred
+
+
+def _seq_trainer(tmp_path):
+    from test_seqlm import make_trainer
+
+    return make_trainer(tmp_path)
+
+
+@pytest.mark.parametrize("family, step", [
+    ("fullbatch", "train_step"), ("seqlm", "_step"),
+])
+def test_the_first_epoch_holds_the_steps_compile_and_the_second_none(
+        family, step, tmp_path):
+    from neutronstarlite_tpu.obs import compiles
+
+    compiles.install()
+    trainer = _seq_trainer(tmp_path) if family == "seqlm" else _loop_trainer(family)
+    trainer.run()
+    spans = _spans(trainer)
+    by_id = {s["span_id"]: s for s in spans}
+    first, second = [s for s in spans if s["name"] == "epoch"]
+
+    def under(span, epoch):
+        while span is not None and span["span_id"] != epoch["span_id"]:
+            span = by_id.get(span["parent_id"])
+        return span is not None
+
+    compiled = [s for s in spans if s["cat"] == "compile"]
+    in_first = [s for s in compiled if under(s, first)]
+    the_step = [s for s in in_first if step in s["fun"]]
+    assert len(the_step) == 1, [s["fun"] for s in in_first]
+    assert by_id[the_step[0]["parent_id"]]["name"] == "step_dispatch"
+    assert the_step[0]["backend_s"] > 0.0 and the_step[0]["trace_s"] > 0.0
+    assert not [s for s in compiled if under(s, second)]
+    # every compile of the run is under a span of the run, the ring kept its
+    # first record, and the summary carries the counters
+    assert all(s["parent_id"] in by_id for s in compiled)
+    assert trainer.metrics.flight.records("run_start")
+    assert len(compiled) < 400
+    summary = trainer.run_summary_record
+    cache = summary["compile_cache"]
+    assert set(cache) == {"persistent_cache_dir", "enabled", "requests", "hits", "misses",
+                          "trace_s", "lower_s", "backend_s", "retrieve_s"}
+    assert cache["requests"] >= len(compiled) >= 1
+    assert cache["backend_s"] >= sum(s["backend_s"] for s in compiled) - 1e-6
+    assert summary["counters"]["compile.requests"] == cache["requests"]
+    assert schema.validate_event(summary) is None
+
+
+def test_the_newest_tracers_registry_is_found_after_its_trainer_is_gone():
+    """The benchmark's readers run when the trainer has gone out of scope:
+    the ring is held by obs/flight, the registry (its gauges) through the
+    newest tracer."""
+    import gc
+
+    from neutronstarlite_tpu.obs import trace
+
+    reg = registry.MetricsRegistry("t", algorithm="A", fingerprint="f")
+    reg.gauge_set("step.generated_code_bytes", 345)
+    tr = Tracer(reg)
+    del reg, tr
+    gc.collect()
+    gauges = trace.newest().registry.snapshot(include_hists=False)["gauges"]
+    assert gauges["step.generated_code_bytes"] == 345
