@@ -750,6 +750,13 @@ class SeqLMTrainer(ToolkitBase):
         self.metrics.gauge_set("kda.key_heads", spec.kda_heads)
         self.metrics.gauge_set("kda.value_heads", spec.kda_value_heads)
         self.metrics.gauge_set("kda.decay_per_head", int(spec.decay_per_head))
+        # delta-rule layers whose chunk walk lowers to ops/delta_rule.py's kernels, over all of
+        # them (one shape a stack: all or none), and the rows of v a grid step of theirs holds
+        rows = spec.batch * spec.kda_value_heads
+        fused = "kda" in spec.mixers and jax.default_backend() == "tpu" and delta_rule.kernel_takes(
+            spec.batch * spec.kda_heads, rows, spec.length, spec.kda_dim, spec.kda_dim, spec.kda_chunk)
+        self.metrics.gauge_set("kda.recur_fused", float(fused))
+        self.metrics.gauge_set("kda.rows_per_block", delta_rule.rows_per_block(rows) if fused else 0)
         log.info(
             "SEQLM: %d layers (%d dense + %d expert; mixers %s), experts %d..%d of %d held, "
             "vocabulary slice %d, %d parameters; a step is %d sequences of %d tokens; corpus of "

@@ -184,8 +184,14 @@ def test_worker_paths_agree(tmp_path, monkeypatch):
     subprocess rebuilds the graph from the cached edge list, and the
     native OpenMP builder orders tie edges nondeterministically per build
     — a different per-segment summation order breaks bitwise equality for
-    reasons that have nothing to do with the layout plumbing under test."""
+    reasons that have nothing to do with the layout plumbing under test.
+    For the same reason the workers' CPU backend runs single-threaded: on a
+    loaded machine (the suite's other workers) its thread pool splits a sum
+    differently from one process to the next, and the three losses then
+    differ in the last bit, whichever path falls out (seen in PR 38's runs,
+    and reproduced beside eight busy processes)."""
     env = dict(os.environ)
+    env["XLA_FLAGS"] = env.get("XLA_FLAGS", "") + " --xla_cpu_multi_thread_eigen=false"
     env["JAX_PLATFORMS"] = "cpu"
     env["NTS_BENCH_CACHE"] = str(tmp_path)
     env["NTS_NO_NATIVE"] = "1"

@@ -117,6 +117,15 @@ def test_the_per_head_chunk_path_is_the_recurrence(rng, gate, chunk, each):
         assert rel(a, b) < (2e-4 if name == "g" else 5e-5), name
 
 
+@pytest.mark.parametrize("dtype, limit", [(jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("gate", [0.1, 3.0, 60.0])
+@pytest.mark.parametrize("each", [1, 2])
+def test_the_kernel_is_the_scan_with_a_decay_per_head(rng, each, gate, dtype, limit):
+    """ops/delta_rule.py's kernels (interpret mode) against its scan: a
+    key head beside its one or two value heads in a row block."""
+    test_kda.the_kernel_is_the_scan(rng, True, each, gate, dtype, limit)
+
+
 @pytest.mark.parametrize("chunk", [64, 16])
 def test_the_per_head_chunk_path_in_bfloat16_stays_near_the_recurrence(rng, chunk):
     """The products read bfloat16 operands (the decay, the system and the
@@ -461,6 +470,7 @@ def test_the_trainer_runs_counts_and_names_its_scopes(tmp_path):
     assert (gauges["seq.kda_layers"], gauges["seq.gqa_layers"], gauges["seq.mla_layers"]) == (3, 1, 0)
     assert (gauges["kda.key_heads"], gauges["kda.value_heads"], gauges["kda.decay_per_head"],
             gauges["kda.chunk"]) == (2, 4, 1, 16)
+    assert (gauges["kda.recur_fused"], gauges["kda.rows_per_block"]) == (0.0, 0)
     assert trainer.metrics.counter_get("kda.token_layers") == 2 * 64 * 3  # epochs x tokens x layers
     assert trainer.metrics.counter_get("gqa.token_layers") == 2 * 64
     assert trainer.metrics.counter_get("seq.tokens") == 2 * 64

@@ -126,6 +126,85 @@ def test_the_inverse_of_a_unit_lower_triangle(rng):
     assert rel(got, want) < 1e-5
 
 
+# ---- the chunk walk as one kernel (interpret mode here) against the scan
+
+def walk_inputs(rng, key_rows, each, positions, dk, dv, gate, per_head, dtype):
+    """The walks' operands: unit q and k, a cumulative log-decay per
+    channel ``[N, S, dk]`` or per head ``[N, S]`` over chunks of 16."""
+    rows = key_rows * each
+    unit = lambda t: t / np.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    q, k = (jnp.asarray(unit(rng.standard_normal((key_rows, positions, dk))), dtype) for _ in "qk")
+    v = jnp.asarray(rng.standard_normal((rows, positions, dv)), dtype)
+    g = -rng.uniform(0.0, gate, (rows, positions, 1 if per_head else dk)).astype(np.float32)
+    decay = delta_rule.chunk_log_decay(jnp.asarray(g), 16).reshape(rows, positions, *([] if per_head else [dk]))
+    beta = jnp.asarray(rng.uniform(0.0, 1.0, (rows, positions)).astype(np.float32))
+    return q, k, v, decay, beta
+
+
+def the_kernel_is_the_scan(rng, per_head, each, gate, dtype, limit, positions=32):
+    """Outputs and all five gradients of the kernels (Pallas' interpret
+    mode) against the scan's, two row blocks of eight value rows."""
+    cast = compute_cast("bfloat16" if dtype == jnp.bfloat16 else None)
+    args = walk_inputs(rng, 16 // each, each, positions, 8, 8, gate, per_head, dtype)
+    weight = jnp.asarray(rng.standard_normal(args[2].shape).astype(np.float32))
+
+    def both(form):
+        out, pull = jax.vjp(lambda *a: delta_rule.recurrence(*a, cast, 16, form), *args)
+        return out, pull(weight.astype(out.dtype))
+
+    (got, d_got), (want, d_want) = both("interpret"), both("scan")
+    assert got.dtype == dtype and np.all(np.isfinite(np.asarray(got, np.float32)))
+    assert rel(got, want) < limit
+    for name, a, b in zip("q k v decay beta".split(), d_got, d_want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert np.all(np.isfinite(np.asarray(a, np.float32))) and rel(a, b) < limit, name
+
+
+@pytest.mark.parametrize("dtype, limit", [(jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("gate", [0.1, 3.0, 60.0])
+@pytest.mark.parametrize("each", [1, 2])
+def test_the_kernel_is_the_scan_with_a_decay_per_channel(rng, each, gate, dtype, limit):
+    the_kernel_is_the_scan(rng, False, each, gate, dtype, limit)
+
+
+def test_the_kernel_carries_the_state_across_grid_steps_and_zeroes_it_for_a_row_block(rng):
+    """512 positions are two grid steps of four chunks of 64; 16 rows are two
+    row blocks: the second starts from zero, whatever the first left in
+    the scratch (what it gives is what it gives alone), and the backward
+    kernel's cotangent of the state likewise."""
+    cast = compute_cast(None)
+    q, k, v, decay, beta = walk_inputs(rng, 16, 1, 512, 8, 8, 0.05, False, jnp.float32)
+    decay = delta_rule.chunk_log_decay(jnp.diff(decay, axis=1, prepend=0.0), 64).reshape(decay.shape)
+    weight = jnp.asarray(rng.standard_normal(v.shape).astype(np.float32))
+
+    def both(form, rows=slice(None)):
+        args = tuple(t[rows] for t in (q, k, v, decay, beta))
+        out, pull = jax.vjp(lambda *a: delta_rule.recurrence(*a, cast, 64, form), *args)
+        return out, pull(weight[rows])
+
+    (got, d_got), (want, d_want) = both("interpret"), both("scan")
+    assert rel(got, want) < 1e-5 and rel(got[:, 256:], want[:, 256:]) < 1e-5
+    assert all(rel(a, b) < 1e-5 for a, b in zip(d_got, d_want))
+    alone, d_alone = both("interpret", slice(8, 16))
+    assert np.array_equal(np.asarray(got[8:]), np.asarray(alone))
+    assert all(np.array_equal(np.asarray(a[8:]), np.asarray(b)) for a, b in zip(d_got, d_alone))
+    # the later steps do read the state: from zero they give another result
+    fresh, _ = jax.vjp(lambda *a: delta_rule.recurrence(*a, cast, 64, "interpret"),
+                       *(t[:, 256:] for t in (q, k, v, decay, beta)))
+    assert rel(fresh, got[:, 256:]) > 0.05
+
+
+def test_the_sizes_the_kernels_take():
+    takes = delta_rule.kernel_takes
+    assert takes(64, 64, 8192, 128, 128, 64) and takes(32, 64, 8192, 128, 128, 64)
+    assert not takes(64, 64, 8192, 64, 128, 64)  # a key head of 64: the scan
+    assert not takes(64, 64, 8192, 128, 128, 8)  # a chunk that is no multiple of SUB_BLOCK
+    assert not takes(6, 12, 8192, 128, 128, 64)  # 12 rows are no whole blocks of 8
+    assert not takes(4, 64, 8192, 128, 128, 64)  # 16 value heads a key head: a block of 8 holds half a key head
+    assert takes(64, 64, 192, 128, 128, 64) and not takes(64, 64, 200, 128, 128, 64)  # whole chunks
+    assert takes(2, 4, 64, 128, 128, 64) and delta_rule.rows_per_block(4) == 4
+
+
 # ---- the convolution and the norms
 
 def _conv_by_hand(x, w):
@@ -283,6 +362,36 @@ def test_the_operand_kernels_compile_for_the_chip(one_chip, scale):
     assert "kda_conv_operand" in text and "kda_conv_operand_backward" in text
 
 
+@pytest.mark.parametrize("per_head, each", [(False, 1), (True, 2)])
+def test_the_recurrence_kernels_compile_for_the_chip_under_their_scope(one_chip, per_head, each):
+    """Mosaic takes both kernels at a layer's sizes in the benchmark's two
+    cells (64 rows of v, heads of 128, chunks of 64; a decay per channel,
+    and a decay per head with two value heads a key head), and the step's
+    scope table finds both custom calls under the recurrence's scope (a
+    compile for a described v5e: nothing runs)."""
+    rows, s, d = 64, 256, 128
+    cast = compute_cast("bfloat16")
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)  # noqa: E731
+    q, v = shape(rows // each, s, d), shape(rows, s, d)
+    log_decay = shape(rows, s // 64, 64, 1 if per_head else d, dtype=jnp.float32)
+    assert delta_rule.kernel_takes(rows // each, rows, s, d, d, 64)
+
+    def step(q, k, v, log_decay, beta, g):
+        def recur(*a):
+            with jax.named_scope("seq/kda/recur"):
+                return delta_rule.recurrence(
+                    *a[:3], a[3].reshape(rows, s, *([] if per_head else [d])), a[4], cast, 64, "kernel")
+        out, pull = jax.vjp(recur, q, k, v, log_decay, beta)
+        return out, pull(g)
+
+    text = jax.jit(step).lower(q, q, v, log_decay, shape(rows, s, dtype=jnp.float32), v).compile().as_text()
+    table = seqlm.scope_table_of(text)
+    calls = {name: scope for name, scope in table.items() if name.startswith("kda_recurrence")}
+    assert any(n.startswith("kda_recurrence_backward") for n in calls), sorted(table)[:40]
+    assert any(not n.startswith("kda_recurrence_backward") for n in calls)
+    assert set(calls.values()) == {"seq/kda/recur"}
+
+
 # ---- the mixer against the reference's, one layer
 
 def test_the_delta_mixer_is_the_reference_mixer(tmp_path, rng):
@@ -386,6 +495,8 @@ def test_the_counters_and_gauges_of_the_new_layers(tmp_path):
     trainer.run()
     gauges = trainer.metrics.snapshot()["gauges"]
     assert (gauges["seq.kda_layers"], gauges["seq.mla_layers"], gauges["kda.chunk"]) == (4, 1, 8)
+    # off the TPU (and at heads of 8) the chunk walk is the scan: no layer lowers to the kernels
+    assert (gauges["kda.recur_fused"], gauges["kda.rows_per_block"]) == (0.0, 0)
     assert trainer.metrics.counter_get("kda.token_layers") == 2 * 64 * 4  # epochs x tokens x layers
     assert trainer.metrics.counter_get("seq.tokens") == 2 * 64
 
