@@ -51,7 +51,8 @@ one mixer kind, each run stacked on a leading axis under its own key
 (``moe``, then ``moe1``, ``moe2``, ...) and walked by one ``lax.scan`` (a
 stack of one kind is the single run ``moe``); every layer recomputed in the
 backward (``jax.checkpoint``); the head and the loss in chunks of tokens
-(the ``[tokens, vocab]`` logits never exist whole); Adam.
+(the ``[tokens, vocab]`` logits never exist whole); Adam, a run's routed
+experts updated as soon as that run's backward is done (``_step``).
 PRECISION:bfloat16 computes the products in bfloat16 over the float32
 masters; norms, rotary, the softmax state, the delta rule's decay, system
 and state, the short convolution and its two gates, the router (its
@@ -81,8 +82,8 @@ from neutronstarlite_tpu.graph.dataset import TokenDatum
 from neutronstarlite_tpu.models.base import ToolkitBase, register_algorithm
 from neutronstarlite_tpu.nn import seq as nnseq
 from neutronstarlite_tpu.nn.layers import compute_cast
-from neutronstarlite_tpu.nn.param import AdamConfig, adam_init, adam_update
-from neutronstarlite_tpu.ops import delta_rule, moe
+from neutronstarlite_tpu.nn.param import AdamConfig, AdamState, adam_init, adam_update
+from neutronstarlite_tpu.ops import causal_attention, delta_rule, moe
 from neutronstarlite_tpu.ops.causal_attention import causal_edge_attention
 from neutronstarlite_tpu.ops.conv_operand import conv_operand
 from neutronstarlite_tpu.resilience.faults import fault_point
@@ -575,6 +576,19 @@ def gated_attention(lp, x, spec: SeqSpec, cast, mid):
         return x + nnseq.matmul(out, lp["wo"], cast)
 
 
+def attention_tiles(spec: SeqSpec):
+    """(key heads, group, positions, block, dk, dv) of the stack's softmax
+    attention as ops/causal_attention.py tiles it; None where no layer
+    attends that way."""
+    block = causal_attention.resolve_block(spec.length, spec.block)
+    if "gqa" in spec.mixers:
+        return (spec.batch * spec.kv_heads, spec.heads // spec.kv_heads, spec.length, block, spec.v_head,
+                spec.v_head)
+    if "mla" in spec.mixers:
+        return spec.batch * spec.heads, 1, spec.length, block, spec.nope + spec.rope, spec.v_head
+    return None
+
+
 OUT_GATES = {"sigmoid": {}, "silu": {"activation": jax.nn.silu}}  # gated_rms_norm's default is sigmoid
 
 
@@ -723,36 +737,73 @@ def expert_mlp(lp, bias, x, spec: SeqSpec, cast):
     return out, plan.group_sizes, choice
 
 
+def _embed(p, _, tokens):
+    with jax.named_scope("seq/embed"):
+        return p["embed"][tokens.reshape(-1)], None
+
+
+def _dense(p, x, spec: SeqSpec, cast, mid, i: int):
+    kind = spec.mixers[i]
+    return jax.checkpoint(lambda lp, x: dense_layer(lp, x, spec, cast, mid, kind))(p[dense_key(i)], x), None
+
+
+def _run(p, x, spec: SeqSpec, cast, mid, run_bias, name: str, kind: str):
+    mixer = MIXERS[kind]
+
+    @jax.checkpoint
+    def layer(x, lp, b):
+        x = mixer(lp, x, spec, cast, mid)
+        return expert_mlp(lp, b, x, spec, cast)
+
+    def body(x, lp_b):
+        x, size, choice = layer(x, *lp_b)
+        return x, (size, choice)
+
+    return lax.scan(body, x, (p[name], run_bias))
+
+
+def stack_parts(bias, tokens, spec: SeqSpec, cast, mid):
+    """The stack below the head as its parts, in order: embed, each leading
+    dense layer, each run of expert layers. A part is (the parameter tree's
+    top-level keys it reads, a function of those parameters and the
+    residual stream it is handed, giving the stream it hands on and, for a
+    run, (rows per held expert, choices), else None)."""
+    parts = [(("embed",), functools.partial(_embed, tokens=tokens))]
+    for i in range(spec.dense_layers):
+        parts.append(((dense_key(i),), functools.partial(_dense, spec=spec, cast=cast, mid=mid, i=i)))
+    for name, kind, start, count in spec.runs:
+        # the run's rows of the bias; a run of all the expert layers takes it whole
+        run_bias = bias if count == spec.moe_layers else bias[start: start + count]
+        parts.append(((name,), functools.partial(_run, spec=spec, cast=cast, mid=mid, run_bias=run_bias,
+                                                 name=name, kind=kind)))
+    return parts
+
+
+ROUTED = ("eg", "eu", "ed")  # a run's routed experts' leaves
+
+
+def _take(tree, keys) -> Dict[str, Any]:
+    return {k: tree[k] for k in keys}
+
+
+def _routed(found):
+    """(rows per held expert [L, held], the choices [L, T, k]) of the runs'
+    (sizes, choices)."""
+    sizes, choices = zip(*found)
+    if len(sizes) == 1:
+        return sizes[0], choices[0]
+    return jnp.concatenate(sizes), jnp.concatenate(choices)
+
+
 def hidden_states(params, bias, tokens, spec: SeqSpec, cast, mid):
     """(the residual stream [T, hidden] after the last layer, rows per held
     expert [L, held], the choices [L, T, k]) of ``tokens`` [batch, length]."""
-    with jax.named_scope("seq/embed"):
-        x = params["embed"][tokens.reshape(-1)]
-    for i in range(spec.dense_layers):
-        kind = spec.mixers[i]
-        x = jax.checkpoint(lambda lp, x: dense_layer(lp, x, spec, cast, mid, kind))(
-            params[dense_key(i)], x)
-    sizes, choices = [], []
-    for name, kind, start, count in spec.runs:
-        mixer = MIXERS[kind]
-
-        @jax.checkpoint
-        def layer(x, lp, b):
-            x = mixer(lp, x, spec, cast, mid)
-            return expert_mlp(lp, b, x, spec, cast)
-
-        def body(x, lp_b):
-            x, size, choice = layer(x, *lp_b)
-            return x, (size, choice)
-
-        # the run's rows of the bias; a run of all the expert layers takes it whole
-        run_bias = bias if count == spec.moe_layers else bias[start: start + count]
-        x, (size, choice) = lax.scan(body, x, (params[name], run_bias))
-        sizes.append(size)
-        choices.append(choice)
-    if len(sizes) == 1:
-        return x, sizes[0], choices[0]
-    return x, jnp.concatenate(sizes), jnp.concatenate(choices)
+    x, found = None, []
+    for keys, part in stack_parts(bias, tokens, spec, cast, mid):
+        x, routed = part(_take(params, keys), x)
+        if routed is not None:
+            found.append(routed)
+    return (x, *_routed(found))
 
 
 def head_loss(params, x, tokens, spec: SeqSpec, cast):
@@ -908,6 +959,13 @@ class SeqLMTrainer(ToolkitBase):
             spec.batch * spec.kda_heads, rows, spec.length, spec.kda_dim, spec.kda_dim, spec.kda_chunk)
         self.metrics.gauge_set("kda.recur_fused", float(fused))
         self.metrics.gauge_set("kda.rows_per_block", delta_rule.rows_per_block(rows) if fused else 0)
+        # whether the stack's softmax attention lowers to ops/causal_attention.py's kernels, and
+        # the query rows a grid step of theirs holds
+        tiles = attention_tiles(spec)
+        on_kernel = tiles is not None and jax.default_backend() == "tpu" and causal_attention.kernel_takes(
+            *tiles[2:])
+        self.metrics.gauge_set("attn.kernel", float(on_kernel))
+        self.metrics.gauge_set("attn.rows_per_tile", causal_attention.rows_per_tile(*tiles) if on_kernel else 0)
         log.info(
             "SEQLM: %d layers (%d dense + %d expert; mixers %s), experts %d..%d of %d held, "
             "vocabulary slice %d, %d parameters; a step is %d sequences of %d tokens; corpus of "
@@ -935,14 +993,50 @@ class SeqLMTrainer(ToolkitBase):
         return head_loss(params, x, tokens, self.spec, cast), (sizes, choice)
 
     def _step(self, params, opt_state, bias, corpus, index):
+        """The loss, its gradients and Adam, part by part: the backward
+        takes the head and the stack's parts (``stack_parts``) from the top
+        down, and each part's parameters take their update from the
+        gradients its backward made. A run's routed experts are updated
+        before the next part's backward begins: an optimization barrier
+        holds that backward until their new values, moments included, are
+        made. Left to itself the compiler may defer a run's expert update
+        to the step's end and keep its float32 gradients (384 MiB in the
+        Qwen3-Next cell's attention layer) through the rest of the backward
+        (PERF.md section 6). The other parts' updates are left where the
+        compiler puts them: held the same way, they raised the peak of the
+        Kimi-Linear cell's step by 281 MB on a v5e."""
         tokens = corpus[index]
-        (loss, (sizes, choice)), grads = jax.value_and_grad(self._loss, has_aux=True)(
-            params, bias, tokens)
-        with jax.named_scope("seq/adam"):
-            params, opt_state = adam_update(params, grads, opt_state, self.adam_cfg)
+        cast, mid = self._casts()
+        spec = self.spec
+        parts = stack_parts(bias, tokens, spec, cast, mid) + [
+            (("norm", "head"), lambda p, x: (head_loss(p, x, tokens, spec, cast), None))]
+        x, pulls, found = None, [], []
+        for keys, part in parts:
+            x, pull, routed = jax.vjp(part, _take(params, keys), x, has_aux=True)
+            pulls.append(pull)
+            if routed is not None:
+                found.append(routed)
+        loss, (sizes, choice) = x, _routed(found)
+        runs = {name for name, *_ in spec.runs}
+        new_params, new_m, new_v = dict(params), dict(opt_state.m), dict(opt_state.v)
+        g = jnp.ones_like(loss)
+        for (keys, _), pull in zip(parts[::-1], pulls[::-1]):
+            grads, g = pull(g)
+            with jax.named_scope("seq/adam"):
+                own, state = adam_update(_take(params, keys), grads, AdamState(
+                    _take(opt_state.m, keys), _take(opt_state.v, keys), opt_state.step), self.adam_cfg)
+            if keys[0] in runs:
+                (name,) = keys
+                trees = (own, state.m, state.v)
+                g, *pinned = lax.optimization_barrier((g, *(_take(t[name], ROUTED) for t in trees)))
+                own, m, v = ({name: dict(t[name], **new)} for t, new in zip(trees, pinned))
+                state = AdamState(m, v, state.step)
+            new_params.update(own)
+            new_m.update(state.m)
+            new_v.update(state.v)
         # the step's own choice of experts [L, T, k] stays on the device: a
         # check that follows the step reads it, the run loop does not
-        return params, opt_state, loss, sizes, choice
+        return new_params, AdamState(new_m, new_v, state.step), loss, sizes, choice
 
     def _logits_at(self, params, bias, tokens, rows):
         """(logits [len(rows), vocab] float32 at the flat positions

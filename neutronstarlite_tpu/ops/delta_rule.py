@@ -483,7 +483,11 @@ def _along_lanes(t, chunk: int):
     return t.reshape(t.shape[0], t.shape[1] // chunk, 1, chunk) if t.ndim == 2 else t
 
 
-def _params(interpret: bool):
+def compiler_params(interpret: bool):
+    """A kernel's compiler parameters on a chip (none in interpret mode): a
+    grid whose first axis is parallel and whose second is walked in order,
+    ``VMEM_LIMIT`` bytes of VMEM. ops/causal_attention.py's kernels take the
+    same."""
     return {} if interpret else {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=VMEM_LIMIT)}
 
@@ -502,7 +506,7 @@ def _kernel_forward(q, k, v, decay, beta, *, step, cast, chunk: int, interpret: 
         out_specs=[sp["value"], sp["states"]],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((n, s // chunk, q.shape[-1], dv), jnp.float32)],
-        scratch_shapes=[scratch], interpret=interpret, name="kda_recurrence", **_params(interpret),
+        scratch_shapes=[scratch], interpret=interpret, name="kda_recurrence", **compiler_params(interpret),
     )(q, k, v, decay, beta))
 
 
@@ -520,7 +524,7 @@ def _kernel_backward(q, k, v, decay, beta, states, g, *, step, cast, chunk: int,
         grid=grid, in_specs=specs + [sp["states"], sp["value"]], out_specs=specs,
         out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in operands],
         scratch_shapes=[scratch], interpret=interpret, name="kda_recurrence_backward",
-        **_params(interpret),
+        **compiler_params(interpret),
     )(*operands, states, g)
     return (*d_rows, d_decay.reshape(shapes[0]), d_beta.reshape(shapes[1]))
 

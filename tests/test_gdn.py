@@ -471,6 +471,7 @@ def test_the_trainer_runs_counts_and_names_its_scopes(tmp_path):
     assert (gauges["kda.key_heads"], gauges["kda.value_heads"], gauges["kda.decay_per_head"],
             gauges["kda.chunk"]) == (2, 4, 1, 16)
     assert (gauges["kda.recur_fused"], gauges["kda.rows_per_block"]) == (0.0, 0)
+    assert (gauges["attn.kernel"], gauges["attn.rows_per_tile"]) == (0.0, 0)
     assert trainer.metrics.counter_get("kda.token_layers") == 2 * 64 * 3  # epochs x tokens x layers
     assert trainer.metrics.counter_get("gqa.token_layers") == 2 * 64
     assert trainer.metrics.counter_get("seq.tokens") == 2 * 64

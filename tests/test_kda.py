@@ -17,7 +17,7 @@ import pytest
 from neutronstarlite_tpu.models import seqlm
 from neutronstarlite_tpu.nn import seq as nnseq
 from neutronstarlite_tpu.nn.layers import compute_cast
-from neutronstarlite_tpu.ops import conv_operand, delta_rule
+from neutronstarlite_tpu.ops import causal_attention, conv_operand, delta_rule
 from neutronstarlite_tpu.utils.config import InputInfo
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -392,6 +392,33 @@ def test_the_recurrence_kernels_compile_for_the_chip_under_their_scope(one_chip,
     assert set(calls.values()) == {"seq/kda/recur"}
 
 
+@pytest.mark.parametrize("scope, n, group, dk, dv", [
+    ("seq/mla/attend", 4, 1, 192, 128), ("seq/gqa/attend", 2, 4, 64, 64), ("seq/gqa/attend", 1, 8, 256, 256)])
+def test_the_attention_kernels_compile_for_the_chip_under_their_scope(one_chip, scope, n, group, dk, dv):
+    """Mosaic takes the attention's forward and backward kernels at M's, L's
+    and Q's head widths and the default tile, chosen by the public call as the
+    program is lowered for the chip, and the step's scope table finds both
+    custom calls under the attention's scope (a compile for a described
+    v5e: nothing runs)."""
+    s = 2 * causal_attention.DEFAULT_BLOCK
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+
+    def step(q, k, v, g):
+        def attend(*a):
+            with jax.named_scope(scope):
+                return causal_attention.causal_edge_attention(*a, dk ** -0.5, 0, group)
+        out, pull = jax.vjp(attend, q, k, v)
+        return out, pull(g)
+
+    text = jax.jit(step).lower(shape(n * group, s, dk), shape(n, s, dk), shape(n, s, dv),
+                               shape(n * group, s, dv)).compile().as_text()
+    table = seqlm.scope_table_of(text)
+    calls = {name: scope for name, scope in table.items() if "causal_attention" in name}
+    assert any("causal_attention_backward" in name for name in calls), sorted(table)[:40]
+    assert any("causal_attention_backward" not in name for name in calls)
+    assert set(calls.values()) == {scope}
+
+
 # ---- the mixer against the reference's, one layer
 
 def test_the_delta_mixer_is_the_reference_mixer(tmp_path, rng):
@@ -497,6 +524,7 @@ def test_the_counters_and_gauges_of_the_new_layers(tmp_path):
     assert (gauges["seq.kda_layers"], gauges["seq.mla_layers"], gauges["kda.chunk"]) == (4, 1, 8)
     # off the TPU (and at heads of 8) the chunk walk is the scan: no layer lowers to the kernels
     assert (gauges["kda.recur_fused"], gauges["kda.rows_per_block"]) == (0.0, 0)
+    assert (gauges["attn.kernel"], gauges["attn.rows_per_tile"]) == (0.0, 0)  # the tile walk, off the TPU
     assert trainer.metrics.counter_get("kda.token_layers") == 2 * 64 * 4  # epochs x tokens x layers
     assert trainer.metrics.counter_get("seq.tokens") == 2 * 64
 

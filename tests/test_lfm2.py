@@ -310,6 +310,7 @@ def test_the_trainer_runs_counts_and_names_its_scopes(tmp_path):
     assert len(trainer.loss_history) == 2 and np.all(np.isfinite(trainer.loss_history))
     gauges = trainer.metrics.snapshot()["gauges"]
     assert (gauges["seq.sconv_layers"], gauges["seq.gqa_layers"], gauges["seq.kda_layers"]) == (5, 1, 0)
+    assert (gauges["attn.kernel"], gauges["attn.rows_per_tile"]) == (0.0, 0)
     assert trainer.metrics.counter_get("sconv.token_layers") == 2 * 64 * 5  # epochs x tokens x layers
     assert trainer.metrics.counter_get("gqa.token_layers") == 2 * 64
     assert trainer.metrics.counter_get("kda.token_layers") == 0

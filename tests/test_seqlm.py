@@ -17,7 +17,7 @@ from neutronstarlite_tpu.graph.dataset import TokenDatum
 from neutronstarlite_tpu.graph.storage import build_graph
 from neutronstarlite_tpu.models import get_algorithm, seqlm
 from neutronstarlite_tpu.nn.layers import compute_cast
-from neutronstarlite_tpu.ops import edge, moe
+from neutronstarlite_tpu.ops import causal_attention, edge, moe
 from neutronstarlite_tpu.ops.causal_attention import causal_edge_attention
 from neutronstarlite_tpu.ops.device_graph import DeviceGraph
 from neutronstarlite_tpu.utils.config import InputInfo
@@ -371,6 +371,41 @@ def test_a_destination_block_sees_the_source_blocks_up_to_itself_and_no_other(bl
         assert np.allclose(out[i], want, atol=1e-6)
 
 
+# ---- the kernels (Pallas' interpret mode here) against the walk
+
+@pytest.mark.parametrize("n, group, dk, dv, block, heads", [
+    (4, 1, 12, 8, 16, 2),  # M's and K's form: group 1, dk != dv, two key heads a grid step
+    (2, 4, 8, 8, 8, 4),  # L's: one whole group a step
+    (1, 8, 16, 16, 8, 4),  # Q's: the row cap engaged, the group in two parts
+])
+def test_the_kernels_are_the_walk(rng, monkeypatch, n, group, dk, dv, block, heads):
+    """Output, log-normaliser and the three gradients, four blocks of
+    positions: masked diagonal tiles and the skipped tiles above them."""
+    s = 4 * block
+    monkeypatch.setattr(causal_attention, "TILE_BYTES", heads * block * (block + dk + dv) * 4)
+    assert causal_attention.tile_heads(n, group, s, block, dk, dv) == heads
+    q, k, v, cot = (jnp.asarray(rng.standard_normal(shape).astype(np.float32)) for shape in (
+        (n * group, s, dk), (n, s, dk), (n, s, dv), (n * group, s, dv)))
+    got = {}
+    for form in ("walk", "interpret"):
+        out, (*_, lse) = causal_attention._attention_fwd(q, k, v, 0.3, block, group, form)
+        _, pull = jax.vjp(lambda *a: causal_attention.attention(*a, 0.3, block, group, form), q, k, v)
+        got[form] = (out, lse, *pull(cot))
+    for name, a, b in zip(("out", "lse", "dq", "dK", "dV"), got["interpret"], got["walk"]):
+        assert a.shape == b.shape and rel(a, b) < 1e-5, name
+
+
+def test_the_kernels_tile_the_cells_shapes():
+    """(key heads, group, positions, block, dk, dv) of M and K, L, Q: 2,048
+    query rows a grid step each (Q's group of 8 in two parts); a block off
+    the lanes, or a sequence whose kept key and value gradients pass VMEM,
+    go to the walk."""
+    for shape in ((64, 1, 8192, 512, 192, 128), (32, 4, 8192, 512, 64, 64), (4, 8, 8192, 512, 256, 256)):
+        assert causal_attention.kernel_takes(*shape[2:]) and causal_attention.rows_per_tile(*shape) == 2048
+    assert not causal_attention.kernel_takes(8192, 64, 64, 64)
+    assert not causal_attention.kernel_takes(65536, 512, 192, 128)
+
+
 # ---- the trainer through run.py's path
 
 def test_two_steps_through_the_cli_path_follow_the_reference(tmp_path):
@@ -532,12 +567,13 @@ def _pinned(trainer):
 def test_a_deepseek_v3_file_is_what_it_was(tmp_path):
     """The spec, the parameter tree and the first losses of this file's
     model at seed 3, as the tree before the second dialect gave them; its
-    seeded weights byte for byte as the tree before the third dialect (PR
-    34's) gave them; its step's lowered text as PR 36 left it (the routed
-    path became one walk of the routed rows: the text changed by design,
-    the weights and the first losses did not)."""
+    seeded weights byte for byte as the tree before the third dialect gave
+    them; its step's lowered text as the step taken part by part left it
+    (each part's backward, then its Adam update, a run's routed experts'
+    behind an optimization barrier: the text changed by design, the weights
+    and the first losses did not)."""
     trainer = make_trainer(tmp_path, EPOCHS=2)
-    assert _pinned(trainer) == ("21620a1d0565a9429f4bb64f51d3bee2aac9fc39c294fed35de750da06bb00c0",
+    assert _pinned(trainer) == ("4596c6c0c6c611fde32dd765bc3e437a3898690fd152643291765e0bc7bbcd2a",
                                 "afabbe6eff396277eee934423cf61cc5cc1387231b808a9ecc5c882cd8a1e332")
     spec = trainer.spec
     assert (spec.hidden, spec.heads, spec.kv_rank, spec.nope, spec.rope, spec.v_head, spec.ffn,
@@ -567,12 +603,9 @@ def test_a_deepseek_v3_file_is_what_it_was(tmp_path):
 def test_a_kimi_linear_file_is_what_it_was(tmp_path):
     """K's twin: the hybrid stack of tests/test_kda.py's model at seed 3:
     its seeded weights and its first losses as the tree before the third
-    dialect (PR 34's) gave them, byte for byte and to 2e-6; its step's
-    lowered text as PR 38 left it (ops/delta_rule.py's chunk walk got a
-    backward of its own, ``jax.custom_vjp`` around the scan and its
-    reverse, and ``_chunk`` values of at most four axes for the kernels'
-    compiler: the text changed by design, the weights and the losses did
-    not)."""
+    dialect gave them, byte for byte and to 2e-6; its step's lowered text
+    as the step taken part by part left it (the text changed by design,
+    the weights and the losses did not)."""
     import test_kda
 
     trainer = test_kda.make_trainer(tmp_path, EPOCHS=2)
@@ -581,7 +614,7 @@ def test_a_kimi_linear_file_is_what_it_was(tmp_path):
     assert (spec.kda_heads, spec.kda_value_heads, spec.decay_per_head, spec.gates_low_rank,
             spec.out_gate) == (4, 4, False, True, "sigmoid")
     assert set(trainer.params) == {"embed", "dense", "moe", "moe1", "moe2", "norm", "head"}
-    assert _pinned(trainer) == ("09343781cb8c13a51899262756cd64647b4ce7c1559b361de5c3108ead5f3b51",
+    assert _pinned(trainer) == ("b99cf1b096a7a94c6298cdf7fc00a7caf2e926e34922426f7f26c025e13ae789",
                                 "4290d11384bbbee69bde339c79da5b15038edd1de46fb7a3214c62b5a3f9b1d1")
     trainer.run()
     assert trainer.loss_history == pytest.approx([4.184228420257568, 4.158705711364746], abs=2e-6)
@@ -591,8 +624,10 @@ def test_a_kimi_linear_file_is_what_it_was(tmp_path):
 def test_a_qwen3_next_file_is_what_it_was(tmp_path):
     """Q's twin: the stack of tests/test_gdn.py's model at seed 3 (three
     delta-rule layers, one gated attention layer, no dense layer): its
-    step's lowered text, its seeded weights and its first losses as PR 38
-    left them, pinned before the dialects became records (PR 39)."""
+    seeded weights and its first losses as they were before the dialects
+    became records; its step's lowered text as the step taken part by part
+    left it (the text changed by design, the weights and the losses did
+    not)."""
     import test_gdn
 
     trainer = test_gdn.make_trainer(tmp_path, EPOCHS=2)
@@ -600,12 +635,44 @@ def test_a_qwen3_next_file_is_what_it_was(tmp_path):
     assert spec.mixers == ("kda", "kda", "kda", "gqa") and spec.dense_layers == 0
     assert spec.runs == (("moe", "kda", 0, 3), ("moe1", "gqa", 3, 1))
     assert set(trainer.params) == {"embed", "moe", "moe1", "norm", "head"}
-    assert _pinned(trainer) == ("d5b0b7594c7185be3d9c7b89be791dff7a8a8b7c0761f10dfc87360086d4dafc",
+    assert _pinned(trainer) == ("cfc41f0ac76e2cecd0221f730a9c94160818735fa8b9f415f62048589454bf71",
                                 "36b9908258e568d8aa4f1de3e6fbb01ad32cdd81dd2daec1d16e7aa3f79557d3")
     trainer.run()
     assert trainer.loss_history == pytest.approx([4.16268253326416, 4.1641926765441895], abs=2e-6)
     assert trainer.metrics.counter_get("gqa.token_layers") == 128
     assert trainer.metrics.counter_get("kda.token_layers") == 384
+
+
+@pytest.mark.parametrize("family", ["test_gdn", "test_lfm2"])
+def test_the_step_part_by_part_is_the_step_whole(tmp_path, family):
+    """The step that takes the backward part by part, each run's routed
+    experts updated behind an optimization barrier, gives the loss, the
+    weights and Adam's moments of one ``value_and_grad`` over the whole loss
+    followed by one Adam update, for stacks with two leading dense layers or
+    none, and two runs; one barrier a run. (This file's own stack, one dense
+    layer and one run, has its step's gradients against the reference.)"""
+    import importlib
+
+    from neutronstarlite_tpu.nn.param import adam_update
+
+    trainer = importlib.import_module(family).make_trainer(tmp_path)
+    spec = trainer.spec
+
+    def whole(params, opt, bias, corpus, index):
+        (loss, _), grads = jax.value_and_grad(trainer._loss, has_aux=True)(params, bias, corpus[index])
+        return (*adam_update(params, grads, opt, trainer.adam_cfg), loss)
+
+    lowered = [jax.jit(step).lower(*trainer.step_args()) for step in (trainer._step, whole)]
+    got, want = (step.compile()(*trainer.initial_state(), *trainer.step_args()[2:]) for step in lowered)
+    assert float(got[2]) == pytest.approx(float(want[2]), rel=1e-6)
+    assert int(got[1].step) == int(want[1].step) == 1
+    for name, a, b in ((n, a, b) for n, tree_a, tree_b in zip(("params", "m", "v"), (
+            got[0], got[1].m, got[1].v), (want[0], want[1].m, want[1].v))
+            for a, b in zip(jax.tree.leaves(tree_a), jax.tree.leaves(tree_b))):
+        assert a.shape == b.shape and rel(a, b) < 1e-6, name
+    # the whole step's barriers are jax.checkpoint's own
+    barriers = [step.as_text().count("= stablehlo.optimization_barrier") for step in lowered]
+    assert barriers[0] - barriers[1] == len(spec.runs)
 
 
 @pytest.mark.parametrize("key, value", [
