@@ -1,4 +1,5 @@
-"""Test rig: force CPU platform with 8 virtual devices.
+"""Test rig: force CPU platform with 8 virtual devices, and a persistent
+compile cache for the run.
 
 This is the analog of the reference's single-machine multi-slot mpiexec rig
 (run_nts.sh, README "use one slot, except for debugging") — multi-"chip"
@@ -8,12 +9,27 @@ Must run before the first jax import in the test process.
 """
 
 import os
+import shutil
+import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-# The suite compiles on the CPU only; entry points would otherwise fill the
-# checkout's .jax_cache with CPU executables (the chip tool copies the tree).
-# Set through the environment so test subprocesses inherit it.
-os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+# JAX's persistent compile cache, one directory per test worker, outside the
+# checkout (its .jax_cache holds the TPU runs' entries, and copies of the
+# tree carry it).
+# Set through the environment so that every process a test starts reads and
+# writes the worker's directory, with every compile cached as the entry
+# points cache it (utils/platform.py). A worker runs one test at a time and
+# waits for the processes it starts, so a directory has one writer at a time:
+# JAX writes an entry without a lock. ``--dist loadfile`` keeps a file's tests
+# on one worker, so a file's cell rehearsals share what they compile.
+if os.environ.get("PYTEST_XDIST_WORKER"):
+    _CACHE_ROOT = os.path.join(
+        tempfile.gettempdir(), "nts-tier1-jax-cache-" + os.environ["PYTEST_XDIST_TESTRUNUID"])
+    COMPILE_CACHE_DIR = os.path.join(_CACHE_ROOT, os.environ["PYTEST_XDIST_WORKER"])
+else:
+    _CACHE_ROOT = COMPILE_CACHE_DIR = tempfile.mkdtemp(prefix="nts-tier1-jax-cache-")
+os.environ["JAX_COMPILATION_CACHE_DIR"] = COMPILE_CACHE_DIR
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
@@ -23,12 +39,23 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 # pytest plugins may import jax before this file runs, and jax reads
-# JAX_PLATFORMS only at import; pin the config as well.
+# JAX_PLATFORMS and the cache's settings only at import; pin the config as well.
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_enable_compilation_cache", False)
+jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+
+def pytest_unconfigure(config):
+    """The worker's cache goes with the run; the last worker out removes
+    the run's directory."""
+    shutil.rmtree(COMPILE_CACHE_DIR, ignore_errors=True)
+    try:
+        os.rmdir(_CACHE_ROOT)
+    except OSError:  # another worker's directory is still there, or gone already
+        pass
 
 
 @pytest.fixture

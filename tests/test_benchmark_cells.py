@@ -3,7 +3,8 @@
 a process of its own. The token-sequence cell (Moonlight-16B-A3B, one
 chip's share) with and without a fault planted under the harness, and the
 fixture cell of benchmark/tests/fixtures/token_cell, which shows that a cell
-of a kind the harness has never seen enters as files and entries."""
+of a kind the harness has never seen enters as files and entries; and where
+the compile cache the rehearsals share lives."""
 
 import json
 import os
@@ -20,9 +21,10 @@ LIMIT_S = 420
 
 
 def last_json_line(argv, cwd=REPO):
-    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
-    done = subprocess.run([sys.executable, *argv], cwd=cwd, env=env, capture_output=True,
-                          text=True, timeout=LIMIT_S)
+    """Run a child in the suite's environment (its compile cache included,
+    which a file's rehearsals share: conftest) and return its last JSON line."""
+    done = subprocess.run([sys.executable, *argv], cwd=cwd, capture_output=True, text=True,
+                          timeout=LIMIT_S)
     rows = [json.loads(line) for line in done.stdout.strip().splitlines() if line.startswith("{")]
     assert rows, done.stderr[-3000:]
     return dict(rows[-1], rc=done.returncode, stderr=done.stderr)
@@ -110,3 +112,20 @@ def test_the_fixture_cell_rehearses_through_added_files_only(token_cell_overlay)
     assert out["rc"] == 0 and out["correct"] is True, out["stderr"][-3000:]
     assert out["would_report"] == ["epoch_s", "peak_device_bytes", "setup_s"]
     assert set(out["compared"]) == {"logits_rel", "grads_rel", "faults", "losses_not_finite"}
+
+
+CHECKOUT_CACHE = os.path.join(REPO, ".jax_cache")
+HAD_CHECKOUT_CACHE = os.path.exists(CHECKOUT_CACHE)  # before this file's rehearsals
+
+
+def test_the_suites_compile_cache_is_outside_the_checkout_and_reaches_the_children():
+    """The file's last test: the suite's compile cache (conftest) is not in
+    the checkout, a child that ``last_json_line`` starts caches there, and
+    the rehearsals above left the checkout's .jax_cache as they found it."""
+    cache = os.environ["JAX_COMPILATION_CACHE_DIR"]
+    repo = os.path.realpath(REPO)
+    assert os.path.commonpath([os.path.realpath(cache), repo]) != repo
+    out = last_json_line(["-c", "import json; from neutronstarlite_tpu.utils.platform import "
+                                "configure_compile_cache; print(json.dumps({'dir': configure_compile_cache()}))"])
+    assert out["rc"] == 0 and out["dir"] == cache, out["stderr"][-3000:]
+    assert os.path.exists(CHECKOUT_CACHE) == HAD_CHECKOUT_CACHE

@@ -421,8 +421,14 @@ def test_the_attention_kernels_compile_for_the_chip_under_their_scope(one_chip, 
 
 # ---- the mixer against the reference's, one layer
 
-def test_the_delta_mixer_is_the_reference_mixer(tmp_path, rng):
-    trainer = make_trainer(tmp_path)
+@pytest.fixture(scope="module")
+def trainer(tmp_path_factory):
+    """The file's model as ``make_trainer`` builds it, for the tests that
+    only read it (a test that runs a trainer builds its own)."""
+    return make_trainer(tmp_path_factory.mktemp("kda"))
+
+
+def test_the_delta_mixer_is_the_reference_mixer(trainer, rng):
     lp = jax.tree.map(np.asarray, trainer.params["dense"])
     x = rng.standard_normal((64, SHAPE.hidden)).astype(np.float32)
     got = seqlm.delta_attention(lp, jnp.asarray(x), trainer.spec, compute_cast(None), jnp.float32)
@@ -436,8 +442,7 @@ def test_the_delta_mixer_is_the_reference_mixer(tmp_path, rng):
     assert np.array_equal(np.asarray(moved)[32:], np.asarray(got)[32:])
 
 
-def test_the_latent_attention_turns_nothing_without_positions(tmp_path, rng):
-    trainer = make_trainer(tmp_path)
+def test_the_latent_attention_turns_nothing_without_positions(trainer, rng):
     assert trainer.spec.rotary is False
     lp = jax.tree.map(lambda a: np.asarray(a[0]), trainer.params["moe1"])
     x = rng.standard_normal((64, SHAPE.hidden)).astype(np.float32)
@@ -529,8 +534,8 @@ def test_the_counters_and_gauges_of_the_new_layers(tmp_path):
     assert trainer.metrics.counter_get("seq.tokens") == 2 * 64
 
 
-def test_the_scope_table_of_a_hybrid_step_covers_every_named_scope(tmp_path):
-    table = make_trainer(tmp_path).scope_table()
+def test_the_scope_table_of_a_hybrid_step_covers_every_named_scope(trainer):
+    table = trainer.scope_table()
     assert set(table.values()) == {s for s in seqlm.SCOPES
                                    if not s.startswith(("seq/gqa/", "seq/sconv/"))}
     assert seqlm.scope_of("jit(step)/transpose(jvp(seq/kda/recur))/while/body/dot") == "seq/kda/recur"

@@ -75,6 +75,9 @@ def _launch(port, pid, epochs, ckpt_dir=""):
         NTS_TEST_REPO=_REPO,
         NTS_TEST_EPOCHS=str(epochs),
         NTS_TEST_CKPT=ckpt_dir,
+        # the two ranks run side by side, and JAX writes a cache entry
+        # without a lock: the suite's compile cache stays off for them
+        JAX_ENABLE_COMPILATION_CACHE="false",
     )
     env.pop("NTS_DIST_SIMULATE", None)
     return subprocess.Popen(
@@ -160,7 +163,7 @@ def test_run_nts_dist_launcher(tmp_path):
         f"LABEL_FILE:{tmp_path}/absent.label\nMASK_FILE:{tmp_path}/absent.mask\n"
         "LEARN_RATE:0.02\nDECAY_EPOCH:-1\nDROP_RATE:0.0\n"
     )
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_ENABLE_COMPILATION_CACHE="false")  # ranks side by side, as above
     env.pop("NTS_DIST_SIMULATE", None)
     env["NTS_PORT"] = str(_free_port())  # a random-port collision is a flake
     # new session + killpg: a deadlocked collective must fail the test at

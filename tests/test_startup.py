@@ -18,7 +18,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _recorded_updates(monkeypatch):
     """Replace jax.config.update with a recorder: the test process's own
-    configuration (conftest: cache off) stays as it is."""
+    configuration (conftest: the suite's cache) stays as it is."""
     updates = {}
     monkeypatch.setattr(
         jax.config, "update", lambda name, value: updates.__setitem__(name, value)

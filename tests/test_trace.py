@@ -20,6 +20,7 @@ Pinned contracts:
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import json
 import os
@@ -910,27 +911,41 @@ def traced():
     return reg, Tracer(reg)
 
 
-@pytest.fixture
-def persistent_cache(tmp_path):
-    """JAX's persistent compile cache on, in a directory of this test."""
+@contextlib.contextmanager
+def _compile_cache_set(**settings):
+    """JAX's persistent compile cache with ``settings`` for one test, then
+    as it was (the suite's: conftest)."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    before = {
-        name: getattr(jax.config, name) for name in (
-            "jax_enable_compilation_cache", "jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes")
-    }
-    jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    cc.reset_cache()
-    yield str(tmp_path / "cache")
-    for name, value in before.items():
+    before = {name: getattr(jax.config, name) for name in settings}
+    for name, value in settings.items():
         jax.config.update(name, value)
     cc.reset_cache()
+    try:
+        yield
+    finally:
+        for name, value in before.items():
+            jax.config.update(name, value)
+        cc.reset_cache()
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """JAX's persistent compile cache on, in a directory of this test."""
+    cache = str(tmp_path / "cache")
+    with _compile_cache_set(
+            jax_enable_compilation_cache=True, jax_compilation_cache_dir=cache,
+            jax_persistent_cache_min_compile_time_secs=0.0,
+            jax_persistent_cache_min_entry_size_bytes=0):
+        yield cache
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """JAX's persistent compile cache off."""
+    with _compile_cache_set(jax_enable_compilation_cache=False):
+        yield
 
 
 def _fresh_step(tag):
@@ -991,7 +1006,7 @@ def test_a_first_call_under_an_open_span_is_one_compile_span(traced, persistent_
     assert got["compile.retrieve_s"] >= hit["retrieve_s"] - 1e-9
 
 
-def test_the_trace_seconds_are_the_lowered_functions_not_a_sum(traced):
+def test_the_trace_seconds_are_the_lowered_functions_not_a_sum(traced, no_persistent_cache):
     """``inner`` reports a trace duration of its own before ``_step``'s:
     the span is ``_step``'s alone, and with the cache off says ``off``."""
     from jax import monitoring
